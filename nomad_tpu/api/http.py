@@ -949,11 +949,18 @@ class HTTPAgent:
             return h._reply(200, {"members": [
                 {"name": "local", "status": "alive", "meta": {}}]})
         if path == "/v1/agent/self":
+            from ..tensor.backend import device
+            from ..tensor.solver import get_service
+
             return h._reply(200, {
                 "stats": {
                     "broker": self.server.broker.stats,
                     "plan_applier": self.server.plan_applier.stats,
                     "blocked_evals": self.server.blocked.blocked_count(),
+                    # which device the placement solves run on, and what
+                    # the solver service did there
+                    "device": device().as_dict(),
+                    "solver": dict(get_service().stats),
                 },
                 "version": "0.1.0",
             })

@@ -2034,6 +2034,9 @@ def main(argv=None) -> int:
     import os
     if args.seed is not None:
         os.environ["NOMAD_TPU_CHAOS_SEED"] = str(args.seed)
+    from ..tensor.backend import bootstrap
+
+    bootstrap()
     if args.raft_smoke:
         return raft_smoke()
     if args.e2e_smoke:

@@ -45,11 +45,102 @@ AGENT_FLAG_KEYS = ("data_dir", "port", "workers", "algorithm",
                    "authoritative_region", "plugin_dir")
 
 
+def _parse_peers(spec: str) -> dict:
+    return dict(p.split("=", 1) for p in spec.split(","))
+
+
+class Agent:
+    """What `agent` serves, started: the scheduling server (replicated
+    when `--peers` is given), its HTTP agent and local clients, on the
+    backend the bootstrap resolved. `cmd_agent` runs it until a signal;
+    `chip_smoke.py` drives the same object."""
+
+    def __init__(self, args):
+        from .api.http import HTTPAgent
+        from .client import Client, ClientConfig
+        from .core import Server, ServerConfig
+        from .structs.operator import SchedulerConfiguration
+        from .tensor.backend import bootstrap
+
+        # before the first compile; a tpu-* algorithm whose backend fell
+        # to the CPU by itself raises here and the agent never starts
+        self.device = bootstrap(args.algorithm)
+        cfg = ServerConfig(
+            num_workers=args.workers,
+            gossip_key=getattr(args, "gossip_key", "") or "",
+            region=getattr(args, "region", "global"),
+            authoritative_region=getattr(args, "authoritative_region", ""),
+            sched_config=SchedulerConfiguration(
+                scheduler_algorithm=args.algorithm))
+
+        self.replicated = self.transport = None
+        if args.peers:
+            # multi-server mode: raft over the socket transport (reference
+            # `nomad agent -server -bootstrap-expect N`)
+            from .raft.cluster import ReplicatedServer
+            from .raft.transport import SocketTransport
+
+            peers = _parse_peers(args.peers)
+            self.transport = SocketTransport(
+                args.server_id, peers[args.server_id], peers).start()
+            joining = bool(getattr(args, "join", ""))
+            cleanup = getattr(args, "dead_server_cleanup", 0.0) or None
+            gossip_bind = getattr(args, "gossip", "") or None
+            gossip_seeds = [a for a in
+                            (getattr(args, "retry_join", "") or "").split(",")
+                            if a]
+            self.replicated = ReplicatedServer(
+                args.server_id, list(peers), self.transport, cfg,
+                data_dir=args.data_dir or None,
+                bootstrap=not joining and not gossip_seeds,
+                dead_server_cleanup_s=cleanup,
+                gossip_bind=gossip_bind, gossip_seeds=gossip_seeds)
+            self.replicated.start()
+            if joining:
+                self.replicated.join(args.join)
+            self.server = self.replicated.server
+            endpoint = self.replicated
+        else:
+            self.server = Server(cfg)
+            self.server.start()
+            endpoint = self.server
+
+        # HTTP first: the status/leader endpoint must be observable while
+        # the clients wait out the initial leader election to register
+        self.http = HTTPAgent(self.server, port=args.port,
+                              writer=self.replicated).start()
+        self.clients = []
+        for i in range(args.clients):
+            c = Client(endpoint, ClientConfig(
+                data_dir=os.path.join(args.data_dir, f"client{i}")
+                if args.data_dir else "",
+                plugin_dir=getattr(args, "plugin_dir", "")))
+            c.start()
+            self.clients.append(c)
+        self.http.clients = self.clients  # /v1/client/* for local clients
+        if self.replicated is not None:
+            # WAN gossip members read this to maintain the region registry
+            self.replicated.set_gossip_http(self.http.address)
+        self.start_line = (
+            f"agent started: {self.http.address} "
+            f"(workers={args.workers} clients={args.clients} "
+            f"algorithm={args.algorithm} device={self.device}"
+            + (f" server-id={args.server_id}" if self.replicated else "")
+            + ")")
+
+    def stop(self) -> None:
+        self.http.stop()
+        for c in self.clients:
+            c.stop()
+        if self.replicated is not None:
+            self.replicated.stop()
+            self.transport.stop()
+        else:
+            self.server.stop()
+
+
 def cmd_agent(args) -> int:
-    from .api.http import HTTPAgent
-    from .client import Client, ClientConfig
-    from .core import Server, ServerConfig
-    from .structs.operator import SchedulerConfiguration
+    from .tensor.backend import BackendError
 
     if args.config:
         from .agent_config import apply_to_args, load_agent_config
@@ -63,69 +154,16 @@ def cmd_agent(args) -> int:
         defaults = {k: getattr(defaults_ns, k) for k in AGENT_FLAG_KEYS}
         apply_to_args(file_cfg, args, defaults)
 
-    cfg = ServerConfig(
-        num_workers=args.workers,
-        gossip_key=getattr(args, "gossip_key", "") or "",
-        region=getattr(args, "region", "global"),
-        authoritative_region=getattr(args, "authoritative_region", ""),
-        sched_config=SchedulerConfiguration(scheduler_algorithm=args.algorithm))
-
-    replicated = transport = None
-    if args.peers:
-        # multi-server mode: raft over the socket transport (reference
-        # `nomad agent -server -bootstrap-expect N`)
-        from .raft.cluster import ReplicatedServer
-        from .raft.transport import SocketTransport
-
-        peers = dict(p.split("=", 1) for p in args.peers.split(","))
-        if args.server_id not in peers:
-            print(f"--server-id {args.server_id!r} not in --peers", file=sys.stderr)
-            return 1
-        transport = SocketTransport(args.server_id, peers[args.server_id],
-                                    peers).start()
-        joining = bool(getattr(args, "join", ""))
-        cleanup = getattr(args, "dead_server_cleanup", 0.0) or None
-        gossip_bind = getattr(args, "gossip", "") or None
-        gossip_seeds = [a for a in
-                        (getattr(args, "retry_join", "") or "").split(",")
-                        if a]
-        replicated = ReplicatedServer(
-            args.server_id, list(peers), transport, cfg,
-            data_dir=args.data_dir or None,
-            bootstrap=not joining and not gossip_seeds,
-            dead_server_cleanup_s=cleanup,
-            gossip_bind=gossip_bind, gossip_seeds=gossip_seeds)
-        replicated.start()
-        if joining:
-            replicated.join(args.join)
-        server = replicated.server
-        endpoint = replicated
-    else:
-        server = Server(cfg)
-        server.start()
-        endpoint = server
-
-    # HTTP first: the status/leader endpoint must be observable while the
-    # clients wait out the initial leader election to register
-    http_agent = HTTPAgent(server, port=args.port,
-                           writer=replicated).start()
-    clients = []
-    for i in range(args.clients):
-        c = Client(endpoint, ClientConfig(
-            data_dir=os.path.join(args.data_dir, f"client{i}")
-            if args.data_dir else "",
-            plugin_dir=getattr(args, "plugin_dir", "")))
-        c.start()
-        clients.append(c)
-    http_agent.clients = clients  # serve /v1/client/* for local clients
-    if replicated is not None:
-        # WAN gossip members read this to maintain the region registry
-        replicated.set_gossip_http(http_agent.address)
-    print(f"agent started: {http_agent.address} "
-          f"(workers={args.workers} clients={args.clients} "
-          f"algorithm={args.algorithm}"
-          + (f" server-id={args.server_id}" if replicated else "") + ")",
-          flush=True)
+    if args.peers and args.server_id not in _parse_peers(args.peers):
+        print(f"--server-id {args.server_id!r} not in --peers", file=sys.stderr)
+        return 1
+    try:
+        agent = Agent(args)
+    except BackendError as e:
+        print(f"agent failed to start: {e}", file=sys.stderr)
+        return 1
+    server, replicated = agent.server, agent.replicated
+    print(agent.start_line, flush=True)
     stop = []
     reload_req = []
     signal.signal(signal.SIGTERM, lambda *a: stop.append(1))
@@ -158,14 +196,7 @@ def cmd_agent(args) -> int:
                     print(f"config reload failed: {e}", flush=True)
             time.sleep(0.2)
     finally:
-        http_agent.stop()
-        for c in clients:
-            c.stop()
-        if replicated is not None:
-            replicated.stop()
-            transport.stop()
-        else:
-            server.stop()
+        agent.stop()
     return 0
 
 

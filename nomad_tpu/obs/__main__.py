@@ -218,6 +218,11 @@ def main(argv=None) -> int:
     logging.basicConfig(
         level=logging.INFO if args.verbose else logging.WARNING,
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
+    if args.trace_smoke or (args.export and not args.addr):
+        # both run an in-process cluster that can schedule
+        from ..tensor.backend import bootstrap
+
+        bootstrap()
     if args.trace_smoke:
         return trace_smoke()
     if args.export:

@@ -32,15 +32,16 @@ class RetraceError(AssertionError):
 
 
 def cache_size(fn) -> int:
-    """Number of compiled entries behind a jitted callable, or -1 when
-    the wrapper exposes no probe (non-jitted callable, API drift)."""
+    """Number of compiled entries behind a jitted callable. A callable
+    without the probe raises: a window that cannot count compiles must
+    not pass for one that saw none."""
     probe = getattr(fn, "_cache_size", None)
     if probe is None:
-        return -1
-    try:
-        return int(probe())
-    except Exception:
-        return -1
+        raise TypeError(
+            f"{getattr(fn, '__name__', fn)!r} has no _cache_size probe: "
+            f"not a jax.jit wrapper of the installed JAX, so a "
+            f"no_retrace window over it could never trip")
+    return int(probe())
 
 
 @contextlib.contextmanager
@@ -69,8 +70,6 @@ def no_retrace(*fns, expect: int = 0) -> Iterator[Dict]:
     grew = []
     for fn, b in before:
         a = cache_size(fn)
-        if b < 0 or a < 0:
-            continue
         out["compiles"] += max(0, a - b)
         if a - b > expect:
             grew.append(f"{getattr(fn, '__name__', fn)}: {b} -> {a}")
@@ -89,6 +88,4 @@ def count_compiles(*fns) -> Iterator[Dict]:
     out: Dict = {"compiles": 0}
     yield out
     for fn, b in before:
-        a = cache_size(fn)
-        if b >= 0 and a >= 0:
-            out["compiles"] += max(0, a - b)
+        out["compiles"] += max(0, cache_size(fn) - b)

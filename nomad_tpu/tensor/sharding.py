@@ -17,16 +17,25 @@ is no hand-written NCCL/MPI analog to port (the reference's comm backend
 is msgpack-RPC/Serf/Raft, SURVEY.md §2.5 — control-plane replication
 stays host-side, this module only distributes the math).
 
-Used by __graft_entry__.dryrun_multichip and the multi-chip benchmarks.
+Engaged by the solver service by itself whenever the process holds more
+than one device (tensor/solver.BulkSolverService._resolve_mesh);
+chip_smoke.py drives it on a four-chip host.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Sequence
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+# replication checking off: outputs declared P() (info row, gather
+# counts) are replicated by construction — every shard runs the same
+# math on the all-gathered pools — not by anything the checker can prove
+_shard_map = partial(shard_map, check_vma=False)
 
 
 def node_mesh(devices: Sequence = None, axis: str = "nodes") -> Mesh:
@@ -159,8 +168,6 @@ def make_state_scatter_sharded(mesh: Mesh, axis: str = "nodes",
         return fn
     import jax.numpy as jnp
 
-    smap = _shard_map_nocheck()
-
     def state_scatter_sharded(used, idx, delta):
         n_loc = used.shape[0]
         me = jax.lax.axis_index(axis)
@@ -170,29 +177,13 @@ def make_state_scatter_sharded(mesh: Mesh, axis: str = "nodes",
         safe = jnp.clip(local, 0, n_loc - 1)
         return used.at[safe].add(jnp.where(own[:, None], delta, 0.0))
 
-    body = smap(state_scatter_sharded, mesh=mesh,
+    body = _shard_map(state_scatter_sharded, mesh=mesh,
                 in_specs=(P(axis, None), P(), P()),
                 out_specs=P(axis, None))
     fn = (jax.jit(body, donate_argnums=(0,)) if donate
           else jax.jit(body))
     _STATE_SCATTER_CACHE[key] = fn
     return fn
-
-
-def _shard_map_nocheck():
-    """shard_map with replication checking disabled under whichever
-    keyword this jax spells it (check_rep was renamed check_vma)."""
-    import inspect
-    from functools import partial
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _shard_map
-    _params = inspect.signature(_shard_map).parameters
-    _nocheck = ({"check_vma": False} if "check_vma" in _params
-                else {"check_rep": False} if "check_rep" in _params
-                else {})
-    return partial(_shard_map, **_nocheck)
 
 
 def _bulk_shard_body(used0, avail, feas, aff, ask, k, seeds, cidx, cdelta,
@@ -345,15 +336,12 @@ def make_solve_bulk_multi_sharded(mesh: Mesh, axis: str = "nodes",
     counts sharded on the node axis, (G,) int32 replicated all-gather
     rounds per eval — the launch's collective cadence).
     """
-    from functools import partial
-
-    shard_map = _shard_map_nocheck()
     n_dev = int(np.prod(mesh.devices.shape))
 
     @partial(jax.jit, static_argnames=("g",), donate_argnums=(0,))
     def solve(used0, avail, feas, aff, ask, k, seeds, cidx, cdelta, *,
               g: int):
-        fn = shard_map(
+        fn = _shard_map(
             partial(_bulk_shard_body, g=g, axis=axis, n_dev=n_dev,
                     top_r=top_r),
             mesh=mesh,
@@ -399,13 +387,11 @@ def make_solve_batch_sharded(mesh: Mesh, axis: str = "nodes",
     portfolio arm and the greedy chain).
     """
     import jax.numpy as jnp
-    from functools import partial
 
     from .batch_solver import (MAX_ROUNDS, PORTFOLIO, PRICE_EPS, TOP_R,
                                _pairwise_sum_xp)
     from .kernels import NEG, TIE_JITTER
 
-    shard_map = _shard_map_nocheck()
     n_dev = int(np.prod(mesh.devices.shape))
 
     def _joint_body(used0, avail, feas, aff, ask, k, seeds, cidx, cdelta,
@@ -612,14 +598,14 @@ def make_solve_batch_sharded(mesh: Mesh, axis: str = "nodes",
                       P(None, axis), P(), P(), P(), P(), P())
         out = (P(axis, None), P(None, axis), P(), P())
         if evict is None:
-            fn = shard_map(
+            fn = _shard_map(
                 partial(_joint_body, g=g), mesh=mesh,
                 in_specs=base_specs, out_specs=out)
             return fn(used0, avail, feas, aff, ask, k, seeds, cidx,
                       cdelta)
         # victim budgets ride the node axis like avail; net_prio is a
         # plain (N,) node row
-        fn = shard_map(
+        fn = _shard_map(
             partial(_joint_body, g=g), mesh=mesh,
             in_specs=base_specs + (P(axis, None), P(axis)),
             out_specs=out)

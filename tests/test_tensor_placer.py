@@ -475,3 +475,67 @@ class TestBulkSolverService:
                 assert fit, (node.id, dim)
         finally:
             TPUPlacer.BULK_MIN = old
+
+
+def test_bulk_solve_after_outside_placements_does_not_block():
+    """Usage the solver service's carry never saw (placements made
+    outside it: the per-placement tier, the host path) must cost one
+    rejected plan, not the eval: the rejection resyncs the carry, so the
+    retry lands. Before, the retry refilled the same full node until the
+    batch eval's two attempts were gone and it blocked for a minute
+    (found by chip_smoke.py: a service-job wave, then a bulk job)."""
+    from nomad_tpu.core.server import Server, ServerConfig
+    from nomad_tpu.tensor.solver import get_service
+
+    srv = Server(ServerConfig(num_workers=1, heartbeat_ttl=3600.0,
+                              gc_interval=3600.0, sched_config=_tpu_config()))
+    srv.start()
+    try:
+        nodes = [mock.node() for _ in range(4)]     # 4000 MHz / 8192 MB each
+        for n in nodes:
+            srv.register_node(n)
+
+        def bulk(cpu, mem, count=256):
+            j = mock.batch_job()
+            j.task_groups[0].count = count
+            j.task_groups[0].tasks[0].resources.cpu = cpu
+            j.task_groups[0].tasks[0].resources.memory_mb = mem
+            j.task_groups[0].ephemeral_disk.size_mb = 1    # cpu-bound
+            return j
+
+        first = bulk(10, 8)             # BestFit: all 256 on one node
+        srv.register_job(first)
+        assert srv.wait_for_idle(30.0)
+        snap = srv.store.snapshot()
+        homes = {a.node_id for a in snap.allocs_by_job(first.id)}
+        assert len(homes) == 1
+        home = next(n for n in snap.nodes() if n.id in homes)
+        # fill that node behind the service's back: 2560 + 1400 of 4000
+        outside = mock.job()
+        outside.task_groups[0].tasks[0].resources.cpu = 1400
+        outside.task_groups[0].tasks[0].resources.memory_mb = 64
+        srv.store.upsert_job(outside)
+        srv.store.upsert_allocs([mock.alloc(outside, home)])
+
+        svc0 = dict(get_service().stats)
+        # the stale carry sees 1440 MHz free at home and sends a whole
+        # bulk-sized row there; the retry is bulk-sized too, so it goes
+        # through the service again
+        second = bulk(2, 1, count=700)
+        srv.register_job(second)
+        assert srv.wait_for_idle(30.0)
+        svc = {k: get_service().stats[k] - svc0[k] for k in svc0}
+        live = [a for a in srv.store.snapshot().allocs_by_job(second.id)
+                if not a.terminal_status()]
+        assert len(live) == 700
+        assert srv.blocked.blocked_count() == 0
+        assert svc["rejections"] >= 1 and svc["resyncs"] >= 1, svc
+        from nomad_tpu.structs import allocs_fit
+
+        for n in srv.store.snapshot().nodes():
+            fit, dim, _ = allocs_fit(n, [
+                a for a in srv.store.snapshot().allocs_by_node(n.id)
+                if not a.terminal_status()])
+            assert fit, (n.id, dim)
+    finally:
+        srv.stop()
