@@ -1080,6 +1080,9 @@ class HTTPAgent:
             body = {
                 "enabled": TRACER.enabled,
                 "total_spans": len(spans),
+                # records overwritten in full rings: spans the trace
+                # below can no longer hold
+                "dropped": TRACER.dropped,
                 "phases": phase_breakdown(spans),
                 # newest spans last, Chrome trace_event format — paste
                 # the traceEvents list into chrome://tracing / Perfetto

@@ -361,6 +361,8 @@ class EvalBroker:
             if info is None or info["token"] != token:
                 return
             del self._unacked[eval_id]
+            TRACER.event("eval.redelivered", trace=info["eval"].trace(),
+                         deliveries=info["deliveries"])
             RECORDER.record("broker", "nack_timeout", eval=eval_id[:8],
                             deliveries=info["deliveries"])
             self._redeliver_locked(info)
@@ -418,6 +420,13 @@ class EvalBroker:
                 now = time.time()
                 while self._delay and self._delay[0][0] <= now:
                     _, _, ev = heapq.heappop(self._delay)
+                    # pushed on the delay heap -> released: the 60 s
+                    # follow-up wait of a failed evaluation, in its chain
+                    pushed = self._enqueue_times.get(ev.id)
+                    if pushed is not None:
+                        TRACER.add_span("eval.delayed", pushed, now,
+                                        trace=ev.trace(),
+                                        reason=ev.triggered_by)
                     ev = _copy.copy(ev)  # store snapshots share the original
                     ev.wait_until = 0.0
                     self._enqueue_locked(ev)

@@ -322,7 +322,8 @@ class PlanApplier:
         self._commit_q: "deque[_CommitEntry]" = deque()
         self._commit_cond = threading.Condition()
         self._stop = threading.Event()
-        self.stats = {"applied": 0, "nodes_rejected": 0, "partial_commits": 0,
+        self.stats = {"applied": 0, "nodes_verified": 0, "nodes_rejected": 0,
+                      "partial_commits": 0,
                       "commit_batches": 0, "batched_commits": 0,
                       "batched_eval_updates": 0}
         # commits are serialized through the 1-worker commit pool, but
@@ -461,7 +462,7 @@ class PlanApplier:
         from .metrics import REGISTRY
 
         with REGISTRY.time("nomad.plan.evaluate"), \
-                TRACER.span("plan.verify",
+                TRACER.span("plan.verify", cpu=True,
                             trace=getattr(plan, "eval_id", None) or None):
             return self._verify_inner(plan, overlay)
 
@@ -661,7 +662,9 @@ class PlanApplier:
         # 2: one transaction for the whole batch
         writers = self._writers_for(entries)
         if writers:
-            with TRACER.span("plan.commit_round", n=len(writers),
+            # cpu_s: wall less the thread's own CPU seconds is time
+            # this thread was blocked or waiting for the interpreter lock
+            with TRACER.span("plan.commit_round", cpu=True, n=len(writers),
                              traces=[e.trace for e in entries if e.trace]):
                 try:
                     index = self.store.upsert_plan_results_batch(
@@ -1035,6 +1038,11 @@ class PlanApplier:
             for nid in exact:
                 verdict[nid] = self._node_plan_valid(snap, plan, nid)
         verdicts = [verdict[nid] for nid in nodes]
+        # the denominator of the rejected share: every node row given a
+        # verdict, a re-verified plan's rows again (nodes_rejected
+        # counts the final verdict once, in _finalize)
+        with self._stats_lock:
+            self.stats["nodes_verified"] += len(nodes)
         vol_bad = self._volume_rejections(snap, plan)
         for node_id, ok in zip(nodes, verdicts):
             if ok and node_id not in vol_bad:

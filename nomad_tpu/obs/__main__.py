@@ -103,11 +103,13 @@ def export_trace(path: str, addr: str = "") -> int:
                 addr.rstrip("/") + "/v1/traces?limit=0", timeout=10) as r:
             body = json.loads(r.read().decode())
         doc = body.get("trace", {"traceEvents": []})
-        doc["otherData"] = {"phases": body.get("phases", {})}
+        doc["otherData"] = {"phases": body.get("phases", {}),
+                            "dropped": body.get("dropped", 0)}
         with open(path, "w") as f:
             json.dump(doc, f)
         print(f"wrote {len(doc['traceEvents'])} span(s) from {addr} "
-              f"-> {path}")
+              f"-> {path} ({doc['otherData']['dropped']} dropped from "
+              "full rings)")
         return 0
     # demo mode: boot a cluster, run a workload, export its spans
     TRACER.set_enabled(True)
@@ -116,14 +118,14 @@ def export_trace(path: str, addr: str = "") -> int:
     try:
         cluster, _leader, _evals = _demo_cluster(tmp)
         try:
-            spans = TRACER.spans()
+            spans, dropped = TRACER.spans(), TRACER.dropped
         finally:
             cluster.stop()
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
-    write_chrome_trace(path, spans)
+    write_chrome_trace(path, spans, dropped=dropped)
     print(f"wrote {len(spans)} span(s) from an in-process demo cluster "
-          f"-> {path}")
+          f"-> {path} ({dropped} dropped from full rings)")
     for name, row in phase_breakdown(spans).items():
         print(f"  {name:<22} n={row['count']:<5} p50={row['p50_ms']:8.3f}ms"
               f" p99={row['p99_ms']:8.3f}ms")

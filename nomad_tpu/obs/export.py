@@ -154,10 +154,12 @@ def render_chain(report: dict) -> str:
 
 
 def write_chrome_trace(path: str, spans: List[tuple],
-                       breakdown: Optional[dict] = None) -> None:
+                       breakdown: Optional[dict] = None,
+                       dropped: int = 0) -> None:
     import json
 
     doc = chrome_trace(spans)
-    doc["otherData"] = {"phases": breakdown or phase_breakdown(spans)}
+    doc["otherData"] = {"phases": breakdown or phase_breakdown(spans),
+                        "dropped": dropped}
     with open(path, "w") as f:
         json.dump(doc, f)

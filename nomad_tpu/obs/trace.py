@@ -29,6 +29,17 @@ Clock: ``time.time()`` (wall). It is shared with the broker's
 ``_enqueue_times`` side table (which powers the retroactive
 ``eval.queued`` span) and comparable across threads; span durations are
 milliseconds-scale, far above its resolution.
+
+Two per-span extras, both off unless the call site asks:
+
+- ``span(..., cpu=True)`` stamps ``cpu_s`` into the span's args: the
+  thread's own CPU seconds over the span (``time.thread_time()``). Wall
+  minus ``cpu_s`` is time the thread was runnable or blocked and not
+  running — the interpreter lock or another lock.
+- ``span(..., device=True)`` also enters a ``jax.profiler.TraceAnnotation``
+  of the same name, so inside a profiler session the span lands in the
+  ``.xplane.pb`` beside the device's ops, on the profiler's clock.
+  Outside a session an annotation is a flag check.
 """
 
 from __future__ import annotations
@@ -43,6 +54,18 @@ from typing import List, Optional
 # core/__init__ -> server -> broker -> back into this half-initialized
 # package (obs must stay a leaf import for every subsystem).
 _REGISTRY = None
+# jax binds lazily too, and only for spans that ask for device=True:
+# a process that never solves never imports it through obs
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
 
 
 def _registry():
@@ -86,12 +109,13 @@ NULL_SPAN = _NullSpan()
 class _Ring:
     """Bounded record ring with a single writer (its owning thread)."""
 
-    __slots__ = ("buf", "cap", "idx")
+    __slots__ = ("buf", "cap", "idx", "dropped")
 
     def __init__(self, cap: int):
         self.buf: list = []
         self.cap = cap
         self.idx = 0  # next overwrite position once full
+        self.dropped = 0  # records overwritten since the ring filled
 
     def append(self, rec: tuple) -> None:
         if len(self.buf) < self.cap:
@@ -99,6 +123,7 @@ class _Ring:
         else:
             self.buf[self.idx] = rec
             self.idx = (self.idx + 1) % self.cap
+            self.dropped += 1
 
     def snapshot(self) -> list:
         # cross-thread read of a single-writer ring: list() is one
@@ -115,9 +140,11 @@ class _Span:
     """One open span (context manager). Created only when the tracer is
     enabled; records itself into the calling thread's ring on exit."""
 
-    __slots__ = ("_tr", "name", "trace", "args", "_parent", "sid", "t0")
+    __slots__ = ("_tr", "name", "trace", "args", "_parent", "sid", "t0",
+                 "_cpu0", "_ann")
 
-    def __init__(self, tr: "Tracer", name: str, trace, args: dict):
+    def __init__(self, tr: "Tracer", name: str, trace, args: dict,
+                 cpu: bool = False, device: bool = False):
         self._tr = tr
         self.name = name
         self.trace = trace
@@ -125,6 +152,8 @@ class _Span:
         self._parent = 0
         self.sid = 0
         self.t0 = 0.0
+        self._cpu0 = 0.0 if cpu else None   # thread_time() at entry
+        self._ann = device                  # the annotation once entered
 
     def __enter__(self):
         tl = self._tr._tl()
@@ -137,11 +166,21 @@ class _Span:
         self._parent = stack[-1][0] if stack else 0
         self.sid = next(_ids)
         stack.append((self.sid, self.trace))
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time()
         self.t0 = time.time()
+        if self._ann:
+            # an annotation's event starts when it is built
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        if self._ann:
+            self._ann.__exit__(*exc)
         t1 = time.time()
+        if self._cpu0 is not None:
+            self.args["cpu_s"] = time.thread_time() - self._cpu0
         tl = self._tr._tl()
         if tl.stack and tl.stack[-1][0] == self.sid:
             tl.stack.pop()
@@ -212,12 +251,15 @@ class Tracer:
 
     # -- recording --
 
-    def span(self, name: str, trace=None, **args):
+    def span(self, name: str, trace=None, *, cpu: bool = False,
+             device: bool = False, **args):
         """Open a named span as a context manager. ``trace`` defaults to
-        the enclosing span's / bind()'s trace id."""
+        the enclosing span's / bind()'s trace id. ``cpu`` stamps the
+        thread's CPU seconds into ``args["cpu_s"]`` at exit; ``device``
+        mirrors the span into the jax profiler's trace (module doc)."""
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name, trace, args)
+        return _Span(self, name, trace, args, cpu, device)
 
     def bind(self, trace):
         """Context manager: spans opened inside (on this thread) inherit
@@ -257,6 +299,13 @@ class Tracer:
             out.extend(r.snapshot())
         out.sort(key=lambda rec: rec[R_T0])
         return out
+
+    @property
+    def dropped(self) -> int:
+        """Records overwritten in full rings since the last clear():
+        what spans() can no longer return."""
+        with self._reg_lock:
+            return sum(r.dropped for r in self._rings.values())
 
     def clear(self) -> None:
         """Drop all recorded spans (bench/test isolation): unregister

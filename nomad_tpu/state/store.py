@@ -36,6 +36,7 @@ from ..structs.job import Job
 from ..structs.node import Node
 from ..analysis.ownership import GLOBAL as _OWN
 from ..analysis.sanitizer import sanitized
+from ..obs import TRACER
 from .mvcc import ConsList, SnapshotTracker, VersionedTable, cons, cons_from_iter, cons_iter
 from .watch import WatchTable
 
@@ -432,6 +433,12 @@ class CanonicalNodeList(list):
     canonical_key = None
 
 
+def _qualname(fn) -> str:
+    """A commit listener's qualified name (`WatchTable._on_commit`), the
+    `fn` arg of its store.listener span."""
+    return getattr(fn, "__qualname__", None) or type(fn).__name__
+
+
 @sanitized
 class StateStore:
     """MVCC tables + serialized write path (reference nomad/state/state_store.go).
@@ -582,14 +589,23 @@ class StateStore:
         live = self._tracker.min_live(self._index)
         return self._next_gen, live
 
-    def _commit(self, gen: int, events: list) -> None:
+    def _commit(self, gen: int, events: list, spans: bool = False) -> None:
+        """Publish `gen` and run the commit listeners inline. `spans`
+        (the plan-results path alone asks) opens one store.listener span
+        a listener, named by its `fn`: every other writer's commit,
+        heartbeats least of all, pays nothing for it."""
         if _OWN.active:
             _OWN.txn_commit(gen, events)
         with self._cond:
             self._index = gen
             self._cond.notify_all()
+        if not spans:
+            for fn in self._listeners:
+                fn(gen, events)
+            return
         for fn in self._listeners:
-            fn(gen, events)
+            with TRACER.span("store.listener", fn=_qualname(fn)):
+                fn(gen, events)
 
     def compact(self) -> int:
         """Prune version chains and drop invisible tombstones across all
@@ -1041,16 +1057,11 @@ class StateStore:
         job=None,
         ts: float = None,
     ) -> int:
-        with self._write_lock:
-            gen, live = self._begin()
-            ts = ts if ts is not None else self._clock()
-            events = []
-            self._apply_plan_payload(
-                result_allocs, stopped_allocs, preempted_allocs, deployment,
-                deployment_updates, evals, alloc_blocks, gen, live, ts, events,
-                job=job)
-            self._commit(gen, events)
-            return gen
+        return self.upsert_plan_results_batch([{
+            "result_allocs": result_allocs, "stopped_allocs": stopped_allocs,
+            "preempted_allocs": preempted_allocs, "deployment": deployment,
+            "deployment_updates": deployment_updates, "evals": evals,
+            "alloc_blocks": alloc_blocks, "job": job}], ts=ts)
 
     def upsert_plan_results_batch(self, payloads: List[dict],
                                   ts: float = None) -> int:
@@ -1062,22 +1073,37 @@ class StateStore:
         earlier payload inserted resolves exactly as it would across two
         back-to-back transactions, because get_latest sees same-gen
         puts."""
-        with self._write_lock:
-            gen, live = self._begin()
-            ts = ts if ts is not None else self._clock()
-            events = []
-            for p in payloads:
-                self._apply_plan_payload(
-                    p.get("result_allocs", ()),
-                    p.get("stopped_allocs", ()),
-                    p.get("preempted_allocs", ()),
-                    p.get("deployment"),
-                    p.get("deployment_updates", ()),
-                    p.get("evals", ()),
-                    p.get("alloc_blocks", ()),
-                    gen, live, ts, events, job=p.get("job"))
-            self._commit(gen, events)
+        # three phase spans split the transaction for the trace: the
+        # wait for the writer lock, the apply, and the publish with its
+        # inline listener pass. Children of plan.commit_round on the
+        # applier's thread; roots beside raft.apply on the FSM's
+        with TRACER.span("store.lock_wait"):
+            self._write_lock.acquire()
+        try:
+            with TRACER.span("store.apply", payloads=len(payloads)) as sp:
+                gen, live = self._begin()
+                ts = ts if ts is not None else self._clock()
+                events = []
+                rows = blocks = 0
+                for p in payloads:
+                    self._apply_plan_payload(
+                        p.get("result_allocs", ()),
+                        p.get("stopped_allocs", ()),
+                        p.get("preempted_allocs", ()),
+                        p.get("deployment"),
+                        p.get("deployment_updates", ()),
+                        p.get("evals", ()),
+                        p.get("alloc_blocks", ()),
+                        gen, live, ts, events, job=p.get("job"))
+                    rows += len(p.get("result_allocs", ())) + sum(
+                        int(b.counts.sum()) for b in p.get("alloc_blocks", ()))
+                    blocks += len(p.get("alloc_blocks", ()))
+                sp.set(rows=rows, blocks=blocks)
+            with TRACER.span("store.publish", events=len(events)):
+                self._commit(gen, events, spans=True)
             return gen
+        finally:
+            self._write_lock.release()
 
     def _rehydrate_alloc_jobs(self, allocs, job) -> None:
         """Reverse of the plan applier's normalization: allocs ride the

@@ -302,9 +302,10 @@ def solve_batch(
     # folded above so the impl's fold sees no-op slots
     zero_cidx = jnp.zeros(1, jnp.int32)
     zero_cdelta = jnp.zeros((1, d), f)
-    used_greedy, counts_greedy = _solve_bulk_multi_impl(
-        used0, available, feas, aff, ask, k, tg_count, seeds,
-        zero_cidx, zero_cdelta, g=g)
+    with jax.named_scope("greedy_arm"):
+        used_greedy, counts_greedy = _solve_bulk_multi_impl(
+            used0, available, feas, aff, ask, k, tg_count, seeds,
+            zero_cidx, zero_cdelta, g=g)
 
     # auction arm: one run per PORTFOLIO entry from the same start state
     # with fresh tie-break jitter each time (scaled per entry); keep the
@@ -321,9 +322,11 @@ def solve_batch(
                 jax.random.fold_in(jax.random.PRNGKey(s), _t), (n,),
                 jnp.float32, 0.0, TIE_JITTER * _js)
         )(seeds)                                                  # (G, N)
-        used_t, take_t, rnd_t = _auction(
-            used0, available, feas, aff, ask, k, jits, g, rounds,
-            price_eps=PRICE_EPS * ptemp, evict=evict, pscore=pscore)
+        # one scope a restart: the arms split the launch in the trace
+        with jax.named_scope(f"auction_arm_{t}"):
+            used_t, take_t, rnd_t = _auction(
+                used0, available, feas, aff, ask, k, jits, g, rounds,
+                price_eps=PRICE_EPS * ptemp, evict=evict, pscore=pscore)
         # dtype pin: placement counts reduce as int32 (associative adds
         # — legal before a comparison; x64 would promote to int64)
         placed_t = take_t.sum(dtype=jnp.int32)

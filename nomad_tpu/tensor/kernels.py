@@ -150,40 +150,80 @@ def score_nodes(
     funcs.go:213), so the appended device/core columns participate in
     feasibility without perturbing the score.
     """
+    # The named scopes (feasibility / score / spread here, select /
+    # usage_update in the scan step) put each HLO op of the step under
+    # its phase in the profiler's trace; they change no computation.
     n = available.shape[0]
     new_used = used + ask[None, :]
 
-    ok = feasible & jnp.all(new_used <= available, axis=1)
-    ok &= jnp.where(dh_job, placed_job == 0, True)
-    ok &= jnp.where(dh_tg, placed_tg == 0, True)
+    with jax.named_scope("feasibility"):
+        ok = feasible & jnp.all(new_used <= available, axis=1)
+        ok &= jnp.where(dh_job, placed_job == 0, True)
+        ok &= jnp.where(dh_tg, placed_tg == 0, True)
 
-    # distinct_property cap (reference scheduler/propertyset.go via
-    # feasible.go:649 DistinctPropertyIterator): a node is infeasible if
-    # it lacks the property or its value's proposed count is at the limit
-    if dp_val_id.shape[0]:
-        dp_at = jnp.take_along_axis(dp_counts, dp_val_id, axis=1)  # (P, N)
-        dp_ok = dp_val_ok & (dp_at < dp_limit[:, None])
-        ok &= jnp.all(dp_ok, axis=0)
+        # distinct_property cap (reference scheduler/propertyset.go via
+        # feasible.go:649 DistinctPropertyIterator): a node is infeasible
+        # if it lacks the property or its value's proposed count is at
+        # the limit
+        if dp_val_id.shape[0]:
+            dp_at = jnp.take_along_axis(dp_counts, dp_val_id, axis=1)  # (P, N)
+            dp_ok = dp_val_ok & (dp_at < dp_limit[:, None])
+            ok &= jnp.all(dp_ok, axis=0)
 
-    fitness = fit_scores(available, new_used, spread_alg)
+    with jax.named_scope("score"):
+        fitness = fit_scores(available, new_used, spread_alg)
 
-    # job anti-affinity (reference rank.go:596)
-    anti_present = placed_tg > 0
-    anti = -(placed_tg.astype(fitness.dtype) + 1.0) / jnp.maximum(tg_count, 1.0)
+        # job anti-affinity (reference rank.go:596)
+        anti_present = placed_tg > 0
+        anti = (-(placed_tg.astype(fitness.dtype) + 1.0)
+                / jnp.maximum(tg_count, 1.0))
 
-    # node rescheduling penalty (reference rank.go:666)
-    resched_present = jnp.arange(n) == penalty_idx
+        # node rescheduling penalty (reference rank.go:666)
+        resched_present = jnp.arange(n) == penalty_idx
 
-    # node affinity (reference rank.go:710); boost precomputed host-side
-    aff_present = affinity_boost != 0.0
+        # node affinity (reference rank.go:710); boost precomputed
+        # host-side
+        aff_present = affinity_boost != 0.0
 
-    # device affinity (host oracle's separate "device-affinity" sub-score;
-    # reference rank.go folds the deviceAllocator offer score in)
-    dev_present = dev_affinity != 0.0
+        # device affinity (host oracle's separate "device-affinity"
+        # sub-score; reference rank.go folds the deviceAllocator offer
+        # score in)
+        dev_present = dev_affinity != 0.0
 
-    # spread (reference spread.go:128 + propertyset.go)
+    with jax.named_scope("spread"):
+        spread_total, boost = _spread_boost(
+            fitness.dtype, spread_val_id, spread_val_ok, spread_counts,
+            spread_desired, spread_has_targets, spread_weight, lowest_boost)
+        spread_present = spread_total != 0.0
+
+    with jax.named_scope("score"):
+        divisor = (
+            1.0
+            + anti_present.astype(fitness.dtype)
+            + resched_present.astype(fitness.dtype)
+            + aff_present.astype(fitness.dtype)
+            + dev_present.astype(fitness.dtype)
+            + spread_present.astype(fitness.dtype)
+        )
+        total = (
+            fitness
+            + jnp.where(anti_present, anti, 0.0)
+            + jnp.where(resched_present, -1.0, 0.0)
+            + jnp.where(aff_present, affinity_boost, 0.0)
+            + jnp.where(dev_present, dev_affinity, 0.0)
+            + jnp.where(spread_present, spread_total, 0.0)
+        )
+        final = total / divisor
+        return jnp.where(ok, final, NEG), fitness, boost
+
+
+def _spread_boost(dtype, spread_val_id, spread_val_ok, spread_counts,
+                  spread_desired, spread_has_targets, spread_weight,
+                  lowest_boost):
+    """The spread sub-score of score_nodes -> ((N,) total, (S, N) boost)
+    (reference spread.go:128 + propertyset.go)."""
     counts_at = jnp.take_along_axis(spread_counts, spread_val_id, axis=1)  # (S, N)
-    used_cnt = counts_at.astype(fitness.dtype) + 1.0  # incl. this placement
+    used_cnt = counts_at.astype(dtype) + 1.0  # incl. this placement
     desired = jnp.take_along_axis(spread_desired, spread_val_id, axis=1)   # (S, N)
 
     explicit = jnp.where(
@@ -203,10 +243,10 @@ def score_nodes(
     present_v = spread_counts > 0                                   # (S, V)
     any_present = jnp.any(present_v, axis=1)                        # (S,)
     minc = jnp.min(jnp.where(present_v, spread_counts, jnp.iinfo(jnp.int32).max),
-                   axis=1).astype(fitness.dtype)                    # (S,)
+                   axis=1).astype(dtype)                            # (S,)
     maxc = jnp.max(jnp.where(present_v, spread_counts, 0),
-                   axis=1).astype(fitness.dtype)                    # (S,)
-    cur = counts_at.astype(fitness.dtype)                           # (S, N)
+                   axis=1).astype(dtype)                            # (S,)
+    cur = counts_at.astype(dtype)                                   # (S, N)
     minc_b = minc[:, None]
     maxc_b = maxc[:, None]
     even = jnp.where(
@@ -226,27 +266,7 @@ def score_nodes(
     boost = jnp.where(spread_has_targets[:, None], explicit, even)  # (S, N)
     # fixed-tree reduction: spread_total feeds the != 0 presence test,
     # so its float add order must not vary with the fusion context
-    spread_total = _pairwise_sum_xp(jnp, boost)                     # (N,)
-    spread_present = spread_total != 0.0
-
-    divisor = (
-        1.0
-        + anti_present.astype(fitness.dtype)
-        + resched_present.astype(fitness.dtype)
-        + aff_present.astype(fitness.dtype)
-        + dev_present.astype(fitness.dtype)
-        + spread_present.astype(fitness.dtype)
-    )
-    total = (
-        fitness
-        + jnp.where(anti_present, anti, 0.0)
-        + jnp.where(resched_present, -1.0, 0.0)
-        + jnp.where(aff_present, affinity_boost, 0.0)
-        + jnp.where(dev_present, dev_affinity, 0.0)
-        + jnp.where(spread_present, spread_total, 0.0)
-    )
-    final = total / divisor
-    return jnp.where(ok, final, NEG), fitness, boost
+    return _pairwise_sum_xp(jnp, boost), boost                      # (N,)
 
 
 def _permute_node_axis(tie_perm, available, used0, placed_tg0, placed_job0,
@@ -342,30 +362,34 @@ def solve_task_group(
             lowest_boost=lowest, tg_count=tg_count,
             dh_job=dh_job, dh_tg=dh_tg, spread_alg=spread_alg,
         )
-        choice = jnp.argmax(score)
-        found = is_active & (score[choice] > NEG)
+        with jax.named_scope("select"):
+            choice = jnp.argmax(score)
+            found = is_active & (score[choice] > NEG)
 
-        onehot = (jnp.arange(n) == choice) & found
-        used = used + ask[None, :] * onehot[:, None]
-        ptg = ptg + onehot.astype(ptg.dtype)
-        pjob = pjob + onehot.astype(pjob.dtype)
+        with jax.named_scope("usage_update"):
+            onehot = (jnp.arange(n) == choice) & found
+            used = used + ask[None, :] * onehot[:, None]
+            ptg = ptg + onehot.astype(ptg.dtype)
+            pjob = pjob + onehot.astype(pjob.dtype)
 
-        sel_ok = spread_val_ok[:, choice] & found                  # (S,)
-        sel_val = spread_val_id[:, choice]                          # (S,)
-        scnt = scnt.at[jnp.arange(s), sel_val].add(sel_ok.astype(scnt.dtype))
+            sel_ok = spread_val_ok[:, choice] & found              # (S,)
+            sel_val = spread_val_id[:, choice]                      # (S,)
+            scnt = scnt.at[jnp.arange(s), sel_val].add(
+                sel_ok.astype(scnt.dtype))
 
-        if p:
-            dsel_ok = dp_val_ok[:, choice] & found                 # (P,)
-            dsel_val = dp_val_id[:, choice]                        # (P,)
-            dpcnt = dpcnt.at[jnp.arange(p), dsel_val].add(
-                dsel_ok.astype(dpcnt.dtype))
+            if p:
+                dsel_ok = dp_val_ok[:, choice] & found             # (P,)
+                dsel_val = dp_val_id[:, choice]                    # (P,)
+                dpcnt = dpcnt.at[jnp.arange(p), dsel_val].add(
+                    dsel_ok.astype(dpcnt.dtype))
 
-        # SpreadIterator tracks the lowest explicit boost it has handed
-        # out (spread.go lowestBoost); we update it with the chosen
-        # node's explicit boosts
-        chosen_boost = jnp.where(spread_has_targets & sel_ok,
-                                 boost[:, choice], jnp.inf)
-        lowest = jnp.minimum(lowest, jnp.min(chosen_boost, initial=jnp.inf))
+            # SpreadIterator tracks the lowest explicit boost it has
+            # handed out (spread.go lowestBoost); we update it with the
+            # chosen node's explicit boosts
+            chosen_boost = jnp.where(spread_has_targets & sel_ok,
+                                     boost[:, choice], jnp.inf)
+            lowest = jnp.minimum(lowest,
+                                 jnp.min(chosen_boost, initial=jnp.inf))
 
         return (used, ptg, pjob, scnt, dpcnt, lowest), (choice, found, score[choice])
 
@@ -722,33 +746,40 @@ def _solve_bulk_multi_impl(
     )(seeds)                                                       # (G, N)
 
     def one_eval(used, gi):
+        # named scopes: the evaluation's phases in the profiler's trace
         ask_g = ask[gi]
         ask_pos = ask_g > 0
         new_used = used + ask_g[None, :]
-        ok = feas[gi] & jnp.all(new_used <= available, axis=1)
-        fitness = fit_scores(available, new_used, False)
-        aff_g = aff[gi]
-        aff_present = aff_g != 0.0
-        divisor = 1.0 + aff_present.astype(f)
-        score = (fitness + jnp.where(aff_present, aff_g, 0.0)) / divisor
-        score = jnp.where(ok, score, NEG)
+        with jax.named_scope("feasibility"):
+            ok = feas[gi] & jnp.all(new_used <= available, axis=1)
+        with jax.named_scope("score"):
+            fitness = fit_scores(available, new_used, False)
+            aff_g = aff[gi]
+            aff_present = aff_g != 0.0
+            divisor = 1.0 + aff_present.astype(f)
+            score = (fitness + jnp.where(aff_present, aff_g, 0.0)) / divisor
+            score = jnp.where(ok, score, NEG)
 
-        free = available - used
-        per_dim = jnp.where(
-            ask_pos[None, :],
-            jnp.floor(free / jnp.where(ask_pos, ask_g, 1.0)[None, :]),
-            jnp.inf)
-        cap = jnp.clip(jnp.min(per_dim, axis=1), 0, None)
-        cap = jnp.where(score > NEG, cap, 0.0)
-        budget = k[gi]
-        cap = jnp.minimum(cap, budget.astype(cap.dtype)).astype(jnp.int32)
-        key = score + jits[gi]
-        order = jnp.argsort(-key)            # residual ties: node index
-        cap_sorted = cap[order]
-        cum = jnp.cumsum(cap_sorted)
-        take_sorted = jnp.clip(budget - (cum - cap_sorted), 0, cap_sorted)
-        take = jnp.zeros(n, jnp.int32).at[order].set(take_sorted)
-        used = used + ask_g[None, :] * take[:, None].astype(used.dtype)
+        with jax.named_scope("capacity"):
+            free = available - used
+            per_dim = jnp.where(
+                ask_pos[None, :],
+                jnp.floor(free / jnp.where(ask_pos, ask_g, 1.0)[None, :]),
+                jnp.inf)
+            cap = jnp.clip(jnp.min(per_dim, axis=1), 0, None)
+            cap = jnp.where(score > NEG, cap, 0.0)
+            budget = k[gi]
+            cap = jnp.minimum(cap, budget.astype(cap.dtype)).astype(jnp.int32)
+        with jax.named_scope("sorted_fill"):
+            key = score + jits[gi]
+            order = jnp.argsort(-key)            # residual ties: node index
+            cap_sorted = cap[order]
+            cum = jnp.cumsum(cap_sorted)
+            take_sorted = jnp.clip(budget - (cum - cap_sorted), 0,
+                                   cap_sorted)
+            take = jnp.zeros(n, jnp.int32).at[order].set(take_sorted)
+        with jax.named_scope("usage_update"):
+            used = used + ask_g[None, :] * take[:, None].astype(used.dtype)
         return used, take.astype(jnp.int16)
 
     used, counts = jax.lax.scan(one_eval, used0, jnp.arange(g))

@@ -245,8 +245,6 @@ class TPUPlacer:
         preemption_enabled: bool = False,
         attempt: int = 0,
     ) -> None:
-        from .kernels import pack_solve_args, solve_task_group_fused
-
         if not nodes:
             from ..scheduler.reconcile import BulkPlacementRequest
 
@@ -334,6 +332,9 @@ class TPUPlacer:
                 # math, parity-tested — without a launch's fixed cost.
                 # HOST_CUTOVER selects the arm; its value is not
                 # measured on the current chip (ROADMAP D3)
+                from ..core.metrics import REGISTRY
+
+                REGISTRY.incr("nomad.placer.host_cutover_groups")
                 for req in reqs:
                     option = self._host_one(ctx, job, tg, nodes, req,
                                             batch, preemption_enabled,
@@ -375,60 +376,23 @@ class TPUPlacer:
             # usage WITH every earlier solve's overlay entries folded
             # (tensor/overlay.py), so racing workers interleave around
             # each other like the bulk path's carry provides for free.
-            from .overlay import INFLIGHT
-
-            # the span covers the lock wait too: serialization behind
-            # racing workers is exactly the stall the trace should show
-            with TRACER.span("worker.solve", k=len(reqs)), \
-                    _PER_EVAL_SOLVE_LOCK:
-                cluster.refresh_usage(ctx)
-                # device/core count columns extend the dense dims
-                has_extra = tgt.extra_ask is not None and len(tgt.extra_ask)
-                if has_extra:
-                    avail = np.concatenate([cluster.available, tgt.extra_cap],
-                                           axis=1)
-                    used = np.concatenate([cluster.used, tgt.extra_used],
-                                          axis=1)
-                    ask = np.concatenate([tgt.ask, tgt.extra_ask])
-                else:
-                    avail, used, ask = (cluster.available, cluster.used,
-                                        tgt.ask)
-
-                packed = pack_solve_args(
-                    avail, used, tgt.placed_tg, tgt.placed_job,
-                    ask, tgt.feasible, tgt.affinity_boost, penalty_idx,
-                    active,
-                    tgt.spread_val_id, tgt.spread_val_ok, tgt.spread_counts,
-                    tgt.spread_desired, tgt.spread_has_targets,
-                    tgt.spread_weight,
-                    -1.0, tgt.tg_count, tgt.dh_job, tgt.dh_tg, tgt.spread_alg,
-                    dev_affinity=tgt.dev_affinity,
-                    dp_val_id=tgt.dp_val_id, dp_val_ok=tgt.dp_val_ok,
-                    dp_counts0=tgt.dp_counts, dp_limit=tgt.dp_limit,
-                    tie_perm=tie_perm)
-                import jax
-
-                # explicit shipment + shape-keyed window; the
-                # device_get is the launch's only host sync
-                dev = jax.device_put(packed)
-                fused_key = tuple(np.shape(a) for a in packed)
-                with _warm_launch(solve_task_group_fused, fused_key,
-                                  _FUSED_WARM):
-                    out = jax.device_get(solve_task_group_fused(*dev))
-                choices = out[0].astype(np.int64)
-                founds = out[1] > 0.5
-                scores = out[2]
-                if ctx.plan is not None and founds.any():
-                    vec = ctx.tg_vec(tg)
-                    kernel_counts: Dict[int, int] = {}
-                    for i in range(len(reqs)):
-                        if founds[i]:
-                            ni = int(choices[i])
-                            kernel_counts[ni] = kernel_counts.get(ni, 0) + 1
-                    INFLIGHT.register(
-                        {cluster.nodes[ni].id: vec * c
-                         for ni, c in kernel_counts.items()},
-                        ctx.plan)
+            #
+            # worker.solve covers the lock wait too: serialization
+            # behind racing workers is exactly the stall the trace
+            # should show. Its children split it, one set per
+            # evaluation and task group: placer.lock_wait, then
+            # placer.locked around gather / pack / ship / device_wait /
+            # fetch / register. device=True mirrors each into the jax
+            # profiler's trace, above the device's ops on one clock.
+            with TRACER.span("worker.solve", k=k):
+                with TRACER.span("placer.lock_wait", device=True):
+                    _PER_EVAL_SOLVE_LOCK.acquire()
+                try:
+                    choices, founds, scores = self._solve_locked(
+                        ctx, tg, tgt, cluster, reqs, k_pad, penalty_idx,
+                        active, tie_perm)
+                finally:
+                    _PER_EVAL_SOLVE_LOCK.release()
 
             # exact port numbers / device instances / core ids are
             # host-side, per chosen node, after the solve (the kernel only
@@ -545,6 +509,83 @@ class TPUPlacer:
             return False
         return all(req.previous_alloc is None and not req.ignore_node
                    and not req.canary for req in reqs)
+
+    def _solve_locked(self, ctx, tg, tgt, cluster, reqs, k_pad,
+                      penalty_idx, active, tie_perm):
+        """One evaluation's usage gather -> solve -> in-flight
+        registration; the caller holds _PER_EVAL_SOLVE_LOCK. Returns
+        (choices, founds, scores) per request."""
+        import jax
+
+        from .kernels import pack_solve_args, solve_task_group_fused
+        from .overlay import INFLIGHT
+
+        k = len(reqs)
+        with TRACER.span("placer.locked", cpu=True, device=True, k=k,
+                         k_pad=k_pad, n_pad=cluster.n_pad):
+            with TRACER.span("placer.gather", device=True):
+                cluster.refresh_usage(ctx)
+                # device/core count columns extend the dense dims
+                has_extra = tgt.extra_ask is not None and len(tgt.extra_ask)
+                if has_extra:
+                    avail = np.concatenate([cluster.available, tgt.extra_cap],
+                                           axis=1)
+                    used = np.concatenate([cluster.used, tgt.extra_used],
+                                          axis=1)
+                    ask = np.concatenate([tgt.ask, tgt.extra_ask])
+                else:
+                    avail, used, ask = (cluster.available, cluster.used,
+                                        tgt.ask)
+
+            with TRACER.span("placer.pack", device=True):
+                packed = pack_solve_args(
+                    avail, used, tgt.placed_tg, tgt.placed_job,
+                    ask, tgt.feasible, tgt.affinity_boost, penalty_idx,
+                    active,
+                    tgt.spread_val_id, tgt.spread_val_ok, tgt.spread_counts,
+                    tgt.spread_desired, tgt.spread_has_targets,
+                    tgt.spread_weight,
+                    -1.0, tgt.tg_count, tgt.dh_job, tgt.dh_tg, tgt.spread_alg,
+                    dev_affinity=tgt.dev_affinity,
+                    dp_val_id=tgt.dp_val_id, dp_val_ok=tgt.dp_val_ok,
+                    dp_counts0=tgt.dp_counts, dp_limit=tgt.dp_limit,
+                    tie_perm=tie_perm)
+
+            # explicit shipment + shape-keyed window. ship is the
+            # enqueue of the transfer and of the launch; device_wait is
+            # what is left of the transfer, the launch latency and the
+            # scan; the device_get of the ready result stays the
+            # launch's one readback
+            fused_key = tuple(np.shape(a) for a in packed)
+            with _warm_launch(solve_task_group_fused, fused_key,
+                              _FUSED_WARM):
+                with TRACER.span("placer.ship", device=True,
+                                 bytes=sum(a.nbytes for a in packed)):
+                    dev = jax.device_put(packed)
+                    res = solve_task_group_fused(*dev)
+                with TRACER.span("placer.device_wait", device=True):
+                    # the wait is split from the readback so the trace
+                    # tells launch latency + scan from the copy back
+                    # san-ok: same one sync as the device_get it precedes
+                    jax.block_until_ready(res)
+                with TRACER.span("placer.fetch", device=True):
+                    out = jax.device_get(res)
+                    choices = out[0].astype(np.int64)
+                    founds = out[1] > 0.5
+                    scores = out[2]
+            with TRACER.span("placer.register", device=True):
+                if ctx.plan is not None and founds.any():
+                    vec = ctx.tg_vec(tg)
+                    kernel_counts: Dict[int, int] = {}
+                    for i in range(k):
+                        if founds[i]:
+                            ni = int(choices[i])
+                            kernel_counts[ni] = kernel_counts.get(ni, 0) + 1
+                    INFLIGHT.register(
+                        {cluster.nodes[ni].id: vec * c
+                         for ni, c in kernel_counts.items()},
+                        ctx.plan)
+        return choices, founds, scores
 
     def _bulk_shape_ok(self, ctx, tg, tgt) -> bool:
         """Task-group-level bulk eligibility (the per-request conditions

@@ -259,11 +259,10 @@ class _Inflight:
     jax.device_get, which is the launch's ONLY host sync."""
 
     __slots__ = ("rs", "static", "counts", "info", "gathers", "rounds",
-                 "joint", "sharded", "mesh_devices", "g", "resync",
-                 "t0", "t_dispatched")
+                 "joint", "sharded", "mesh_devices", "g", "resync", "t0")
 
     def __init__(self, rs, static, counts, info, gathers, rounds, joint,
-                 sharded, mesh_devices, g, resync, t0, t_dispatched):
+                 sharded, mesh_devices, g, resync, t0):
         self.rs = rs
         self.static = static
         self.counts = counts        # (G, N) device handle
@@ -275,8 +274,7 @@ class _Inflight:
         self.mesh_devices = mesh_devices
         self.g = g
         self.resync = resync
-        self.t0 = t0                # perf_counter at dispatch start
-        self.t_dispatched = t_dispatched  # perf_counter at dispatch end
+        self.t0 = t0                # time.time() at dispatch start
 
 
 class BulkSolverService:
@@ -315,7 +313,7 @@ class BulkSolverService:
         # retrace and raises jit_guard.RetraceError (stats["retraces"]
         # counts them for the agent stats surface before propagating)
         self.stats = {"launches": 0, "solves": 0, "resyncs": 0,
-                      "launch_s": 0.0, "rejections": 0, "sharded": 0,
+                      "rejections": 0, "sharded": 0,
                       "joint_launches": 0, "joint_solves": 0,
                       "auction_won": 0, "auction_rounds": 0,
                       "joint_score": 0.0, "greedy_score": 0.0,
@@ -324,10 +322,11 @@ class BulkSolverService:
                       # back to the host rebuild (expect 0)
                       "twin_failures": 0,
                       # pipeline telemetry: launches whose fetch was
-                      # deferred behind a newer dispatch, host time spent
-                      # off the fetch while a launch ran, device-window
-                      # time, and the sharded launches' collective count
-                      "pipelined": 0, "overlap_s": 0.0, "busy_s": 0.0,
+                      # deferred behind a newer dispatch, and the sharded
+                      # launches' collective count. How long the device
+                      # was busy is the profiler's to say, not a host
+                      # clock's around an asynchronous dispatch
+                      "pipelined": 0,
                       "allgathers": 0, "mesh_devices": 0}
         self._warm_shapes: set = set()
         # double buffer: the one dispatched-but-unfetched launch. Only
@@ -531,7 +530,11 @@ class BulkSolverService:
             groups.setdefault((id(r.static), r.joint), []).append(r)
         for rs in groups.values():
             try:
-                inflight = self._dispatch_group(rs)
+                # live, and mirrored into the profiler's trace: the
+                # service thread's phases sit above the device's ops
+                with TRACER.span("solver.dispatch", device=True,
+                                 g=len(rs), joint=bool(rs[0].joint)):
+                    inflight = self._dispatch_group(rs)
             except Exception as e:  # propagate to every blocked worker
                 # the launch may have consumed (donated) the usage carry
                 # before failing — drop the state so the next solve
@@ -745,7 +748,7 @@ class BulkSolverService:
         import jax
         import time as _time
 
-        t0 = _time.perf_counter()
+        t0 = _time.time()
         static = rs[0].static
         d = static.available.shape[1]
         mesh = self._resolve_mesh(static.n_pad)
@@ -781,8 +784,10 @@ class BulkSolverService:
                                   for e in self._ledger.values()
                                   if e.static is static]
         if need_resync:
-            used_dev = self._resync_base(rs[0], static, mesh, d,
-                                         ledger_entries)
+            with TRACER.span("solver.resync", device=True,
+                             entries=len(ledger_entries)):
+                used_dev = self._resync_base(rs[0], static, mesh, d,
+                                             ledger_entries)
             since = 0
             with self._lock:
                 self.stats["resyncs"] += 1
@@ -852,68 +857,58 @@ class BulkSolverService:
                     used_dev, avail, feas, aff, ask, k, tgc, seeds, cidx,
                     cdelta, g=g_pad)
         self._state = (static, new_used, since + g)
-        t1 = _time.perf_counter()
         if mesh is not None:
             # dispatch-side span: the sharded launch is queued, the host
             # keeps running — the solve/apply overlap window opens here
-            wall = _time.time()
-            TRACER.add_span("solver.shard", wall - (t1 - t0), wall,
+            TRACER.add_span("solver.shard", t0, _time.time(),
                             g=g, joint=bool(joint), mesh_devices=n_dev)
         RECORDER.record("solver", "launch", g=g, joint=bool(joint),
                         sharded=mesh is not None, resync=need_resync)
         return _Inflight(rs=rs, static=static, counts=counts, info=info,
                          gathers=gathers, rounds=rounds, joint=joint,
                          sharded=mesh is not None, mesh_devices=n_dev,
-                         g=g, resync=need_resync, t0=t0, t_dispatched=t1)
+                         g=g, resync=need_resync, t0=t0)
 
     def _fetch(self, inf: "_Inflight", pipelined: bool = False) -> None:
         """The launch's ONLY host sync: read the counts (+ info/gather
         stats) back, register ledger entries, account stats, resolve the
         workers' futures. Everything between dispatch and this call is
-        host time the device solve ran under — the overlap the
-        nomad.solver.overlap_occupancy gauge reports."""
+        host time the device solve ran under."""
         import jax
         import time as _time
 
         g = inf.g
-        t_f0 = _time.perf_counter()
+        t_f0 = _time.time()
         handles = [h for h in (inf.counts, inf.info, inf.gathers,
                                inf.rounds) if h is not None]
-        got = list(jax.device_get(handles))
+        with TRACER.span("solver.fetch", device=True, g=g,
+                         pipelined=pipelined):
+            got = list(jax.device_get(handles))
         counts_np = got.pop(0)
         info_np = got.pop(0) if inf.info is not None else None
         gathers_np = got.pop(0) if inf.gathers is not None else None
         rounds_np = got.pop(0) if inf.rounds is not None else None
-        t_f1 = _time.perf_counter()
         born = _time.time()
         allg = 0
         if gathers_np is not None:
             allg = int(gathers_np)
         elif rounds_np is not None:
             allg = int(rounds_np[:g].sum())
-        overlap = max(0.0, t_f0 - inf.t_dispatched)
-        busy = max(0.0, t_f1 - inf.t_dispatched)
         # trace-less batch spans (the service thread serves many evals
         # at once); chain gap-attribution picks them up by time overlap,
-        # like the raft spans
-        TRACER.add_span("solver.launch", born - (t_f1 - inf.t0), born,
+        # like the raft spans. solver.launch is dispatch start -> fetch
+        # end on the clock of the live solver.dispatch / solver.fetch
+        TRACER.add_span("solver.launch", inf.t0, born,
                         g=g, joint=bool(inf.joint), sharded=inf.sharded,
                         pipelined=pipelined)
         if inf.sharded:
-            TRACER.add_span("solver.allgather", born - (t_f1 - t_f0),
-                            born, gathers=allg,
+            TRACER.add_span("solver.allgather", t_f0, born, gathers=allg,
                             per_eval=allg / max(g, 1))
         with self._lock:
             # counters share self._lock with the ledger: solve()/confirm()
             # mutate stats from API threads under the same lock
             self.stats["launches"] += 1
             self.stats["solves"] += g
-            # host cost only: dispatch + fetch, NOT the device wait a
-            # pipelined launch absorbed while the host worked elsewhere
-            self.stats["launch_s"] += ((inf.t_dispatched - inf.t0)
-                                       + (t_f1 - t_f0))
-            self.stats["overlap_s"] += overlap
-            self.stats["busy_s"] += busy
             self.stats["allgathers"] += allg
             if pipelined:
                 self.stats["pipelined"] += 1
@@ -935,8 +930,6 @@ class BulkSolverService:
                 self._ledger[r.token] = _LedgerEntry(
                     inf.static, idx, row[idx].astype(np.int64), r.ask,
                     born)
-            occupancy = (self.stats["overlap_s"] / self.stats["busy_s"]
-                         if self.stats["busy_s"] > 0 else 0.0)
         # mirror the service stats into the Registry so /v1/metrics and
         # bench dumps carry them without reaching into the singleton
         # (REGISTRY is a leaf lock — taken after self._lock is dropped)
@@ -944,7 +937,6 @@ class BulkSolverService:
         REGISTRY.incr("nomad.solver.solves", g)
         if allg:
             REGISTRY.incr("nomad.solver.allgathers", allg)
-        REGISTRY.set_gauge("nomad.solver.overlap_occupancy", occupancy)
         if info_np is not None:
             REGISTRY.incr("nomad.solver.auction_won",
                           int(info_np[5] > 0.5))

@@ -216,10 +216,8 @@ def test_double_buffer_exact_fill_under_slow_apply(monkeypatch,
         assert not svc._ledger, dict(svc._ledger)
     assert svc.stats["resyncs"] >= 2, svc.stats
     # the double buffer engaged: at least one launch was fetched AFTER
-    # its successor was dispatched, and host time ran under device time
+    # its successor was dispatched
     assert svc.stats["pipelined"] >= 1, svc.stats
-    assert svc.stats["overlap_s"] > 0.0, svc.stats
-    assert svc.stats["busy_s"] >= svc.stats["overlap_s"]
 
 
 def test_inflight_drained_before_resync(monkeypatch, eight_devices):

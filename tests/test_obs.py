@@ -82,6 +82,40 @@ class TestTracer:
         # newest survive
         assert [s[R_NAME] for s in spans] == [f"s{i}" for i in range(12, 20)]
 
+    def test_dropped_counts_a_wrapped_ring(self):
+        tr = Tracer(enabled=True, ring_cap=4)
+        for _ in range(3):
+            _span(tr, "s")
+        assert tr.dropped == 0            # not full yet
+        for _ in range(7):
+            _span(tr, "s")
+        assert len(tr.spans()) == 4 and tr.dropped == 6
+        other = threading.Thread(
+            target=lambda: [_span(tr, "o") for _ in range(5)])
+        other.start()
+        other.join(timeout=10)
+        assert not other.is_alive()
+        assert tr.dropped == 7            # summed over the rings
+        tr.clear()
+        assert tr.dropped == 0
+
+    def test_cpu_seconds_of_a_sleeping_and_a_spinning_span(self):
+        tr = Tracer(enabled=True)
+        with tr.span("sleeps", cpu=True):
+            time.sleep(0.1)
+        with tr.span("spins", cpu=True):
+            t_end = time.thread_time() + 0.05
+            while time.thread_time() < t_end:
+                pass
+        _span(tr, "plain")
+        sleeps, spins, plain = tr.spans()
+        # off-CPU time is wall less cpu_s: all of a sleep, none of a spin
+        assert sleeps[R_ARGS]["cpu_s"] < 0.02
+        assert sleeps[R_T1] - sleeps[R_T0] >= 0.1
+        assert 0.05 <= spins[R_ARGS]["cpu_s"] \
+            <= spins[R_T1] - spins[R_T0] + 0.005
+        assert "cpu_s" not in plain[R_ARGS]
+
     def test_event_and_add_span(self):
         tr = Tracer(enabled=True)
         tr.event("e", trace="t", job="j1")
@@ -230,6 +264,7 @@ class TestExport:
         doc = json.load(open(path))
         assert doc["traceEvents"][0]["name"] == "a"
         assert doc["otherData"]["phases"]["a"]["count"] == 1
+        assert doc["otherData"]["dropped"] == 0
 
 
 class TestLiveTracing:
@@ -264,6 +299,7 @@ class TestLiveTracing:
                 body = json.loads(r.read())
             assert body["enabled"] is True
             assert body["total_spans"] == len(spans)
+            assert body["dropped"] == 0
             assert 0 < len(body["trace"]["traceEvents"]) <= 50
             assert body["phases"]["worker.schedule"]["count"] >= 1
             # the span histograms surfaced in /v1/metrics too
