@@ -504,6 +504,7 @@ def leg_c2m(run: Run, agent, api) -> None:
 
 def leg_service(run: Run, agent, api) -> None:
     """B: the service job users write, on the per-placement tier."""
+    from nomad_tpu.core.metrics import REGISTRY
     from nomad_tpu.obs import TRACER
     from nomad_tpu.obs.trace import R_NAME
     from nomad_tpu.structs import Spread
@@ -529,20 +530,25 @@ def leg_service(run: Run, agent, api) -> None:
     warm_mark = run.watch.mark()
     TRACER.clear()
     tail = ledger_tail()
+    staged0 = REGISTRY.get("nomad.placer.staged_solves")
     jobs = service_jobs(f"svc-{run.seed}", z.service_jobs)
     submit(api, jobs)
     wall = drain(server, jobs)
-    solves = [rec for rec in TRACER.spans() if rec[R_NAME] == "worker.solve"]
+    names = [rec[R_NAME] for rec in TRACER.spans()]
+    solves, stages = names.count("worker.solve"), names.count("placer.stage")
+    staged = int(REGISTRY.get("nomad.placer.staged_solves") - staged0)
     fused = launches_since(tail, "solve_task_group_fused")
     run.say(leg, jobs=z.service_jobs, allocs=z.service_jobs * z.service_count,
-            drain_wall_s=round(wall, 3), worker_solve_spans=len(solves),
-            fused_launches=fused)
+            drain_wall_s=round(wall, 3), worker_solve_spans=solves,
+            fused_launches=fused, staged_solves=staged)
     check_cluster(run, leg, server, warm + jobs, z.service_count)
     evals_settled(api, jobs)
     # HOST_CUTOVER must not have eaten the launch: every job's group went
-    # through a worker.solve span that opened a fused launch window
-    assert len(solves) >= z.service_jobs and fused >= z.service_jobs, \
-        (len(solves), fused)
+    # through a worker.solve span that opened a fused launch window, its
+    # static arguments staged on the device before the lock (usage apart)
+    assert solves >= z.service_jobs and fused >= z.service_jobs, \
+        (solves, fused)
+    assert staged == stages == solves, (staged, stages, solves)
     run.counted(leg, cold_mark, warm_mark, led,
                 warmed=("solve_task_group_fused",))
     run.check_threads(leg)

@@ -215,7 +215,8 @@ class ClusterTensors:
         self._used_shared = False
         return u
 
-    def refresh_usage(self, ctx: EvalContext) -> None:
+    def refresh_usage(self, ctx: EvalContext,
+                      out: Optional[np.ndarray] = None) -> None:
         """Proposed usage (state - evictions + placements). Base usage is
         one fancy-index gather from the store's dense usage matrix when
         available (latest-committed state: fresher than the snapshot,
@@ -223,7 +224,15 @@ class ClusterTensors:
         re-verifies), else O(nodes) snapshot rows. Only nodes the
         in-progress plan touches are recomputed from ctx.proposed_allocs
         (reference context.go:176 ProposedAllocs). Called between task
-        groups so group B sees group A's in-plan placements."""
+        groups so group B sees group A's in-plan placements.
+
+        `out` is the per-placement tier's gather under
+        _PER_EVAL_SOLVE_LOCK: a caller-owned (n_pad, D) f32 buffer,
+        allocated before the lock, filled here in one cast copy and
+        shipped as it is; it becomes `used` for every later reader.
+        Resource vectors are whole MHz / MB / port counts, exact in f32
+        below 2**24 (latest_usage folds in f32 on the same ground);
+        a plan-touched row is still summed in f64 and cast once."""
         snap = ctx.snapshot
         n = len(self.nodes)
         plan = ctx.plan
@@ -243,14 +252,20 @@ class ClusterTensors:
             feed = feed_for(self._store)
             if feed is not None:
                 base = feed.base_for(self.static)
+        if out is not None:
+            self.used, self._used_shared = out, False
         if base is not None:
-            if not touched and not INFLIGHT.has_entries(
+            if out is not None:
+                np.copyto(out, base)
+                used = out
+            elif not touched and not INFLIGHT.has_entries(
                     exclude_plan=ctx.plan):
                 self.used = base
                 self._used_shared = True
                 return
-            used = self.used = base.copy()
-            self._used_shared = False
+            else:
+                used = self.used = base.copy()
+                self._used_shared = False
         else:
             used = self._ensure_private()
             rows = (self.static.usage_rows if self.static is not None
@@ -269,10 +284,11 @@ class ClusterTensors:
                 i = self.node_index.get(node_id)
                 if i is None:
                     continue
-                used[i] = 0.0
+                row = np.zeros(RESOURCE_DIMS)
                 for a in ctx.proposed_allocs(node_id):
                     if a.should_count_for_usage():
-                        used[i] += a.allocated_vec
+                        row += a.allocated_vec
+                used[i] = row
         # other racing evals' in-flight (solved, not yet committed)
         # placements: fold LAST so this solve plans around them instead
         # of colliding on the same best-fit nodes (tensor/overlay.py;

@@ -407,14 +407,23 @@ def solve_task_group(
 # ---------------------------------------------------------------------------
 #
 # A small solve is bound by its launch's fixed dispatch + readback cost,
-# not by FLOPs, so the fused entry point packs the 20 logical arguments
-# into 8 arrays and returns one packed output: a whole task-group solve
-# costs one upload batch and one readback. How much that saves was
+# not by FLOPs, so the fused entry point takes the 20 logical arguments
+# as 9 arrays (usage and 8 packed ones) and returns one packed output:
+# a whole task-group solve costs two upload batches, one of them off
+# the critical path, and one readback. How much that saves was
 # judged in an earlier environment; not measured on the current chip
 # (ROADMAP D3).
 #
-# node_mat (N, 2D+6): avail[D] | used[D] | placed_tg | placed_job | feasible
-#                     | affinity | dev_affinity | tie_perm
+# The arguments are split by who can change them. `used` is the one
+# input a racing evaluation moves (through the in-flight overlay and
+# the feed's base), so it travels alone and is gathered under the
+# placer's lock; everything pack_solve_args packs is fixed once the
+# task group's tensors are built and is on the device before the lock
+# is taken (placer.stage).
+#
+# used (N, D): proposed usage, f32
+# node_mat (N, D+6): avail[D] | placed_tg | placed_job | feasible
+#                    | affinity | dev_affinity | tie_perm
 # step_mat (K, 2):  penalty_idx | active
 # spread_node (2S, N): val_id rows then val_ok rows
 # spread_tab (2S, V):  counts rows then desired rows
@@ -424,14 +433,15 @@ def solve_task_group(
 # scalars (5+D,): lowest_boost | tg_count | dh_job | dh_tg | spread_alg | ask[D]
 
 
-def pack_solve_args(available, used0, placed_tg0, placed_job0, ask, feasible,
+def pack_solve_args(available, placed_tg0, placed_job0, ask, feasible,
                     affinity_boost, penalty_idx, active, spread_val_id,
                     spread_val_ok, spread_counts0, spread_desired,
                     spread_has_targets, spread_weight, lowest_boost0,
                     tg_count, dh_job, dh_tg, spread_alg,
                     dev_affinity=None, dp_val_id=None, dp_val_ok=None,
                     dp_counts0=None, dp_limit=None, tie_perm=None):
-    """Host-side packing (numpy) for solve_task_group_fused."""
+    """Host-side packing (numpy) of solve_task_group_fused's static
+    arguments: all of them but `used`."""
     import numpy as np
 
     f = np.float32
@@ -441,7 +451,7 @@ def pack_solve_args(available, used0, placed_tg0, placed_job0, ask, feasible,
     if tie_perm is None:
         tie_perm = np.arange(n)
     node_mat = np.concatenate([
-        np.asarray(available, f), np.asarray(used0, f),
+        np.asarray(available, f),
         np.asarray(placed_tg0, f)[:, None], np.asarray(placed_job0, f)[:, None],
         np.asarray(feasible, f)[:, None], np.asarray(affinity_boost, f)[:, None],
         np.asarray(dev_affinity, f)[:, None], np.asarray(tie_perm, f)[:, None],
@@ -471,19 +481,19 @@ def pack_solve_args(available, used0, placed_tg0, placed_job0, ask, feasible,
 
 
 @jax.jit
-def solve_task_group_fused(node_mat, step_mat, spread_node, spread_tab,
+def solve_task_group_fused(used, node_mat, step_mat, spread_node, spread_tab,
                            spread_meta, dp_node, dp_tab, scalars):
     """Transfer-fused solve: unpack on device, run the same scan, return
     one (3, K) array of [choice, found, score] rows."""
     s = spread_meta.shape[0]
     p = dp_node.shape[0] // 2
-    d = (node_mat.shape[1] - 6) // 2
+    d = used.shape[1]
     choices, founds, scores = solve_task_group(
-        node_mat[:, 0:d], node_mat[:, d:2 * d],
-        node_mat[:, 2 * d].astype(jnp.int32),
-        node_mat[:, 2 * d + 1].astype(jnp.int32),
-        scalars[5:5 + d], node_mat[:, 2 * d + 2] > 0.5, node_mat[:, 2 * d + 3],
-        node_mat[:, 2 * d + 4],
+        node_mat[:, 0:d], used,
+        node_mat[:, d].astype(jnp.int32),
+        node_mat[:, d + 1].astype(jnp.int32),
+        scalars[5:5 + d], node_mat[:, d + 2] > 0.5, node_mat[:, d + 3],
+        node_mat[:, d + 4],
         step_mat[:, 0].astype(jnp.int32), step_mat[:, 1] > 0.5,
         spread_node[:s].astype(jnp.int32), spread_node[s:] > 0.5,
         spread_tab[:s].astype(jnp.int32), spread_tab[s:],
@@ -492,7 +502,7 @@ def solve_task_group_fused(node_mat, step_mat, spread_node, spread_tab,
         dp_tab[:, :-1].astype(jnp.int32), dp_tab[:, -1],
         scalars[0], scalars[1], scalars[2] > 0.5, scalars[3] > 0.5,
         scalars[4] > 0.5,
-        node_mat[:, 2 * d + 5].astype(jnp.int32),
+        node_mat[:, d + 5].astype(jnp.int32),
     )
     return jnp.stack([choices.astype(scores.dtype),
                       founds.astype(scores.dtype), scores])

@@ -11,9 +11,11 @@ node lists, and the spread rung's rejection rate ran ABOVE stock
 decorrelates workers by accident).
 
 This overlay is the host-side twin of the service's ledger: each
-per-eval solve registers its placements' per-node usage deltas keyed by
-node ID; every ClusterTensors usage gather folds the open entries in,
-so the NEXT racing eval plans around them. Entries close through the
+per-eval solve registers its placements' per-node usage deltas as the
+rows of its cluster they land on; every ClusterTensors usage gather
+folds the open entries in (one indexed add an entry while the row
+order is the one it was registered against, by node ID otherwise), so
+the NEXT racing eval plans around them. Entries close through the
 same plan post-apply hooks the service uses (confirmed usage is then in
 the store; rejected nodes' deltas die with the entry), with a TTL
 backstop for evals that die between solve and submit. Like the carry,
@@ -27,6 +29,8 @@ import threading
 import time
 from typing import Dict
 
+import numpy as np
+
 ENTRY_TTL = 60.0
 
 
@@ -37,18 +41,23 @@ class InflightOverlay:
         self._token = 0
         self.stats = {"registered": 0, "confirmed": 0, "expired": 0}
 
-    def register(self, deltas: Dict[str, object], plan) -> None:
-        """Record one eval's in-flight per-node usage deltas
-        ({node_id: vec}) and arrange for the plan outcome to close the
-        entry (planner contract: hooks fire with the commit)."""
-        if not deltas:
+    def register(self, cluster, rows, deltas, plan) -> None:
+        """Record one eval's in-flight usage: `deltas[i]` (a resource
+        vector) on row `rows[i]` of `cluster` (a ClusterTensors; each
+        row once), and arrange for the plan outcome to close the entry
+        (planner contract: hooks fire with the commit). The entry keeps
+        its rows for the row order it was registered against, so a fold
+        into that order is one indexed add."""
+        if not len(rows):
             return
         now = time.time()
         with self._lock:
             self._token += 1
             token = self._token
-            self._entries[token] = {"deltas": deltas, "born": now,
-                                    "plan": id(plan)}
+            self._entries[token] = {
+                "rows": rows, "deltas": deltas, "nodes": cluster.nodes,
+                "node_index": cluster.node_index, "born": now,
+                "plan": id(plan)}
             self.stats["registered"] += 1
         if plan is not None:
             plan.post_apply_hooks.append(
@@ -99,10 +108,15 @@ class InflightOverlay:
                        if e.get("plan") != exclude or exclude is None]
         d = used.shape[1]
         for e in entries:
-            for node_id, vec in e["deltas"].items():
-                row = node_index.get(node_id)
-                if row is not None:
-                    used[row] += vec[:d]
+            rows, deltas = e["rows"], e["deltas"][:, :d]
+            if e["node_index"] is not node_index:
+                # another row order (a node joined or left since): find
+                # each node's row by id
+                nodes = e["nodes"]
+                at = np.array([node_index.get(nodes[r].id, -1)
+                               for r in rows], dtype=np.int64)
+                rows, deltas = at[at >= 0], deltas[at >= 0]
+            used[rows] += deltas
 
 
 INFLIGHT = InflightOverlay()

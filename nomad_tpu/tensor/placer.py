@@ -365,6 +365,14 @@ class TPUPlacer:
                 if req.ignore_node:
                     penalty_idx[i] = cluster.node_index.get(req.ignore_node, -1)
 
+            # Everything the solve reads that no racing evaluation can
+            # change is packed and put on the device here, while the
+            # worker would otherwise only wait for the lock: the hold
+            # below is left with what depends on other evaluations'
+            # placements (the usage gather) and the launch itself.
+            staged = self._stage_statics(tgt, cluster, penalty_idx, active,
+                                         tie_perm)
+
             # The usage gather -> solve -> in-flight registration runs
             # as ONE critical section across racing workers: the device
             # serializes launches anyway, and without this ordering two
@@ -389,8 +397,7 @@ class TPUPlacer:
                     _PER_EVAL_SOLVE_LOCK.acquire()
                 try:
                     choices, founds, scores = self._solve_locked(
-                        ctx, tg, tgt, cluster, reqs, k_pad, penalty_idx,
-                        active, tie_perm)
+                        ctx, tg, cluster, reqs, k_pad, staged)
                 finally:
                     _PER_EVAL_SOLVE_LOCK.release()
 
@@ -510,59 +517,80 @@ class TPUPlacer:
         return all(req.previous_alloc is None and not req.ignore_node
                    and not req.canary for req in reqs)
 
-    def _solve_locked(self, ctx, tg, tgt, cluster, reqs, k_pad,
-                      penalty_idx, active, tie_perm):
+    def _stage_statics(self, tgt, cluster, penalty_idx, active, tie_perm):
+        """Pack the fused solve's static arguments and put them on the
+        device, before _PER_EVAL_SOLVE_LOCK is taken: capacity, this
+        job's own placement counts, feasibility, affinities, the tie
+        permutation, the step, spread and distinct_property tables and
+        the scalars are all fixed once the group's tensors are built.
+        -> (device arrays, usage buffer, extra usage columns or None).
+        The usage buffer is this group's (n_pad, D) f32 gather target,
+        allocated here so that the hold does not; the extra columns
+        (device / core counts, this evaluation's own view) extend it
+        under placer.pack."""
+        import jax
+
+        from ..core.metrics import REGISTRY
+        from .kernels import pack_solve_args
+
+        with TRACER.span("placer.stage", device=True) as span:
+            extra_used = None
+            avail, ask = cluster.available, tgt.ask
+            if tgt.extra_ask is not None and len(tgt.extra_ask):
+                # device/core count columns extend the dense dims
+                avail = np.concatenate([avail, tgt.extra_cap], axis=1)
+                ask = np.concatenate([ask, tgt.extra_ask])
+                extra_used = np.asarray(tgt.extra_used, np.float32)
+            packed = pack_solve_args(
+                avail, tgt.placed_tg, tgt.placed_job, ask, tgt.feasible,
+                tgt.affinity_boost, penalty_idx, active,
+                tgt.spread_val_id, tgt.spread_val_ok, tgt.spread_counts,
+                tgt.spread_desired, tgt.spread_has_targets,
+                tgt.spread_weight,
+                -1.0, tgt.tg_count, tgt.dh_job, tgt.dh_tg, tgt.spread_alg,
+                dev_affinity=tgt.dev_affinity,
+                dp_val_id=tgt.dp_val_id, dp_val_ok=tgt.dp_val_ok,
+                dp_counts0=tgt.dp_counts, dp_limit=tgt.dp_limit,
+                tie_perm=tie_perm)
+            span.set(bytes=sum(a.nbytes for a in packed))
+            dev = jax.device_put(packed)
+            usage_buf = np.empty(cluster.available.shape, np.float32)
+        REGISTRY.incr("nomad.placer.staged_solves")
+        return dev, usage_buf, extra_used
+
+    def _solve_locked(self, ctx, tg, cluster, reqs, k_pad, staged):
         """One evaluation's usage gather -> solve -> in-flight
-        registration; the caller holds _PER_EVAL_SOLVE_LOCK. Returns
+        registration; the caller holds _PER_EVAL_SOLVE_LOCK and staged
+        the static arguments (_stage_statics) before taking it. Returns
         (choices, founds, scores) per request."""
         import jax
 
-        from .kernels import pack_solve_args, solve_task_group_fused
+        from .kernels import solve_task_group_fused
         from .overlay import INFLIGHT
 
+        statics, usage, extra_used = staged
         k = len(reqs)
         with TRACER.span("placer.locked", cpu=True, device=True, k=k,
                          k_pad=k_pad, n_pad=cluster.n_pad):
             with TRACER.span("placer.gather", device=True):
-                cluster.refresh_usage(ctx)
-                # device/core count columns extend the dense dims
-                has_extra = tgt.extra_ask is not None and len(tgt.extra_ask)
-                if has_extra:
-                    avail = np.concatenate([cluster.available, tgt.extra_cap],
-                                           axis=1)
-                    used = np.concatenate([cluster.used, tgt.extra_used],
-                                          axis=1)
-                    ask = np.concatenate([tgt.ask, tgt.extra_ask])
-                else:
-                    avail, used, ask = (cluster.available, cluster.used,
-                                        tgt.ask)
+                cluster.refresh_usage(ctx, out=usage)
 
             with TRACER.span("placer.pack", device=True):
-                packed = pack_solve_args(
-                    avail, used, tgt.placed_tg, tgt.placed_job,
-                    ask, tgt.feasible, tgt.affinity_boost, penalty_idx,
-                    active,
-                    tgt.spread_val_id, tgt.spread_val_ok, tgt.spread_counts,
-                    tgt.spread_desired, tgt.spread_has_targets,
-                    tgt.spread_weight,
-                    -1.0, tgt.tg_count, tgt.dh_job, tgt.dh_tg, tgt.spread_alg,
-                    dev_affinity=tgt.dev_affinity,
-                    dp_val_id=tgt.dp_val_id, dp_val_ok=tgt.dp_val_ok,
-                    dp_counts0=tgt.dp_counts, dp_limit=tgt.dp_limit,
-                    tie_perm=tie_perm)
+                if extra_used is not None:
+                    usage = np.concatenate([usage, extra_used], axis=1)
 
             # explicit shipment + shape-keyed window. ship is the
             # enqueue of the transfer and of the launch; device_wait is
             # what is left of the transfer, the launch latency and the
             # scan; the device_get of the ready result stays the
             # launch's one readback
-            fused_key = tuple(np.shape(a) for a in packed)
+            fused_key = (usage.shape,) + tuple(a.shape for a in statics)
             with _warm_launch(solve_task_group_fused, fused_key,
                               _FUSED_WARM):
                 with TRACER.span("placer.ship", device=True,
-                                 bytes=sum(a.nbytes for a in packed)):
-                    dev = jax.device_put(packed)
-                    res = solve_task_group_fused(*dev)
+                                 bytes=usage.nbytes):
+                    res = solve_task_group_fused(jax.device_put(usage),
+                                                 *statics)
                 with TRACER.span("placer.device_wait", device=True):
                     # the wait is split from the readback so the trace
                     # tells launch latency + scan from the copy back
@@ -574,17 +602,12 @@ class TPUPlacer:
                     founds = out[1] > 0.5
                     scores = out[2]
             with TRACER.span("placer.register", device=True):
-                if ctx.plan is not None and founds.any():
-                    vec = ctx.tg_vec(tg)
-                    kernel_counts: Dict[int, int] = {}
-                    for i in range(k):
-                        if founds[i]:
-                            ni = int(choices[i])
-                            kernel_counts[ni] = kernel_counts.get(ni, 0) + 1
+                if ctx.plan is not None:
+                    rows, counts = np.unique(choices[founds],
+                                             return_counts=True)
                     INFLIGHT.register(
-                        {cluster.nodes[ni].id: vec * c
-                         for ni, c in kernel_counts.items()},
-                        ctx.plan)
+                        cluster, rows,
+                        counts[:, None] * ctx.tg_vec(tg)[None, :], ctx.plan)
         return choices, founds, scores
 
     def _bulk_shape_ok(self, ctx, tg, tgt) -> bool:

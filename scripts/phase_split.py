@@ -11,6 +11,10 @@ the TRACER records the harness handed its readers and the profiler's
 `[phase_split]` JSON line:
 
 - per span name: count, median, total and longest (ms);
+- placer.stage, the packing and shipping that left the lock: median,
+  total, the share of it that ran while another thread was inside
+  placer.locked, and nomad.placer.staged_solves beside the count of
+  placer.locked spans over the whole process;
 - per evaluation: placer.lock_wait + placer.locked over worker.solve,
   and the six children over placer.locked (the least share seen);
 - placer.locked wall against its `cpu_s`; plan.commit_round the same;
@@ -45,7 +49,7 @@ LOCKED_CHILDREN = ("placer.gather", "placer.pack", "placer.ship",
                    "placer.device_wait", "placer.fetch", "placer.register")
 PROGRAM = "jit_solve_task_group_fused"
 # record layout of nomad_tpu.obs.trace
-NAME, PARENT, ID, T0, T1, ARGS = 0, 2, 3, 4, 5, 7
+NAME, PARENT, ID, T0, T1, THREAD, ARGS = 0, 2, 3, 4, 5, 6, 7
 
 ADMIT = {
     "c2m.backlog": {
@@ -146,6 +150,38 @@ def handover(records) -> dict:
     return {"n": len(gaps), "median_ms": ms(statistics.median(gaps)),
             "total_ms": ms(sum(gaps)), "longest_ms": ms(max(gaps)),
             "negative": sum(1 for g in gaps if g < 0)}
+
+
+def stage_overlap(records) -> dict:
+    """placer.stage beside the holds of the other threads: how much of
+    the staging ran while somebody else held the lock."""
+    stages = [r for r in records if r[NAME] == "placer.stage"]
+    held = [r for r in records if r[NAME] == "placer.locked"]
+    if not stages:
+        return {}
+    total = sum(dur(r) for r in stages)
+    # holds never overlap one another, so the parts add up
+    under = sum(max(0.0, min(s[T1], h[T1]) - max(s[T0], h[T0]))
+                for s in stages for h in held if h[THREAD] != s[THREAD])
+    return {"n": len(stages), "locked_spans": len(held),
+            "median_ms": ms(statistics.median(dur(r) for r in stages)),
+            "total_ms": ms(total), "longest_ms": ms(max(map(dur, stages))),
+            "under_another_hold_ms": ms(under),
+            "under_another_hold_pct": round(100 * under / total, 2)
+            if total else None,
+            "bytes": stages[0][ARGS].get("bytes")}
+
+
+def staged_against_locked() -> dict:
+    """Over the whole process, warm-up included: evaluations staged
+    before the lock against holds of the lock (the tracer counts every
+    span it closes into the registry)."""
+    from nomad_tpu.core.metrics import REGISTRY
+
+    held = REGISTRY.dump().get("nomad.eval.phase.placer.locked") or {}
+    return {"nomad.placer.staged_solves":
+            REGISTRY.get("nomad.placer.staged_solves"),
+            "placer.locked": held.get("count")}
 
 
 def wall_against_cpu(records, name: str):
@@ -285,6 +321,8 @@ def main() -> int:
              "spans": span_table(records),
              "coverage": coverage(records, kids),
              "handover": handover(records),
+             "placer.stage": stage_overlap(records),
+             "staged_against_locked": staged_against_locked(),
              "placer.locked": wall_against_cpu(records, "placer.locked"),
              "plan.commit_round": wall_against_cpu(records,
                                                    "plan.commit_round"),

@@ -1,6 +1,7 @@
 """The phase spans inside the two critical sections: `worker.solve`
 under the placer's one lock (placer.lock_wait, placer.locked and its six
-children) and `plan.commit_round` (store.lock_wait / apply / publish and
+children; placer.stage before it, outside the lock) and
+`plan.commit_round` (store.lock_wait / apply / publish and
 a store.listener a commit listener); that they share a clock with the
 jax profiler's trace; and the counters and waits added beside them."""
 
@@ -8,6 +9,7 @@ import os
 import statistics
 import subprocess
 import sys
+import threading
 import time
 import warnings
 
@@ -89,7 +91,77 @@ def spread_server():
         s.stop()
 
 
+@pytest.fixture
+def stage_inside_a_hold(monkeypatch):
+    """Orders the race: the first holder of _PER_EVAL_SOLVE_LOCK stays
+    in its hold (at the in-flight registration) until another worker
+    has staged its statics, and no other worker stages before that hold
+    is open. A stage that needed the lock would time out here."""
+    from nomad_tpu.tensor.overlay import InflightOverlay
+    from nomad_tpu.tensor.placer import TPUPlacer
+
+    stage, register = TPUPlacer._stage_statics, InflightOverlay.register
+    first_stage, first_hold = threading.Lock(), threading.Lock()
+    held, other_staged = threading.Event(), threading.Event()
+
+    def staged(self, *args):
+        if first_stage.acquire(blocking=False):
+            return stage(self, *args)
+        assert held.wait(60.0)
+        try:
+            return stage(self, *args)
+        finally:
+            other_staged.set()
+
+    def registered(self, *args):
+        if first_hold.acquire(blocking=False):
+            held.set()
+            assert other_staged.wait(60.0)
+        register(self, *args)
+
+    monkeypatch.setattr(TPUPlacer, "_stage_statics", staged)
+    monkeypatch.setattr(InflightOverlay, "register", registered)
+
+
 class TestPlacerPhases:
+    def test_stage_precedes_worker_solve_outside_the_lock(
+            self, spread_server):
+        _, spans = spread_server
+        solves = [r for r in spans if r[R_NAME] == "worker.solve"]
+        stages = [r for r in spans if r[R_NAME] == "placer.stage"]
+        locked = [r for r in spans if r[R_NAME] == "placer.locked"]
+        assert len(stages) == len(solves) == len(locked) >= 4
+        for solve in solves:
+            stage = max((r for r in stages
+                         if r[R_THREAD] == solve[R_THREAD]
+                         and r[R_T1] <= solve[R_T0]), key=lambda r: r[R_T1])
+            # a sibling that comes just before, not a child
+            assert stage[R_PARENT] == solve[R_PARENT]
+            assert stage[R_TRACE] == solve[R_TRACE]
+            assert stage[R_ARGS]["bytes"] > 0
+            assert not [r for r in spans
+                        if r[R_THREAD] == solve[R_THREAD]
+                        and r[R_NAME] in ("worker.solve", "placer.stage")
+                        and stage[R_T1] <= r[R_T0] < solve[R_T0]]
+            # the hold ships the usage matrix alone, less than the stage
+            hold, = [r for r in locked if r[R_PARENT] == solve[R_ID]]
+            ship = _children(spans, hold)[2]
+            assert 0 < ship[R_ARGS]["bytes"] < stage[R_ARGS]["bytes"]
+
+    def test_a_waiting_workers_stage_overlaps_another_threads_hold(
+            self, stage_inside_a_hold, spread_server):
+        _, spans = spread_server
+        locked = [r for r in spans if r[R_NAME] == "placer.locked"]
+        overlaps = [(s, h) for s in spans if s[R_NAME] == "placer.stage"
+                    for h in locked
+                    if h[R_THREAD] != s[R_THREAD]
+                    and h[R_T0] <= s[R_T0] and s[R_T1] <= h[R_T1]]
+        assert overlaps
+        # and the holds still follow one another
+        locked.sort(key=lambda r: r[R_T0])
+        for a, b in zip(locked, locked[1:]):
+            assert a[R_T1] <= b[R_T0], (a, b)
+
     def test_phases_nest_under_worker_solve_and_cover_it(self, spread_server):
         _, spans = spread_server
         solves = [r for r in spans if r[R_NAME] == "worker.solve"]
@@ -237,7 +309,8 @@ class TestOneClockWithTheProfiler:
         (c0, _, stats), = events["test.clock"]
         offset = float(stats["t"]) - c0   # trace seconds -> wall
         records = {r[R_NAME]: r for r in TRACER.spans()}
-        for name in ["placer.lock_wait", "placer.locked"] + LOCKED_CHILDREN:
+        for name in (["placer.stage", "placer.lock_wait", "placer.locked"]
+                     + LOCKED_CHILDREN):
             (e0, e1, _), = events[name]
             rec = records[name]
             assert abs(e0 + offset - rec[R_T0]) < 0.002, name
