@@ -949,18 +949,24 @@ class HTTPAgent:
             return h._reply(200, {"members": [
                 {"name": "local", "status": "alive", "meta": {}}]})
         if path == "/v1/agent/self":
-            from ..tensor.backend import device
-            from ..tensor.solver import get_service
+            if self.server.workers:
+                from ..tensor.backend import device
+                from ..tensor.solver import get_service
 
+                # which device the placement solves run on, and what
+                # the solver service did there
+                dev, solver = device().as_dict(), dict(get_service().stats)
+            else:
+                # a server that never schedules (--workers 0) resolved no
+                # backend, and asking for one here would open the device
+                dev, solver = {"platform": "none", "kind": "", "count": 0}, {}
             return h._reply(200, {
                 "stats": {
                     "broker": self.server.broker.stats,
                     "plan_applier": self.server.plan_applier.stats,
                     "blocked_evals": self.server.blocked.blocked_count(),
-                    # which device the placement solves run on, and what
-                    # the solver service did there
-                    "device": device().as_dict(),
-                    "solver": dict(get_service().stats),
+                    "device": dev,
+                    "solver": solver,
                 },
                 "version": "0.1.0",
             })

@@ -53,7 +53,13 @@ class Agent:
     """What `agent` serves, started: the scheduling server (replicated
     when `--peers` is given), its HTTP agent and local clients, on the
     backend the bootstrap resolved. `cmd_agent` runs it until a signal;
-    `chip_smoke.py` drives the same object."""
+    `chip_smoke.py` drives the same object.
+
+    `--workers 0` is a server that makes no scheduling decision
+    (upstream's `num_schedulers = 0`): it votes, replicates and serves
+    reads, resolves no backend and opens no device, and says
+    `device=none` on its start line. On a host with one chip that is
+    every server but the one that schedules."""
 
     def __init__(self, args):
         from .api.http import HTTPAgent
@@ -61,10 +67,15 @@ class Agent:
         from .core import Server, ServerConfig
         from .structs.operator import SchedulerConfiguration
         from .tensor.backend import bootstrap
+        from .utils import gcpolicy
 
+        # full collector passes walk what is new, not the whole heap
+        gcpolicy.install()
         # before the first compile; a tpu-* algorithm whose backend fell
-        # to the CPU by itself raises here and the agent never starts
-        self.device = bootstrap(args.algorithm)
+        # to the CPU by itself raises here and the agent never starts.
+        # A server without scheduler workers never compiles: it must not
+        # take the chip from the server on this host that does
+        self.device = bootstrap(args.algorithm) if args.workers > 0 else None
         cfg = ServerConfig(
             num_workers=args.workers,
             gossip_key=getattr(args, "gossip_key", "") or "",
@@ -124,7 +135,7 @@ class Agent:
         self.start_line = (
             f"agent started: {self.http.address} "
             f"(workers={args.workers} clients={args.clients} "
-            f"algorithm={args.algorithm} device={self.device}"
+            f"algorithm={args.algorithm} device={self.device or 'none'}"
             + (f" server-id={args.server_id}" if self.replicated else "")
             + ")")
 
@@ -904,7 +915,11 @@ def build_parser() -> argparse.ArgumentParser:
                     help="agent config file (HCL-shaped or .json); "
                          "flags override file values; SIGHUP reloads")
     ag.add_argument("--clients", type=int, default=1)
-    ag.add_argument("--workers", type=int, default=2)
+    ag.add_argument("--workers", type=int, default=2,
+                    help="scheduler workers on this server; 0 makes it a "
+                         "server that never schedules (upstream's "
+                         "num_schedulers = 0): it resolves no backend, "
+                         "opens no device and starts with device=none")
     ag.add_argument("--port", type=int, default=4646)
     ag.add_argument("--algorithm", default="binpack")
     ag.add_argument("--data-dir", default="")

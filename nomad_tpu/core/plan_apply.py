@@ -766,7 +766,9 @@ class PlanApplier:
         self._reverify_stale(plans, prior)
         writers = self._writers_for(entries)
         round_ = {"entries": entries, "plans": plans, "writers": writers,
-                  "prop": None, "error": None}
+                  "prop": None, "error": None, "t0": time.time(),
+                  "cpu_s": 0.0}
+        cpu0 = time.thread_time()
         if writers:
             with TRACER.span("plan.propose", n=len(writers),
                              traces=[e.trace for e in entries if e.trace]):
@@ -791,6 +793,7 @@ class PlanApplier:
                     e.result.node_allocation)
                 conservative.alloc_blocks = list(e.result.alloc_blocks)
                 self._poison(e.cell, conservative)
+        round_["cpu_s"] = time.thread_time() - cpu0
         return round_
 
     def _finish_round(self, round_: dict) -> None:
@@ -808,10 +811,23 @@ class PlanApplier:
                              traces=[e.trace for e in round_["entries"]
                                      if e.trace]):
                 try:
+                    cpu0 = time.thread_time()
                     index = self.store.wait_applied(prop, timeout=30.0)
                     for e, _ in writers:
                         if e.result is not None:
                             e.result.alloc_index = index
+                    # the round as the serialized path's span times it:
+                    # the store transaction, here from its proposal to
+                    # applied. Two threads share it: cpu_s is the
+                    # proposer's CPU seconds over the propose plus this
+                    # thread's over the wait, so wall less cpu_s is the
+                    # wait for the quorum and for the interpreter lock
+                    TRACER.add_span(
+                        "plan.commit_round", round_["t0"], time.time(),
+                        n=len(writers), pipelined=True,
+                        cpu_s=round_["cpu_s"] + time.thread_time() - cpu0,
+                        traces=[e.trace for e in round_["entries"]
+                                if e.trace])
                 except Exception:
                     if self.logger:
                         self.logger.exception(

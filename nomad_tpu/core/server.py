@@ -1182,6 +1182,13 @@ class Server:
 
         from ..structs.job import spec_diff
 
+        if not self.workers:
+            # a server built without scheduler workers resolved no
+            # backend (cli.Agent); running a scheduler here would open
+            # the device behind the back of the server that holds it
+            raise RuntimeError(
+                "this server runs no scheduler (--workers 0); ask a "
+                "server that does for the dry run")
         snap = self.store.snapshot()
         prev = snap.job_by_id(job.id, job.namespace)
         planned = _c.copy(job)

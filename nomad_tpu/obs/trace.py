@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from typing import List, Optional
@@ -60,8 +61,13 @@ _ANNOTATION = None
 
 
 def _annotation(name: str):
+    """A profiler annotation, or None in a process that has not loaded
+    jax: there is no profiler session there to land in, and a server
+    that never schedules must not import jax through a span."""
     global _ANNOTATION
     if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
         from jax.profiler import TraceAnnotation
 
         _ANNOTATION = TraceAnnotation
@@ -172,7 +178,8 @@ class _Span:
         if self._ann:
             # an annotation's event starts when it is built
             self._ann = _annotation(self.name)
-            self._ann.__enter__()
+            if self._ann is not None:
+                self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):

@@ -16,6 +16,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 from ..core import loadctl
+from ..core.heartbeat import HeartbeatPlaneInactive
 from ..core.loadctl import RetryLater
 from ..core.server import Server, ServerConfig
 from ..state import StateStore
@@ -506,33 +507,39 @@ class ReplicatedServer:
         # synchronized polls pile onto the freshly elected leader
         for _ in Retryer(deadline_s=fwd_deadline, base=0.02, cap=0.25,
                          jitter=0.5):
-            if self.is_leader():
-                return getattr(self.server, name)(*args, **kwargs)
-            lid = self.raft.leader_id
-            if lid and lid != self.id:
-                if self._peer_lookup is not None:
-                    peer = self._peer_lookup(lid)
-                    if peer is not None and peer.is_leader():
-                        return getattr(peer.server, name)(*args, **kwargs)
-                elif hasattr(self.transport, "call"):
-                    try:
-                        return self.transport.call(lid, name, args, kwargs)
-                    except RemoteCallError as e:
-                        if e.error_type == "NotLeaderError":
-                            # stale leader hint: wait for the next election
-                            continue
-                        cls = self._WIRE_ERRORS.get(e.error_type)
-                        if cls is not None:
-                            raise cls(str(e)) from e
+            try:
+                if self.is_leader():
+                    return getattr(self.server, name)(*args, **kwargs)
+                lid = self.raft.leader_id
+                peer = (self._peer_lookup(lid) if lid and lid != self.id
+                        and self._peer_lookup is not None else None)
+                if peer is not None and peer.is_leader():
+                    return getattr(peer.server, name)(*args, **kwargs)
+            except HeartbeatPlaneInactive:
+                # elected a moment ago and still establishing: the
+                # heartbeat plane comes up a few statements after the
+                # server counts as running (Server.start)
+                continue
+            if (lid and lid != self.id and self._peer_lookup is None
+                    and hasattr(self.transport, "call")):
+                try:
+                    return self.transport.call(lid, name, args, kwargs)
+                except RemoteCallError as e:
+                    if e.error_type == "NotLeaderError":
+                        # stale leader hint: wait for the next election
+                        continue
+                    cls = self._WIRE_ERRORS.get(e.error_type)
+                    if cls is not None:
+                        raise cls(str(e)) from e
+                    raise
+                except TransportError as e:
+                    # "connection died after the frame left" is NOT
+                    # retriable: the leader may have applied the
+                    # mutation, and these endpoints are not idempotent
+                    # (create_acl_token, register_job evals)
+                    if getattr(e, "maybe_delivered", False):
                         raise
-                    except TransportError as e:
-                        # "connection died after the frame left" is NOT
-                        # retriable: the leader may have applied the
-                        # mutation, and these endpoints are not idempotent
-                        # (create_acl_token, register_job evals)
-                        if getattr(e, "maybe_delivered", False):
-                            raise
-                        # connect failure: definitely not delivered; retry
+                    # connect failure: definitely not delivered; retry
         raise NotLeaderError(self.raft.leader_id)
 
     def __getattr__(self, name: str):

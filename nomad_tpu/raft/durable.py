@@ -273,12 +273,27 @@ class DurableLog:
                     f.truncate(good_offset)
         self._fh = open(self._path, "a")
 
+    @staticmethod
+    def encode_command(command: tuple) -> str:
+        """A command as the text its log line carries. The leader's log
+        writer calls this once a proposal, before the append: the
+        encoding is timed apart from the write and the fsync, and the
+        same text goes to the followers and into their logs."""
+        return json.dumps(wire_encode(list(command)))
+
+    @classmethod
+    def _line(cls, e: Entry) -> str:
+        """An entry's line: the command's text as it came (from the
+        leader's log writer, or over the wire from the leader), encoded
+        here only where nobody has yet."""
+        return '{"index": %d, "term": %d, "command": %s}\n' % (
+            e.index, e.term,
+            e.wire if e.wire is not None else cls.encode_command(e.command))
+
     def _write(self, entries: List[Entry]) -> None:
         _check_fault("log_append", self._path)
         for e in entries:
-            self._fh.write(json.dumps({
-                "index": e.index, "term": e.term,
-                "command": wire_encode(list(e.command))}) + "\n")
+            self._fh.write(self._line(e))
         self._fh.flush()
         if self._fsync:
             os.fsync(self._fh.fileno())
@@ -292,9 +307,7 @@ class DurableLog:
         try:
             with open(tmp, "w") as f:
                 for e in self._entries:
-                    f.write(json.dumps({
-                        "index": e.index, "term": e.term,
-                        "command": wire_encode(list(e.command))}) + "\n")
+                    f.write(self._line(e))
                 f.flush()
                 os.fsync(f.fileno())
             os.replace(tmp, self._path)
@@ -364,7 +377,8 @@ class DurableLog:
             return e
 
     def append_batch(self, term: int, commands: List[tuple],
-                     prev: Optional[Tuple[int, int]] = None
+                     prev: Optional[Tuple[int, int]] = None,
+                     encoded: Optional[List[str]] = None
                      ) -> Optional[List[Entry]]:
         """Group commit: append a whole batch of commands with ONE
         buffered write and ONE fsync — the amortization the leader's
@@ -378,7 +392,9 @@ class DurableLog:
         log. Entries become visible (and replicable) only after the
         fsync returns: memory never claims what disk might lose, and a
         disk fault rolls the whole batch back — the same atomicity
-        contract as append()."""
+        contract as append(). ``encoded`` holds each command's
+        text from ``encode_command``, where the caller made it ahead;
+        the entries keep it (``Entry.wire``) for replication."""
         with self._lock:
             if not self._entries:
                 tail = (self.base_index, self.base_term)
@@ -387,7 +403,8 @@ class DurableLog:
                 tail = (e.index, e.term)
             if prev is not None and tail != tuple(prev):
                 return None
-            batch = [Entry(index=tail[0] + 1 + i, term=term, command=c)
+            batch = [Entry(index=tail[0] + 1 + i, term=term, command=c,
+                           wire=encoded[i] if encoded is not None else None)
                      for i, c in enumerate(commands)]
             before = len(self._entries)
             self._entries.extend(batch)

@@ -5,15 +5,55 @@ boltdb log store; in-memory here, with the same term/index invariants).
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
 
-@dataclass(slots=True)
 class Entry:
-    index: int
-    term: int
-    command: tuple  # (op, payload) — see fsm.py
+    """One log entry. `wire` is the command as the text of its log line
+    (`DurableLog.encode_command`), where some server has made it: the
+    leader's log writer encodes a proposal once, replication ships that
+    text, a follower writes it to its own log as it came, and `command`
+    decodes it on first use, which is the apply thread's, off the path
+    of the acknowledgement."""
+
+    __slots__ = ("index", "term", "_command", "wire")
+
+    def __init__(self, index: int, term: int, command: tuple = None,
+                 wire: Optional[str] = None):
+        self.index = index
+        self.term = term
+        self._command = command   # (op, args, kwargs) — see fsm.py
+        self.wire = wire
+
+    @property
+    def command(self) -> tuple:
+        command = self._command
+        if command is None and self.wire is not None:
+            import json
+
+            from ..structs.wire import wire_decode
+
+            # two threads may both decode: they get equal commands and
+            # either may stay
+            command = self._command = tuple(wire_decode(json.loads(
+                self.wire)))
+        return command
+
+    def is_config(self) -> bool:
+        """A membership entry? Answered from the text where that is all
+        this server has, without decoding it."""
+        if self._command is None and self.wire is not None:
+            return self.wire.startswith('["config"')
+        return tuple(self._command)[:1] == ("config",)
+
+    def __eq__(self, other) -> bool:
+        return (isinstance(other, Entry) and self.index == other.index
+                and self.term == other.term
+                and self.command == other.command)
+
+    def __repr__(self) -> str:
+        return (f"Entry(index={self.index}, term={self.term}, "
+                f"command={self.command!r})")
 
 
 class RaftLog:
@@ -56,7 +96,8 @@ class RaftLog:
             return e
 
     def append_batch(self, term: int, commands: List[tuple],
-                     prev: Optional[Tuple[int, int]] = None
+                     prev: Optional[Tuple[int, int]] = None,
+                     encoded: Optional[List[str]] = None
                      ) -> Optional[List[Entry]]:
         """Append a whole batch in one lock hold (the group-commit
         primitive; DurableLog adds the single-fsync disk write on top).
@@ -67,7 +108,8 @@ class RaftLog:
         it, and any interleaved append — a config entry, a new leader's
         noop, a follower truncation after step-down — fails the
         compare-and-swap instead of landing the batch on a diverged log.
-        Returns None on a CAS mismatch."""
+        Returns None on a CAS mismatch. ``encoded`` (the commands' log
+        text, DurableLog's to write) is unused: this log keeps no bytes."""
         with self._lock:
             if not self._entries:
                 tail = (0, 0)

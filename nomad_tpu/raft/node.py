@@ -76,7 +76,8 @@ class _Proposal:
     whose waiter has already given up instead of burning an fsync slot
     on them (core/loadctl.py deadline propagation)."""
 
-    __slots__ = ("command", "index", "result", "error", "done", "deadline")
+    __slots__ = ("command", "index", "result", "error", "done", "deadline",
+                 "t0", "nbytes")
 
     def __init__(self, command: tuple, deadline: Optional[float] = None):
         self.command = command
@@ -85,6 +86,10 @@ class _Proposal:
         self.error: Optional[BaseException] = None
         self.done = threading.Event()
         self.deadline = deadline
+        # the raft.commit span: enqueue -> the waiter resolved; nbytes
+        # is the command's encoded size where the log is on disk
+        self.t0 = time.time()
+        self.nbytes = 0
 
 
 _loadctl = None
@@ -101,11 +106,65 @@ def _lc():
     return _loadctl
 
 
+# Timer scale (hashicorp/raft's defaults, which upstream Nomad runs at
+# raft_multiplier 1): a follower campaigns after 1-2 s without a word
+# from the leader, the leader's idle heartbeat goes out every 0.1 s and
+# its read lease lasts half the election timeout. The leader shares its
+# interpreter with the scheduler workers, the plan applier and the
+# solver's dispatch; single host phases of 0.12-0.14 s and one gap of a
+# second on one thread are on record under load (PERF.md section 5). At
+# the earlier 0.3 s / 0.05 s one such phase between two heartbeats put
+# a follower within a tick of campaigning, and a campaign deposes the
+# leader whether or not it wins (the candidate's term is higher at the
+# next append). One scale for every deployment, no knob.
+ELECTION_TIMEOUT = 1.0
+HEARTBEAT_INTERVAL = 0.1
+# A leader whose interpreter stands still says nothing, and no timer on
+# the leader's side can help it: one call that keeps the interpreter
+# lock for over a second (on record: the profiler's trace file being
+# parsed, 1.3-2.8 s for 47 MB) silences every heartbeat thread at once.
+# Its process is alive all the same, and the kernel of its machine says
+# so: the raft port still completes a connect. A follower whose election
+# deadline has passed asks that before it campaigns (a campaign deposes
+# the leader whether or not it wins). Port answers: the leader is
+# stalled, not dead, and the follower waits on, for at most this many
+# election timeouts since it last heard from it (upstream's
+# raft_multiplier 5, the scale hashicorp documents for starved servers,
+# here only where there is evidence of life). Connect refused or timed
+# out, the process gone or the machine cut off: it campaigns at once,
+# so a crash is noticed as fast as before. A transport that cannot tell
+# (in process, or under a fault plan) gives no grace.
+LEADER_STALL_GRACE = 5.0
+
+
+def _command_rows(command: tuple) -> int:
+    """Records a command carries, for the raft.encode span: allocations
+    of a plan-results command (rows, and AllocBlock members by count),
+    list entries of a batched upsert, else 1."""
+    if len(command) < 3:
+        return 1    # not an FSM command (tests propose bare tuples)
+    op, args, kwargs = command[:3]
+    if op == "upsert_plan_results_batch" and args:
+        payloads = args[0]
+    elif op == "upsert_plan_results":
+        payloads = [{"result_allocs": args[0] if args
+                     else kwargs.get("result_allocs"),
+                     "alloc_blocks": args[6] if len(args) > 6
+                     else kwargs.get("alloc_blocks")}]
+    else:
+        first = args[0] if args else None
+        return len(first) if isinstance(first, (list, tuple)) else 1
+    return sum(len(p.get("result_allocs") or ())
+               + sum(int(b.counts.sum())
+                     for b in p.get("alloc_blocks") or ())
+               for p in payloads)
+
+
 class RaftNode:
     def __init__(self, node_id: str, peers: List[str], transport,
                  fsm_apply: Callable[[tuple], object],
-                 election_timeout: float = 0.3,
-                 heartbeat_interval: float = 0.05,
+                 election_timeout: float = ELECTION_TIMEOUT,
+                 heartbeat_interval: float = HEARTBEAT_INTERVAL,
                  on_leadership: Optional[Callable[[bool], None]] = None,
                  log=None, stable=None, snapshots=None,
                  fsm_snapshot: Optional[Callable[[], dict]] = None,
@@ -213,6 +272,7 @@ class RaftNode:
         # recovered log wins over the snapshot's
         self._recover_config_from_log_locked()
         self._last_leader_contact = 0.0
+        self._stall_logged = 0.0    # the contact time a stall was logged for
 
         self._snap_inflight: set = set()  # peers mid-install-snapshot
         self._snap_active = False  # a local snapshot worker is running
@@ -241,6 +301,9 @@ class RaftNode:
         # nomadload: the owning server's AdmissionController (set by
         # ReplicatedServer.attach); None = no admission at propose
         self.admission = None
+        # present at 0 from the first reading on: a window's delta of 0
+        # then says "no change of leadership", not "no such counter"
+        _registry().incr("nomad.raft.leader_changes", 0)
 
         transport.register(node_id, self.handle)
 
@@ -441,9 +504,27 @@ class RaftNode:
                 continue
             for p in live:
                 p.command = copy.deepcopy(p.command)
-            self._commit_batch(live)
+            self._commit_batch(live, self._encode_batch(live))
 
-    def _commit_batch(self, batch: List[_Proposal]) -> None:
+    def _encode_batch(self, batch: List[_Proposal]) -> Optional[List[str]]:
+        """Each proposal's command as the text of its log line, one
+        raft.encode span a proposal, before the append and outside any
+        lock. None where the log keeps no bytes (in-memory)."""
+        encode = getattr(self.log, "encode_command", None)
+        if encode is None:
+            return None
+        encoded = []
+        for p in batch:
+            with TRACER.span("raft.encode", device=True,
+                             rows=_command_rows(p.command)) as sp:
+                text = encode(p.command)
+                p.nbytes = len(text)
+                sp.set(bytes=p.nbytes)
+            encoded.append(text)
+        return encoded
+
+    def _commit_batch(self, batch: List[_Proposal],
+                      encoded: Optional[List[str]] = None) -> None:
         """Land a drained batch: one buffered write + one fsync via
         DurableLog.append_batch, outside the node lock. The append is
         CAS-guarded on the log tail: if a config entry, a new leader's
@@ -477,7 +558,7 @@ class RaftNode:
                 with TRACER.span("raft.fsync", n=len(batch)):
                     entries = self.log.append_batch(
                         term, [p.command for p in batch],
-                        prev=(last_index, last_term))
+                        prev=(last_index, last_term), encoded=encoded)
             except OSError as e:
                 # disk fault: the log rolled the whole batch back;
                 # surface the error to every caller in it
@@ -495,6 +576,10 @@ class RaftNode:
                 for p in batch:
                     if self._waiters.get(p.index) is p:
                         del self._waiters[p.index]
+        reg = _registry()
+        reg.incr("nomad.raft.entries", len(batch))
+        reg.incr("nomad.raft.fsyncs")
+        reg.incr("nomad.raft.append_bytes", sum(p.nbytes for p in batch))
         with self._lock:
             self._maybe_advance_commit_locked()
             self._repl_cond.notify_all()
@@ -551,7 +636,7 @@ class RaftNode:
             if not chunk:
                 break
             for e in chunk:
-                if tuple(e.command)[:1] == ("config",):
+                if e.is_config():
                     latest = (e.index, e.command[1][0])
             idx = chunk[-1].index + 1
         if latest is not None:
@@ -736,8 +821,7 @@ class RaftNode:
                 # the whole batch lands with a single buffered write +
                 # fsync (DurableLog.append_entries) before the ack below
                 truncated = self.log.append_entries(prev_index, entries)
-                configs = [e for e in entries
-                           if tuple(e.command)[:1] == ("config",)]
+                configs = [e for e in entries if e.is_config()]
                 if truncated and not configs:
                     # a dropped conflicting suffix may have contained a
                     # config entry: recompute membership from the log
@@ -1026,14 +1110,17 @@ class RaftNode:
         # wake commit-index waiters (change_config) so they observe the
         # step-down now rather than at their next poll tick
         self._apply_cond.notify_all()
-        if was_leader and self.on_leadership:
-            self.on_leadership(False)
+        if was_leader:
+            _registry().incr("nomad.raft.leader_changes")
+            if self.on_leadership:
+                self.on_leadership(False)
 
     def _become_leader_locked(self) -> None:
         self.state = LEADER
         self.leader_id = self.id
         RECORDER.record("raft", "leader", node=self.id,
                         term=self.current_term)
+        _registry().incr("nomad.raft.leader_changes")
         last_index, _ = self.log.last()
         now = time.time()
         for p in self.peers:
@@ -1118,7 +1205,39 @@ class RaftNode:
                         self._autopilot = t
                         t.start()
             elif expired and can_elect:
+                if state == FOLLOWER and self._leader_stalled():
+                    continue
                 self._start_election()
+
+    def _leader_stalled(self) -> bool:
+        """Tick thread, election deadline passed: is the silent leader
+        still alive by its transport's word (LEADER_STALL_GRACE)? If so
+        push the deadline out by half an election timeout, at most to
+        the end of the grace, and say so."""
+        probe = getattr(self.transport, "peer_alive", None)
+        if probe is None:
+            return False
+        with self._lock:
+            leader, contact = self.leader_id, self._last_leader_contact
+        grace_ends = contact + LEADER_STALL_GRACE * self.election_timeout
+        if leader in (None, self.id) or contact <= 0.0 \
+                or time.time() >= grace_ends or not probe(leader):
+            return False
+        with self._lock:
+            if time.time() < self._deadline:
+                return True     # heard from it, or voted, meanwhile
+            self._deadline = min(time.time() + self.election_timeout / 2,
+                                 grace_ends)
+        _registry().incr("nomad.raft.elections_deferred")
+        # once a silence at WARNING, its later deferrals at DEBUG
+        log.log(logging.DEBUG if self._stall_logged == contact
+                else logging.WARNING,
+                "%s: leader %s silent for %.2f s but its raft port "
+                "answers: stalled, not dead; no campaign before %.1f s "
+                "of silence", self.id, leader, time.time() - contact,
+                LEADER_STALL_GRACE * self.election_timeout)
+        self._stall_logged = contact
+        return True
 
     # -- replication (one pipelined replicator thread per peer) --
 
@@ -1189,8 +1308,12 @@ class RaftNode:
             reply = self.transport.send(self.id, peer, {
                 "kind": "append_entries", "term": term, "leader": self.id,
                 "prev_log_index": prev_index, "prev_log_term": prev_term,
+                # the command as the log writer encoded it, where it
+                # did: encoded once a proposal, not once more a peer
                 "entries": [{"index": e.index, "term": e.term,
-                             "command": e.command} for e in entries],
+                             "wire": e.wire} if e.wire is not None
+                            else {"index": e.index, "term": e.term,
+                                  "command": e.command} for e in entries],
                 "leader_commit": commit,
             })
         with self._lock:
@@ -1379,6 +1502,10 @@ class RaftNode:
             # piggyback the new commit index to followers promptly so
             # their FSMs converge without waiting for the idle heartbeat
             self._repl_cond.notify_all()
+        if self.peers:
+            # how far the slowest voter's durable log trails the commit
+            _registry().set_gauge("nomad.raft.follower_lag",
+                                  max(0, self.commit_index - matches[-1]))
 
     # -- apply loop --
 
@@ -1394,6 +1521,19 @@ class RaftNode:
                 pass
             self._maybe_snapshot()
 
+    def _decode_committed(self) -> None:
+        """Decode the commands of the next chunk before the node lock is
+        taken for it: a follower holds what the leader shipped as text
+        (`Entry.wire`), a committed entry never changes, and the
+        handlers of append_entries and request_vote wait on that lock."""
+        with self._lock:
+            start = self.last_applied + 1
+            end = min(self.commit_index, start + APPLY_CHUNK - 1)
+        for idx in range(start, end + 1):
+            entry = self.log.get(idx)
+            if entry is not None:
+                entry.command
+
     def _apply_chunk(self) -> bool:
         """Apply up to APPLY_CHUNK committed entries under ONE lock hold
         and wake all waiters with ONE notify_all. The re-check, fetch,
@@ -1403,6 +1543,7 @@ class RaftNode:
         restore land in between, after which applying the stale entry
         regresses the restored store. The chunk bound keeps RPC handlers
         from stalling behind an arbitrarily large committed backlog."""
+        self._decode_committed()
         with self._lock:
             start = self.last_applied + 1
             end = min(self.commit_index, start + APPLY_CHUNK - 1)
@@ -1431,6 +1572,11 @@ class RaftNode:
                         del self._waiters[idx]
                         waiter.result = result
                         waiter.done.set()
+                        TRACER.add_span("raft.commit", waiter.t0,
+                                        time.time(),
+                                        kind=str(entry.command[0])
+                                        if entry.command else "",
+                                        bytes=waiter.nbytes)
             progressed = self.last_applied >= start
             self._apply_cond.notify_all()
         return progressed

@@ -222,6 +222,9 @@ class SocketTransport:
         self._inflight: Dict[str, int] = {}   # peer host -> frames in dispatch
         self._inflight_lock = threading.Lock()
         self.dropped_frames = 0
+        # sends that got no reply, by channel: a timeout, a reset, a
+        # refused connect, a peer in its reconnect cooldown
+        self.failed_sends: Dict[str, int] = {}
         # Raft ticks send to every peer serially: connecting to a dead
         # peer must fail fast and then back off, or one crashed server
         # stalls heartbeats to the live ones and triggers elections. The
@@ -391,6 +394,7 @@ class SocketTransport:
         jittered backoff; log once when the backoff saturates (retry
         exhaustion — the peer has been down for many probes)."""
         with self._lock:
+            self.failed_sends[key[1]] = self.failed_sends.get(key[1], 0) + 1
             bo = self._backoffs.get(key)
             if bo is None:
                 bo = self._backoffs[key] = Backoff(
@@ -445,6 +449,24 @@ class SocketTransport:
                 return existing, lock, True
             self._conns[key] = sock
         return sock, lock, False
+
+    def peer_alive(self, peer_id: str) -> Optional[bool]:
+        """Does the peer's process still hold its raft port? A connect
+        is completed by the kernel of the peer's machine, so it succeeds
+        while the peer's interpreter stands still and is refused once
+        the process is gone. None under a fault plan: chaos decides
+        reachability frame by frame, and a bare connect sees through it."""
+        if self.fault_plan is not None:
+            return None
+        addr = self.peer_addrs.get(peer_id)
+        if addr is None:
+            return False
+        try:
+            socket.create_connection(self._split(addr),
+                                     timeout=self.connect_timeout).close()
+        except OSError:
+            return False
+        return True
 
     def _drop(self, key: Tuple[str, str]) -> None:
         with self._lock:
