@@ -247,6 +247,10 @@ class ClusterTensors:
         # in-flight placements the base is handed out as a shared
         # read-only view — the O(N) gather disappears entirely from the
         # warm path; otherwise it seeds a copy-on-write private buffer.
+        #
+        # Other racing evals' in-flight placements are read first, the
+        # committed usage after them: see InflightOverlay.open_entries.
+        inflight = INFLIGHT.open_entries(exclude_plan=ctx.plan)
         base = None
         if self._store is not None and self.static is not None:
             feed = feed_for(self._store)
@@ -258,8 +262,7 @@ class ClusterTensors:
             if out is not None:
                 np.copyto(out, base)
                 used = out
-            elif not touched and not INFLIGHT.has_entries(
-                    exclude_plan=ctx.plan):
+            elif not touched and not inflight:
                 self.used = base
                 self._used_shared = True
                 return
@@ -293,8 +296,7 @@ class ClusterTensors:
         # placements: fold LAST so this solve plans around them instead
         # of colliding on the same best-fit nodes (tensor/overlay.py;
         # the per-eval twin of the bulk solver service's carry)
-        INFLIGHT.fold(used[:n], self.node_index,
-                      exclude_plan=ctx.plan)
+        INFLIGHT.fold(used[:n], self.node_index, entries=inflight)
 
     def latest_usage(self) -> np.ndarray:
         """Freshly-gathered LATEST committed usage, (n_pad, D) float32.
@@ -305,6 +307,8 @@ class ClusterTensors:
         carry — the round-5 oversubscription cascade."""
         rows = self.static.usage_rows if self.static is not None else None
         if rows is not None and self._store is not None:
+            # read before the committed usage, as in refresh_usage
+            inflight = INFLIGHT.open_entries()
             mat = self._store._usage_mat  # local ref: matrix may be
             # swapped by a concurrent restore (_rebuild_usage_matrix);
             # row assignments may then be stale — bounds-check and fall
@@ -316,9 +320,8 @@ class ClusterTensors:
                 # not in the store yet NOR in the service's own ledger —
                 # fold them so a bulk resync can't double-book against
                 # racing spread/constraint evals
-                from .overlay import INFLIGHT
-
-                INFLIGHT.fold(out[: len(self.nodes)], self.node_index)
+                INFLIGHT.fold(out[: len(self.nodes)], self.node_index,
+                              entries=inflight)
                 return out
         return self.used.astype(np.float32)
 

@@ -3,7 +3,7 @@
 Reproduces the reference scoring pipeline (scheduler/rank.go:205-835,
 nomad/structs/funcs.go:236-278, scheduler/spread.go) as dense vector math
 over all nodes at once, and the greedy placement loop
-(generic_sched.go:511 computePlacements) as a `lax.scan` whose carry is
+(generic_sched.go:511 computePlacements) as a device loop whose carry is
 the cluster usage state — so each placement sees every earlier one, the
 same commit-visibility contract the host path gets via
 ctx.proposed_allocs.
@@ -138,9 +138,16 @@ def score_nodes(
     dh_job,           # ()    bool: job-level distinct_hosts
     dh_tg,            # ()    bool: group-level distinct_hosts
     spread_alg,       # ()    bool: WorstFit instead of BestFit
+    spread_counts_at=None,   # (S, N) spread_counts at each node's own value
+    spread_desired_at=None,  # (S, N) spread_desired likewise
+    dp_counts_at=None,       # (P, N) dp_counts likewise
 ):
     """Score every node for one placement. Returns (score, fitness) each
-    (N,); infeasible nodes score NEG.
+    (N,) and the (S, N) spread boost; infeasible nodes score NEG.
+
+    The three `*_at` arguments are the tables read at each node's own
+    value. A caller that carries them from placement to placement (the
+    per-placement loop) passes them; left out, they are looked up here.
 
     Mirrors the host oracle NodeScorer.rank (scheduler/rank.py): the final
     score is the *mean of the sub-scores that apply* (reference
@@ -151,8 +158,8 @@ def score_nodes(
     feasibility without perturbing the score.
     """
     # The named scopes (feasibility / score / spread here, select /
-    # usage_update in the scan step) put each HLO op of the step under
-    # its phase in the profiler's trace; they change no computation.
+    # usage_update in the placement loop) put each HLO op of the step
+    # under its phase in the profiler's trace; they change no computation.
     n = available.shape[0]
     new_used = used + ask[None, :]
 
@@ -166,7 +173,9 @@ def score_nodes(
         # if it lacks the property or its value's proposed count is at
         # the limit
         if dp_val_id.shape[0]:
-            dp_at = jnp.take_along_axis(dp_counts, dp_val_id, axis=1)  # (P, N)
+            dp_at = dp_counts_at                                   # (P, N)
+            if dp_at is None:
+                dp_at = jnp.take_along_axis(dp_counts, dp_val_id, axis=1)
             dp_ok = dp_val_ok & (dp_at < dp_limit[:, None])
             ok &= jnp.all(dp_ok, axis=0)
 
@@ -193,7 +202,8 @@ def score_nodes(
     with jax.named_scope("spread"):
         spread_total, boost = _spread_boost(
             fitness.dtype, spread_val_id, spread_val_ok, spread_counts,
-            spread_desired, spread_has_targets, spread_weight, lowest_boost)
+            spread_desired, spread_has_targets, spread_weight, lowest_boost,
+            spread_counts_at, spread_desired_at)
         spread_present = spread_total != 0.0
 
     with jax.named_scope("score"):
@@ -219,12 +229,15 @@ def score_nodes(
 
 def _spread_boost(dtype, spread_val_id, spread_val_ok, spread_counts,
                   spread_desired, spread_has_targets, spread_weight,
-                  lowest_boost):
+                  lowest_boost, counts_at=None, desired=None):
     """The spread sub-score of score_nodes -> ((N,) total, (S, N) boost)
-    (reference spread.go:128 + propertyset.go)."""
-    counts_at = jnp.take_along_axis(spread_counts, spread_val_id, axis=1)  # (S, N)
+    (reference spread.go:128 + propertyset.go). `counts_at` and
+    `desired` are the two (S, V) tables at each node's own value."""
+    if counts_at is None:
+        counts_at = jnp.take_along_axis(spread_counts, spread_val_id, axis=1)  # (S, N)
     used_cnt = counts_at.astype(dtype) + 1.0  # incl. this placement
-    desired = jnp.take_along_axis(spread_desired, spread_val_id, axis=1)   # (S, N)
+    if desired is None:
+        desired = jnp.take_along_axis(spread_desired, spread_val_id, axis=1)   # (S, N)
 
     explicit = jnp.where(
         jnp.isnan(desired),
@@ -273,7 +286,7 @@ def _permute_node_axis(tie_perm, available, used0, placed_tg0, placed_job0,
                        feasible, affinity_boost, dev_affinity,
                        spread_val_id, spread_val_ok, dp_val_id, dp_val_ok):
     """Gather every per-node array into tie-break-permuted space — the
-    single definition shared by the per-placement scan and the bulk
+    single definition shared by the per-placement loop and the bulk
     solver, so a new per-node input can't be permuted in one and
     forgotten in the other."""
     return (available[tie_perm], used0[tie_perm], placed_tg0[tie_perm],
@@ -282,6 +295,21 @@ def _permute_node_axis(tie_perm, available, used0, placed_tg0, placed_job0,
             spread_val_id[:, tie_perm], spread_val_ok[:, tie_perm],
             dp_val_id[:, tie_perm] if dp_val_id.shape[0] else dp_val_id,
             dp_val_ok[:, tie_perm] if dp_val_ok.shape[0] else dp_val_ok)
+
+
+def _scan_steps_xp(xp, active):
+    """Steps the placement loop runs for an `active` column: up to and
+    including its last active row, 0 if none. One rule for the kernel
+    (jnp) and for the host's counters (numpy)."""
+    k = active.shape[0]
+    return xp.max(xp.where(active, xp.arange(1, k + 1), 0), initial=0)
+
+
+def scan_steps(active) -> int:
+    """Host twin of the bound solve_task_group computes on the device."""
+    import numpy as np
+
+    return int(_scan_steps_xp(np, np.asarray(active, dtype=bool)))
 
 
 @partial(jax.jit, donate_argnums=())
@@ -317,24 +345,35 @@ def solve_task_group(
     (choice, found, score): the chosen node index, whether any node fit,
     and the winning normalized score.
 
-    The scan carry is the proposed cluster state — usage, per-node
+    A device loop of as many steps as placements are asked: K is the
+    padded length one compiled program serves, and the loop stops after
+    the last active row (scan_steps), so rows past it place nothing and
+    cost nothing; they read choice 0, found False, score NEG. An
+    inactive row before that bound runs its step and masks `found`.
+
+    The loop's carry is the proposed cluster state — usage, per-node
     placement counts, spread value counts, distinct_property value
     counts — exactly the state the host path threads through
     ctx.proposed_allocs + SpreadScorer + propertyset between placements
-    (generic_sched.go:511-600 commit loop).
+    (generic_sched.go:511-600 commit loop). Beside the spread's (S, V)
+    table it carries the count of each node's own value, (S, N) (and
+    (P, N) for distinct_property, whose table it then does not need): a
+    placement adds one to the nodes that share the chosen node's value,
+    where reading the table at every node every step is, on the TPU, a
+    chain of compare-selects over V that was most of a step's time.
 
     tie_perm replaces the host path's per-eval node shuffle (reference
     scheduler/util.go:167 shuffleNodes): the whole solve runs in
     PERMUTED node space (one up-front gather of every per-node array, so
-    the scan body stays a plain argmax) and choices map back through the
+    the loop body stays a plain argmax) and choices map back through the
     permutation at the end. Equal-scoring winners follow the
     permutation's priority order — racing workers diverge on ties
     without reordering the (cached, canonical) per-node arrays
     host-side.
     """
-    s = spread_val_id.shape[0]
     p = dp_val_id.shape[0]
     n = available.shape[0]
+    k = active.shape[0]
     if tie_perm is not None:
         (available, used0, placed_tg0, placed_job0, feasible,
          affinity_boost, dev_affinity, spread_val_id, spread_val_ok,
@@ -346,25 +385,34 @@ def solve_task_group(
             jnp.arange(n, dtype=jnp.int32))
         penalty_idx = jnp.where(penalty_idx >= 0, inv[penalty_idx], -1)
 
-    def step(carry, xs):
-        used, ptg, pjob, scnt, dpcnt, lowest = carry
-        pen_idx, is_active = xs
+    n_steps = _scan_steps_xp(jnp, active)
+    desired_at = jnp.take_along_axis(spread_desired, spread_val_id, axis=1)
 
-        score, fitness, boost = score_nodes(
+    def score_step(state, pen_idx):
+        used, ptg, pjob, scnt, scnt_at, dp_at, lowest = state
+        return score_nodes(
             available=available, used=used, ask=ask, feasible=feasible,
             placed_tg=ptg, placed_job=pjob, affinity_boost=affinity_boost,
             dev_affinity=dev_affinity, penalty_idx=pen_idx,
             spread_val_id=spread_val_id, spread_val_ok=spread_val_ok,
             spread_counts=scnt, spread_desired=spread_desired,
             spread_has_targets=spread_has_targets, spread_weight=spread_weight,
-            dp_val_id=dp_val_id, dp_val_ok=dp_val_ok, dp_counts=dpcnt,
+            dp_val_id=dp_val_id, dp_val_ok=dp_val_ok, dp_counts=dp_counts0,
             dp_limit=dp_limit,
             lowest_boost=lowest, tg_count=tg_count,
             dh_job=dh_job, dh_tg=dh_tg, spread_alg=spread_alg,
+            spread_counts_at=scnt_at, spread_desired_at=desired_at,
+            dp_counts_at=dp_at,
         )
+
+    def step(i, carry):
+        state, choices, scores = carry
+        used, ptg, pjob, scnt, scnt_at, dp_at, lowest = state
+        score, _, boost = score_step(state, penalty_idx[i])
+
         with jax.named_scope("select"):
-            choice = jnp.argmax(score)
-            found = is_active & (score[choice] > NEG)
+            choice = jnp.argmax(score).astype(choices.dtype)
+            found = active[i] & (score[choice] > NEG)
 
         with jax.named_scope("usage_update"):
             onehot = (jnp.arange(n) == choice) & found
@@ -374,14 +422,16 @@ def solve_task_group(
 
             sel_ok = spread_val_ok[:, choice] & found              # (S,)
             sel_val = spread_val_id[:, choice]                      # (S,)
-            scnt = scnt.at[jnp.arange(s), sel_val].add(
-                sel_ok.astype(scnt.dtype))
+            scnt = scnt + ((jnp.arange(scnt.shape[1]) == sel_val[:, None])
+                           & sel_ok[:, None]).astype(scnt.dtype)
+            scnt_at = scnt_at + ((spread_val_id == sel_val[:, None])
+                                 & sel_ok[:, None]).astype(scnt_at.dtype)
 
             if p:
                 dsel_ok = dp_val_ok[:, choice] & found             # (P,)
                 dsel_val = dp_val_id[:, choice]                    # (P,)
-                dpcnt = dpcnt.at[jnp.arange(p), dsel_val].add(
-                    dsel_ok.astype(dpcnt.dtype))
+                dp_at = dp_at + ((dp_val_id == dsel_val[:, None])
+                                 & dsel_ok[:, None]).astype(dp_at.dtype)
 
             # SpreadIterator tracks the lowest explicit boost it has
             # handed out (spread.go lowestBoost); we update it with the
@@ -391,14 +441,22 @@ def solve_task_group(
             lowest = jnp.minimum(lowest,
                                  jnp.min(chosen_boost, initial=jnp.inf))
 
-        return (used, ptg, pjob, scnt, dpcnt, lowest), (choice, found, score[choice])
+        return ((used, ptg, pjob, scnt, scnt_at, dp_at, lowest),
+                choices.at[i].set(choice), scores.at[i].set(score[choice]))
 
-    init = (used0, placed_tg0, placed_job0, spread_counts0, dp_counts0,
+    init = (used0, placed_tg0, placed_job0, spread_counts0,
+            jnp.take_along_axis(spread_counts0, spread_val_id, axis=1),
+            jnp.take_along_axis(dp_counts0, dp_val_id, axis=1),
             lowest_boost0)
-    _, (choices, founds, scores) = jax.lax.scan(
-        init=init, f=step, xs=(penalty_idx, active))
+    score_dtype = jax.eval_shape(score_step, init, penalty_idx[0])[0].dtype
+    _, choices, scores = jax.lax.fori_loop(
+        0, n_steps, step,
+        (init, jnp.zeros(k, jnp.int32), jnp.full(k, NEG, score_dtype)))
+    # a step found a node iff its row is active and its best score is one
+    founds = active & (scores > NEG)
     if tie_perm is not None:
-        choices = tie_perm[choices]
+        # a row past the bound keeps choice 0, not tie_perm[0]
+        choices = jnp.where(jnp.arange(k) < n_steps, tie_perm[choices], 0)
     return choices, founds, scores
 
 
@@ -483,7 +541,7 @@ def pack_solve_args(available, placed_tg0, placed_job0, ask, feasible,
 @jax.jit
 def solve_task_group_fused(used, node_mat, step_mat, spread_node, spread_tab,
                            spread_meta, dp_node, dp_tab, scalars):
-    """Transfer-fused solve: unpack on device, run the same scan, return
+    """Transfer-fused solve: unpack on device, run the same loop, return
     one (3, K) array of [choice, found, score] rows."""
     s = spread_meta.shape[0]
     p = dp_node.shape[0] // 2
@@ -513,8 +571,10 @@ def solve_task_group_fused(used, node_mat, step_mat, spread_node, spread_tab,
 # ---------------------------------------------------------------------------
 #
 # The C2M engine. A fresh job's task group asks for K identical
-# placements; the per-placement scan costs K sequential steps (the
-# sequential chain is the latency floor at K=4096). This solver instead
+# placements; the per-placement loop costs K sequential steps of some
+# twenty small device ops each (15 us a step at 16,384 nodes on a v5e,
+# PERF.md section 5: their count sets it, not the bytes a step
+# touches). This solver instead
 # assigns a BATCH of B placements per step: score all nodes once
 # (identical math to score_nodes), then give the best-scoring nodes
 # their fill in score order — per-node capacity for binpack (the greedy

@@ -74,38 +74,32 @@ class InflightOverlay:
             if self._entries.pop(token, None) is not None:
                 self.stats["confirmed"] += 1
 
-    def has_entries(self, exclude_plan=None) -> bool:
-        """True when fold() would add anything: at least one live
-        (non-TTL-expired) entry not owned by `exclude_plan`. Lets the
-        incremental-state fast path hand out a shared read-only base
-        instead of copying it just to fold nothing in."""
+    def open_entries(self, exclude_plan=None) -> list:
+        """The live (non-TTL-expired) entries not owned by
+        `exclude_plan`, as fold() takes them. A usage gather reads them
+        BEFORE it reads committed usage (the feed's drain, the store's
+        matrix): an entry closes right after its commit is published,
+        so one that was read open is either still outside the committed
+        usage read next or, rarely, inside it as well (counted twice:
+        that solve plans around nodes that are freer than they look);
+        read in the other order, a commit that lands between the two
+        reads is in neither, the solve fills nodes that are already
+        full and the applier rejects its rows."""
         now = time.time()
         exclude = id(exclude_plan) if exclude_plan is not None else None
         with self._lock:
-            return any(
-                now - e["born"] <= ENTRY_TTL
-                and (e.get("plan") != exclude or exclude is None)
-                for e in self._entries.values())
-
-    def fold(self, used, node_index: Dict[str, int],
-             exclude_plan=None) -> None:
-        """Add every open entry's deltas into a canonical-order usage
-        matrix (in place). Called from ClusterTensors usage gathers.
-        `exclude_plan` skips the calling eval's OWN entries — its
-        placements are already in the plan the usage recompute reads
-        (double-counting them made multi-group evals see full nodes)."""
-        now = time.time()
-        exclude = id(exclude_plan) if exclude_plan is not None else None
-        with self._lock:
-            if not self._entries:
-                return
             dead = [t for t, e in self._entries.items()
                     if now - e["born"] > ENTRY_TTL]
             for t in dead:
                 del self._entries[t]
                 self.stats["expired"] += 1
-            entries = [e for e in self._entries.values()
-                       if e.get("plan") != exclude or exclude is None]
+            return [e for e in self._entries.values()
+                    if e.get("plan") != exclude or exclude is None]
+
+    def fold(self, used, node_index: Dict[str, int], entries) -> None:
+        """Add the deltas of `entries`, as open_entries() returned them
+        before committed usage was read, into a canonical-order usage
+        matrix (in place). Called from the usage gathers."""
         d = used.shape[1]
         for e in entries:
             rows, deltas = e["rows"], e["deltas"][:, :d]

@@ -220,6 +220,20 @@ def _changed_allocs_since_last_build(store=None) -> int:
 # in place(). The bulk path has its own serializer (the solver service).
 _PER_EVAL_SOLVE_LOCK = __import__("threading").Lock()
 
+# How many evaluations may be between staging their solve and the end of
+# their hold of that lock: the holder, and two staged behind it so that
+# the lock never waits for one. The lock serializes this tier whatever
+# the number of workers, and staging is interpreted work: every further
+# evaluation that stages alongside only takes the interpreter from the
+# holder at each of its releases (the gather's copy, device_put, the
+# launch, the wake from block_until_ready, device_get), and from the
+# commit path behind it. With 24 workers staging at once the same
+# backlog drained at 7,000 to 14,600 allocations a second run by run
+# (PERF.md section 6, PR 29). A constant, not an option: `--workers`
+# says how many evaluations may be in flight, this how many of them
+# queue for one lock with their hands full.
+_SOLVE_ADMIT = __import__("threading").BoundedSemaphore(3)
+
 
 class TPUPlacer:
     """Placer implementation: dense-tensor batch solve on the device."""
@@ -370,36 +384,15 @@ class TPUPlacer:
             # worker would otherwise only wait for the lock: the hold
             # below is left with what depends on other evaluations'
             # placements (the usage gather) and the launch itself.
-            staged = self._stage_statics(tgt, cluster, penalty_idx, active,
-                                         tie_perm)
-
-            # The usage gather -> solve -> in-flight registration runs
-            # as ONE critical section across racing workers: the device
-            # serializes launches anyway, and without this ordering two
-            # concurrent evals both fill the same near-full best-fit
-            # nodes to the brim and the applier rejects the loser's
-            # whole node lists (the round-4 spread-rung rejection gap —
-            # measured: overflows on the smallest-capacity nodes, base +
-            # planned > available). Inside the lock each solve re-reads
-            # usage WITH every earlier solve's overlay entries folded
-            # (tensor/overlay.py), so racing workers interleave around
-            # each other like the bulk path's carry provides for free.
-            #
-            # worker.solve covers the lock wait too: serialization
-            # behind racing workers is exactly the stall the trace
-            # should show. Its children split it, one set per
-            # evaluation and task group: placer.lock_wait, then
-            # placer.locked around gather / pack / ship / device_wait /
-            # fetch / register. device=True mirrors each into the jax
-            # profiler's trace, above the device's ops on one clock.
-            with TRACER.span("worker.solve", k=k):
-                with TRACER.span("placer.lock_wait", device=True):
-                    _PER_EVAL_SOLVE_LOCK.acquire()
-                try:
-                    choices, founds, scores = self._solve_locked(
-                        ctx, tg, cluster, reqs, k_pad, staged)
-                finally:
-                    _PER_EVAL_SOLVE_LOCK.release()
+            with TRACER.span("placer.admit"):
+                _SOLVE_ADMIT.acquire()
+            try:
+                staged = self._stage_statics(tgt, cluster, penalty_idx,
+                                             active, tie_perm)
+                choices, founds, scores = self._solve_staged(
+                    ctx, tg, cluster, reqs, k_pad, staged)
+            finally:
+                _SOLVE_ADMIT.release()
 
             # exact port numbers / device instances / core ids are
             # host-side, per chosen node, after the solve (the kernel only
@@ -531,7 +524,7 @@ class TPUPlacer:
         import jax
 
         from ..core.metrics import REGISTRY
-        from .kernels import pack_solve_args
+        from .kernels import pack_solve_args, scan_steps
 
         with TRACER.span("placer.stage", device=True) as span:
             extra_used = None
@@ -556,7 +549,42 @@ class TPUPlacer:
             dev = jax.device_put(packed)
             usage_buf = np.empty(cluster.available.shape, np.float32)
         REGISTRY.incr("nomad.placer.staged_solves")
+        # steps the device loop will run, of the padded length its
+        # program was compiled for
+        REGISTRY.incr("nomad.placer.scan_steps", scan_steps(active))
+        REGISTRY.incr("nomad.placer.scan_steps_padded", len(active))
         return dev, usage_buf, extra_used
+
+    def _solve_staged(self, ctx, tg, cluster, reqs, k_pad, staged):
+        """Take _PER_EVAL_SOLVE_LOCK, solve, release -> (choices, founds,
+        scores)."""
+        # The usage gather -> solve -> in-flight registration runs
+        # as ONE critical section across racing workers: the device
+        # serializes launches anyway, and without this ordering two
+        # concurrent evals both fill the same near-full best-fit
+        # nodes to the brim and the applier rejects the loser's
+        # whole node lists (the round-4 spread-rung rejection gap —
+        # measured: overflows on the smallest-capacity nodes, base +
+        # planned > available). Inside the lock each solve re-reads
+        # usage WITH every earlier solve's overlay entries folded
+        # (tensor/overlay.py), so racing workers interleave around
+        # each other like the bulk path's carry provides for free.
+        #
+        # worker.solve covers the lock wait too: serialization
+        # behind racing workers is exactly the stall the trace
+        # should show. Its children split it, one set per
+        # evaluation and task group: placer.lock_wait, then
+        # placer.locked around gather / pack / ship / device_wait /
+        # fetch / register. device=True mirrors each into the jax
+        # profiler's trace, above the device's ops on one clock.
+        with TRACER.span("worker.solve", k=len(reqs)):
+            with TRACER.span("placer.lock_wait", device=True):
+                _PER_EVAL_SOLVE_LOCK.acquire()
+            try:
+                return self._solve_locked(ctx, tg, cluster, reqs, k_pad,
+                                          staged)
+            finally:
+                _PER_EVAL_SOLVE_LOCK.release()
 
     def _solve_locked(self, ctx, tg, cluster, reqs, k_pad, staged):
         """One evaluation's usage gather -> solve -> in-flight

@@ -617,12 +617,18 @@ class BulkSolverService:
         not hide behind the repair."""
         import jax
 
+        from .overlay import INFLIGHT
+
         if r.used_dev_fn is not None:
             try:
+                # the overlay before committed usage, as every usage
+                # gather reads them (InflightOverlay.open_entries)
+                inflight = INFLIGHT.open_entries()
                 dev_base = r.used_dev_fn(mesh)
                 if dev_base is not None:
                     return self._fold_base_scatter(dev_base, static, mesh,
-                                                   d, ledger_entries)
+                                                   d, ledger_entries,
+                                                   inflight)
             except Exception:
                 logger.exception("device-twin resync failed; rebuilding "
                                  "the usage carry on the host")
@@ -640,7 +646,7 @@ class BulkSolverService:
             return jax.device_put(base)
 
     def _fold_base_scatter(self, dev_base, static, mesh, d,
-                           ledger_entries):
+                           ledger_entries, inflight):
         """Fold open-ledger + per-eval in-flight (overlay) usage into
         the feed's device base with ONE non-donating scatter launch.
         Non-donating on purpose: the solve kernels donate their usage
@@ -659,7 +665,7 @@ class BulkSolverService:
             delta_list.append(counts[:, None].astype(np.float32)
                               * np.asarray(ask, np.float32)[None, :])
         tmp = np.zeros((n_pad, d), dtype=np.float32)
-        INFLIGHT.fold(tmp[: len(static.nodes)], static.node_index)
+        INFLIGHT.fold(tmp[: len(static.nodes)], static.node_index, inflight)
         nz = np.nonzero(np.any(tmp != 0.0, axis=1))[0]
         if nz.size:
             rows_list.append(nz.astype(np.int32))

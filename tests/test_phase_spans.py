@@ -162,6 +162,30 @@ class TestPlacerPhases:
         for a, b in zip(locked, locked[1:]):
             assert a[R_T1] <= b[R_T0], (a, b)
 
+    def test_no_more_than_three_evaluations_are_staged_or_holding(
+            self, spread_server):
+        """placer.admit (the wait for one of _SOLVE_ADMIT's three slots)
+        comes before every stage, and from its end to the end of
+        worker.solve at most three evaluations overlap."""
+        _, spans = spread_server
+        solves = [r for r in spans if r[R_NAME] == "worker.solve"]
+        admits = [r for r in spans if r[R_NAME] == "placer.admit"]
+        stages = [r for r in spans if r[R_NAME] == "placer.stage"]
+        assert len(admits) == len(stages) == len(solves) >= 4
+        edges = []
+        for solve in solves:
+            admit = max((r for r in admits if r[R_THREAD] == solve[R_THREAD]
+                         and r[R_T1] <= solve[R_T0]), key=lambda r: r[R_T1])
+            stage, = [r for r in stages if r[R_THREAD] == solve[R_THREAD]
+                      and admit[R_T1] <= r[R_T0] and r[R_T1] <= solve[R_T0]]
+            assert admit[R_PARENT] == stage[R_PARENT] == solve[R_PARENT]
+            edges += [(admit[R_T1], 1), (solve[R_T1], -1)]
+        inside = peak = 0
+        for _, step in sorted(edges, key=lambda e: (e[0], e[1])):
+            inside += step
+            peak = max(peak, inside)
+        assert 1 <= peak <= 3
+
     def test_phases_nest_under_worker_solve_and_cover_it(self, spread_server):
         _, spans = spread_server
         solves = [r for r in spans if r[R_NAME] == "worker.solve"]

@@ -335,6 +335,11 @@ class TestReadIndex:
             assert leader is not None
             follower = cluster.followers()[0]
             leader.register_node(mock.node())
+            # the write needs a quorum, not this follower: on a loaded
+            # host it may not have heard from the new leader yet
+            deadline = time.time() + 5.0
+            while not follower.known_leader() and time.time() < deadline:
+                time.sleep(0.02)
             idx = follower.read_index()
             follower.wait_applied(idx, timeout=5.0)
             snap = follower.store.snapshot()
