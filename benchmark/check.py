@@ -181,13 +181,23 @@ def end_state(server, specs: list, complete: set, used0: np.ndarray,
     }
 
 
-def add_reference(state: dict) -> dict:
+def add_reference(state: dict, memo: dict = None) -> dict:
     """Run the plain reference on the same cluster and jobs and put its
-    fitness beside the system's."""
+    fitness beside the system's. The reference is a pure function of
+    its inputs: rounds that start from the same usage with the same
+    jobs (ids apart) share one run of it through `memo`."""
     import time
 
     t0 = time.perf_counter()
-    ref = reference_fitness(*state.pop("_reference_input"))
+    cap, used0, value_of, n_values, jobs = state.pop("_reference_input")
+    key = (cap.tobytes(), used0.tobytes(), value_of.tobytes(), n_values,
+           repr([sorted((k, v) for k, v in job.items() if k != "id")
+                 for job in jobs]))
+    ref = None if memo is None else memo.get(key)
+    if ref is None:
+        ref = reference_fitness(cap, used0, value_of, n_values, jobs)
+        if memo is not None:
+            memo[key] = ref
     state["reference_fitness"] = ref["fitness"]
     state["reference_unplaced"] = ref["unplaced"]
     state["took"]["reference"] = round(time.perf_counter() - t0, 3)
@@ -195,40 +205,83 @@ def add_reference(state: dict) -> dict:
 
 
 def judge(state: dict, rules: dict) -> dict:
-    """-> {"correct": bool, "reasons": [...], "failed_jobs": [ids]}."""
+    """-> {"correct": bool, "reasons": [...], "failed_jobs": [ids],
+    "compared": {name: [number, limit]}}: every number that is held,
+    beside what it is held to. A count fails above 0; the evenness and
+    the fitness gap fail above their limit."""
     reasons, failed_jobs = [], []
     for job_id, row in state["jobs"].items():
         if row["live"] != row["count"]:
             failed_jobs.append(job_id)
+    compared = {"jobs_off_count": [len(failed_jobs), 0]}
     if failed_jobs:
         reasons.append(f"{len(failed_jobs)} complete job(s) without exactly "
                        f"`count` live allocations, e.g. {failed_jobs[:3]}")
     over = [n["id"] for n in state["sample"]
             if (np.asarray(n["used"]) > np.asarray(n["cap"]) + 1e-6).any()]
+    compared["nodes_over_capacity"] = [len(over), 0]
     if over:
         reasons.append(f"nodes over capacity: {over[:3]}")
     clash = [n["id"] for n in state["sample"]
              if len(set(n["ports"])) != len(n["ports"])]
+    compared["ports_twice"] = [len(clash), 0]
     if clash:
         reasons.append(f"port handed out twice on: {clash[:3]}")
+    compared["nodes_down"] = [len(state["not_ready"]), 0]
     if state["not_ready"]:
         reasons.append(f"nodes down: {state['not_ready'][:3]}")
     rel = float(rules.get("spread_rel_tol", 0.0))
     slack = float(rules.get("spread_abs_tol", 1))
-    uneven = [j for j, per in state["spread"].items()
-              if max(per) - min(per) > slack + rel * sum(per) / len(per)]
+    # per spread job (max - min, its limit); the line shows the job
+    # that comes nearest to its limit or passes it farthest
+    evenness = {j: (max(per) - min(per), slack + rel * sum(per) / len(per))
+                for j, per in state["spread"].items()}
+    if evenness:
+        compared["spread_max_less_min"] = list(max(
+            evenness.values(), key=lambda e: e[0] - e[1]))
+    uneven = [j for j, (got, limit) in evenness.items() if got > limit]
     if uneven:
         reasons.append(f"spread broken (max - min > {slack} + {rel} x mean) "
                        f"in {[(j, state['spread'][j]) for j in uneven[:3]]}")
         failed_jobs.extend(j for j in uneven if j not in failed_jobs)
     tol = float(rules.get("fitness_rel_tol", 5e-3))
-    if state["fitness"] < state["reference_fitness"] * (1.0 - tol):
+    ref = state["reference_fitness"]
+    compared["fitness_under_reference"] = [
+        (ref - state["fitness"]) / ref if ref else 0.0, tol]
+    if state["fitness"] < ref * (1.0 - tol):
         reasons.append(f"mean fitness {state['fitness']:.5f} below the "
-                       f"reference's {state['reference_fitness']:.5f}")
+                       f"reference's {ref:.5f}")
+    compared["retraces"] = [state["retraces"], 0]
+    compared["twin_failures"] = [state["twin_failures"], 0]
     if state["retraces"] or state["twin_failures"]:
         reasons.append(f"solver retraces={state['retraces']} "
                        f"twin_failures={state['twin_failures']}")
+    compared["errors"] = [len(state["errors"]), 0]
     if state["errors"]:
         reasons.append(f"errors off the main thread: {state['errors'][:3]}")
     return {"correct": not reasons, "reasons": reasons,
-            "failed_jobs": failed_jobs}
+            "failed_jobs": failed_jobs, "compared": compared}
+
+
+def judge_rounds(states: list, rules: dict) -> dict:
+    """`judge` over the end states of a run's rounds, as one verdict:
+    correct only if every round is; of each number compared, the
+    rounds' sum where it is a count held to 0, else the round that
+    comes nearest its limit or passes it farthest."""
+    verdicts = [judge(state, rules) for state in states]
+    compared: dict = {}
+    for v in verdicts:
+        for name, (value, limit) in v["compared"].items():
+            if name not in compared:
+                compared[name] = [value, limit]
+            elif limit == 0 and compared[name][1] == 0:
+                compared[name][0] += value
+            elif value - limit > compared[name][0] - compared[name][1]:
+                compared[name] = [value, limit]
+    many = len(verdicts) > 1
+    return {"correct": all(v["correct"] for v in verdicts),
+            "reasons": [f"round {k}: {r}" if many else r
+                        for k, v in enumerate(verdicts)
+                        for r in v["reasons"]],
+            "failed_jobs": [j for v in verdicts for j in v["failed_jobs"]],
+            "compared": compared}

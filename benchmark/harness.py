@@ -12,7 +12,14 @@ Phases of a run: set-up (agent, fleet, warm-up of the cell's own
 shapes, backlog; all of it is `setup_s`), the window (`--seconds`; with
 `--trace 1` under jax.profiler until the clock stops, its first seconds
 at most), the check (`correct`, outside the window), teardown, the
-result line.
+result line. Where the configuration asks for the backlog in several
+rounds (`window.rounds`, generators/backlog.py) the window is the
+rounds' windows together: the end-to-end rate is taken over all of
+them, what lies between two of them is set-up and counts in `setup_s`,
+every round's end state is checked before the next round purges it, and
+the per-layer observations (spans, counters, the trace) are the first
+window's. A deploy kind whose `quiesce()` ends the deployment
+(`three_servers`) runs one round.
 """
 
 from __future__ import annotations
@@ -192,30 +199,49 @@ def main(argv, t_start: float) -> int:
             bool(warm.get("scatter_buckets"))))
         gen = gen_mod.Generator(ctx)
         gen.prepare()
-        used0 = check.cluster_arrays(dep.server.store.snapshot(),
-                                     check.spread_attribute(traffic))["used"]
+        attribute = check.spread_attribute(traffic)
+        rules = traffic.get("check", {})
         tracing = (Tracing(ctx, float(traffic.get("trace_seconds", 4)))
                    if args.trace else None)
-        marks: dict = {}
+        marks: dict = {"compiles": []}
+        states: list = []
 
-        def on_open() -> None:
-            if tracing is not None:
+        def on_open(k: int) -> None:
+            marks["used0"] = check.cluster_arrays(
+                dep.server.store.snapshot(), attribute)["used"]
+            if k == 0 and tracing is not None:
                 tracing.start()
-            marks["c0"] = observe.counters(dep.server)
-            marks["setup_s"] = time.time() - t_start
+            marks["open"] = observe.counters(dep.server)
+            if k == 0:
+                marks["c0"] = marks["open"]
+                marks["setup_s"] = time.time() - t_start
 
-        def on_clock_stop(t0: float, t1: float) -> None:
+        def on_clock_stop(k: int, t0: float, t1: float) -> None:
             if tracing is not None:
                 tracing.stop()      # the trace never outlasts the clock
-            marks["c1"] = observe.counters(dep.server)
-            marks["compiles"] = watch.between(t0, t1)
+            marks["stop"] = observe.counters(dep.server)
+            if k == 0:
+                marks["c1"] = marks["stop"]
+            marks["compiles"] += watch.between(t0, t1)
+
+        def on_round_end(k: int, rnd: dict) -> None:
+            # the round's end state, read from a store nobody writes to;
+            # the reference runs on it after the deployment is stopped
+            marks["quiesced"] = dep.quiesce()
+            solver = observe.delta(marks["stop"], marks["open"])["solver"]
+            states.append(check.end_state(
+                dep.server, rnd["specs"], rnd["complete"], marks["used0"],
+                rules, args.seed, solver, [], attribute))
 
         ctx.note("setup", **{k: round(v, 3) for k, v in dep.timings.items()},
                  compiles=len(watch.compiles),
                  compile_s=round(sum(c[1] for c in watch.compiles), 3),
                  cache_hits=sum(1 for c in watch.compiles if c[2]))
-        out = gen.run(seconds, on_open, on_clock_stop)
-        ctx.note("setup", setup_s=round(marks["setup_s"], 3))
+        out = gen.run(seconds, on_open, on_clock_stop, on_round_end)
+        # what lies between two rounds is set-up
+        setup_s = marks["setup_s"] + out["rearm_s"]
+        ctx.note("setup", setup_s=round(setup_s, 3),
+                 to_first_window_s=round(marks["setup_s"], 3))
 
         # -- per-layer observations (read after the clock stopped) ------
         window = (out["t0"], out["t1"])
@@ -248,8 +274,11 @@ def main(argv, t_start: float) -> int:
             if path is None:
                 raise RuntimeError("the profiler wrote no trace")
             t_lo, t_hi = tracing.t0, tracing.t1
+            # a second of records past the trace: the launch that ends
+            # its last idle gap may open after it
             reduced = obs["profile"] = xplane.reduce_trace(
-                path, (t_lo, t_hi), observe.spans_overlapping(t_lo, t_hi))
+                path, (t_lo, t_hi),
+                observe.spans_overlapping(t_lo, t_hi + 1.0))
             ctx.note("profile", trace=path,
                      trace_bytes=os.path.getsize(path),
                      busy_s=reduced["busy_s"], window_s=reduced["window_s"],
@@ -264,41 +293,46 @@ def main(argv, t_start: float) -> int:
 
         # -- correct (outside the window) --------------------------------
         t_check = time.perf_counter()
-        quiet = dep.quiesce()
-        rules = traffic.get("check", {})
-        # an error inside the window always counts; outside it (set-up,
+
+        def in_a_window(t: float) -> bool:
+            return any(t0 <= t <= t1 for t0, t1 in out["windows"])
+
+        # an error inside a window always counts; outside them (set-up,
         # the check's own pause, teardown) the configuration may list a
         # pattern it has seen there and explains
         tolerated = config.get("tolerated_errors_outside_window", {})
         fatal = [e for t, e in watch.errors
-                 if window[0] <= t <= window[1]
-                 or not any(pat in e for pat in tolerated)]
+                 if in_a_window(t) or not any(pat in e for pat in tolerated)]
         ctx.note("errors", fatal=len(fatal), in_window=sum(
-            window[0] <= t <= window[1] for t, _ in watch.errors),
+            in_a_window(t) for t, _ in watch.errors),
             tolerated={pat: sum(pat in e for _, e in watch.errors)
                        for pat in tolerated})
-        state = check.end_state(
-            dep.server, gen.specs, out["complete"], used0, rules, args.seed,
-            delta["solver"], fatal, check.spread_attribute(traffic))
+        states[-1]["errors"] = fatal
         beats = dict(dep.swarm.stats)
         stats = devs[0].memory_stats() or {}
         gen.close()
         dep.stop()
         stopped = True
-        verdict = check.judge(check.add_reference(state), rules)
+        memo: dict = {}
+        verdict = check.judge_rounds(
+            [check.add_reference(state, memo) for state in states], rules)
         failed = sorted(set(out["failed_ids"]) | set(verdict["failed_jobs"]))
         ctx.note("check", correct=verdict["correct"],
-                 reasons=verdict["reasons"], complete_jobs=len(state["jobs"]),
-                 fitness=round(state["fitness"], 5),
-                 reference_fitness=round(state["reference_fitness"], 5),
-                 reference_unplaced=state["reference_unplaced"],
-                 spread_jobs=len(state["spread"]),
-                 spread_worst=max((max(p) - min(p)
-                                   for p in state["spread"].values()),
+                 reasons=verdict["reasons"],
+                 complete_jobs=[len(st["jobs"]) for st in states],
+                 fitness=[round(st["fitness"], 5) for st in states],
+                 reference_fitness=[round(st["reference_fitness"], 5)
+                                    for st in states],
+                 reference_unplaced=[st["reference_unplaced"]
+                                     for st in states],
+                 spread_jobs=[len(st["spread"]) for st in states],
+                 spread_worst=max((max(p) - min(p) for st in states
+                                   for p in st["spread"].values()),
                                   default=None),
                  heartbeats=beats["heartbeats"],
                  hb_failures=beats["hb_failures"],
-                 quiesced=quiet, took=state["took"],
+                 quiesced=marks["quiesced"], took=states[0]["took"],
+                 references_run=len(memo),
                  check_s=round(time.perf_counter() - t_check, 3))
         ctx.note("counters", solver={k: v for k, v in delta["solver"].items()
                                      if v}, applier=delta["applier"],
@@ -312,7 +346,7 @@ def main(argv, t_start: float) -> int:
         else:
             units = {m["name"]: m["unit"]
                      for m in metrics_of(bench, "end_to_end", cell["name"])}
-            values = dict(out["end_to_end"], setup_s=marks["setup_s"])
+            values = dict(out["end_to_end"], setup_s=setup_s)
             metrics = {n: {"value": float(values[n]), "unit": u}
                        for n, u in units.items() if n in values}
         result = {
@@ -327,6 +361,9 @@ def main(argv, t_start: float) -> int:
         }
         if breakdown is not None:
             result["breakdown"] = breakdown
+        # each number `correct` compared, beside its limit: last here
+        # and, below, the last lines of standard error
+        result["checked"] = verdict["compared"]
     except Exception:
         traceback.print_exc()
     finally:
@@ -340,6 +377,8 @@ def main(argv, t_start: float) -> int:
         watch.close()
     if result is None:
         return 1
+    for name, (value, limit) in result["checked"].items():
+        print(f"checked {name} = {value} (limit {limit})", file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0

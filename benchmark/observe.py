@@ -134,13 +134,14 @@ def spans_in_window(t0: float, t1: float) -> dict:
 
 
 def spans_overlapping(t0: float, t1: float) -> list:
-    """[(name, t0, t1)] of every TRACER span that overlaps [t0, t1]: what
-    the host was doing, for labelling the device's idle gaps."""
+    """[(name, t0, t1, thread)] of every TRACER span that overlaps
+    [t0, t1]: what each thread of the host was doing, for labelling the
+    device's idle gaps."""
     from nomad_tpu.obs import TRACER
-    from nomad_tpu.obs.trace import R_NAME, R_T0, R_T1
+    from nomad_tpu.obs.trace import R_NAME, R_T0, R_T1, R_THREAD
 
-    return [(r[R_NAME], r[R_T0], r[R_T1]) for r in TRACER.spans()
-            if r[R_T1] > t0 and r[R_T0] < t1]
+    return [(r[R_NAME], r[R_T0], r[R_T1], r[R_THREAD])
+            for r in TRACER.spans() if r[R_T1] > t0 and r[R_T0] < t1]
 
 
 def stat(values: list, which: str) -> float:

@@ -7,7 +7,9 @@ The cell is looked up in BENCHMARK.json; its configuration, traffic mix
 and per-layer metrics are data files under benchmark/ found by name
 (benchmark/harness.py). The last line of standard output is the result:
 one JSON object with `correct`, `attempted`, `failed`, `metrics` and
-`device` (and `breakdown` in a traced run). Off a TPU, with fewer chips
+`device` (and `breakdown` in a traced run), then `checked`: each number
+`correct` compared beside its limit, which are also the last lines of
+standard error. Off a TPU, with fewer chips
 than the cell asks for, or in a directory without the program, nothing
 is printed there and the exit code is not 0.
 

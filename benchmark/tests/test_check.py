@@ -37,6 +37,13 @@ def good_state() -> dict:
 def test_a_clean_state_is_correct():
     v = check.judge(good_state(), RULES)
     assert v["correct"] and not v["reasons"] and not v["failed_jobs"]
+    # every number compared stands beside its limit, and is within it
+    assert v["compared"] == {
+        "jobs_off_count": [0, 0], "nodes_over_capacity": [0, 0],
+        "ports_twice": [0, 0], "nodes_down": [0, 0],
+        "spread_max_less_min": [1, 1 + 0.25 * 4 / 5],
+        "fitness_under_reference": [pytest.approx(0.002 / 0.802), 5e-3],
+        "retraces": [0, 0], "twin_failures": [0, 0], "errors": [0, 0]}
 
 
 def over_capacity(s):
@@ -71,6 +78,13 @@ def error_logged(s):
     s["errors"] = ["log nomad_tpu.worker: eval failed"]
 
 
+PAST = {over_capacity: "nodes_over_capacity", port_twice: "ports_twice",
+        one_alloc_short: "jobs_off_count",
+        spread_broken: "spread_max_less_min",
+        fitness_below: "fitness_under_reference", node_down: "nodes_down",
+        retraced: "retraces", error_logged: "errors"}
+
+
 @pytest.mark.parametrize("doctor", [
     over_capacity, port_twice, one_alloc_short, spread_broken,
     fitness_below, node_down, retraced, error_logged])
@@ -81,6 +95,33 @@ def test_a_doctored_state_is_refused(doctor):
     assert not v["correct"] and v["reasons"]
     if doctor in (one_alloc_short, spread_broken):
         assert v["failed_jobs"]
+    # exactly the doctored number is past its limit
+    past = [n for n, (got, limit) in v["compared"].items() if got > limit]
+    assert past == [PAST[doctor]]
+
+
+def test_rounds_are_one_verdict_and_one_bad_round_fails_the_run():
+    """A run of several rounds is correct only if each round is; a count
+    held to 0 is the rounds' sum, a graded number the round nearest its
+    limit."""
+    clean = check.judge_rounds([good_state(), good_state()], RULES)
+    assert clean["correct"] and not clean["reasons"]
+    assert clean["compared"] == check.judge(good_state(), RULES)["compared"]
+    short, uneven = good_state(), good_state()
+    one_alloc_short(short)
+    fitness_below(uneven)
+    one_alloc_short(uneven)
+    v = check.judge_rounds([good_state(), short, uneven], RULES)
+    assert not v["correct"]
+    assert [r.split(":")[0] for r in v["reasons"]] == [
+        "round 1", "round 2", "round 2"]
+    assert v["compared"]["jobs_off_count"] == [2, 0]
+    assert v["compared"]["fitness_under_reference"] == check.judge(
+        uneven, RULES)["compared"]["fitness_under_reference"]
+    assert len(v["failed_jobs"]) == 2
+    # one round is reported as it always was
+    assert check.judge_rounds([short], RULES)["reasons"] == check.judge(
+        short, RULES)["reasons"]
 
 
 def toy_fleet(n=96, seed=0):
@@ -181,3 +222,44 @@ def test_references_agree_with_the_host_scheduler_at_toy_size():
     want_fit = mean_fitness(fleet["cap"], ref["used"], ref["counts"])
     print("spread arm fitness: host", got, "reference", want_fit)
     assert got == pytest.approx(want_fit, rel=0.10)
+
+
+def test_the_control_of_the_fitness_comparison_at_the_cells_own_size():
+    """The reference put in the program's place with worst-fit scoring
+    (upstream's spread algorithm) where the configuration states bin
+    packing, on the grid's 10,000 nodes and the cell's 50 x 300 jobs:
+    it reads 10.4% under the reference, and the cell's limit refuses it
+    with room (PERF.md section 2 has the sound runs' readings)."""
+    import json
+    from pathlib import Path
+
+    from benchmark import traffic as traffic_mod
+    from benchmark.reference import spread_greedy
+
+    here = Path(__file__).resolve().parents[1]
+    traffic = json.loads((here / "traffic/spread.300.json").read_text())
+    config = json.loads((here / "configs/grid-10k.json").read_text())
+    n, racks = config["nodes"], config["node_mix"]["racks"]
+    cap = np.tile(np.array([[float(config["node_mix"]["cpu_mhz"][0]),
+                             float(config["node_mix"]["memory_mb"][0])]]),
+                  (n, 1))
+    value_of = np.arange(n) % racks
+    jobs = traffic_mod.job_specs(traffic, 7, int(traffic["jobs"]), "c")
+    ref = reference_fitness(cap, np.zeros_like(cap), value_of, racks, jobs)
+    spread_greedy.bestfit = lambda c, u: 1.0 - bestfit(c, u)
+    try:
+        control = reference_fitness(cap, np.zeros_like(cap), value_of,
+                                    racks, jobs)
+    finally:
+        spread_greedy.bestfit = bestfit
+    assert ref["unplaced"] == control["unplaced"] == 0
+    state = good_state()
+    state["fitness"] = control["fitness"]
+    state["reference_fitness"] = ref["fitness"]
+    v = check.judge(state, traffic["check"])
+    got, limit = v["compared"]["fitness_under_reference"]
+    assert got == pytest.approx(0.1042, abs=2e-3) and limit == 0.02
+    assert not v["correct"]
+    # the widest sound reading on the chip, one drain in 289, passes
+    state["fitness"] = ref["fitness"] * (1 - 0.00601)
+    assert check.judge(state, traffic["check"])["correct"]

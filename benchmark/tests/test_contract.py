@@ -10,6 +10,9 @@ from pathlib import Path
 
 import pytest
 
+from benchmark.generators.backlog import rounds_of
+from benchmark.harness import load_cell
+
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -65,21 +68,25 @@ def test_keys_names_units_and_bounds():
     assert (ROOT / "BENCHMARK.json").stat().st_size < 64 * 1024
 
 
-def run_cell(cell: str, trace: int, seconds: int = 8):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+def run_cell(cell: str, trace: int, seconds: int = 8, root: Path = ROOT):
+    """One toy run of `cell` from the checkout at `root` -> (the result
+    line, standard output, standard error)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT))
     proc = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", cell, "--seed",
          "3", "--seconds", str(seconds), "--trace", str(trace), "--toy"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+        cwd=root, env=env, capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    return line, proc.stdout, proc.stderr
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_toy_cell_prints_the_contracts_line(cell):
-    line, _ = run_cell(cell, trace=0)
+    line, out, err = run_cell(cell, trace=0)
     assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+                         "device", "checked"}
+    assert list(line)[-1] == "checked"
     assert set(line["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
     want = {m["name"] for m in BENCH["end_to_end"]
@@ -88,14 +95,25 @@ def test_toy_cell_prints_the_contracts_line(cell):
     assert all(set(v) == {"value", "unit"} and v["value"] > 0
                for v in line["metrics"].values())
     assert line["correct"] is True and line["failed"] == 0, line
-    assert line["attempted"] > 0
+    # the backlog as often as the configuration asks for it (rounds)
+    _, _, config, traffic = load_cell(cell, True)
+    assert line["attempted"] == traffic["jobs"] * rounds_of(config, True)
+    assert f"made={rounds_of(config, True)} " in out
+    # each number compared beside its limit: last in the line, and the
+    # last lines of standard error
+    assert line["checked"]["fitness_under_reference"][1] > 0
+    assert all(got <= limit for got, limit in line["checked"].values())
+    last = err.strip().splitlines()[-len(line["checked"]):]
+    assert [l.split()[1] for l in last] == list(line["checked"])
+    assert all(l.startswith("checked ") and "(limit " in l for l in last)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_toy_traced_cell_reports_layers_and_breakdown(cell):
-    line, out = run_cell(cell, trace=1)
+    line, out, _ = run_cell(cell, trace=1)
     assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device", "breakdown"}
+                         "device", "breakdown", "checked"}
+    assert list(line)[-1] == "checked"
     assert line["device"]["busy_s"] > 0 and line["device"]["window_s"] > 0
     allowed = {m["name"] for m in BENCH["per_layer"]
                if cell in m.get("workloads", CELLS)}
@@ -106,7 +124,14 @@ def test_toy_traced_cell_reports_layers_and_breakdown(cell):
     assert "[timeline]" in out
     assert line["breakdown"]["device_ops"]
     assert len(line["breakdown"]["device_ops"]) <= 10
-    assert len(line["breakdown"]["idle_gaps"]) <= 10
+    gaps = line["breakdown"]["idle_gaps"]
+    assert len(gaps) <= 10
+    # cut along the launching thread: the labels' seconds are the idle
+    # time, and more than one thing stood between the device and its
+    # next launch
+    assert sum(s for _, s in gaps) == pytest.approx(
+        line["device"]["window_s"] - line["device"]["busy_s"], rel=1e-3)
+    assert len({n for n, _ in gaps} - {"no_span", "other"}) >= 2
 
 
 def test_off_a_tpu_there_is_no_result_line():

@@ -2,68 +2,60 @@
 directories is found with no edit to code."""
 
 import json
-import os
-import shutil
-import subprocess
-import sys
 from pathlib import Path
 
+import pytest
+
 from benchmark import layers
+from benchmark.tests.kept_cells import (C2M_METRICS, SPREAD_1200,
+                                        write_copy)
+from benchmark.tests.test_contract import run_cell
+from benchmark.tests.test_phase_metrics import (assert_phase_metrics,
+                                                phase_metrics_of)
+from benchmark.tests.test_stage_metric import assert_stage_metric
 
 ROOT = Path(__file__).resolve().parents[2]
 
 
-C2M_METRICS = [("solver.wait_ms", "ms", "lower", "program_span"),
-               ("solver.evals_per_launch", "evals", "higher",
-                "program_counter"),
-               ("solver.resyncs", "count", "lower", "program_counter"),
-               ("solve_bulk_multi_ms", "ms", "lower", "device_trace"),
-               ("solve_bulk_multi_roofline", "%", "higher", "device_trace")]
-
-
-def test_a_new_cell_and_a_new_layer_metric_are_files_and_entries(tmp_path):
-    """`c2m.backlog` is kept as files (configuration, traffic, readers)
-    and listed in no BENCHMARK.json until its metric repeats (PERF.md
-    section 4). Here a copy of the benchmark gets its entries, plus one
-    new layer-metric file and entry: the cell runs (at --toy size) with
-    no edit to code, and its traced line carries the metrics."""
-    shutil.copytree(ROOT / "benchmark", tmp_path / "benchmark",
-                    ignore=shutil.ignore_patterns(".work", "__pycache__",
-                                                  "tests"))
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    config = json.loads(
-        (ROOT / "benchmark/configs/c2m-10k.json").read_text())
-    bench["configs"].append({
-        "name": "c2m-10k", "source": config["source"],
-        "file": "benchmark/configs/c2m-10k.json",
-        "reduced": config["reduced"], "why": "test"})
-    bench["workloads"].append({
-        "name": "c2m.backlog", "config": "c2m-10k", "traffic": "backlog",
-        "chips": 1, "why": "test"})
-    (tmp_path / "benchmark/layer_metrics/worker.snapshot_ms.json").write_text(
+@pytest.fixture(scope="module")
+def copy_with_the_kept_cells(tmp_path_factory):
+    """`kept_cells.write_copy` plus one new layer-metric file and its
+    entry: a cell and a metric are files and entries, never code."""
+    root = tmp_path_factory.mktemp("kept") / "copy"
+    bench = write_copy(root, extra_metrics=(
+        ("worker.snapshot_ms", "ms", "lower", "program_span",
+         "scheduler worker"),))
+    (root / "benchmark/layer_metrics/worker.snapshot_ms.json").write_text(
         json.dumps({"layer": "scheduler worker", "unit": "ms",
                     "moves": "allocs_per_s",
                     "reader": {"kind": "span", "span": "worker.snapshot",
                                "stat": "median", "scale": 1000}}))
-    for name, unit, better, source in C2M_METRICS + [
-            ("worker.snapshot_ms", "ms", "lower", "program_span")]:
-        bench["per_layer"].append({
-            "name": name, "unit": unit, "better": better, "source": source,
-            "layer": layers.load(name, tmp_path / "benchmark/layer_metrics")[
-                "layer"],
-            "moves": "allocs_per_s", "workloads": ["c2m.backlog"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT))
-    proc = subprocess.run(
-        [sys.executable, "benchmark/run.py", "--workload", "c2m.backlog",
-         "--seed", "4", "--seconds", "8", "--trace", "1", "--toy"],
-        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["attempted"] == 24
-    for name in ("worker.snapshot_ms", "solver.wait_ms",
-                 "solver.evals_per_launch", "solve_bulk_multi_ms"):
-        assert line["metrics"][name]["value"] > 0, name
+    return root, bench
+
+
+@pytest.mark.parametrize("cell", ["c2m.backlog", "grid.spread.1200"])
+def test_a_kept_cell_is_files_and_entries(copy_with_the_kept_cells, cell):
+    """`c2m.backlog` and, since PR 31, `grid.spread.1200` are kept as
+    files and listed in no BENCHMARK.json until the parent runs them
+    steadily (PERF.md sections 2 and 4). With its entries put back in a
+    copy, each runs (at --toy size) with no edit to code, and its traced
+    line carries its metrics."""
+    root, bench = copy_with_the_kept_cells
+    assert len(SPREAD_1200["why"]) <= 200
+    line, out, _ = run_cell(cell, trace=1, root=root)
+    assert line["correct"] is True and line["failed"] == 0
+    if cell == "c2m.backlog":
+        assert line["attempted"] == 24
+        for name in ("worker.snapshot_ms", "solver.wait_ms",
+                     "solver.evals_per_launch", "solve_bulk_multi_ms"):
+            assert line["metrics"][name]["value"] > 0, name
+        return
+    # the phase spans of PR 25 and the stage of PR 26, as in the two
+    # listed cells of its configuration
+    assert_phase_metrics(line, phase_metrics_of(cell, bench))
+    assert_stage_metric(line, out, ["placer.gather_ms", "placer.ship_ms",
+                                    "placer.host_locked_pct"])
+    assert line["metrics"]["placer.scan_steps_run_pct"]["value"] > 0
 
 
 def test_layer_metric_readers(tmp_path):

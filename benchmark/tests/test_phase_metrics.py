@@ -1,10 +1,12 @@
 """The per-layer metrics over the phase spans of the two critical
 sections (PR 25): every file loads, a program without the spans gives
-nothing to read, and the toy traced run of each cell reports them."""
+nothing to read, and the toy traced run of each listed cell reports those
+that list it. Entries are found by name, wherever later PRs appended."""
 
 import pytest
 
 from benchmark import layers
+from benchmark.harness import metrics_of
 from benchmark.tests.test_contract import BENCH, CELLS, run_cell
 
 PLACER_MS = ["placer.lock_wait_ms", "placer.gather_ms", "placer.pack_ms",
@@ -15,15 +17,21 @@ NEW = PLACER_MS + ["placer.host_locked_pct", "store.lock_wait_ms",
                    "store.commit_offcpu_pct", "applier.rows_rejected_pct"]
 
 
-def test_every_new_metric_is_listed_last_and_its_file_loads():
-    listed = [m["name"] for m in BENCH["per_layer"]]
-    assert listed[-len(NEW):] == NEW
-    for m in BENCH["per_layer"][-len(NEW):]:
-        spec = layers.load(m["name"])
+# where a metric's own `workloads` list leaves a listed cell out, and
+# why (PERF.md section 3)
+NOT_IN = {"placer.host_locked_pct": {"grid.r3.spread.300"}}
+
+
+def test_every_new_metric_is_listed_and_its_file_loads():
+    listed = {m["name"]: m for m in BENCH["per_layer"]}
+    for name in NEW:
+        m = listed[name]
+        spec = layers.load(name)
         assert (spec["unit"], spec["layer"], spec["moves"]) == (
             m["unit"], m["layer"], m["moves"])
-        assert m["workloads"] == CELLS
-        code = layers.HERE / f"{m['name']}.py"
+        assert m["workloads"] == [c for c in CELLS
+                                  if c not in NOT_IN.get(name, ())]
+        code = layers.HERE / f"{name}.py"
         assert ("_read" in spec) == code.exists()
         assert spec["reader"]["kind"] == ("code" if code.exists() else
                                           "counter" if m["source"] ==
@@ -74,17 +82,30 @@ def test_rows_rejected_pct_is_a_share_of_rows_verified():
     assert layers.read_all(["applier.rows_rejected_pct"], parent) == {}
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_toy_traced_cell_reports_the_phase_metrics(cell):
-    line, _ = run_cell(cell, trace=1)
+def phase_metrics_of(cell: str, bench: dict = BENCH) -> list:
+    """PR 25's metrics that list `cell`, by each metric's own list."""
+    return [m["name"] for m in metrics_of(bench, "per_layer", cell)
+            if m["name"] in NEW]
+
+
+def assert_phase_metrics(line: dict, names: list) -> None:
     metrics = line["metrics"]
-    for name in NEW:
+    for name in names:
         assert name in metrics, name
     for name in PLACER_MS:
         assert metrics[name]["value"] > 0 and metrics[name]["unit"] == "ms"
-    assert 0 <= metrics["placer.host_locked_pct"]["value"] <= 100
-    assert 0 <= metrics["store.commit_offcpu_pct"]["value"] <= 100
-    assert 0 <= metrics["applier.rows_rejected_pct"]["value"] <= 100
+    for name in ("placer.host_locked_pct", "store.commit_offcpu_pct",
+                 "applier.rows_rejected_pct"):
+        if name in names:
+            assert 0 <= metrics[name]["value"] <= 100
     # the phases are parts of the span the accepted metric reads
     parts = sum(metrics[n]["value"] for n in PLACER_MS)
     assert metrics["placer.solve_ms"]["value"] > 0 and parts > 0
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_toy_traced_cell_reports_the_phase_metrics(cell):
+    line, _, _ = run_cell(cell, trace=1)
+    names = phase_metrics_of(cell)
+    assert set(PLACER_MS) <= set(names)
+    assert_phase_metrics(line, names)

@@ -6,14 +6,15 @@ nothing to read, and the toy traced run of each cell reports it."""
 import pytest
 
 from benchmark import layers
+from benchmark.harness import metrics_of
 from benchmark.tests.test_contract import BENCH, CELLS, run_cell
 
 NAME = "placer.stage_ms"
 
 
-def test_the_metric_is_listed_last_and_its_file_is_data():
-    m = BENCH["per_layer"][-1]
-    assert m["name"] == NAME and m["workloads"] == CELLS
+def test_the_metric_is_listed_and_its_file_is_data():
+    m = next(m for m in BENCH["per_layer"] if m["name"] == NAME)
+    assert m["workloads"] == CELLS
     assert m["source"] == "program_span"
     spec = layers.load(NAME)
     assert (spec["unit"], spec["layer"], spec["moves"]) == (
@@ -51,15 +52,22 @@ def test_reads_the_median_stage_in_ms_and_nothing_from_the_parent():
                                               "self": {}}}) == {}
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_toy_traced_cell_reports_the_stage(cell):
-    line, out = run_cell(cell, trace=1)
+def assert_stage_metric(line: dict, out: str, names: list) -> None:
     stage = line["metrics"][NAME]
     assert stage["unit"] == "ms" and stage["value"] > 0
     # the phases under the lock keep reading: same spans, same names
-    for name in ("placer.gather_ms", "placer.pack_ms", "placer.ship_ms",
-                 "placer.device_wait_ms", "placer.host_locked_pct"):
+    for name in names:
         assert name in line["metrics"], name
     # one stage a hold
     spans = next(l for l in out.splitlines() if l.startswith("[spans]"))
     assert "placer.stage" in spans
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_toy_traced_cell_reports_the_stage(cell):
+    line, out, _ = run_cell(cell, trace=1)
+    assert_stage_metric(line, out, [
+        m["name"] for m in metrics_of(BENCH, "per_layer", cell)
+        if m["name"] in ("placer.gather_ms", "placer.pack_ms",
+                         "placer.ship_ms", "placer.device_wait_ms",
+                         "placer.host_locked_pct")])
