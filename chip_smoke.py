@@ -80,7 +80,7 @@ class Sizes:
     rtt_reps: int         # round-trip probe repetitions
 
 
-# BASELINE.json "C2M" and bench.py cfg_c2m / cfg4, uncut
+# BASELINE.json "C2M", uncut
 FULL = Sizes(nodes=10_240, jobs=500, per_job=4_000, service_jobs=4,
              service_count=256, joint_jobs=8, joint_count=1_000,
              preempt_nodes=1_024, preempt_hi=512, workers=24,
@@ -422,12 +422,12 @@ def launches_since(tail, name: str) -> int:
 
 def batch_jobs(prefix: str, n: int, count: int, cpu: int, mem: int,
                priority: int = 50, batch: bool = True):
-    import bench
+    from nomad_tpu import mock
 
     jobs = []
     for i in range(n):
-        j = bench.service_job(count, cpu=cpu, mem=mem, batch=batch,
-                              priority=priority)
+        j = mock.service_job(count, cpu=cpu, mem=mem, batch=batch,
+                             priority=priority)
         j.id = j.name = f"{prefix}-{i:04d}"
         jobs.append(j)
     return jobs
@@ -593,7 +593,8 @@ def leg_joint(run: Run, agent, api) -> None:
 
 
 def leg_preempt(run: Run) -> None:
-    """D: bench.py cfg4's shape through a served agent of its own."""
+    """D: BASELINE.md config 4 (system + preemption, mixed priority)
+    through a served agent of its own."""
     from nomad_tpu.api.client import ApiClient
     from nomad_tpu.structs import enums
     from nomad_tpu.tensor.cluster import _pad_pow2
@@ -873,7 +874,7 @@ def run_smoke(sizes: Sizes, seed: int) -> dict:
     import jax
     import jaxlib
 
-    import bench
+    from nomad_tpu import mock
     from nomad_tpu.analysis import launch_ledger
     from nomad_tpu.api.client import ApiClient
     from nomad_tpu.structs import enums
@@ -906,7 +907,7 @@ def run_smoke(sizes: Sizes, seed: int) -> dict:
             t0 = time.perf_counter()
             rng = random.Random(seed)
             swarm = join_fleet(agent.server, sizes.nodes, f"c2m{seed}",
-                               lambda node, i: bench.shape_node(node, i, rng))
+                               lambda node, i: mock.shape_node(node, i, rng))
             run.say("fleet", nodes=sizes.nodes,
                     join_s=round(time.perf_counter() - t0, 3))
             leg_c2m(run, agent, api)
@@ -950,7 +951,7 @@ def main(argv=None) -> int:
 
     # alone in a directory there is no program to drive: say so before
     # anything is printed on stdout
-    missing = [m for m in ("nomad_tpu", "bench", "__graft_entry__")
+    missing = [m for m in ("nomad_tpu", "__graft_entry__")
                if importlib.util.find_spec(m) is None]
     if missing:
         print(f"chip_smoke: {', '.join(missing)} not found next to the "

@@ -1060,7 +1060,7 @@ def _scenario_raft_commit(env: ScenarioEnv) -> None:
                 nid, [p for p in ("a", "b", "c") if p != nid],
                 transport, applied[nid].append,
                 election_timeout=1e6,      # no spontaneous elections
-                heartbeat_interval=0.05, log=log, batch=True))
+                heartbeat_interval=0.05, log=log))
         for n in nodes:
             n.start()
         _force_leader(nodes[0])
@@ -1121,8 +1121,7 @@ def _scenario_raft_stepdown(env: ScenarioEnv) -> None:
 
     transport = InProcTransport()
     node = RaftNode("a", ["b", "c"], transport, lambda cmd: None,
-                    election_timeout=1e6, heartbeat_interval=0.05,
-                    batch=True)
+                    election_timeout=1e6, heartbeat_interval=0.05)
     transport.partition("b")       # peers exist but never answer
     transport.partition("c")
     node.start()
@@ -1184,7 +1183,7 @@ def _scenario_read_index(env: ScenarioEnv) -> None:
             nid, [p for p in ("a", "b", "c") if p != nid],
             transport, lambda cmd: None,
             election_timeout=1e6,      # no spontaneous elections
-            heartbeat_interval=0.05, batch=True,
+            heartbeat_interval=0.05,
             lease_duration=0.01)       # lapses within one sleep below
     try:
         for n in nodes.values():
@@ -1336,7 +1335,7 @@ def _scenario_snapshot_compact(env: ScenarioEnv) -> None:
                 fsm_capture=(lambda lst=lst: list(lst)),
                 fsm_serialize=(lambda cap: {"items": [list(c)
                                                       for c in cap]}),
-                snapshot_threshold=3, batch=True,
+                snapshot_threshold=3,
                 snapshot_chunk_bytes=64)   # force a multi-frame install
             snaps.node = n
             nodes.append(n)
@@ -1493,7 +1492,7 @@ def _scenario_plan_pipeline(env: ScenarioEnv) -> None:
 
     store = _PipelineStore()
     store.start()
-    applier = PlanApplier(store, PlanQueue(), batch=True)
+    applier = PlanApplier(store, PlanQueue())
     applier.start()
     try:
         stranded: List[str] = []
@@ -2098,7 +2097,7 @@ def _scenario_overload(env: ScenarioEnv) -> None:
             return clock[0]
 
     depth = [0]
-    adm = AdmissionController(enabled=True, clock=now, refresh_s=0.0,
+    adm = AdmissionController(clock=now, refresh_s=0.0,
                               brownout_after=0.05, brownout_exit=0.1)
     adm.register_queue("q", lambda: depth[0], soft=10, hard=100,
                        commit_path=True)

@@ -293,8 +293,7 @@ def e2e_smoke(jobs_n: int = 300, nodes_n: int = 75, workers: int = 4) -> int:
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=workers, plan_commit_batching=True,
-            eval_batch_size=8,
+            num_workers=workers, eval_batch_size=8,
             heartbeat_ttl=3600.0, gc_interval=3600.0, nack_timeout=900.0,
             failed_eval_followup_delay=3600.0,
             failed_eval_unblock_interval=0.5)
@@ -318,7 +317,7 @@ def e2e_smoke(jobs_n: int = 300, nodes_n: int = 75, workers: int = 4) -> int:
                 j.task_groups[0].count = 1
                 # small tasks, low cluster utilization: the gate measures
                 # pipeline safety across a failover, not placement
-                # contention (bench.py's rungs own the contention axis)
+                # contention (the benchmark's cells own that axis)
                 j.task_groups[0].tasks[0].resources.cpu = 100
                 j.task_groups[0].tasks[0].resources.memory_mb = 64
                 jobs.append(j)
@@ -389,7 +388,7 @@ def e2e_smoke(jobs_n: int = 300, nodes_n: int = 75, workers: int = 4) -> int:
 
             # rejection across BOTH leaderships: optimistic-concurrency
             # rejects are retried by the submitter, so the rate is
-            # rejected / (placed + rejected) like bench.py's rungs
+            # rejected / (placed + rejected)
             stats = dict(fresh.server.plan_applier.stats)
             rejected = (stats.get("nodes_rejected", 0)
                         + old_stats.get("nodes_rejected", 0))
@@ -439,17 +438,15 @@ def load_smoke(nodes_n: int = 30, burst_s: float = 6.0,
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=2, plan_commit_batching=True, eval_batch_size=8,
+            num_workers=2, eval_batch_size=8,
             heartbeat_ttl=10.0, gc_interval=3600.0, nack_timeout=900.0,
             failed_eval_followup_delay=3600.0,
-            # the plane under test: force-enabled (the smoke is
-            # meaningless against the kill-switch baseline) with
-            # watermarks low enough that a 10x burst genuinely trips
-            # them on a laptop-scale cluster. They must sit BELOW the
+            # the plane under test: watermarks low enough that a 10x
+            # burst genuinely trips them on a laptop-scale cluster.
+            # They must sit BELOW the
             # open-loop worker pool: submits block in propose, so queue
             # depth is bounded by the number of in-flight clients — a
             # soft mark above that can never be reached.
-            loadctl_enabled=True,
             loadctl_proposal_soft=8, loadctl_proposal_hard=24,
             loadctl_plan_soft=8, loadctl_plan_hard=24,
             loadctl_broker_soft=16, loadctl_broker_hard=48,
@@ -653,8 +650,7 @@ def flow_smoke(jobs_n: int = 120, nodes_n: int = 40,
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=workers, plan_commit_batching=True,
-            eval_batch_size=8,
+            num_workers=workers, eval_batch_size=8,
             heartbeat_ttl=3600.0, gc_interval=3600.0, nack_timeout=900.0,
             failed_eval_followup_delay=3600.0,
             failed_eval_unblock_interval=0.5)
@@ -785,8 +781,7 @@ def state_smoke(jobs_n: int = 120, nodes_n: int = 40,
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=workers, plan_commit_batching=True,
-            eval_batch_size=8,
+            num_workers=workers, eval_batch_size=8,
             # the tensor path is the whole point: every build must route
             # through ClusterTensors (and so the incremental feed)
             sched_config=SchedulerConfiguration(
@@ -954,7 +949,7 @@ def solve_smoke(nodes_n: int = 40, jobs_n: int = 4,
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=2, eval_batch_size=4, plan_commit_batching=True,
+            num_workers=2, eval_batch_size=4,
             sched_config=SchedulerConfiguration(
                 scheduler_algorithm=enums.SCHED_ALG_TPU_SOLVE,
                 preemption_config=PreemptionConfig(
@@ -1143,7 +1138,7 @@ def mesh_smoke(nodes_n: int = 40, jobs_n: int = 4,
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=2, eval_batch_size=4, plan_commit_batching=True,
+            num_workers=2, eval_batch_size=4,
             sched_config=SchedulerConfiguration(
                 scheduler_algorithm=enums.SCHED_ALG_TPU_SOLVE),
             heartbeat_ttl=3600.0, gc_interval=3600.0, nack_timeout=900.0,
@@ -1264,8 +1259,7 @@ def snap_smoke(jobs_n: int = 200, nodes_n: int = 60, workers: int = 4,
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=workers, plan_commit_batching=True,
-            eval_batch_size=8,
+            num_workers=workers, eval_batch_size=8,
             heartbeat_ttl=3600.0, gc_interval=3600.0, nack_timeout=900.0,
             failed_eval_followup_delay=3600.0,
             failed_eval_unblock_interval=0.5)
@@ -1412,7 +1406,7 @@ def swarm_smoke(nodes_n: int = 200, ttl: float = 2.0,
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=2, plan_commit_batching=True, eval_batch_size=8,
+            num_workers=2, eval_batch_size=8,
             heartbeat_ttl=ttl, heartbeat_shards=4,
             heartbeat_expiry_rate=128.0,
             gc_interval=3600.0, nack_timeout=900.0,
@@ -1619,7 +1613,7 @@ def swarm_scale_smoke(nodes_n: int = 50000, ttl: float = 10.0,
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=4, plan_commit_batching=True, eval_batch_size=8,
+            num_workers=4, eval_batch_size=8,
             heartbeat_ttl=ttl, heartbeat_shards=8,
             gc_interval=3600.0, nack_timeout=900.0,
             failed_eval_followup_delay=3600.0,

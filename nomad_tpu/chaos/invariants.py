@@ -381,8 +381,7 @@ class InvariantChecker:
         usage base must equal a fresh gen-bounded snapshot rebuild
         bit-exactly, flushed device twins included. Unlike the shadow
         prong the feeds attach in production, so this sweep runs
-        whenever any feed exists (NOMAD_TPU_INCR=0 turns each digest
-        into a no-op)."""
+        whenever any feed exists."""
         from ..tensor.incremental import GLOBAL as state
 
         if not state.feeds:

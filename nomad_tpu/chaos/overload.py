@@ -1,5 +1,4 @@
-"""nomadload open-loop arrival generator (chaos `overload` family +
-bench.py overload_goodput).
+"""nomadload open-loop arrival generator (chaos `overload` family).
 
 The defining property of an overload test is that the offered load
 does NOT let up when the server slows down: a closed-loop client (next

@@ -245,9 +245,8 @@ class ClientUpdateBatcher:
     the in-flight leader drains into the next single command. Callers
     block until their round commits."""
 
-    def __init__(self, store, batch: bool = True):
+    def __init__(self, store):
         self._store = store
-        self.batch_enabled = batch
         self._cond = threading.Condition()   # guards pending/flags/stats
         self._pending: List[Tuple[List, _Waiter]] = []
         self._committing = False
@@ -255,8 +254,6 @@ class ClientUpdateBatcher:
         self.stats = {"rounds": 0, "batched_updates": 0, "fallbacks": 0}
 
     def start(self) -> None:
-        if not self.batch_enabled:
-            return
         with self._cond:
             self.running = True
 
@@ -276,7 +273,7 @@ class ClientUpdateBatcher:
     def submit(self, updates: List) -> None:
         """Commit a client status batch; blocks until it is durable (or
         raises the per-caller failure). Falls through to a direct store
-        commit when batching is off or stopped."""
+        commit while not running (before start, after stop)."""
         if not updates:
             return
         lead = False

@@ -39,16 +39,14 @@ it system-wide. Three mechanisms, one module:
    keeps retries <= ~10% of requests so a rejection storm never
    amplifies itself.
 
-Kill switch: ``NOMAD_TPU_LOADCTL=0`` disables the whole plane (the
-bench baseline arm; see PERF.md "Overload goodput"). The controller
-keeps a bounded admit/shed ledger per server so chaos invariant 10
-(tier ordering: no tier-0 request ever shed while any tier-2 request
-is admitted) is checkable after the fact on every replica.
+The controller keeps a bounded admit/shed ledger per server so chaos
+invariant 10 (tier ordering: no tier-0 request ever shed while any
+tier-2 request is admitted) is checkable after the fact on every
+replica.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from collections import deque
@@ -67,12 +65,6 @@ TIER_NONE = 4       # sentinel: "no tier is shed"
 
 TIER_NAMES = {TIER_LIVENESS: "liveness", TIER_COMMIT: "commit",
               TIER_SUBMIT: "submit", TIER_READ: "read"}
-
-
-def env_enabled() -> bool:
-    """The NOMAD_TPU_LOADCTL kill switch (default on)."""
-    return os.environ.get("NOMAD_TPU_LOADCTL", "1").lower() not in (
-        "0", "false", "off")
 
 
 class RetryLater(Exception):
@@ -244,15 +236,13 @@ class AdmissionController:
     DEFAULT_RATES = {TIER_COMMIT: 16384.0, TIER_SUBMIT: 8192.0,
                      TIER_READ: 16384.0}
 
-    def __init__(self, enabled: Optional[bool] = None,
-                 rates: Optional[Dict[int, float]] = None,
+    def __init__(self, rates: Optional[Dict[int, float]] = None,
                  burst_s: float = 2.0,
                  refresh_s: float = 0.005,
                  brownout_after: float = 1.0,
                  brownout_exit: float = 3.0,
                  clock: Callable[[], float] = time.monotonic,
                  ledger_size: int = 4096):
-        self.enabled = env_enabled() if enabled is None else bool(enabled)
         self._clock = clock
         self._lock = threading.Lock()
         now = clock()
@@ -379,8 +369,6 @@ class AdmissionController:
         """True while the brownout state machine holds the server in
         degraded mode (reads answer stale-only, watch wakeups
         coalesce)."""
-        if not self.enabled:
-            return False
         with self._lock:
             self._eval_pressure_locked(self._clock())
             return self._degraded
@@ -391,8 +379,6 @@ class AdmissionController:
                   cost: float = 1.0) -> Optional[float]:
         """Non-raising admit: None on admission, else the suggested
         retry-after in seconds."""
-        if not self.enabled:
-            return None
         name = TIER_NAMES.get(tier, str(tier))
         with self._lock:
             now = self._clock()
@@ -467,7 +453,7 @@ class AdmissionController:
         with self._lock:
             now = self._clock()
             floor = self._shed_floor_locked(now)
-            return {"enabled": self.enabled, "pressure": self._pressure,
+            return {"pressure": self._pressure,
                     "degraded": self._degraded, "shed_floor": floor,
                     "alive": self._alive, **self.stats}
 

@@ -283,8 +283,8 @@ class PlanApplier:
 
     # Per-node verification CAN fan out over the pool (set this lower),
     # but _node_plan_valid is pure-Python and GIL-bound: measured at 5K
-    # touched nodes the pool runs ~3x SLOWER than the serial loop
-    # (bench.py cfg6), unlike the reference's Go EvaluatePool. Serial is
+    # touched nodes the pool runs ~3x SLOWER than the serial loop,
+    # unlike the reference's Go EvaluatePool. Serial is
     # therefore the default; the pool pays off only if the per-node check
     # grows GIL-releasing work (native fit kernels, IO).
     PARALLEL_THRESHOLD = 1 << 30
@@ -308,14 +308,12 @@ class PlanApplier:
 
     def __init__(self, store, queue: PlanQueue, logger=None,
                  pool_workers: Optional[int] = None,
-                 bad_node_tracker: Optional[BadNodeTracker] = None,
-                 batch: bool = True):
+                 bad_node_tracker: Optional[BadNodeTracker] = None):
         import os
 
         self.store = store
         self.queue = queue
         self.logger = logger
-        self.batch = batch
         self._thread: Optional[threading.Thread] = None
         self._commit_thread: Optional[threading.Thread] = None
         # verified-and-waiting commit entries the commit thread coalesces
@@ -326,14 +324,13 @@ class PlanApplier:
                       "partial_commits": 0,
                       "commit_batches": 0, "batched_commits": 0,
                       "batched_eval_updates": 0}
-        # commits are serialized through the 1-worker commit pool, but
-        # the synchronous apply() entrypoint can run concurrently with
-        # the loop; counters get their own leaf lock
+        # commits are serialized through the commit thread, but the
+        # synchronous apply() entrypoint can run concurrently with the
+        # loop; counters get their own leaf lock
         self._stats_lock = threading.Lock()
         # reference plan_apply_pool.go: half the cores
         self.pool_workers = pool_workers or max(2, (os.cpu_count() or 2) // 2)
         self._pool: Optional[ThreadPoolExecutor] = None
-        self._commit_pool: Optional[ThreadPoolExecutor] = None
         self.bad_nodes = bad_node_tracker or BadNodeTracker()
         # Poison generation for the pipelined overlay: bumped whenever a
         # commit fails OR a commit-time re-verification rewrites a result
@@ -347,13 +344,9 @@ class PlanApplier:
         self._stop.clear()
         self._pool = ThreadPoolExecutor(max_workers=self.pool_workers,
                                         thread_name_prefix="plan-verify")
-        if self.batch:
-            self._commit_thread = threading.Thread(
-                target=self._run_commit, daemon=True, name="plan-commit")
-            self._commit_thread.start()
-        else:
-            self._commit_pool = ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix="plan-commit")
+        self._commit_thread = threading.Thread(
+            target=self._run_commit, daemon=True, name="plan-commit")
+        self._commit_thread.start()
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="plan-applier")
         self._thread.start()
@@ -384,8 +377,6 @@ class PlanApplier:
                         RuntimeError("plan applier stopped"))
         if self._pool is not None:
             self._pool.shutdown(wait=False)
-        if self._commit_pool is not None:
-            self._commit_pool.shutdown(wait=True)
 
     def _run(self) -> None:
         # pipeline state: every submitted-but-unlanded commit, oldest
@@ -416,30 +407,23 @@ class PlanApplier:
                 verify_gen = self._poison_gen
                 overlays = [c["result"] for _, c in inflight]
                 result, rejected = self._verify(pending.plan, overlays)
-                # commits are serialized in submission order — through
-                # the batching commit thread (which coalesces every
-                # verified-and-waiting plan into one store/raft round)
-                # or the single-worker pool (batch=False A/B baseline);
-                # either way the submitter is answered from the future's
-                # callback the moment its commit lands
+                # commits are serialized in submission order through
+                # the commit thread, which coalesces every
+                # verified-and-waiting plan into one store/raft round;
+                # the submitter is answered from the future's callback
+                # the moment its commit lands
                 cell = {"result": result}
-                if self.batch:
-                    fut: Future = Future()
-                    fut.add_done_callback(self._responder(pending))
-                    entry = _CommitEntry(pending.plan, result, rejected,
-                                         verify_gen, cell, fut)
-                    with self._commit_cond:
-                        if self._stop.is_set() and self._commit_thread is None:
-                            # stop() already drained the commit queue;
-                            # an entry appended now is never answered
-                            raise RuntimeError("plan applier stopped")
-                        self._commit_q.append(entry)
-                        self._commit_cond.notify()
-                else:
-                    fut = self._commit_pool.submit(
-                        self._commit_task, pending.plan, result, rejected,
-                        verify_gen, cell)
-                    fut.add_done_callback(self._responder(pending))
+                fut: Future = Future()
+                fut.add_done_callback(self._responder(pending))
+                entry = _CommitEntry(pending.plan, result, rejected,
+                                     verify_gen, cell, fut)
+                with self._commit_cond:
+                    if self._stop.is_set() and self._commit_thread is None:
+                        # stop() already drained the commit queue;
+                        # an entry appended now is never answered
+                        raise RuntimeError("plan applier stopped")
+                    self._commit_q.append(entry)
+                    self._commit_cond.notify()
                 inflight.append((fut, cell))
             except Exception as e:  # surface to the submitting worker
                 if self.logger:
@@ -480,34 +464,6 @@ class PlanApplier:
 
     # -- the serialized commit --
 
-    def _commit_task(self, plan: Plan, result: PlanResult,
-                     rejected: List[str], verify_gen: int,
-                     cell: dict) -> PlanResult:
-        """Pipelined commit entry: if ANY commit failed — or was itself
-        rewritten by a commit-time re-verification — while this plan's
-        overlay was assembled, the overlay may contain state that never
-        landed, so re-verify against the real store before writing (the
-        reference treats a failed plan apply as fatal; re-verification is
-        the non-fatal equivalent). Commits are serialized, so by the time
-        this runs every predecessor has landed, been rewritten (its cell
-        updated), or failed (its cell emptied) — re-verifying against the
-        bare store is exact. The generation only moves when an overlayed
-        result actually changed, so one transient failure does not cascade
-        into re-verifying the whole pipeline forever."""
-        if self._poison_gen != verify_gen:
-            new_result, new_rejected = self._verify(plan, None)
-            if not self._result_equal(result, rejected,
-                                      new_result, new_rejected):
-                self._poison(cell, new_result)
-            result, rejected = new_result, new_rejected
-        try:
-            return self._commit(plan, result, rejected)
-        except Exception:
-            # nothing landed: empty the overlay cell (and bump) so a
-            # reader that sees the new generation also sees the new cell
-            self._poison(cell, PlanResult())
-            raise
-
     @staticmethod
     def _result_equal(r1: PlanResult, rej1: List[str],
                       r2: PlanResult, rej2: List[str]) -> bool:
@@ -524,7 +480,7 @@ class PlanApplier:
         b2 = {(b.id, b.rejected_rows) for b in r2.alloc_blocks}
         return b1 == b2
 
-    # -- the batching commit thread (batch=True) --
+    # -- the batching commit thread --
 
     def _run_commit(self) -> None:
         """Group commit for plans: drain every verified-and-waiting
@@ -532,7 +488,7 @@ class PlanApplier:
         raft, one replicated command, one fsync+quorum round (riding the
         log writer's append_batch) — instead of one round per plan.
         Entries keep submission order, so the pipelined-overlay
-        invariants are exactly the serialized commit pool's.
+        invariants are those of committing one plan at a time.
 
         When the store can propose without waiting (a group-commit raft
         node), commit rounds additionally PIPELINE up to
@@ -653,11 +609,11 @@ class PlanApplier:
 
     def _commit_entries(self, entries: List[_CommitEntry]) -> None:
         plans = self._round_prologue(entries)
-        # 1: poisoned-overlay re-verification, in order. Unlike the
-        # serialized pool, in-batch predecessors have NOT landed yet, so
-        # a stale entry re-verifies against the bare store overlaid with
-        # its predecessors' current cells (they land atomically with it).
-        # Eval-only entries carry no placements: nothing to verify.
+        # 1: poisoned-overlay re-verification, in order. In-batch
+        # predecessors have NOT landed yet, so a stale entry re-verifies
+        # against the bare store overlaid with its predecessors' current
+        # cells (they land atomically with it). Eval-only entries carry
+        # no placements: nothing to verify.
         self._reverify_stale(plans, [])
         # 2: one transaction for the whole batch
         writers = self._writers_for(entries)
@@ -967,13 +923,7 @@ class PlanApplier:
         second half of the per-eval raft cost the batched pipeline
         amortizes. The returned future resolves (to None) when the
         update is committed; callers needing durability-before-ack wait
-        on it, preserving the direct write's semantics exactly.
-
-        Only meaningful on a batching applier; batch=False callers
-        should write through the store directly (the A/B baseline
-        path)."""
-        if not self.batch:
-            raise RuntimeError("submit_eval_updates requires batch=True")
+        on it, preserving the direct write's semantics exactly."""
         fut: Future = Future()
         entry = _CommitEntry(None, None, (), 0, None, fut,
                              payload={"evals": list(evals)})

@@ -77,20 +77,10 @@ class ServerConfig:
     # backlog) degrades to a paced trickle of mark-down batches instead
     # of an FSM thundering herd. <= 0 disables the limiter.
     heartbeat_expiry_rate: float = 512.0
-    # Coalesce concurrent client alloc-status commits into one FSM
-    # command per round (the PR-5 plan-commit batching shape applied to
-    # the node plane). False restores one command per client sync.
-    client_update_batching: bool = True
     nack_timeout: float = 60.0
     eval_delivery_limit: int = 3
-    # End-to-end pipeline batching (PERF.md "End-to-end pipeline").
-    # plan_commit_batching: the applier's commit thread coalesces every
-    # verified-and-waiting plan into one store/raft transaction; False
-    # restores the serialized one-commit-per-plan pool (A/B baseline).
-    plan_commit_batching: bool = True
     # eval_batch_size: max ready evals a scheduler worker drains per
-    # dequeue and runs against one shared snapshot + ClusterStatic;
-    # 1 = classic one-eval-per-dequeue behavior (A/B baseline).
+    # dequeue and runs against one shared snapshot + ClusterStatic
     eval_batch_size: int = 8
     # backoff before a delivery-limited eval is retried
     # (reference leader.go failedEvalUnblockInterval)
@@ -125,9 +115,6 @@ class ServerConfig:
     acl_replication_interval: float = 30.0
     replication_token: str = ""
     # -- nomadload overload envelope (ROBUSTNESS.md) -----------------
-    # loadctl_enabled: None reads the NOMAD_TPU_LOADCTL env kill
-    # switch; True/False overrides it (the bench baseline arm).
-    loadctl_enabled: Optional[bool] = None
     # queue-depth watermarks feeding the shed floor: soft sheds reads,
     # hard sheds submits too (loadctl.AdmissionController). Generous by
     # design — they bound collapse, they don't police steady state.
@@ -165,7 +152,6 @@ class Server:
         # to the live queue depths below (ROBUSTNESS.md "Overload
         # envelope"). Constructed first so every subsystem can take it.
         self.loadctl = AdmissionController(
-            enabled=self.config.loadctl_enabled,
             brownout_after=self.config.loadctl_brownout_after,
             brownout_exit=self.config.loadctl_brownout_exit)
         self.broker = EvalBroker(
@@ -180,7 +166,6 @@ class Server:
 
         self.plan_applier = PlanApplier(
             self.store, self.plan_queue, self.logger,
-            batch=self.config.plan_commit_batching,
             bad_node_tracker=BadNodeTracker(
                 threshold=self.config.plan_rejection_threshold,
                 window=self.config.plan_rejection_window,
@@ -212,9 +197,9 @@ class Server:
         from ..analysis import shadow as _shadow
 
         _shadow.maybe_attach(self.store, self.events)
-        # nomadstate incremental feed (always on; NOMAD_TPU_INCR=0 is a
-        # call-time kill switch): maintains the device-resident cluster
-        # usage base off this same event stream — tensor/incremental.py
+        # nomadstate incremental feed: maintains the device-resident
+        # cluster usage base off this same event stream —
+        # tensor/incremental.py
         from ..tensor import incremental as _incremental
 
         _incremental.maybe_attach(self.store, self.events)
@@ -222,8 +207,7 @@ class Server:
 
         # delta alloc push to clients + batched client status commits
         self.alloc_sync = AllocSyncHub(self)
-        self.client_updates = ClientUpdateBatcher(
-            self.store, batch=self.config.client_update_batching)
+        self.client_updates = ClientUpdateBatcher(self.store)
         self._running = False
         # Commit listeners fire inline on the store's write path — which
         # under raft is the apply thread. The unblock path re-proposes

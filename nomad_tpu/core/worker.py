@@ -117,28 +117,22 @@ class _EvalRun:
         return result, None
 
     def _persist_eval(self, ev: Evaluation) -> None:
-        """Durably commit one eval's status before acting on it. On a
-        batching applier the write rides the plan-commit batch — one
-        replicated round shared with every plan and eval update
-        concurrently waiting at the commit thread — and blocks until
-        that round lands, preserving the direct write's
-        durability-before-ack semantics exactly. batch=False keeps the
-        dedicated upsert_evals write (A/B baseline)."""
+        """Durably commit one eval's status before acting on it. The
+        write rides the plan-commit batch — one replicated round shared
+        with every plan and eval update concurrently waiting at the
+        commit thread — and blocks until that round lands, preserving
+        the direct write's durability-before-ack semantics exactly."""
         with TRACER.span("eval.persist"):
-            applier = self.server.plan_applier
-            if getattr(applier, "batch", False):
-                try:
-                    fut = applier.submit_eval_updates([ev])
-                except RuntimeError:
-                    # applier already stopped (leadership lost mid-eval):
-                    # fall through to the direct write, which surfaces
-                    # the real not-leader error to run()'s nack path
-                    self.server.store.upsert_evals([ev])
-                    return
-                fut.result(timeout=max(
-                    10.0, self.server.config.nack_timeout / 2.0))
-            else:
+            try:
+                fut = self.server.plan_applier.submit_eval_updates([ev])
+            except RuntimeError:
+                # applier already stopped (leadership lost mid-eval):
+                # fall through to the direct write, which surfaces
+                # the real not-leader error to run()'s nack path
                 self.server.store.upsert_evals([ev])
+                return
+            fut.result(timeout=max(
+                10.0, self.server.config.nack_timeout / 2.0))
 
     def update_eval(self, ev: Evaluation) -> None:
         self._persist_eval(ev)
@@ -167,7 +161,7 @@ class Worker:
         self._thread: Optional[threading.Thread] = None
         self.stats = {"processed": 0, "nacked": 0}
         self._stats_lock = threading.Lock()
-        # batch-member pool (created at start when eval_batch_size > 1)
+        # batch-member pool (created at start)
         self._batch_pool: Optional[ThreadPoolExecutor] = None
         # the still-settling previous batch: (futures, publish_delta).
         # process_batch leaves a batch draining on the pool and returns
@@ -189,14 +183,13 @@ class Worker:
 
     def start(self) -> None:
         self._stop.clear()
-        batch_size = getattr(self.server.config, "eval_batch_size", 1)
-        if batch_size > 1 and self._batch_pool is None:
+        if self._batch_pool is None:
             # 2x: one batch plan-applying + one batch solving at any
             # moment (the double buffer) — a pool sized at batch_size
             # would make the fresh batch's rendezvous wait out the
             # previous batch's commits thread-by-thread
             self._batch_pool = ThreadPoolExecutor(
-                max_workers=2 * batch_size,
+                max_workers=2 * self.server.config.eval_batch_size,
                 thread_name_prefix=f"worker-{self.id}-eval")
         self._thread = threading.Thread(target=self.run, daemon=True,
                                         name=f"worker-{self.id}")
@@ -216,22 +209,15 @@ class Worker:
 
     def run(self) -> None:
         while not self._stop.is_set():
-            batch_size = getattr(self.server.config, "eval_batch_size", 1)
-            if batch_size > 1:
-                batch = self.server.broker.dequeue_batch(
-                    self.sched_types, max_batch=batch_size, timeout=0.2)
-                if not batch:
-                    # idle: settle the deferred batch so its ack/nack
-                    # and stats publish promptly
-                    self._drain_prev()
-                    continue
-                self.process_batch(batch)
-            else:
-                ev, token = self.server.broker.dequeue(
-                    self.sched_types, timeout=0.2)
-                if ev is None:
-                    continue
-                self.process_one(ev, token)
+            batch = self.server.broker.dequeue_batch(
+                self.sched_types,
+                max_batch=self.server.config.eval_batch_size, timeout=0.2)
+            if not batch:
+                # idle: settle the deferred batch so its ack/nack
+                # and stats publish promptly
+                self._drain_prev()
+                continue
+            self.process_batch(batch)
         self._drain_prev()
 
     def _drain_prev(self) -> None:
@@ -254,11 +240,11 @@ class Worker:
         snapshot_min_index is paid once for the whole batch (at the max
         member index), and every scheduler in the batch reuses the
         store-cached ClusterStatic for that node-set version — the
-        per-eval constant costs the small-eval bench rungs showed
-        dominating. Members run concurrently on the worker's pool, so
-        their plan commits and status writes coalesce at the applier's
-        commit thread. Members still ack/nack individually; a failure
-        redelivers that eval alone."""
+        per-eval constant costs that dominate small evals. Members run
+        concurrently on the worker's pool, so their plan commits and
+        status writes coalesce at the applier's commit thread. Members
+        still ack/nack individually; a failure redelivers that eval
+        alone."""
         from .metrics import REGISTRY
         from ..tensor import incremental
         from ..tensor.placer import preempt_stats

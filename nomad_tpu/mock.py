@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import itertools
+import random
 
 from .structs import (
     Allocation,
@@ -166,3 +167,51 @@ def gpu_node(**overrides) -> Node:
     ]
     n.compute_class()
     return n
+
+
+# -- the ladder mix: BASELINE.md's cluster shape, used by chip_smoke.py
+#    and the sharded-solver tests ----------------------------------------
+
+RACKS = 20
+ZONES = 4
+KERNELS = ["4.14.0", "4.19.0", "5.10.0"]
+ITYPES = ["small", "large"]
+
+
+def shape_node(n: Node, i: int, rng: random.Random) -> None:
+    """The ladder's node mix, applied to node number `i`: 20 racks, 4
+    zones, 3 kernels, 2 instance types, and a seeded draw of capacity."""
+    n.attributes["rack"] = f"r{i % RACKS}"
+    n.attributes["zone"] = f"z{i % ZONES}"
+    n.attributes["kernel.version"] = KERNELS[i % len(KERNELS)]
+    n.attributes["instance.type"] = ITYPES[i % len(ITYPES)]
+    n.resources.cpu = rng.choice([8000, 16000, 32000])
+    n.resources.memory_mb = rng.choice([16384, 32768, 65536])
+    n.compute_class()
+
+
+def build_nodes(store, n_nodes: int, seed: int = 0) -> None:
+    """`n_nodes` ladder-mix nodes into `store`, capacities from `seed`."""
+    rng = random.Random(seed)
+    for i in range(n_nodes):
+        n = node()
+        shape_node(n, i, rng)
+        store.upsert_node(n)
+
+
+def service_job(count: int, cpu: int = 100, mem: int = 64, *,
+                spreads=None, constraints=None, affinities=None,
+                batch: bool = False, priority: int = 50) -> Job:
+    j = batch_job() if batch else job()
+    j.priority = priority
+    tg = j.task_groups[0]
+    tg.count = count
+    tg.tasks[0].resources.cpu = cpu
+    tg.tasks[0].resources.memory_mb = mem
+    if spreads:
+        tg.spreads = list(spreads)
+    if constraints:
+        tg.constraints = list(constraints)
+    if affinities:
+        tg.affinities = list(affinities)
+    return j

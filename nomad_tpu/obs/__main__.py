@@ -76,8 +76,7 @@ def _demo_cluster(tmp: str, jobs_n: int = 60, nodes_n: int = 20,
 
     def config_fn(_i: int) -> ServerConfig:
         return ServerConfig(
-            num_workers=workers, plan_commit_batching=True,
-            eval_batch_size=4,
+            num_workers=workers, eval_batch_size=4,
             heartbeat_ttl=3600.0, gc_interval=3600.0, nack_timeout=900.0,
             failed_eval_followup_delay=3600.0,
             failed_eval_unblock_interval=0.5)

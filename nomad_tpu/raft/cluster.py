@@ -70,8 +70,7 @@ class ReplicatedServer:
                  bootstrap: bool = True,
                  dead_server_cleanup_s: Optional[float] = None,
                  gossip_bind: Optional[str] = None,
-                 gossip_seeds: Optional[List[str]] = None,
-                 batch: bool = True):
+                 gossip_seeds: Optional[List[str]] = None):
         self.id = node_id
         self.crashed = False  # set by crash(); chaos invariants skip dead nodes
         self.local_store = StateStore()
@@ -112,10 +111,7 @@ class ReplicatedServer:
                              peer_addrs=getattr(transport, "peer_addrs", None),
                              on_config_change=self._on_config_change,
                              bootstrap=bootstrap,
-                             dead_server_cleanup_s=dead_server_cleanup_s,
-                             # batch=False preserves the pre-group-commit
-                             # write path (bench A/B baseline)
-                             batch=batch)
+                             dead_server_cleanup_s=dead_server_cleanup_s)
         self.store = RaftStore(self.local_store, self.raft)
         self.server = Server(config, store=self.store)
         # nomadload: proposes consult the server's admission plane, and
@@ -556,15 +552,13 @@ class RaftCluster:
     in-process multi-server test topology, nomad/testing.go)."""
 
     def __init__(self, n: int = 3, config_fn: Optional[Callable[[int], ServerConfig]] = None,
-                 data_dir: Optional[str] = None, snapshot_threshold: int = 1024,
-                 batch: bool = True):
+                 data_dir: Optional[str] = None, snapshot_threshold: int = 1024):
         self.transport = InProcTransport()
         ids = [f"server-{i}" for i in range(n)]
         self._ids = ids
         self._config_fn = config_fn
         self._data_dir = data_dir
         self._snapshot_threshold = snapshot_threshold
-        self._batch = batch
         self.servers: Dict[str, ReplicatedServer] = {}
         for i, node_id in enumerate(ids):
             cfg = config_fn(i) if config_fn else ServerConfig(heartbeat_ttl=30.0)
@@ -576,7 +570,7 @@ class RaftCluster:
             self.servers[node_id] = ReplicatedServer(
                 node_id, ids, self.transport, cfg,
                 peer_lookup=self.servers.get, data_dir=node_dir,
-                snapshot_threshold=snapshot_threshold, batch=batch)
+                snapshot_threshold=snapshot_threshold)
 
     def start(self) -> "RaftCluster":
         for s in self.servers.values():
@@ -617,7 +611,7 @@ class RaftCluster:
         replacement = ReplicatedServer(
             node_id, self._ids, self.transport, cfg,
             peer_lookup=self.servers.get, data_dir=old.data_dir,
-            snapshot_threshold=self._snapshot_threshold, batch=self._batch)
+            snapshot_threshold=self._snapshot_threshold)
         self.servers[node_id] = replacement
         replacement.start()
         return replacement

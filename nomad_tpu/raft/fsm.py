@@ -115,13 +115,11 @@ class RaftStore:
             return propose
         return getattr(self._store, name)
 
-    @property
-    def can_propose_async(self) -> bool:
-        """True when the raft node runs the group-commit log writer —
-        the prerequisite for propose_async/wait_applied. Callers (the
-        plan applier's commit pipeline) probe this to decide whether
-        commit rounds may overlap."""
-        return bool(getattr(self._raft, "batch", False))
+    # A raft-backed store can start a mutation without waiting for its
+    # commit (propose_async/wait_applied); a plain StateStore cannot.
+    # The plan applier's commit thread probes this to decide whether
+    # commit rounds may overlap.
+    can_propose_async = True
 
     def propose_async(self, name: str, *args, **kwargs):
         """Start a replicated mutation without waiting for its commit:

@@ -25,10 +25,6 @@ loop + group commit, PERF.md "The replicated write path"):
 - **Batched apply** — the apply thread applies a whole committed range
   per lock hold with one notify_all; leader-side waiters are per-
   proposal events in a registry (no polling, no unbounded results map).
-
-`batch=False` keeps the pre-batch single-proposal path (synchronous
-append+fsync under the lock, tick-paced replication) for A/B
-comparison — bench.py's raft_commit_throughput_3node rung.
 """
 
 from __future__ import annotations
@@ -174,8 +170,6 @@ class RaftNode:
                  on_config_change: Optional[Callable[[Dict[str, str]], None]] = None,
                  bootstrap: bool = True,
                  dead_server_cleanup_s: Optional[float] = None,
-                 batch: bool = True,
-                 max_append_entries: int = MAX_APPEND_ENTRIES,
                  fsm_capture: Optional[Callable[[], object]] = None,
                  fsm_serialize: Optional[Callable[[object], dict]] = None,
                  snapshot_chunk_bytes: int = SNAPSHOT_CHUNK_BYTES,
@@ -197,8 +191,6 @@ class RaftNode:
         # real membership from the leader's append_entries
         self.bootstrap = bootstrap
         self.dead_server_cleanup_s = dead_server_cleanup_s
-        self.batch = batch
-        self.max_append_entries = max_append_entries
         self._last_contact: Dict[str, float] = {}
         self._config_index = 0  # log index of the latest config entry
         # replication state precedes the durability restore below:
@@ -355,8 +347,6 @@ class RaftNode:
         controller is consulted at the propose enqueue (the proposal
         queue IS the watermark it reads)."""
         deadline = self._propose_checks(time.time() + timeout)
-        if not self.batch:
-            return self._apply_single(command, deadline)
         prop = _Proposal(command, deadline=deadline)
         with self._lock:
             if self._stop.is_set():
@@ -384,14 +374,12 @@ class RaftNode:
         return deadline
 
     def apply_async(self, command: tuple) -> _Proposal:
-        """First half of apply (batch mode only): enqueue the command
+        """First half of apply: enqueue the command
         for the group-commit log writer and return the proposal handle
         without waiting. Proposals enter the log in apply_async call
         order, so one caller serializing its apply_async calls gets FSM
         apply order equal to its propose order — the ordering contract
         the plan applier's pipelined commit rounds depend on."""
-        if not self.batch:
-            raise RuntimeError("apply_async requires batch mode")
         self._propose_checks(time.time() + 3600.0)
         prop = _Proposal(command, deadline=_lc().current_deadline())
         with self._lock:
@@ -408,31 +396,6 @@ class RaftNode:
         return the FSM result. Same timeout/step-down semantics as
         apply; safe to call at most once per proposal."""
         return self._await_proposal(prop, time.time() + timeout)
-
-    def _apply_single(self, command: tuple, deadline: float):
-        """The pre-batch write path (batch=False): one synchronous
-        append + fsync under the node lock per proposal, replication
-        left to the idle-heartbeat cadence. Kept as the A/B baseline
-        for the group-commit rung in bench.py."""
-        # Freeze the payload: callers keep mutating their structs after
-        # proposing (eval status transitions, alloc updates), and a log
-        # entry aliasing those objects would retransmit the MUTATED
-        # payload to any follower that catches up later — replicas
-        # applying different commands at the same index.
-        command = copy.deepcopy(command)
-        prop = _Proposal(command)
-        with self._lock:
-            if self._stop.is_set():
-                raise TimeoutError("raft node stopped")
-            if self.state != LEADER:
-                raise NotLeaderError(self.leader_id)
-            entry = self.log.append(self.current_term, command)
-            prop.index = entry.index
-            self._waiters[entry.index] = prop
-            # single-node cluster commits immediately; otherwise
-            # replication advances commit on acks
-            self._maybe_advance_commit_locked()
-        return self._await_proposal(prop, deadline)
 
     def _await_proposal(self, prop: _Proposal, deadline: float):
         prop.done.wait(max(0.0, deadline - time.time()))
@@ -1252,11 +1215,6 @@ class RaftNode:
             return False
         if now >= self._next_heartbeat.get(peer, 0.0):
             return True
-        if not self.batch:
-            # pre-batch semantics (the bench baseline): replication runs
-            # only at the heartbeat cadence, never woken by backlog —
-            # exactly the old tick-paced _replicate_all
-            return False
         if peer in self._snap_inflight:
             return False
         last_index, _ = self.log.last()
@@ -1296,9 +1254,7 @@ class RaftNode:
                 return self._send_snapshot_locked(peer, term, base)
             prev_index = next_idx - 1
             prev_term = self.log.term_at(prev_index)
-            # pre-batch mode keeps the old 64-entry default window
-            window = self.max_append_entries if self.batch else 64
-            entries = self.log.slice_from(next_idx, window)
+            entries = self.log.slice_from(next_idx, MAX_APPEND_ENTRIES)
             commit = self.commit_index
         # span only when entries ship — idle heartbeats would drown the
         # trace in zero-payload sends

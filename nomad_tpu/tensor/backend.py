@@ -1,7 +1,7 @@
 """One backend bootstrap for every entry point that can schedule.
 
 `bootstrap()` runs before the first compile in `cli.cmd_agent`,
-`bench.main`, `chip_smoke.py`, `python -m nomad_tpu.chaos` and
+`chip_smoke.py`, `python -m nomad_tpu.chaos` and
 `python -m nomad_tpu.obs`, and does two things nothing else in the tree
 repeats:
 

@@ -128,7 +128,7 @@ def _packing_score_xp(xp, counts, available, used_final):
 
 def packing_score_np(counts, available, used_final) -> float:
     """Numpy twin of the in-kernel portfolio metric — used by the
-    property tests and the bench A/B rung to score end states."""
+    property tests to score end states."""
     return float(_packing_score_xp(
         np, np.asarray(counts), np.asarray(available, dtype=np.float64),
         np.asarray(used_final, dtype=np.float64)))

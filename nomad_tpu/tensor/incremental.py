@@ -41,9 +41,9 @@ Consistency contract (the part chaos + NOMAD_TPU_SAN=1 enforce):
   included — against a fresh rebuild from the same gen-bounded tables.
   Resource vectors are integral, so f64 folds commute exactly and the
   compare demands bit-equality, no tolerance.
-- ``NOMAD_TPU_INCR=0`` kills the feature at every call site: builds
-  fall back to the exact prior per-round rebuild (the feed still
-  drains lazily, it just hands nothing out).
+- A store with no feed attached, or a resync that failed, gets the
+  exact per-round rebuild: ``base_for`` / ``feed_for`` answer None and
+  every call site falls back on that.
 
 The shared base view is refreshed in place by later drains, so a solve
 that kept the view may observe newer committed usage mid-read — the
@@ -54,7 +54,6 @@ design; the serialized plan applier owns correctness either way.
 from __future__ import annotations
 
 import _thread
-import os
 from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
@@ -78,11 +77,6 @@ LOG_CAP_MULT = 4
 # shapes already compiled for the delta scatter / resync fold launches
 # (tensor/solver.warm_launch discipline: warm shapes compile nothing)
 _STATE_WARM: set = set()
-
-
-def incr_enabled() -> bool:
-    """Kill switch, read at call time so tests can flip it per-case."""
-    return os.environ.get("NOMAD_TPU_INCR", "1") != "0"
 
 
 def _pad_bucket(n: int) -> int:
@@ -193,9 +187,9 @@ class IncrementalFeed:
 
     def base_for(self, static) -> Optional[np.ndarray]:
         """The fed usage base aligned to `static`'s row order, as a
-        read-only (n_pad, D) f64 view — or None (kill switch off, or
-        resync failed), which means: do the legacy full build."""
-        if not incr_enabled() or static is None:
+        read-only (n_pad, D) f64 view — or None (resync failed), which
+        means: do the full build."""
+        if static is None:
             return None
         with self._lock:
             self._builds += 1
@@ -216,7 +210,7 @@ class IncrementalFeed:
         """Device-resident f32 twin of the base (sharded over `mesh`
         when given), flushed through one scatter launch. None when the
         feed can't serve this static — caller falls back to host."""
-        if not incr_enabled() or static is None:
+        if static is None:
             return None
         with self._lock:
             ep = self._epoch_for_locked(static)
@@ -239,8 +233,6 @@ class IncrementalFeed:
         """Drain + parity-digest now (chaos sweep, state smoke,
         teardowns). Builds an epoch over the store's node set first if
         none exists, so follower replicas verify meaningfully."""
-        if not incr_enabled():
-            return True
         with self._lock:
             if self._epoch is None or self._epoch.stale:
                 snap = self.store.snapshot()
@@ -704,7 +696,7 @@ def device_used_fn(store, static):
     """A (mesh) -> device array | None closure for the bulk solver's
     resync, or None when no feed serves this store."""
     feed = feed_for(store)
-    if feed is None or static is None or not incr_enabled():
+    if feed is None or static is None:
         return None
 
     def fn(mesh=None):

@@ -143,7 +143,7 @@ def _preempt_solve_host(available, used, ask, feasible, net_prio, active,
 # allocs_fit before commit (every kernel row takes this check, so
 # kernel_preempted counts only validated successes). Mirrored into the
 # Registry as nomad.preempt.* for the obs plane; read via
-# preempt_stats() (bench cfg4, chaos solve-smoke).
+# preempt_stats() (chip_smoke leg D, chaos solve-smoke).
 PREEMPT_STATS = {"kernel_preempted": 0, "host_preempted": 0,
                  "victim_parity_checked": 0}
 _PREEMPT_STATS_LOCK = __import__("threading").Lock()
@@ -200,9 +200,9 @@ def _changed_allocs_since_last_build(store=None) -> int:
     from ..core.metrics import REGISTRY
 
     if store is not None:
-        from .incremental import feed_for, incr_enabled
+        from .incremental import feed_for
 
-        feed = feed_for(store) if incr_enabled() else None
+        feed = feed_for(store)
         if feed is not None:
             delta = float(feed.take_build_delta_count())
             REGISTRY.observe("nomad.worker.changed_allocs_per_build", delta)

@@ -138,44 +138,6 @@ def test_agent_names_the_device(config_updates):
         agent.stop()
 
 
-def test_bench_exits_nonzero_when_a_rung_raises(monkeypatch, capsys):
-    import bench
-
-    ran = []
-
-    def boom():
-        raise RuntimeError("rung broke")
-
-    monkeypatch.setattr(backend, "bootstrap", lambda algorithm="": None)
-    monkeypatch.setattr(bench, "CONFIGS",
-                        [("boom", boom), ("after", lambda: ran.append(1))])
-    monkeypatch.setattr(sys, "argv", ["bench.py"])
-    assert bench.main() == 1
-    assert ran == [1]               # the remaining rungs still ran
-    out = capsys.readouterr()
-    assert '"boom_error"' in out.out and "rung broke" in out.out
-    monkeypatch.setattr(bench, "CONFIGS", [("fine", lambda: None)])
-    assert bench.main() == 0
-
-
-def test_bench_lines_name_the_device(capsys):
-    import bench
-
-    line = bench.emit("m", 1.0, "u", None)
-    assert line["device"] == backend.device().as_dict()
-    assert json.loads(capsys.readouterr().out)["device"]["platform"] == "cpu"
-
-
-def test_bench_pins_no_child_to_the_cpu():
-    """One process per chip: no rung may hold the chip in the parent and
-    time a JAX_PLATFORMS=cpu child."""
-    import bench
-
-    src = Path(bench.__file__).read_text()
-    assert "subprocess" not in src
-    assert 'JAX_PLATFORMS="cpu"' not in src and "'jax_platforms'" not in src
-
-
 # -- failures surface ------------------------------------------------------
 
 

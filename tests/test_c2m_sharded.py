@@ -24,7 +24,6 @@ import time
 import numpy as np
 import pytest
 
-import bench
 from nomad_tpu import mock
 from nomad_tpu.structs import enums
 from nomad_tpu.structs.operator import SchedulerConfiguration
@@ -49,12 +48,12 @@ def _run_pipeline(monkeypatch, mesh_devices: int, algorithm: str):
     svc = _fresh_service(monkeypatch, mesh_devices)
     try:
         h = Harness()
-        bench.build_nodes(h.store, 256)
+        mock.build_nodes(h.store, 256)
         cfg = SchedulerConfiguration(scheduler_algorithm=algorithm)
         jobs = []
         for i, (count, cpu, mem) in enumerate(
                 ((700, 50, 32), (900, 60, 48), (500, 80, 64))):
-            j = bench.service_job(count, cpu=cpu, mem=mem, batch=True)
+            j = mock.service_job(count, cpu=cpu, mem=mem, batch=True)
             j.id = f"parity-{algorithm}-{i}"  # pins the solver jitter seeds
             jobs.append(j)
         for i, j in enumerate(jobs):
@@ -117,12 +116,12 @@ def test_warm_sharded_launch_no_retrace(monkeypatch, eight_devices):
     svc = _fresh_service(monkeypatch, 8)
     try:
         h = Harness()
-        bench.build_nodes(h.store, 256)
+        mock.build_nodes(h.store, 256)
         cfg = SchedulerConfiguration(
             scheduler_algorithm=enums.SCHED_ALG_TPU_BINPACK)
 
         def one(i):
-            j = bench.service_job(300, cpu=50, mem=32, batch=True)
+            j = mock.service_job(300, cpu=50, mem=32, batch=True)
             j.id = f"warm-{i}"
             h.store.upsert_job(j)
             h.process(mock.eval_for(j, id=f"warm-ev-{i}"),

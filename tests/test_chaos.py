@@ -552,7 +552,7 @@ def _batched_pipeline_cfg(_i):
     plan-commit batching + pipelined commit rounds on, background
     timers parked so the scenario only exercises the eval pipeline."""
     return ServerConfig(
-        num_workers=4, plan_commit_batching=True, eval_batch_size=8,
+        num_workers=4, eval_batch_size=8,
         heartbeat_ttl=3600.0, gc_interval=3600.0, nack_timeout=900.0,
         failed_eval_followup_delay=3600.0,
         failed_eval_unblock_interval=0.5)

@@ -3,7 +3,9 @@
 deployment_endpoint.go suites — the round-2 paths that shipped untested).
 """
 
+import contextlib
 import copy
+import threading
 import time
 
 import pytest
@@ -22,6 +24,27 @@ def wait_until(pred, timeout=10.0, interval=0.05):
             return out
         time.sleep(interval)
     return pred()
+
+
+@contextlib.contextmanager
+def heartbeating(s, node_ids, interval=0.05):
+    """Clients that stay connected: heartbeat every node of `node_ids`
+    (a set the test may add to) until the block ends, so that no wait
+    of the test's own thread lets a live node's TTL run out."""
+    stop = threading.Event()
+
+    def beat():
+        while not stop.wait(interval):
+            for node_id in list(node_ids):
+                s.heartbeat(node_id)
+
+    t = threading.Thread(target=beat, daemon=True, name="test-heartbeats")
+    t.start()
+    try:
+        yield
+    finally:
+        stop.set()
+        t.join(2.0)
 
 
 def live_allocs(s, job_id):
@@ -261,54 +284,54 @@ class TestDisconnectE2E:
             assert victims, "expected at least one alloc on n1"
 
             # n1 stops heartbeating; n2 stays alive
-            deadline = time.time() + 5
-            while time.time() < deadline:
-                s.heartbeat(n2.id)
-                node = s.store.snapshot().node_by_id(n1.id)
-                if node.status == enums.NODE_STATUS_DISCONNECTED:
-                    break
-                time.sleep(0.05)
-            assert (s.store.snapshot().node_by_id(n1.id).status
-                    == enums.NODE_STATUS_DISCONNECTED), \
-                "max_client_disconnect must yield disconnected, not down"
+            alive = {n2.id}
+            with heartbeating(s, alive):
+                assert wait_until(
+                    lambda: s.store.snapshot().node_by_id(n1.id).status
+                    == enums.NODE_STATUS_DISCONNECTED, timeout=5.0), \
+                    "max_client_disconnect must yield disconnected, not down"
 
-            def unknown_and_replaced():
+                def unknown_and_replaced():
+                    snap = s.store.snapshot()
+                    vs = [snap.alloc_by_id(v.id) for v in victims]
+                    if not all(v.client_status == enums.ALLOC_CLIENT_UNKNOWN
+                               for v in vs):
+                        return False
+                    repl = [a for a in snap.allocs_by_job(job.id)
+                            if a.previous_allocation in {v.id for v in victims}
+                            and not a.terminal_status()]
+                    return len(repl) == len(victims)
+                assert wait_until(unknown_and_replaced, timeout=10.0), \
+                    "allocs should go unknown with replacements placed"
+                # the expiry follow-up eval is parked in the delay heap: the
+                # scheduler creates it after its plan has landed, so the
+                # store shows the replacements a commit round before this
+                assert wait_until(lambda: s.broker.delayed_count() >= 1), \
+                    "the disconnect-timeout eval should be waiting"
+
+                # client returns: re-register + heartbeat + alloc sync
+                s.update_node_status(n1.id, enums.NODE_STATUS_READY)
+                alive.add(n1.id)
                 snap = s.store.snapshot()
-                vs = [snap.alloc_by_id(v.id) for v in victims]
-                if not all(v.client_status == enums.ALLOC_CLIENT_UNKNOWN
-                           for v in vs):
-                    return False
-                repl = [a for a in snap.allocs_by_job(job.id)
-                        if a.previous_allocation in {v.id for v in victims}
-                        and not a.terminal_status()]
-                return len(repl) == len(victims)
-            assert wait_until(unknown_and_replaced, timeout=10.0), \
-                "allocs should go unknown with replacements placed"
-            # the expiry follow-up eval is parked in the delay heap
-            assert s.broker.delayed_count() >= 1
+                for v in victims:
+                    got = snap.alloc_by_id(v.id)
+                    upd = got.copy_for_update()
+                    upd.client_status = enums.ALLOC_CLIENT_RUNNING
+                    s.update_allocs_from_client([upd])
+                s.wait_for_idle(10.0, include_delayed=False)
 
-            # client returns: re-register + heartbeat + alloc sync
-            s.update_node_status(n1.id, enums.NODE_STATUS_READY)
-            snap = s.store.snapshot()
-            for v in victims:
-                got = snap.alloc_by_id(v.id)
-                upd = got.copy_for_update()
-                upd.client_status = enums.ALLOC_CLIENT_RUNNING
-                s.update_allocs_from_client([upd])
-            s.wait_for_idle(10.0, include_delayed=False)
-
-            def settled():
-                snap = s.store.snapshot()
-                vs = [snap.alloc_by_id(v.id) for v in victims]
-                if not all(v.desired_status == enums.ALLOC_DESIRED_RUN
-                           for v in vs):
-                    return False
-                live = [a for a in snap.allocs_by_job(job.id)
-                        if not a.terminal_status() and not a.server_terminal()]
-                return len(live) == 2 and {v.id for v in victims} <= {
-                    a.id for a in live}
-            assert wait_until(settled, timeout=10.0), \
-                "reconnected originals win; replacements stop"
+                def settled():
+                    snap = s.store.snapshot()
+                    vs = [snap.alloc_by_id(v.id) for v in victims]
+                    if not all(v.desired_status == enums.ALLOC_DESIRED_RUN
+                               for v in vs):
+                        return False
+                    live = [a for a in snap.allocs_by_job(job.id)
+                            if not a.terminal_status() and not a.server_terminal()]
+                    return len(live) == 2 and {v.id for v in victims} <= {
+                        a.id for a in live}
+                assert wait_until(settled, timeout=10.0), \
+                    "reconnected originals win; replacements stop"
 
     def test_expiry_without_reconnect_goes_lost(self):
         with Server(ServerConfig(heartbeat_ttl=0.3)) as s:

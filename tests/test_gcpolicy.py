@@ -95,18 +95,32 @@ def test_young_generations_are_left_alone(policy):
     assert fake.calls == [] and gcpolicy.STATS["full_passes"] == 0
 
 
-def test_install_is_idempotent_and_works_on_the_real_collector():
-    before, was = list(gc.callbacks), gcpolicy._state["installed"]
+def test_install_is_idempotent_and_works_on_the_real_collector(monkeypatch):
+    before = list(gc.callbacks)
+    # the policy as a process starts with it. An earlier test of this
+    # worker that built an agent leaves it installed with a whole-heap
+    # pass on record: once that is a minute old and the heap has
+    # doubled, the pass below is the one that thaws, and nothing is
+    # frozen after it
+    monkeypatch.setattr(gcpolicy, "_state", dict(
+        gcpolicy._state, whole_heap=0, whole_at=0.0, thawed=True, t0=0.0))
+    monkeypatch.setattr(gcpolicy, "STATS", dict.fromkeys(gcpolicy.STATS, 0))
+    auto = gc.isenabled()
     try:
         gcpolicy.install()
         gcpolicy.install()
         assert gc.callbacks.count(gcpolicy._on_collection) == 1
+        # no automatic pass, on this thread or another, between the
+        # reading and the pass this test makes
+        gc.disable()
         passes = gcpolicy.STATS["full_passes"]
         keep = [[i] for i in range(1000)]
         gc.collect()
         assert gcpolicy.STATS["full_passes"] == passes + 1
+        assert gcpolicy.STATS["whole_heap_passes"] == 1
         assert gc.get_freeze_count() >= len(keep)
     finally:
+        if auto:
+            gc.enable()
         gc.unfreeze()
         gc.callbacks[:] = before
-        gcpolicy._state["installed"] = was

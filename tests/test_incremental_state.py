@@ -10,7 +10,7 @@ Contracts pinned here:
   state/deltas.py);
 - ring truncation / the restore sentinel force a full resync, never
   incremental patching;
-- the NOMAD_TPU_INCR=0 kill switch restores the exact legacy build;
+- a store with no feed attached gets the exact full build;
 - the sharded scatter twin is bit-exact against the single-device
   scatter, and device twins flush to exactly base.astype(f32);
 - NodeSlotRegistry keeps node→slot identity stable and recycles slots
@@ -31,7 +31,7 @@ from nomad_tpu.structs import enums
 from nomad_tpu.structs.alloc import AllocBlock, Allocation
 from nomad_tpu.structs.resources import RESOURCE_DIMS
 from nomad_tpu.tensor.cluster import ClusterStatic, ClusterTensors, NodeSlotRegistry
-from nomad_tpu.tensor.incremental import StateTracker, incr_enabled
+from nomad_tpu.tensor.incremental import StateTracker, feed_for
 from nomad_tpu.tensor.overlay import INFLIGHT
 
 
@@ -194,7 +194,7 @@ def test_membership_change_resyncs_same_layout_keeps_epoch(tracked):
     assert tracker.violations == []
 
 
-def test_kill_switch_restores_exact_legacy_build(tracked, monkeypatch):
+def test_store_without_feed_gets_the_exact_full_build(tracked, monkeypatch):
     store, _, tracker, feed = tracked
     nodes, _ = _static_over(store, 5)
     for i in range(9):
@@ -204,14 +204,15 @@ def test_kill_switch_restores_exact_legacy_build(tracked, monkeypatch):
     ctx = EvalContext(store.snapshot(), eval_id="inc-on")
     warm = ClusterTensors.build(ctx, nodes)
     assert warm._used_shared and not warm.used.flags.writeable
-    monkeypatch.setenv("NOMAD_TPU_INCR", "0")
-    assert not incr_enabled()
-    assert feed.base_for(warm.static) is None       # switch read per call
+    # no feed on the store (as before maybe_attach, or on a bare
+    # StateStore): the build gathers usage row by row
+    monkeypatch.setattr(store, "_incremental_feed", None)
+    assert feed_for(store) is None
     cold = ClusterTensors.build(
         EvalContext(store.snapshot(), eval_id="inc-off"), nodes)
     assert not cold._used_shared and cold.used.flags.writeable
     assert np.array_equal(np.asarray(warm.used), cold.used)
-    monkeypatch.delenv("NOMAD_TPU_INCR")
+    monkeypatch.undo()
     # copy-on-write: a private view detaches from the shared base
     private = warm._ensure_private()
     assert private.flags.writeable and not warm._used_shared

@@ -33,7 +33,6 @@ from nomad_tpu.core.loadctl import (
     current_deadline,
     current_tier,
     deadline_expired,
-    env_enabled,
     remaining,
     tier_for_method,
 )
@@ -54,7 +53,6 @@ class FakeClock:
 
 
 def controller(clk=None, **kw):
-    kw.setdefault("enabled", True)
     return AdmissionController(clock=clk or FakeClock(), **kw)
 
 
@@ -71,22 +69,6 @@ class TestAdmission:
         assert adm.stats["admitted"] == 4
         assert adm.stats["shed"] == 0
         assert all(kind == "admit" for _, _, kind, _ in adm.ledger())
-
-    def test_kill_switch_disables_everything(self):
-        adm = controller(enabled=False)
-        adm.register_queue("q", lambda: 10 ** 6, soft=1, hard=2)
-        for tier in (TIER_LIVENESS, TIER_COMMIT, TIER_SUBMIT, TIER_READ):
-            assert adm.try_admit(tier) is None
-        assert not adm.degraded()
-        assert adm.snapshot()["enabled"] is False
-
-    def test_env_kill_switch(self, monkeypatch):
-        for raw, want in (("0", False), ("false", False), ("off", False),
-                          ("1", True), ("", True)):
-            monkeypatch.setenv("NOMAD_TPU_LOADCTL", raw)
-            assert env_enabled() is want
-        monkeypatch.delenv("NOMAD_TPU_LOADCTL")
-        assert env_enabled() is True
 
     def test_soft_watermark_sheds_reads_only(self):
         clk = FakeClock()

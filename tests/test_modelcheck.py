@@ -169,8 +169,6 @@ class TestPinnedSeedRegressions:
                 self._commit_thread = None
             if self._pool is not None:
                 self._pool.shutdown(wait=False)
-            if self._commit_pool is not None:
-                self._commit_pool.shutdown(wait=True)
 
         def old_submit(self, evals):
             # pre-fix submit: no running-commit-thread guard

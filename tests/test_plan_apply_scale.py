@@ -4,12 +4,13 @@ pipelined verify-vs-commit overlay, bad-node quarantine
 plan_apply_node_tracker.go:17)."""
 
 import time
+from concurrent.futures import Future
 
 import pytest
 
 from nomad_tpu import mock
 from nomad_tpu.core.plan_apply import (BadNodeTracker, PlanApplier, PlanQueue,
-                                       _OverlaySnapshot)
+                                       _CommitEntry, _OverlaySnapshot)
 from nomad_tpu.core.server import Server, ServerConfig
 from nomad_tpu.state import StateStore
 from nomad_tpu.structs import enums
@@ -114,7 +115,7 @@ class TestOverlayPipeline:
         assert new.id in got
         assert ov.node_by_id(node.id) is not None
 
-    def test_commit_failure_poisons_overlay_descendants(self):
+    def test_commit_failure_poisons_overlay_descendants(self, monkeypatch):
         """If plan A's commit FAILS after later plans were verified
         against an overlay containing A's never-landed result, those
         plans must re-verify at commit time — even when they are not A's
@@ -149,17 +150,22 @@ class TestOverlayPipeline:
         result_c, rej_c = ap._verify(pc, [result_a])
         assert not rej_c
 
-        # A's commit fails (transient raft failure): the stop never lands
-        real_upsert = store.upsert_plan_results
+        # A's commit fails (transient raft failure): neither the batch
+        # transaction nor its per-plan fallback lands the stop
+        def commit(plan, result, rejected, gen, cell):
+            entry = _CommitEntry(plan, result, rejected, gen, cell, Future())
+            ap._commit_entries([entry])
+            return entry.future.result(timeout=0)
 
         def boom(*a, **kw):
             raise RuntimeError("leadership lost")
 
-        store.upsert_plan_results = boom
         cell_a = {"result": result_a}
-        with pytest.raises(RuntimeError):
-            ap._commit_task(pa, result_a, rej_a, gen_a, cell_a)
-        store.upsert_plan_results = real_upsert
+        with monkeypatch.context() as m:
+            m.setattr(store, "upsert_plan_results_batch", boom)
+            m.setattr(store, "upsert_plan_results", boom)
+            with pytest.raises(RuntimeError):
+                commit(pa, result_a, rej_a, gen_a, cell_a)
         assert ap._poison_gen != gen_c
         # the failed entry's overlay cell was emptied: readers that catch
         # the new generation must not see the never-landed stop either
@@ -167,7 +173,7 @@ class TestOverlayPipeline:
 
         # C's commit must re-verify against the real store (big still
         # live) and reject the node instead of overcommitting
-        out = ap._commit_task(pc, result_c, rej_c, gen_c, {"result": result_c})
+        out = commit(pc, result_c, rej_c, gen_c, {"result": result_c})
         assert out.rejected_nodes == [node.id]
         live = [a for a in store.snapshot().allocs_by_node(node.id)
                 if not a.terminal_status()]

@@ -359,6 +359,32 @@ class TestBatchedWritePath:
             for n in nodes.values():
                 n.stop()
 
+    def test_cluster_of_one_commits_through_the_log_writer(self):
+        """A single node has no one to replicate to and still commits
+        through the group-commit log writer: one apply() is one flush,
+        and a burst queued while the writer cannot drain lands as ONE
+        append (one `nomad.raft.fsyncs`), each proposal answered with
+        its own FSM result."""
+        from nomad_tpu.core.metrics import REGISTRY
+
+        transport, nodes, applied = _mini_cluster(n=1)
+        try:
+            leader = _wait_leader(nodes)
+            before = REGISTRY.get("nomad.raft.fsyncs")
+            first = leader.apply(("cmd", (-1,), {}))
+            assert REGISTRY.get("nomad.raft.fsyncs") - before == 1
+            with leader._lock:      # the writer drains under this lock
+                props = [leader.apply_async(("cmd", (i,), {}))
+                         for i in range(16)]
+            results = [leader.apply_wait(p) for p in props]
+            assert REGISTRY.get("nomad.raft.fsyncs") - before == 2
+            assert results == list(range(first + 1, first + 17))
+            mine = [c[1][0] for c in applied[leader.id] if c[0] == "cmd"]
+            assert mine == [-1] + list(range(16))
+        finally:
+            for n in nodes.values():
+                n.stop()
+
     def test_follower_conflict_hint_shape(self):
         """On a prev-entry mismatch the follower reports the conflicting
         term and its first index, so the leader backtracks a term per
