@@ -182,6 +182,14 @@ class _OverlaySnapshot:
                     for a in bucket.get(node_id, ()):
                         by_id[a.id] = a
             for block in result.alloc_blocks:
+                # a result listed as in flight whose commit landed
+                # before this snapshot was taken is in the snapshot
+                # already: a row nets itself out by its id (node_usage
+                # below), a block has to be left out here, or its nodes
+                # read twice as full and the next plan's rows on them
+                # are rejected
+                if snap.alloc_block_by_id(block.id) is not None:
+                    continue
                 for m in block.live_rows():
                     self._block_rows.setdefault(
                         block.node_ids[m], []).append((block, m))
@@ -1129,6 +1137,12 @@ class PlanApplier:
             block_allocs = plan.block_allocs_for_node(node_id)
             if block_allocs:
                 all_allocation = list(all_allocation) + block_allocs
+        if not all_allocation:
+            # stops and preemptions only: nothing is placed or updated
+            # here, so there is nothing to fit (the checks below all
+            # pass on an empty `placements`) and no reason to read the
+            # node's allocations, each block position of them a row
+            return True
         # classify placement-vs-update by id-existence on the node including
         # client-terminal allocs: a follow_up_eval_id annotation on a failed
         # alloc is an update, not a new placement

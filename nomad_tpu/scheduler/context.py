@@ -129,6 +129,8 @@ class EvalContext:
         placed_ids = {a.id for a in self.plan.node_allocation.get(node_id, ())}
         out = [a for a in out if a.id not in placed_ids]
         out.extend(self.plan.node_allocation.get(node_id, ()))
+        if self.plan.alloc_blocks:
+            out.extend(self.plan.block_allocs_for_node(node_id))
         return out
 
     def shuffled_nodes(self, nodes: List[Node], attempt: int = 0) -> List[Node]:

@@ -383,11 +383,15 @@ class GenericScheduler:
                 self.queued_allocs.get(tg_name, 0) + len(reqs))
 
         def commit_block(tg, node_ids, node_names, counts, name_indices,
-                         mean_score):
+                         mean_score, scores=None, nodes_evaluated=0,
+                         nodes_in_pool=0):
             """Columnar bulk commit: ONE AllocBlock rides the plan for K
             placements (structs/alloc.py AllocBlock). Only reachable for
             the fresh-placement shape commit_many covers, so the same
-            constants apply; per-alloc ids/names materialize lazily."""
+            constants apply; per-alloc ids/names materialize lazily.
+            `scores` (per position) and the two node counts are the
+            per-placement scan's: what its rows carried in their
+            AllocMetric."""
             from ..structs.alloc import AllocBlock
 
             block = AllocBlock(
@@ -407,10 +411,14 @@ class GenericScheduler:
                 counts=counts,
                 allocated_vec=ctx.tg_vec(tg),
                 mean_score=float(mean_score),
+                nodes_evaluated=nodes_evaluated,
+                nodes_in_pool=nodes_in_pool,
                 allocated_at=now,
             )
             metrics = ctx.metrics
-            if metrics is not None:
+            if scores is not None:
+                block.scores = scores
+            elif metrics is not None:
                 metrics.scores.setdefault("bulk.normalized-score",
                                           float(mean_score))
             self.plan.append_block(block)

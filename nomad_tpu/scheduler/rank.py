@@ -402,6 +402,9 @@ def _plan_aware_job_allocs(ctx: EvalContext, job: Job) -> List[Allocation]:
     out = [a for a in out if a.id not in removed]
     for allocs in ctx.plan.node_allocation.values():
         out.extend(a for a in allocs if a.job_id == job.id)
+    for block in ctx.plan.alloc_blocks:
+        if block.job_id == job.id:
+            out.extend(block.iter_allocs())
     return out
 
 

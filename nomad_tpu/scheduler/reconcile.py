@@ -40,8 +40,8 @@ class BulkPlacementRequest:
     placementResult per missing alloc). `name_indices[i]` is the alloc
     name index of placement i; names/ids materialize lazily in the
     AllocBlock the placer commits. The placer expands this into
-    individual PlacementRequests when the task group's features (spread,
-    ports, devices) rule out the count-based bulk solve."""
+    individual PlacementRequests only when the task group asks for
+    ports, devices or cores, which are assigned a placement at a time."""
 
     task_group: TaskGroup
     name_indices: object = None  # (K,) int array

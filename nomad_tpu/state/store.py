@@ -203,6 +203,9 @@ class StateSnapshot:
     def alloc_blocks(self) -> Iterator[AllocBlock]:
         return (b for _, b in self._store._alloc_blocks.iterate(self.index))
 
+    def alloc_block_by_id(self, block_id: str) -> Optional[AllocBlock]:
+        return self._store._alloc_blocks.get(block_id, self.index)
+
     def _ids_from_index(self, table: VersionedTable, key) -> Iterator[str]:
         cell = table.get(key, self.index)
         seen = set()

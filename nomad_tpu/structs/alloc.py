@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -267,6 +267,12 @@ class AllocBlock:
     counts: np.ndarray = field(default_factory=lambda: np.zeros(0, np.int64))
     allocated_vec: np.ndarray = field(default_factory=lambda: comparable())
     mean_score: float = 0.0
+    # the per-placement scan's blocks: position p's own normalized score
+    # (f32, as the kernel returned it) and the group's two node counts,
+    # once; empty for the count solve's blocks, which keep mean_score
+    scores: np.ndarray = field(default_factory=lambda: np.zeros(0, np.float32))
+    nodes_evaluated: int = 0
+    nodes_in_pool: int = 0
     allocated_at: float = 0.0
     modify_time: float = 0.0
     create_index: int = 0
@@ -279,6 +285,7 @@ class AllocBlock:
     _offsets: object = field(default=None, repr=False, compare=False)
     _mat: dict = field(default_factory=dict, repr=False, compare=False)
     _metrics: object = field(default=None, repr=False, compare=False)
+    _rows_of: object = field(default=None, repr=False, compare=False)
 
     def __deepcopy__(self, memo):
         import copy as _copy
@@ -292,7 +299,10 @@ class AllocBlock:
             node_ids=list(self.node_ids), node_names=list(self.node_names),
             counts=self.counts.copy(),
             allocated_vec=self.allocated_vec.copy(),
-            mean_score=self.mean_score, allocated_at=self.allocated_at,
+            mean_score=self.mean_score, scores=self.scores.copy(),
+            nodes_evaluated=self.nodes_evaluated,
+            nodes_in_pool=self.nodes_in_pool,
+            allocated_at=self.allocated_at,
             modify_time=self.modify_time, create_index=self.create_index,
             modify_index=self.modify_index,
             rejected_rows=self.rejected_rows, dropped=self.dropped,
@@ -330,6 +340,15 @@ class AllocBlock:
         return (m for m in range(len(self.node_ids))
                 if m not in self.rejected_rows)
 
+    def live_node_counts(self) -> tuple:
+        """(node ids, counts) of the node rows the applier did not
+        reject: the block as per-node columns, for readers that add a
+        plan's placements up without materializing them."""
+        if not self.rejected_rows:
+            return self.node_ids, self.counts
+        rows = list(self.live_rows())
+        return [self.node_ids[m] for m in rows], self.counts[rows]
+
     def positions_for_row(self, m: int) -> range:
         off = self.offsets()
         return range(int(off[m]), int(off[m + 1]))
@@ -341,7 +360,15 @@ class AllocBlock:
 
     # -- materialization --
 
-    def _shared_metrics(self):
+    def _metrics_at(self, p: int, node_id: str):
+        """The AllocMetric the per-placement row path wrote for position
+        p where the block carries per-position scores; else the one
+        shared bulk metric."""
+        if len(self.scores):
+            return AllocMetric(
+                nodes_evaluated=self.nodes_evaluated,
+                nodes_in_pool=self.nodes_in_pool,
+                scores={f"{node_id}.normalized-score": float(self.scores[p])})
         metrics = self._metrics
         if metrics is None:
             metrics = self._metrics = AllocMetric(
@@ -369,7 +396,7 @@ class AllocBlock:
                 task_group=self.task_group,
                 deployment_id=self.deployment_id,
                 allocated_vec=self.allocated_vec,
-                metrics=self._shared_metrics(),
+                metrics=self._metrics_at(p, self.node_ids[m]),
                 allocated_at=self.allocated_at,
                 modify_time=self.modify_time,
                 create_index=self.create_index,
@@ -383,11 +410,21 @@ class AllocBlock:
         return [self.alloc_at(p) for p in self.positions_for_row(m)
                 if p not in self.dropped]
 
+    def rows_for_node(self, node_id: str) -> Sequence[int]:
+        """Node rows of `node_id` (layout is frozen at plan time, so the
+        index is built once)."""
+        rows_of = self._rows_of
+        if rows_of is None:
+            rows_of = {}
+            for m, nid in enumerate(self.node_ids):
+                rows_of.setdefault(nid, []).append(m)
+            self._rows_of = rows_of
+        return rows_of.get(node_id, ())
+
     def allocs_for_node(self, node_id: str) -> List["Allocation"]:
         out: List[Allocation] = []
-        for m, nid in enumerate(self.node_ids):
-            if nid == node_id:
-                out.extend(self.allocs_for_row(m))
+        for m in self.rows_for_node(node_id):
+            out.extend(self.allocs_for_row(m))
         return out
 
     def iter_allocs(self):

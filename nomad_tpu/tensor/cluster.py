@@ -223,8 +223,9 @@ class ClusterTensors:
         which only helps an optimistic solve — the serialized applier
         re-verifies), else O(nodes) snapshot rows. Only nodes the
         in-progress plan touches are recomputed from ctx.proposed_allocs
-        (reference context.go:176 ProposedAllocs). Called between task
-        groups so group B sees group A's in-plan placements.
+        (reference context.go:176 ProposedAllocs); the plan's AllocBlocks
+        are added a block at a time. Called between task groups so
+        group B sees group A's in-plan placements.
 
         `out` is the per-placement tier's gather under
         _PER_EVAL_SOLVE_LOCK: a caller-owned (n_pad, D) f32 buffer,
@@ -241,6 +242,7 @@ class ClusterTensors:
                                  or plan.node_allocation):
             touched = (set(plan.node_update) | set(plan.node_preemptions)
                        | set(plan.node_allocation))
+        blocks = plan.alloc_blocks if plan is not None else ()
         # incremental fast path (tensor/incremental.py): the feed's
         # delta-fed base already IS latest-committed usage in this
         # static's row order. With no plan-touched rows and no racing
@@ -262,7 +264,7 @@ class ClusterTensors:
             if out is not None:
                 np.copyto(out, base)
                 used = out
-            elif not touched and not inflight:
+            elif not touched and not inflight and not blocks:
                 self.used = base
                 self._used_shared = True
                 return
@@ -292,11 +294,30 @@ class ClusterTensors:
                     if a.should_count_for_usage():
                         row += a.allocated_vec
                 used[i] = row
+        # the plan's columnar placements (an earlier group's block):
+        # indexed adds a block, but for a touched node, whose row above
+        # was summed from proposed_allocs and holds them already
+        for block in blocks:
+            rows, counts = self._block_rows(block, skip=touched)
+            np.add.at(used, rows,
+                      counts[:, None] * block.allocated_vec[None, :])
         # other racing evals' in-flight (solved, not yet committed)
         # placements: fold LAST so this solve plans around them instead
         # of colliding on the same best-fit nodes (tensor/overlay.py;
         # the per-eval twin of the bulk solver service's carry)
         INFLIGHT.fold(used[:n], self.node_index, entries=inflight)
+
+    def _block_rows(self, block, skip=()) -> Tuple[np.ndarray, np.ndarray]:
+        """(row indices, counts) of a plan block's live node rows in
+        this cluster's order; nodes in `skip` or not in the order are
+        left out."""
+        ids, counts = block.live_node_counts()
+        index = self.node_index
+        rows = np.fromiter(
+            (-1 if nid in skip else index.get(nid, -1) for nid in ids),
+            np.int64, len(ids))
+        keep = rows >= 0
+        return rows[keep], np.asarray(counts, np.int64)[keep]
 
     def latest_usage(self) -> np.ndarray:
         """Freshly-gathered LATEST committed usage, (n_pad, D) float32.
@@ -362,6 +383,14 @@ class ClusterTensors:
                     pjob[i] += 1
                     if a.task_group == tg.name:
                         ptg[i] += 1
+            for block in plan.alloc_blocks:
+                if (block.job_id != job.id
+                        or block.namespace != job.namespace):
+                    continue
+                rows, counts = self._block_rows(block)
+                np.add.at(pjob, rows, counts)
+                if block.task_group == tg.name:
+                    np.add.at(ptg, rows, counts)
         return ptg, pjob
 
 

@@ -11,7 +11,9 @@ Lowering strategy per evaluation:
   4. commits mapped back through the scheduler's commit callback so the
      plan object and ctx.proposed_allocs stay authoritative. Exact port
      numbers, device instance ids, and core ids are assigned host-side
-     per chosen node after the solve (counts were fit on-device).
+     per chosen node after the solve (counts were fit on-device). A
+     group of fresh placements that needs none of them is committed as
+     one AllocBlock, from the scan as from the count solve.
 
 Preemption stays host-side: when the kernel finds no fit and preemption
 is enabled, the per-request fallback runs the host NodeScorer preemption
@@ -322,8 +324,8 @@ class TPUPlacer:
                 bulk = reqs[0]
                 tgt = build_task_group_tensors(ctx, job, tg, cluster,
                                                algorithm=self.algorithm)
-                if (self._bulk_shape_ok(ctx, tg, tgt)
-                        and getattr(commit, "commit_block", None) is not None):
+                takes_blocks = getattr(commit, "commit_block", None) is not None
+                if takes_blocks and self._bulk_shape_ok(ctx, tg, tgt):
                     with TRACER.span("worker.solve_bulk", k=bulk.count,
                                      columnar=True):
                         self._place_bulk_columnar(
@@ -332,9 +334,20 @@ class TPUPlacer:
                             preemption_enabled=preemption_enabled,
                             attempt=attempt)
                     continue
-                # group features (spread/ports/devices/...) need the
-                # per-placement machinery: expand and fall through
-                # (reusing the tensors just built)
+                if (takes_blocks and bulk.count > self.HOST_CUTOVER
+                        and not self._wants_exact_ids(ctx, tg)):
+                    # spread / distinct_hosts / distinct_property rule
+                    # out the count solve, not the block: the scan's K
+                    # placements are committed as ONE AllocBlock too
+                    self._place_scan_columnar(
+                        ctx, job, tg, bulk, cluster, tgt, commit, tie_perm,
+                        sched_batch=batch,
+                        preemption_enabled=preemption_enabled,
+                        attempt=attempt)
+                    continue
+                # ports / devices / cores are assigned per placement on
+                # its chosen node: expand and fall through (reusing the
+                # tensors just built)
                 reqs = bulk.expand()
                 prebuilt_tgt = tgt
             else:
@@ -370,29 +383,13 @@ class TPUPlacer:
                                      attempt=attempt)
                 continue
 
-            k = len(reqs)
-            k_pad = _pad_pow2(k, floor=1)
-            penalty_idx = np.full(k_pad, -1, dtype=np.int32)
-            active = np.zeros(k_pad, dtype=bool)
-            active[:k] = True
+            penalty_idx = np.full(_pad_pow2(len(reqs), floor=1), -1,
+                                  dtype=np.int32)
             for i, req in enumerate(reqs):
                 if req.ignore_node:
                     penalty_idx[i] = cluster.node_index.get(req.ignore_node, -1)
-
-            # Everything the solve reads that no racing evaluation can
-            # change is packed and put on the device here, while the
-            # worker would otherwise only wait for the lock: the hold
-            # below is left with what depends on other evaluations'
-            # placements (the usage gather) and the launch itself.
-            with TRACER.span("placer.admit"):
-                _SOLVE_ADMIT.acquire()
-            try:
-                staged = self._stage_statics(tgt, cluster, penalty_idx,
-                                             active, tie_perm)
-                choices, founds, scores = self._solve_staged(
-                    ctx, tg, cluster, reqs, k_pad, staged)
-            finally:
-                _SOLVE_ADMIT.release()
+            choices, founds, scores = self._scan_group(
+                ctx, tg, cluster, tgt, len(reqs), penalty_idx, tie_perm)
 
             # exact port numbers / device instances / core ids are
             # host-side, per chosen node, after the solve (the kernel only
@@ -510,6 +507,115 @@ class TPUPlacer:
         return all(req.previous_alloc is None and not req.ignore_node
                    and not req.canary for req in reqs)
 
+    @staticmethod
+    def _wants_exact_ids(ctx, tg) -> bool:
+        """Whether a placement of this group carries something assigned
+        on its chosen node after the solve (exact port numbers, device
+        instances, core ids): the row loop's wants_ports /
+        wants_devices / wants_cores as one test."""
+        ask_res = ctx.tg_resources(tg)
+        return bool(ask_res.reserved_port_asks()
+                    or ask_res.dynamic_port_count()
+                    or ask_res.devices or ask_res.cores)
+
+    def _scan_group(self, ctx, tg, cluster, tgt, k: int, penalty_idx,
+                    tie_perm, columnar: bool = False):
+        """One group's per-placement scan of k placements: admitted,
+        staged outside _PER_EVAL_SOLVE_LOCK, solved under it ->
+        (choices, founds, scores), each of the padded length.
+        `penalty_idx` is the padded (k_pad,) reschedule-penalty row;
+        `columnar` says the caller hands the result over as one
+        AllocBlock (counted beside the staged solves: the share of
+        groups that take that arm)."""
+        from ..core.metrics import REGISTRY
+
+        REGISTRY.incr("nomad.placer.columnar_scan_groups", int(columnar))
+        k_pad = len(penalty_idx)
+        active = np.zeros(k_pad, dtype=bool)
+        active[:k] = True
+        # Everything the solve reads that no racing evaluation can
+        # change is packed and put on the device here, while the
+        # worker would otherwise only wait for the lock: the hold
+        # below is left with what depends on other evaluations'
+        # placements (the usage gather) and the launch itself.
+        with TRACER.span("placer.admit"):
+            _SOLVE_ADMIT.acquire()
+        try:
+            staged = self._stage_statics(tgt, cluster, penalty_idx,
+                                         active, tie_perm)
+            return self._solve_staged(ctx, tg, cluster, k, k_pad, staged)
+        finally:
+            _SOLVE_ADMIT.release()
+
+    def _place_scan_columnar(self, ctx, job, tg, bulk, cluster, tgt,
+                             commit, tie_perm, *, sched_batch: bool,
+                             preemption_enabled: bool, attempt: int) -> None:
+        """The per-placement scan's K fresh placements handed over as
+        one AllocBlock: the launch, its choices and so the placements
+        are the row loop's, node for node; what follows the scan is
+        numpy over K and a list over the touched nodes, not a
+        RankedNode, an AllocMetric and an Allocation a placement. Block
+        positions are the found placements in stable order of their
+        chosen node, each with its own name index and score."""
+        k = bulk.count
+        # a bulk request has no ignore_node: no reschedule penalty
+        penalty_idx = np.full(_pad_pow2(k, floor=1), -1, dtype=np.int32)
+        choices, founds, scores = self._scan_group(
+            ctx, tg, cluster, tgt, k, penalty_idx, tie_perm, columnar=True)
+
+        nodes = cluster.nodes
+        metrics = ctx.new_metrics()
+        metrics.nodes_in_pool = len(nodes)
+        metrics.nodes_evaluated = len(nodes)
+        name_indices = np.asarray(bulk.name_indices, dtype=np.int64)
+        found = np.flatnonzero(founds[:k])
+        if len(found):
+            # np.unique's sorted rows are the stable order's node runs
+            found = found[np.argsort(choices[found], kind="stable")]
+            rows, counts = np.unique(choices[found], return_counts=True)
+            rows = rows.tolist()
+            placed_scores = scores[found]
+            commit.commit_block(
+                tg,
+                [nodes[ni].id for ni in rows],
+                [nodes[ni].name for ni in rows],
+                counts.astype(np.int64),
+                name_indices[found],
+                float(placed_scores.mean()),
+                scores=placed_scores,
+                nodes_evaluated=len(nodes),
+                nodes_in_pool=len(nodes))
+
+        self._bulk_tail(ctx, job, tg, bulk.job_id,
+                        name_indices[~founds[:k]], metrics, cluster, tgt,
+                        commit, sched_batch=sched_batch,
+                        preemption_enabled=preemption_enabled,
+                        attempt=attempt)
+
+    def _bulk_tail(self, ctx, job, tg, job_id, tail_indices, metrics,
+                   cluster, tgt, commit, *, sched_batch: bool,
+                   preemption_enabled: bool, attempt: int) -> None:
+        """What a bulk request's solve left unplaced (`tail_indices`:
+        their name indices): one coalesced failure, or, with preemption
+        enabled, the per-request preemption machinery for the remainder
+        ALONE, expanded here."""
+        if not len(tail_indices):
+            return
+        nodes = cluster.nodes
+        n_feasible = int(tgt.feasible[: len(nodes)].sum())
+        if preemption_enabled:
+            from ..scheduler.reconcile import BulkPlacementRequest
+
+            remainder = BulkPlacementRequest(
+                task_group=tg, job_id=job_id,
+                name_indices=tail_indices).expand()
+            self._preempt_batch(ctx, job, tg, remainder, cluster, tgt,
+                                commit, sched_batch=sched_batch,
+                                attempt=attempt, n_feasible=n_feasible)
+            return
+        self._attribute_failure(ctx, metrics, len(nodes), n_feasible)
+        commit.fail_bulk(tg, len(tail_indices))
+
     def _stage_statics(self, tgt, cluster, penalty_idx, active, tie_perm):
         """Pack the fused solve's static arguments and put them on the
         device, before _PER_EVAL_SOLVE_LOCK is taken: capacity, this
@@ -555,7 +661,7 @@ class TPUPlacer:
         REGISTRY.incr("nomad.placer.scan_steps_padded", len(active))
         return dev, usage_buf, extra_used
 
-    def _solve_staged(self, ctx, tg, cluster, reqs, k_pad, staged):
+    def _solve_staged(self, ctx, tg, cluster, k, k_pad, staged):
         """Take _PER_EVAL_SOLVE_LOCK, solve, release -> (choices, founds,
         scores)."""
         # The usage gather -> solve -> in-flight registration runs
@@ -577,27 +683,26 @@ class TPUPlacer:
         # placer.locked around gather / pack / ship / device_wait /
         # fetch / register. device=True mirrors each into the jax
         # profiler's trace, above the device's ops on one clock.
-        with TRACER.span("worker.solve", k=len(reqs)):
+        with TRACER.span("worker.solve", k=k):
             with TRACER.span("placer.lock_wait", device=True):
                 _PER_EVAL_SOLVE_LOCK.acquire()
             try:
-                return self._solve_locked(ctx, tg, cluster, reqs, k_pad,
+                return self._solve_locked(ctx, tg, cluster, k, k_pad,
                                           staged)
             finally:
                 _PER_EVAL_SOLVE_LOCK.release()
 
-    def _solve_locked(self, ctx, tg, cluster, reqs, k_pad, staged):
+    def _solve_locked(self, ctx, tg, cluster, k, k_pad, staged):
         """One evaluation's usage gather -> solve -> in-flight
         registration; the caller holds _PER_EVAL_SOLVE_LOCK and staged
         the static arguments (_stage_statics) before taking it. Returns
-        (choices, founds, scores) per request."""
+        (choices, founds, scores) per request, k of k_pad of them."""
         import jax
 
         from .kernels import solve_task_group_fused
         from .overlay import INFLIGHT
 
         statics, usage, extra_used = staged
-        k = len(reqs)
         with TRACER.span("placer.locked", cpu=True, device=True, k=k,
                          k_pad=k_pad, n_pad=cluster.n_pad):
             with TRACER.span("placer.gather", device=True):
@@ -770,24 +875,11 @@ class TPUPlacer:
                 np.asarray(bulk.name_indices[:total], dtype=np.int64),
                 mean_score)
 
-        n_unplaced = k - total
-        if not n_unplaced:
-            return
-        n_feasible = int(tgt.feasible[: len(nodes)].sum())
-        if preemption_enabled:
-            # rare tail: expand ONLY the remainder for the per-request
-            # preemption machinery
-            from ..scheduler.reconcile import BulkPlacementRequest
-
-            remainder = BulkPlacementRequest(
-                task_group=tg, job_id=bulk.job_id,
-                name_indices=bulk.name_indices[total:]).expand()
-            self._preempt_batch(ctx, job, tg, remainder, cluster, tgt,
-                                commit, sched_batch=sched_batch,
-                                attempt=attempt, n_feasible=n_feasible)
-            return
-        self._attribute_failure(ctx, metrics, len(nodes), n_feasible)
-        commit.fail_bulk(tg, n_unplaced)
+        self._bulk_tail(ctx, job, tg, bulk.job_id,
+                        bulk.name_indices[total:], metrics, cluster, tgt,
+                        commit, sched_batch=sched_batch,
+                        preemption_enabled=preemption_enabled,
+                        attempt=attempt)
 
     def _place_bulk(self, ctx, job, tg, reqs, cluster, tgt, commit,
                     tie_perm, seed, *, sched_batch: bool,
@@ -898,12 +990,9 @@ class TPUPlacer:
         from ..structs.alloc import Allocation
 
         nodes = cluster.nodes
-        ask_res = ctx.tg_resources(tg)
         # exact port numbers / device instances / cores can't come from
         # the dense victim columns — those groups keep the host scanner
-        exact_needed = bool(ask_res.reserved_port_asks()
-                            or ask_res.dynamic_port_count()
-                            or ask_res.devices or ask_res.cores)
+        exact_needed = self._wants_exact_ids(ctx, tg)
         ask_vec = ctx.tg_vec(tg)
 
         scorer = NodeScorer(ctx, job, tg, algorithm=self._host_algorithm(),

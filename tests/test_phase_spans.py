@@ -242,6 +242,60 @@ class TestPlacerPhases:
             assert a[R_T1] <= b[R_T0], (a, b)
 
 
+class TestBlockArmPhases:
+    def test_the_block_arm_keeps_the_span_tree_and_counts_every_group(self):
+        """A drain of the grid's job (300 fresh placements, a rack
+        spread, no port): every staged group is handed over as one
+        AllocBlock, under the spans the row loop runs under."""
+        TRACER.set_enabled(True)
+        TRACER.clear()
+        staged = REGISTRY.get("nomad.placer.staged_solves")
+        columnar = REGISTRY.get("nomad.placer.columnar_scan_groups")
+        s = Server(ServerConfig(num_workers=4, sched_config=_tpu_config()))
+        s.start()
+        try:
+            for i in range(64):
+                node = _racked_node(i)
+                node.resources.cpu, node.resources.memory_mb = 16000, 65536
+                node.compute_class()
+                s.register_node(node)
+            jobs = [_spread_job(300) for _ in range(4)]
+            for job in jobs:
+                job.task_groups[0].tasks[0].resources.networks = []
+                s.register_job(job)
+            assert s.wait_for_idle(120.0)
+            snap = s.store.snapshot()
+            assert all(len(snap.allocs_by_job(j.id)) == 300 for j in jobs)
+            assert sum(b.live_size() for b in snap.alloc_blocks()) == 1200
+            spans = TRACER.spans()
+        finally:
+            s.stop()
+        solves = [r for r in spans if r[R_NAME] == "worker.solve"]
+        groups = REGISTRY.get("nomad.placer.staged_solves") - staged
+        assert groups == len(solves) >= 4
+        # (a partly rejected plan's remainder, under BULK_PLACE_MIN, is
+        # a staged group of the row loop: rare, and not this arm's)
+        assert REGISTRY.get(
+            "nomad.placer.columnar_scan_groups") - columnar == len(
+                [r for r in solves if r[R_ARGS]["k"] >= 256]) >= 4
+        for solve in solves:
+            before = sorted(
+                (r for r in spans if r[R_PARENT] == solve[R_PARENT]
+                 and r[R_THREAD] == solve[R_THREAD]
+                 and r[R_T1] <= solve[R_T0]
+                 and r[R_NAME] in ("placer.admit", "placer.stage")),
+                key=lambda r: r[R_T0])[-2:]
+            assert [r[R_NAME] for r in before] == ["placer.admit",
+                                                   "placer.stage"]
+            wait, locked = _children(spans, solve)
+            assert (wait[R_NAME], locked[R_NAME]) == ("placer.lock_wait",
+                                                      "placer.locked")
+            assert solve[R_ARGS]["k"] == locked[R_ARGS]["k"]
+            assert locked[R_ARGS]["k_pad"] >= locked[R_ARGS]["k"]
+            assert [p[R_NAME] for p in _children(spans, locked)] \
+                == LOCKED_CHILDREN
+
+
 class TestStorePhases:
     def test_phases_nest_under_commit_round(self, spread_server):
         _, spans = spread_server
