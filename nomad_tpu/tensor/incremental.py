@@ -182,6 +182,13 @@ class IncrementalFeed:
         self._parity_checks = 0
         self._alloc_uncounted = 0
         self._gauge_pub = None
+        # moves whenever committed usage may have gone DOWN under a
+        # consumer that chains its own copy (the bulk solver's carry,
+        # tensor/solver.py): once a folded negative delta, once a
+        # resync, whose discarded backlog may have held some. Read
+        # without the lock: a consumer only compares it with the value
+        # its copy was rebuilt at
+        self._free_epoch = 0
 
     # -- public surface ------------------------------------------------
 
@@ -217,6 +224,13 @@ class IncrementalFeed:
             if ep is None:
                 return None
             return self._twin_locked(ep, mesh).arr
+
+    def free_epoch(self) -> int:
+        """How often this feed has seen committed usage fall (a job
+        stopped or purged, an allocation gone terminal) since it was
+        attached; a resync counts as one. Nothing is drained here: the
+        worker's tensor build drains before it asks for a solve."""
+        return self._free_epoch
 
     def take_build_delta_count(self) -> int:
         """Exact Allocation-delta count since the previous take — the
@@ -317,6 +331,7 @@ class IncrementalFeed:
             snap.close()
         self._epoch = ep
         self._resyncs += 1
+        self._free_epoch += 1
         self._gauges()
         return True
 
@@ -429,7 +444,11 @@ class IncrementalFeed:
         row = ep.node_index.get(node_id)
         if row is None:
             return
-        delta = vec[:RESOURCE_DIMS] if sign > 0 else -vec[:RESOURCE_DIMS]
+        if sign > 0:
+            delta = vec[:RESOURCE_DIMS]
+        else:
+            delta = -vec[:RESOURCE_DIMS]
+            self._free_epoch += 1
         ep.base[row] += delta
         self._deltas_applied += 1
         if ep.twins:
@@ -703,6 +722,13 @@ def device_used_fn(store, static):
         return feed.device_used(static, mesh)
 
     return fn
+
+
+def free_epoch_fn(store):
+    """() -> the store's feed's free epoch (`IncrementalFeed.free_epoch`)
+    for the bulk solver's stale-carry test, or None without a feed."""
+    feed = feed_for(store)
+    return None if feed is None else feed.free_epoch
 
 
 def violations() -> List[Violation]:

@@ -782,7 +782,7 @@ class TPUPlacer:
             # Cost: the carry solve drops the per-node anti-affinity
             # term for the retried remainder (a score preference, not a
             # capacity constraint; fresh solves have placed_* == 0).
-            from .incremental import device_used_fn
+            from .incremental import device_used_fn, free_epoch_fn
             from .solver import get_service
 
             service = get_service()
@@ -792,6 +792,7 @@ class TPUPlacer:
                 tg_count=tgt.tg_count, seed=seed,
                 used_fn=cluster.latest_usage,
                 used_dev_fn=device_used_fn(cluster._store, static),
+                free_epoch_fn=free_epoch_fn(cluster._store),
                 joint=(self.algorithm == enums.SCHED_ALG_TPU_SOLVE))
             if ctx.plan is not None:
                 ctx.plan.post_apply_hooks.append(

@@ -30,11 +30,17 @@ correctness. Drift is then actively repaired instead of tolerated:
   post_apply_hooks) -> confirm(): a fully-committed solve just closes
   its entry (its usage is now in the store); a solve with rejected
   nodes closes its entry too and marks the carry STALE (why: confirm());
-- resync (every RESYNC_SOLVES solves, on node-set change, or after a
-  rejection) rebuilds the carry as committed store usage PLUS the
-  still-open ledger entries, so in-flight work is never dropped from
-  the overlay and a rejected placement's phantom never outlives one
-  launch.
+- resync (every RESYNC_SOLVES solves, on node-set change, after a
+  rejection, or after a committed FREE) rebuilds the carry as committed
+  store usage PLUS the still-open ledger entries, so in-flight work is
+  never dropped from the overlay and a rejected placement's phantom
+  never outlives one launch;
+- a free is usage the carry keeps and the store no longer has (a job
+  stopped or purged, an allocation gone terminal): the incremental feed
+  counts the ones it folds (IncrementalFeed.free_epoch) and a dispatch
+  whose carry was rebuilt at another count resyncs first. Without that
+  a backlog submitted to a cluster that was just emptied is solved
+  against a full one and blocks with nothing left to unblock it.
 
 Without the ledger the carry both leaks rejected-placement phantoms
 (solve shortfalls -> blocked-eval retry storms as the cluster fills)
@@ -209,11 +215,12 @@ def ensure_resident(static, feas_base, aff, mesh=None):
 
 class _Request:
     __slots__ = ("static", "feas_base", "aff", "ask", "k", "tg_count",
-                 "seed", "used_fn", "used_dev_fn", "future", "token",
-                 "joint", "batch_ctx")
+                 "seed", "used_fn", "used_dev_fn", "free_epoch_fn", "future",
+                 "token", "joint", "batch_ctx")
 
     def __init__(self, static, feas_base, aff, ask, k, tg_count, seed,
-                 used_fn, joint=False, batch_ctx=None, used_dev_fn=None):
+                 used_fn, joint=False, batch_ctx=None, used_dev_fn=None,
+                 free_epoch_fn=None):
         self.static = static
         self.feas_base = feas_base
         self.aff = aff
@@ -231,6 +238,10 @@ class _Request:
         # ledger entries with one scatter instead of shipping an O(N)
         # host rebuild. None or a failed call falls back to used_fn.
         self.used_dev_fn = used_dev_fn
+        # optional () -> count of frees the store's feed has folded
+        # (tensor/incremental.py free_epoch_fn): a carry rebuilt at
+        # another count holds usage the store has let go of
+        self.free_epoch_fn = free_epoch_fn
         self.future = Future()
         self.token = 0
         self.joint = joint          # solve via the batch auction tier
@@ -290,9 +301,10 @@ class BulkSolverService:
         self._q: "queue.Queue" = queue.Queue()
         self._thread: Optional[threading.Thread] = None
         self._lock = threading.Lock()
-        # single-entry device state: (static, used_dev, solves_since_sync).
-        # One entry only — a new node-set version replaces it, and the
-        # strong static ref keeps id()-keyed device_arrays coherent.
+        # single-entry device state: (static, used_dev, solves_since_sync,
+        # the feed's free epoch at the last resync). One entry only — a
+        # new node-set version replaces it, and the strong static ref
+        # keeps id()-keyed device_arrays coherent.
         self._state = None
         self._token = 0
         self._ledger: Dict[int, _LedgerEntry] = {}
@@ -313,7 +325,10 @@ class BulkSolverService:
         # retrace and raises jit_guard.RetraceError (stats["retraces"]
         # counts them for the agent stats surface before propagating)
         self.stats = {"launches": 0, "solves": 0, "resyncs": 0,
-                      "rejections": 0, "sharded": 0,
+                      "rejections": 0,
+                      # resyncs forced by a committed free (the feed's
+                      # free epoch moved under a live carry)
+                      "stale_frees": 0, "sharded": 0,
                       "joint_launches": 0, "joint_solves": 0,
                       "auction_won": 0, "auction_rounds": 0,
                       "joint_score": 0.0, "greedy_score": 0.0,
@@ -372,7 +387,7 @@ class BulkSolverService:
     # -- caller side (scheduler worker threads) --
 
     def solve(self, *, static, feas_base, aff, ask, k, tg_count, seed,
-              used_fn, joint=False, used_dev_fn=None):
+              used_fn, joint=False, used_dev_fn=None, free_epoch_fn=None):
         """Blocking solve of one fresh-placement bulk eval ->
         ((N_pad,) int64 per-node counts in canonical order, token).
         The caller must arrange for confirm(token, rejected_node_ids)
@@ -387,7 +402,7 @@ class BulkSolverService:
                        float(tg_count), np.uint32(seed), used_fn,
                        joint=joint,
                        batch_ctx=current_batch() if joint else None,
-                       used_dev_fn=used_dev_fn)
+                       used_dev_fn=used_dev_fn, free_epoch_fn=free_epoch_fn)
         # put BEFORE ensure: the service thread clears self._thread
         # before its final stop-drain, so a request racing stop() is
         # either caught by that drain (failed, answered) or observes
@@ -464,7 +479,10 @@ class BulkSolverService:
                 # — fetch it (resolving them) BEFORE parking on the
                 # queue, or the pipeline deadlocks on an empty queue
                 self._fetch_inflight()
-                req = self._q.get()
+                # parked with nothing in flight: the span says whose
+                # turn it is while the device idles
+                with TRACER.span("solver.idle"):
+                    req = self._q.get()
             if req is _STOP:
                 self._fetch_inflight()
                 self._retire()
@@ -759,15 +777,25 @@ class BulkSolverService:
         d = static.available.shape[1]
         mesh = self._resolve_mesh(static.n_pad)
         state = self._state
-        used_dev, since = None, 0
+        used_dev, since, synced_at = None, 0, None
         if state is not None and state[0] is static:
-            used_dev, since = state[1], state[2]
+            used_dev, since, synced_at = state[1:]
+        # read BEFORE a resync takes its base: a free folded in between
+        # is then in the base and costs one resync more; read after, it
+        # could be in neither
+        free_epoch = (rs[0].free_epoch_fn()
+                      if rs[0].free_epoch_fn is not None else None)
+        freed = used_dev is not None and free_epoch != synced_at
 
         with self._lock:
             need_resync = (used_dev is None
                            or since >= self.RESYNC_SOLVES
-                           or self._stale)
+                           or self._stale or freed)
             self._stale = False
+            if freed:
+                self.stats["stale_frees"] += 1
+        if freed:
+            REGISTRY.incr("nomad.solver.stale_frees")
         if need_resync:
             # the resync base is committed usage + OPEN ledger entries.
             # A still-unfetched launch has no entries yet — drain it
@@ -794,7 +822,7 @@ class BulkSolverService:
                              entries=len(ledger_entries)):
                 used_dev = self._resync_base(rs[0], static, mesh, d,
                                              ledger_entries)
-            since = 0
+            since, synced_at = 0, free_epoch
             with self._lock:
                 self.stats["resyncs"] += 1
 
@@ -862,7 +890,7 @@ class BulkSolverService:
                 new_used, counts = solve_bulk_multi(
                     used_dev, avail, feas, aff, ask, k, tgc, seeds, cidx,
                     cdelta, g=g_pad)
-        self._state = (static, new_used, since + g)
+        self._state = (static, new_used, since + g, synced_at)
         if mesh is not None:
             # dispatch-side span: the sharded launch is queued, the host
             # keeps running — the solve/apply overlap window opens here
