@@ -36,10 +36,13 @@ from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from ..analysis.sanitizer import sanitized
 from ..obs import RECORDER, TRACER
 from ..structs import allocs_fit, enums
 from ..structs.plan import Plan, PlanResult
+from ..structs.resources import RESOURCE_DIMS
 
 
 class PendingPlan:
@@ -158,9 +161,59 @@ class BadNodeTracker:
         return fire
 
 
+class _BlockRows:
+    """A block's live node rows in the row numbers of the store's dense
+    columns: `rows` (k,), the `counts` (k,) of placements on them and
+    the `vec` each placement takes (blocks are resource-only fresh
+    placements by construction, so count x vec is the exact fit
+    input), `row_set` for asking whether two blocks meet, whether the
+    rows are `distinct`, and how many nodes they are. Kept on the
+    block, whose layout is frozen at plan time: a block is read at its
+    own verify and again, verify after verify, while it is in flight."""
+
+    __slots__ = ("assignment", "rows", "counts", "vec", "row_set",
+                 "distinct", "n_nodes")
+
+    def __init__(self, cols, block):
+        node_ids, counts = block.live_node_counts()
+        self.assignment = cols.assignment
+        self.rows = cols.rows(node_ids)
+        self.counts = np.asarray(counts, dtype=np.float64)
+        self.vec = block.allocated_vec
+        self.row_set = frozenset(self.rows.tolist())
+        self.distinct = len(self.row_set) == len(self.rows)
+        # a node the store never saw has no row of its own: -1 may
+        # stand for several, so the ids are counted where rows repeat
+        self.n_nodes = (len(self.rows) if self.distinct
+                        else len(set(node_ids)))
+
+    @classmethod
+    def of(cls, cols, block) -> "_BlockRows":
+        kept = block._store_rows
+        if kept is None or kept.assignment is not cols.assignment:
+            kept = block._store_rows = cls(cols, block)
+        return kept
+
+    def usage(self) -> np.ndarray:
+        return self.counts[:, None] * self.vec[None, :]
+
+
+def _fits(used, asked_column, avail) -> np.ndarray:
+    """used + asked <= avail in every dimension -> (M,) bool, float64 as
+    _node_plan_valid's allocs_fit. A column at a time: inside a ufunc
+    over more than 500 elements numpy lets go of the interpreter lock,
+    and an applier that lets go of it among busy workers waits
+    milliseconds to have it back (PERF.md section 6, PR 37); a column of
+    a few hundred nodes stays under that."""
+    ok = np.ones(len(used), dtype=bool)
+    for d in range(RESOURCE_DIMS):
+        ok &= used[:, d] + asked_column(d) <= avail[:, d]
+    return ok
+
+
 class _OverlaySnapshot:
     """In-flight plan results layered over a snapshot (oldest first),
-    exposing just the reads _node_plan_valid performs — so a new plan
+    exposing just the reads _evaluate performs — so a new plan
     verifies against "state as of every pending commit" while those raft
     rounds are still in the air (reference plan_apply.go:355-363
     optimistic snapshot). More than one result can be pending at once:
@@ -169,9 +222,6 @@ class _OverlaySnapshot:
     def __init__(self, snap, results: List[PlanResult]):
         self._snap = snap
         self._replaced: Dict[str, dict] = {}
-        self._usage_deltas: Dict[str, object] = {}
-        # node id -> [(block, row)] of in-flight columnar placements
-        self._block_rows: Dict[str, list] = {}
         for result in results:  # later results override earlier ones
             for node_id in (set(result.node_allocation)
                             | set(result.node_update)
@@ -181,50 +231,60 @@ class _OverlaySnapshot:
                                result.node_allocation):
                     for a in bucket.get(node_id, ()):
                         by_id[a.id] = a
-            for block in result.alloc_blocks:
-                # a result listed as in flight whose commit landed
-                # before this snapshot was taken is in the snapshot
-                # already: a row nets itself out by its id (node_usage
-                # below), a block has to be left out here, or its nodes
-                # read twice as full and the next plan's rows on them
-                # are rejected
-                if snap.alloc_block_by_id(block.id) is not None:
-                    continue
-                for m in block.live_rows():
-                    self._block_rows.setdefault(
-                        block.node_ids[m], []).append((block, m))
+        # a result listed as in flight whose commit landed before this
+        # snapshot was taken is in the snapshot already: a row nets
+        # itself out by its id (inflight below), a block has to
+        # be left out here, or its nodes read twice as full and the
+        # next plan's rows on them are rejected. The snapshot that
+        # decides is the one whose generation the usage is read at
+        # (node_columns), so a commit landing after it is in neither
+        # and is added
+        self._blocks = [block for result in results
+                        for block in result.alloc_blocks
+                        if snap.alloc_block_by_id(block.id) is None]
+        # node id -> [(block, row)] of those blocks: the per-node reads'
+        # index (allocs_by_node), built when the exact path first asks
+        self._block_rows: Optional[Dict[str, list]] = None
 
     def node_by_id(self, node_id):
         return self._snap.node_by_id(node_id)
 
-    def node_usage(self, node_id):
-        """Usage row (the scheduler's `not terminal_status()` predicate)
-        with the in-flight results' net effect folded in — powers the
-        applier's vectorized fit pass through overlays too."""
-        base = self._snap.node_usage(node_id)
-        by_id = self._replaced.get(node_id)
-        rows = self._block_rows.get(node_id)
-        if not by_id and not rows:
-            return base
-        delta = self._usage_deltas.get(node_id)
-        if delta is None:
-            delta = 0.0
-            for aid, a in (by_id or {}).items():
+    def node_columns(self):
+        return self._snap.node_columns()
+
+    def inflight(self, cols) -> Tuple[List[_BlockRows], Optional[tuple]]:
+        """What the in-flight results add to what the snapshot's columns
+        hold: their blocks' live node rows, and for the nodes they touch
+        with rows a (rows, usage) pair of what each row will count less
+        what the snapshot counts for it already (the usage rows' `not
+        terminal_status()` predicate)."""
+        blocks = [_BlockRows.of(cols, block) for block in self._blocks]
+        if not self._replaced:
+            return blocks, None
+        deltas = []
+        for by_id in self._replaced.values():
+            delta = np.zeros(RESOURCE_DIMS)
+            for aid, a in by_id.items():
                 if not a.terminal_status():
-                    delta = delta + a.allocated_vec
+                    delta += a.allocated_vec
                 base_a = self._snap.alloc_by_id(aid)
                 if base_a is not None and not base_a.terminal_status():
-                    delta = delta - base_a.allocated_vec
-            for block, m in rows or ():
-                delta = delta + block.allocated_vec * int(block.counts[m])
-            self._usage_deltas[node_id] = delta
-        if base is None:
-            return delta
-        return base + delta
+                    delta -= base_a.allocated_vec
+            deltas.append(delta)
+        return blocks, (cols.rows(list(self._replaced)), np.asarray(deltas))
+
+    def _block_rows_of(self, node_id: str) -> list:
+        rows = self._block_rows
+        if rows is None:
+            rows = self._block_rows = {}
+            for block in self._blocks:
+                for m in block.live_rows():
+                    rows.setdefault(block.node_ids[m], []).append((block, m))
+        return rows.get(node_id, ())
 
     def allocs_by_node(self, node_id):
         overlay = self._replaced.get(node_id)
-        rows = self._block_rows.get(node_id)
+        rows = self._block_rows_of(node_id)
         base = self._snap.allocs_by_node(node_id)
         if not overlay and not rows:
             return base
@@ -233,7 +293,7 @@ class _OverlaySnapshot:
         if overlay:
             have = {a.id for a in base}
             out.extend(a for aid, a in overlay.items() if aid not in have)
-        for block, m in rows or ():
+        for block, m in rows:
             out.extend(block.allocs_for_row(m))
         return out
 
@@ -328,7 +388,8 @@ class PlanApplier:
         self._commit_q: "deque[_CommitEntry]" = deque()
         self._commit_cond = threading.Condition()
         self._stop = threading.Event()
-        self.stats = {"applied": 0, "nodes_verified": 0, "nodes_rejected": 0,
+        self.stats = {"applied": 0, "nodes_verified": 0,
+                      "nodes_verified_columnar": 0, "nodes_rejected": 0,
                       "partial_commits": 0,
                       "commit_batches": 0, "batched_commits": 0,
                       "batched_eval_updates": 0}
@@ -347,6 +408,10 @@ class PlanApplier:
         # store before committing (commits are serialized, so by then
         # every predecessor has landed or failed).
         self._poison_gen = 0
+        # per-thread sparse accumulator of the array fit check: verify
+        # runs on the applier's thread, on the commit thread when it
+        # re-verifies, and on whoever calls apply()
+        self._scratch = threading.local()
 
     def start(self) -> None:
         self._stop.clear()
@@ -951,9 +1016,10 @@ class PlanApplier:
         result, rejected = self._verify(plan, None)
         return self._commit(plan, result, rejected)
 
-    # Nodes whose plan entries are all NEW, port/device/core-free
-    # placements verify as one vectorized numpy fit pass when at least
-    # this many qualify (below it the python loop wins on set-up cost).
+    # A plan of rows alone that touches fewer array-path nodes than this
+    # takes the python loop: the arrays' set-up cost (some twenty numpy
+    # calls whatever the size) wins from about a dozen nodes on. A plan
+    # with a block takes the arrays whatever its size.
     VECTOR_THRESHOLD = 16
 
     def _evaluate(self, snap, plan: Plan) -> Tuple[PlanResult, List[str]]:
@@ -963,94 +1029,147 @@ class PlanApplier:
 
         The GIL-free scale path (reference plan_apply_pool.go:21
         EvaluatePool's role): nodes touched ONLY by new placements that
-        carry no ports/devices/cores — the entire bulk-placement shape —
-        skip the per-node alloc walk entirely. Their fit check is
-        usage_row + sum(new vecs) <= available, batched into one numpy
-        comparison; the accounting is exactly _node_plan_valid's
-        (existing filters `not terminal_status()`, the usage rows'
-        predicate, and no new ports/cores means no new collision is
-        possible). Everything else keeps the exact python check."""
+        carry no ports/devices/cores — a block's nodes and the entire
+        bulk-placement shape — skip the per-node alloc walk entirely.
+        Their fit check is usage_row + sum(new vecs) <= available as
+        arrays from the plan to the verdict (_columnar_verdicts); the
+        accounting is exactly _node_plan_valid's (existing filters `not
+        terminal_status()`, the usage rows' predicate, and no new
+        ports/cores means no new collision is possible). Everything
+        else keeps the exact python check."""
+        from .metrics import REGISTRY
+
         result = PlanResult()
-        rejected: List[str] = []
-        # columnar blocks contribute per-node usage deltas; a node row
-        # rejects wholesale exactly like a node_allocation bucket
-        block_delta: Dict[str, object] = {}
-        block_nodes: set = set()
-        for block in plan.alloc_blocks:
-            vec = block.allocated_vec
-            for m in block.live_rows():
-                nid = block.node_ids[m]
-                block_nodes.add(nid)
-                prev = block_delta.get(nid)
-                d = vec * int(block.counts[m])
-                block_delta[nid] = d if prev is None else prev + d
-        nodes = sorted(set(plan.node_allocation) | set(plan.node_update)
-                       | set(plan.node_preemptions) | block_nodes)
-        fast: List[str] = []
-        exact: List[str] = []
-        for nid in nodes:
-            if nid in plan.node_update or nid in plan.node_preemptions:
-                exact.append(nid)
-                continue
-            if all(a.create_index == 0 and not a.allocated_ports
-                   and not a.allocated_devices and not a.allocated_cores
-                   for a in plan.node_allocation.get(nid, ())):
-                fast.append(nid)
-            else:
-                exact.append(nid)
-        if len(fast) < self.VECTOR_THRESHOLD and not block_nodes:
-            exact.extend(fast)
-            fast = []
-        verdict: Dict[str, bool] = {}
-        if fast:
-            verdict.update(self._vector_verdicts(snap, plan, fast,
-                                                 block_delta))
+        row_nodes = (set(plan.node_allocation) | set(plan.node_update)
+                     | set(plan.node_preemptions))
+        exact = [nid for nid in sorted(row_nodes)
+                 if nid in plan.node_update or nid in plan.node_preemptions
+                 or not all(a.create_index == 0 and not a.allocated_ports
+                            and not a.allocated_devices
+                            and not a.allocated_cores
+                            for a in plan.node_allocation.get(nid, ()))]
+        fresh = sorted(row_nodes.difference(exact))
+        if len(fresh) < self.VECTOR_THRESHOLD and not plan.alloc_blocks:
+            exact, fresh = sorted(row_nodes), []
+        n_columnar, bad = self._columnar_verdicts(snap, plan, fresh, exact)
         if len(exact) >= self.PARALLEL_THRESHOLD and self._pool is not None:
-            verdict.update(zip(exact, self._pool.map(
-                lambda nid: self._node_plan_valid(snap, plan, nid), exact)))
+            valid = self._pool.map(
+                lambda nid: self._node_plan_valid(snap, plan, nid), exact)
         else:
-            for nid in exact:
-                verdict[nid] = self._node_plan_valid(snap, plan, nid)
-        verdicts = [verdict[nid] for nid in nodes]
+            valid = (self._node_plan_valid(snap, plan, nid) for nid in exact)
+        bad.update(itertools.compress(exact, (not v for v in valid)))
         # the denominator of the rejected share: every node row given a
         # verdict, a re-verified plan's rows again (nodes_rejected
-        # counts the final verdict once, in _finalize)
+        # counts the final verdict once, in _finalize); and how many of
+        # them the array path gave it
         with self._stats_lock:
-            self.stats["nodes_verified"] += len(nodes)
-        vol_bad = self._volume_rejections(snap, plan)
-        for node_id, ok in zip(nodes, verdicts):
-            if ok and node_id not in vol_bad:
-                if node_id in plan.node_allocation:
-                    result.node_allocation[node_id] = plan.node_allocation[node_id]
-                if node_id in plan.node_update:
-                    result.node_update[node_id] = plan.node_update[node_id]
-                if node_id in plan.node_preemptions:
-                    result.node_preemptions[node_id] = plan.node_preemptions[node_id]
-            else:
-                rejected.append(node_id)
-                # only per-node plan invalidity feeds the tracker — losing
-                # a cross-node single-writer-volume race says nothing about
-                # the node's health (reference evaluateNodePlan-only
-                # accounting, plan_apply_node_tracker.go)
-                if not ok:
-                    # san-ok: BadNodeTracker.add locks internally
-                    self.bad_nodes.add(node_id)
+            self.stats["nodes_verified"] += n_columnar + len(exact)
+            self.stats["nodes_verified_columnar"] += n_columnar
+        REGISTRY.incr("nomad.plan.nodes_verified", n_columnar + len(exact))
+        REGISTRY.incr("nomad.plan.nodes_verified_columnar", n_columnar)
+        # only per-node plan invalidity feeds the tracker — losing a
+        # cross-node single-writer-volume race says nothing about the
+        # node's health (reference evaluateNodePlan-only accounting,
+        # plan_apply_node_tracker.go)
+        for node_id in sorted(bad):
+            # san-ok: BadNodeTracker.add locks internally
+            self.bad_nodes.add(node_id)
+        rejected = sorted(bad | self._volume_rejections(snap, plan))
         if rejected and plan.all_at_once:
             # all-or-nothing plan: reject everything
-            result.node_allocation.clear()
-            result.node_update.clear()
-            result.node_preemptions.clear()
-            rejected = sorted(nodes)
-            return result, rejected
-        if plan.alloc_blocks:
-            rej_set = set(rejected) & block_nodes
-            for block in plan.alloc_blocks:
-                sliced = (block.without_nodes(rej_set) if rej_set else block)
-                if any(True for _ in sliced.live_rows()):
-                    result.alloc_blocks.append(sliced)
+            return result, sorted(row_nodes.union(
+                *(b.live_node_counts()[0] for b in plan.alloc_blocks)))
+        for node_id in sorted(row_nodes.difference(rejected)):
+            if node_id in plan.node_allocation:
+                result.node_allocation[node_id] = plan.node_allocation[node_id]
+            if node_id in plan.node_update:
+                result.node_update[node_id] = plan.node_update[node_id]
+            if node_id in plan.node_preemptions:
+                result.node_preemptions[node_id] = plan.node_preemptions[node_id]
+        # no rejected node is the common case: the plan's blocks as they are
+        for block in plan.alloc_blocks:
+            sliced = block.without_nodes(rejected) if rejected else block
+            if any(True for _ in sliced.live_rows()):
+                result.alloc_blocks.append(sliced)
         result.deployment = plan.deployment
         result.deployment_updates = plan.deployment_updates
         return result, rejected
+
+    def _columnar_verdicts(self, snap, plan: Plan, fresh: List[str],
+                           exact: List[str]) -> Tuple[int, set]:
+        """The fit re-check of new-placements-only nodes, as arrays in
+        the row numbers of the store's dense columns. Every live node
+        row of the plan's blocks and every placement on the nodes of
+        `fresh` is one (row, usage) pair, and so is what the in-flight
+        results add; committed usage and open capacity of the plan's
+        rows are one gather each at the snapshot's generation; all
+        pairs are summed a node by one indexed add into a sparse
+        accumulator and read back at the plan's rows; one float64
+        comparison a dimension gives the verdicts. A block's row on a
+        node of `exact` gets none here: _node_plan_valid judges that
+        node, the block's placements on it. -> (nodes given a verdict,
+        ids of those it went against); a node that is down, draining or
+        gone is one."""
+        cols = snap.node_columns()
+        blocks = [_BlockRows.of(cols, block) for block in plan.alloc_blocks]
+        ids = [nid for nid in fresh for _ in plan.node_allocation[nid]]
+        if not blocks and not ids:
+            return 0, set()
+        inflight = getattr(snap, "inflight", None)
+        others, other_rows = inflight(cols) if inflight else ([], None)
+        if (len(blocks) == 1 and not ids and not exact and other_rows is None
+                and blocks[0].distinct
+                and all(blocks[0].row_set.isdisjoint(o.row_set)
+                        for o in others)):
+            # one block on nodes of its own that nothing in flight
+            # touches, the common case: what it asks is all that is
+            # asked of them, and its node ids are read only if a
+            # verdict went against one
+            own = blocks[0]
+            used, avail = cols.read(own.rows)
+            ok = _fits(used, lambda d: own.counts * own.vec[d], avail)
+            if ok.all():
+                return own.n_nodes, set()
+            every = plan.alloc_blocks[0].live_node_counts()[0]
+            return own.n_nodes, set(itertools.compress(every, (~ok).tolist()))
+        pairs = [(b.rows, b.usage()) for b in blocks]
+        if ids:
+            pairs.append((cols.rows(ids), np.asarray(
+                [a.allocated_vec for nid in fresh
+                 for a in plan.node_allocation[nid]])))
+        rows = (pairs[0][0] if len(pairs) == 1
+                else np.concatenate([r for r, _ in pairs]))
+        # the committed side first, while the store still stands where
+        # the snapshot was taken
+        used, avail = cols.read(rows)
+        pairs += [(o.rows, o.usage()) for o in others]
+        if other_rows is not None:
+            pairs.append(other_rows)
+        at = np.concatenate([r for r, _ in pairs])
+        acc = self._accumulator(cols.capacity())
+        try:
+            np.add.at(acc, at, np.concatenate([u for _, u in pairs]))
+            asked = acc[rows]
+        finally:
+            acc[at] = 0.0
+        ok = _fits(used, lambda d: asked[:, d], avail)
+        every = [nid for block in plan.alloc_blocks
+                 for nid in block.live_node_counts()[0]] + ids
+        if exact:
+            kept = set(exact)
+            mine = [nid not in kept for nid in every]
+            every = list(itertools.compress(every, mine))
+            ok = ok[np.asarray(mine, dtype=bool)]
+        return (len(set(every)),
+                set(itertools.compress(every, (~ok).tolist())))
+
+    def _accumulator(self, n_rows: int) -> np.ndarray:
+        """This thread's (n_rows, D) float64 zeros, for summing usage a
+        node by row number: whoever writes on it puts the zeros back."""
+        acc = getattr(self._scratch, "acc", None)
+        if acc is None or acc.shape[0] < n_rows:
+            acc = self._scratch.acc = np.zeros((n_rows, RESOURCE_DIMS))
+        return acc
 
     def _volume_rejections(self, snap, plan: Plan) -> set:
         """Cross-node claim re-verification for csi-volume placements:
@@ -1094,41 +1213,6 @@ class PlanApplier:
                 else:
                     bad.add(node_id)
         return bad
-
-    def _vector_verdicts(self, snap, plan: Plan, node_ids: List[str],
-                         block_delta: Optional[Dict[str, object]] = None,
-                         ) -> Dict[str, bool]:
-        """Batched fit re-check for new-placements-only nodes: one
-        (M, D) numpy comparison instead of M python alloc walks.
-        `block_delta` carries the columnar plan's per-node usage sums
-        (blocks are resource-only fresh placements by construction, so
-        a summed vector is the exact fit input)."""
-        import numpy as np
-
-        from ..structs.resources import RESOURCE_DIMS
-
-        m = len(node_ids)
-        used = np.zeros((m, RESOURCE_DIMS))
-        avail = np.zeros((m, RESOURCE_DIMS))
-        ok = np.ones(m, dtype=bool)
-        for i, nid in enumerate(node_ids):
-            node = snap.node_by_id(nid)
-            if node is None or node.status != enums.NODE_STATUS_READY \
-                    or node.drain:
-                ok[i] = False
-                continue
-            base = snap.node_usage(nid)
-            if base is not None:
-                used[i] = base
-            for a in plan.node_allocation.get(nid, ()):
-                used[i] += a.allocated_vec
-            if block_delta:
-                d = block_delta.get(nid)
-                if d is not None:
-                    used[i] += d
-            avail[i] = node.available_vec()
-        ok &= (used <= avail).all(axis=1)
-        return dict(zip(node_ids, ok.tolist()))
 
     def _node_plan_valid(self, snap, plan: Plan, node_id: str) -> bool:
         node = snap.node_by_id(node_id)

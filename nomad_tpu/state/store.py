@@ -23,6 +23,7 @@ import copy
 import threading
 import time
 import weakref
+from itertools import repeat
 from typing import Callable, Dict, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
@@ -73,6 +74,100 @@ class BlockRef:
     def __init__(self, block_id: str, row: int = -1):
         self.block_id = block_id
         self.row = row
+
+
+class _RowIndex(dict):
+    """node id -> row of the store's dense per-node columns, with the
+    reverse list. One object for the lifetime of a row assignment: a
+    restore rebuilds the columns under a new one, so holding it is
+    holding the assignment a row number was read under."""
+
+    __slots__ = ("ids",)
+
+    def __init__(self):
+        super().__init__()
+        self.ids: List[str] = []
+
+
+class NodeColumns:
+    """Reader of the store's dense per-node columns at ONE committed
+    generation, a snapshot's: usage (node_usage) and the capacity open
+    to a new placement (available_vec() of a node that is ready and not
+    draining, -inf in every dimension of any other), addressed by row
+    number so that a plan's node ids are looked up once and a block's
+    once in its life. Row -1 is a node the store never saw: the
+    columns' last row, which no node is given, reads like a node gone.
+
+    `read` takes no lock. A generation check before and after the
+    gathers stands in for one (a commit keeps _write_lock through its
+    listener pass, and a verify that queued there would wait out every
+    round): every writer of the columns works between _begin, which
+    moves _next_gen first, and _commit, which moves _index last
+    (restore_store sets _next_gen before it rebuilds them), so while
+    both read the snapshot's generation no transaction is open and none
+    has committed since, and if _next_gen still reads it after the
+    gathers none began meanwhile. Otherwise, or after a restore gave
+    the rows out anew, the rows are read a node at a time from the MVCC
+    tables at the same generation: either way one committed generation,
+    never a transaction half applied."""
+
+    __slots__ = ("_snap", "_store", "_rows")
+
+    def __init__(self, snap: "StateSnapshot"):
+        self._snap = snap
+        self._store = snap._store
+        self._rows: _RowIndex = snap._store._usage_rows
+
+    @property
+    def assignment(self) -> _RowIndex:
+        """The row assignment the row numbers belong to (what a cache
+        of them has to be keyed by)."""
+        return self._rows
+
+    def capacity(self) -> int:
+        """Rows the columns hold now, the spare last one included."""
+        return self._store._usage_mat.shape[0]
+
+    def rows(self, node_ids: List[str]) -> np.ndarray:
+        """Row number per node id; -1 for a node the store never saw."""
+        return np.fromiter(map(self._rows.get, node_ids, repeat(-1)),
+                           np.int64, len(node_ids))
+
+    def read(self, rows: np.ndarray):
+        """-> (used (M, D), avail (M, D)) of `rows`, the caller's to
+        write on."""
+        store, index = self._store, self._snap.index
+        if (store._index == index and store._next_gen == index
+                and store._usage_rows is self._rows):
+            used = store._usage_mat[rows]
+            avail = store._avail_mat[rows]
+            if store._next_gen == index:
+                return used, avail
+        return self._read_per_node(rows)
+
+    def _read_per_node(self, rows: np.ndarray):
+        snap, ids = self._snap, self._rows.ids
+        used = np.zeros((len(rows), RESOURCE_DIMS))
+        avail = np.full((len(rows), RESOURCE_DIMS), -np.inf)
+        for i, row in enumerate(rows.tolist()):
+            node = snap.node_by_id(ids[row]) if row >= 0 else None
+            if node is None:
+                continue
+            avail[i] = open_capacity(node)
+            base = snap.node_usage(node.id)
+            if base is not None:
+                used[i] = base
+        return used, avail
+
+
+def open_capacity(node: Node):
+    """What a new placement may use of the node: available_vec(), or
+    -inf where none may land (the plan applier's gate: status ready and
+    not draining; eligibility keeps the scheduler away, not the
+    applier)."""
+    if node.status == enums.NODE_STATUS_READY and not node.drain:
+        return node.available_vec()
+    return -np.inf
 
 
 class StateSnapshot:
@@ -335,6 +430,11 @@ class StateSnapshot:
         """{device_group_id: instances_used, "cores": n} or None."""
         return self._store._node_dev_usage.get(node_id, self.index)
 
+    def node_columns(self) -> "NodeColumns":
+        """The dense per-node columns as this snapshot's generation
+        holds them (the plan applier's fit input)."""
+        return NodeColumns(self)
+
     # --- namespaces ---
 
     def namespace(self, name: str):
@@ -530,8 +630,19 @@ class StateStore:
         # freshest-committed usage (not snapshot usage) by design — newer
         # usage only makes the optimistic solve MORE accurate, and the
         # serialized plan applier still owns correctness.
-        self._usage_rows: Dict[str, int] = {}
+        self._usage_rows = _RowIndex()
         self._usage_mat = np.zeros((256, RESOURCE_DIMS))
+        # One more dense column on the same row index, for the plan
+        # applier's array fit check: the capacity open to a new
+        # placement (open_capacity: `available_vec()` of the node's
+        # latest row while it is ready and not draining, -inf
+        # otherwise, so that "used + asked <= avail" is the gate and
+        # the fit in one comparison). Derived like _usage_mat: never in
+        # a dump or the log, rebuilt on restore. Both are written only
+        # between _begin and _commit, which is what lets
+        # NodeColumns.read gather them without the lock. The last row
+        # is given to no node: row -1, a node the store never saw.
+        self._avail_mat = np.full((256, RESOURCE_DIMS), -np.inf)
 
         self._all_tables = [
             self._nodes, self._jobs, self._job_versions, self._evals, self._allocs,
@@ -649,7 +760,7 @@ class StateStore:
             if not node.computed_class:
                 node.compute_class()
             self._nodes.put(node.id, node, gen, live)
-            self._usage_row(node.id)  # matrix row exists for every node
+            self._node_columns_put(node)  # a row exists for every node
             self._bump_node_set(gen)
             self._commit(gen, [("node-upsert", node)])
             return gen
@@ -676,7 +787,7 @@ class StateStore:
                 if not node.computed_class:
                     node.compute_class()
                 self._nodes.put(node.id, node, gen, live)
-                self._usage_row(node.id)
+                self._node_columns_put(node)
                 events.append(("node-upsert", node))
             self._bump_node_set(gen)
             self._commit(gen, events)
@@ -701,6 +812,7 @@ class StateStore:
                 node.status_updated_at = ts
                 node.modify_index = gen
                 self._nodes.put(node_id, node, gen, live)
+                self._node_columns_put(node)
                 events.append(("node-status", node))
             self._bump_node_set(gen)
             self._commit(gen, events)
@@ -716,6 +828,7 @@ class StateStore:
             mutate(node)
             node.modify_index = gen
             self._nodes.put(node_id, node, gen, live)
+            self._node_columns_put(node)
             self._bump_node_set(gen)
             self._commit(gen, [(event, node)])
             return gen
@@ -752,6 +865,7 @@ class StateStore:
             row = self._usage_rows.get(node_id)
             if row is not None:
                 self._usage_mat[row] = 0.0
+                self._avail_mat[row] = -np.inf
             self._bump_node_set(gen)
             self._commit(gen, [("node-delete", node)])
             return gen
@@ -885,16 +999,29 @@ class StateStore:
         self._ready_nodes_cache.clear()
 
     def _usage_row(self, node_id: str) -> int:
-        """Must hold _write_lock when the row may need creating."""
+        """Must hold _write_lock when the row may need creating. The
+        columns grow before the row is published in the index, so a
+        lock-free reader that finds a row finds it in every column; the
+        last row stays spare (NodeColumns)."""
         row = self._usage_rows.get(node_id)
         if row is None:
             row = len(self._usage_rows)
+            size = self._usage_mat.shape[0]
+            if row >= size - 1:
+                for name, fill in (("_usage_mat", 0.0),
+                                   ("_avail_mat", -np.inf)):
+                    grown = np.full((size * 2, RESOURCE_DIMS), fill)
+                    grown[: size - 1] = getattr(self, name)[: size - 1]
+                    setattr(self, name, grown)
+            self._usage_rows.ids.append(node_id)
             self._usage_rows[node_id] = row
-            if row >= self._usage_mat.shape[0]:
-                grown = np.zeros((self._usage_mat.shape[0] * 2, RESOURCE_DIMS))
-                grown[: self._usage_mat.shape[0]] = self._usage_mat
-                self._usage_mat = grown
         return row
+
+    def _node_columns_put(self, node: Node) -> None:
+        """Must hold _write_lock, inside a transaction: the capacity the
+        node opens to a new placement, on its usage row."""
+        row = self._usage_row(node.id)  # first: it may swap the columns
+        self._avail_mat[row] = open_capacity(node)
 
     def usage_rows_for(self, node_ids: List[str]) -> np.ndarray:
         """Matrix row index per node id (for the tensor layer's one-gather
@@ -909,21 +1036,24 @@ class StateStore:
                                    dtype=np.int64, count=len(node_ids))
 
     def _rebuild_usage_matrix(self) -> None:
-        """Must hold _write_lock. Re-derive the dense matrix from the
-        MVCC usage rows (restore/install-snapshot path)."""
-        self._usage_rows = {}
+        """Must hold _write_lock. Re-derive the dense columns from the
+        MVCC node and usage rows (restore/install-snapshot path)."""
+        self._usage_rows = _RowIndex()
         self._usage_mat = np.zeros((256, RESOURCE_DIMS))
-        for node_id, _ in self._nodes.iterate(self._next_gen):
-            self._usage_row(node_id)
+        self._avail_mat = np.full((256, RESOURCE_DIMS), -np.inf)
+        for _, node in self._nodes.iterate(self._next_gen):
+            self._node_columns_put(node)
         for node_id, vec in self._node_usage.iterate(self._next_gen):
             if vec is not None:
-                self._usage_mat[self._usage_row(node_id)] = vec
+                row = self._usage_row(node_id)
+                self._usage_mat[row] = vec
 
     def _usage_add(self, node_id: str, delta, gen: int, live: int) -> None:
         cur = self._node_usage.get_latest(node_id)
         new = delta if cur is None else cur + delta
         self._node_usage.put(node_id, new, gen, live)
-        self._usage_mat[self._usage_row(node_id)] += delta
+        row = self._usage_row(node_id)  # first: it may swap the columns
+        self._usage_mat[row] += delta
 
     def _usage_apply(self, prev: Optional[Allocation], new: Optional[Allocation],
                      gen: int, live: int) -> None:

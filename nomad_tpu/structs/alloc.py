@@ -286,6 +286,9 @@ class AllocBlock:
     _mat: dict = field(default_factory=dict, repr=False, compare=False)
     _metrics: object = field(default=None, repr=False, compare=False)
     _rows_of: object = field(default=None, repr=False, compare=False)
+    # the live node rows in the row numbers of the store's dense
+    # per-node columns (core/plan_apply.py _BlockRows)
+    _store_rows: object = field(default=None, repr=False, compare=False)
 
     def __deepcopy__(self, memo):
         import copy as _copy
@@ -445,6 +448,7 @@ class AllocBlock:
         new._offsets = self._offsets
         new._mat = {}
         new._metrics = None
+        new._store_rows = None
         return new
 
     def with_dropped(self, positions) -> "AllocBlock":

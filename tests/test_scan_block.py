@@ -574,15 +574,21 @@ def test_a_block_that_landed_is_not_counted_again_by_the_overlay():
     in_flight = PlanResult(alloc_blocks=[landed])
     before = store.snapshot()
     overlay = _OverlaySnapshot(before, [in_flight])
-    assert np.array_equal(overlay.node_usage(nodes[0].id), vec)
+    cols = overlay.node_columns()
+    (flying,), rows_too = overlay.inflight(cols)
+    assert rows_too is None
+    assert flying.rows.tolist() == cols.rows([nodes[0].id,
+                                              nodes[1].id]).tolist()
+    assert np.array_equal(flying.usage(), np.tile(vec, (2, 1)))
     assert len(overlay.allocs_by_node(nodes[0].id)) == 1
 
     store.upsert_plan_results([], alloc_blocks=[landed])
     after = store.snapshot()
     overlay = _OverlaySnapshot(after, [in_flight])
-    assert np.array_equal(overlay.node_usage(nodes[0].id),
-                          after.node_usage(nodes[0].id))
-    assert np.array_equal(overlay.node_usage(nodes[0].id), vec)
+    cols = overlay.node_columns()
+    assert overlay.inflight(cols) == ([], None)
+    assert np.array_equal(cols.read(cols.rows([nodes[0].id]))[0][0], vec)
+    assert np.array_equal(after.node_usage(nodes[0].id), vec)
     assert [a.id for a in overlay.allocs_by_node(nodes[0].id)] == ["blk-a.0"]
 
     # two of the task fit a node: the second plan's rows hold
