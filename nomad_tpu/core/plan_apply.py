@@ -390,7 +390,7 @@ class PlanApplier:
         self._stop = threading.Event()
         self.stats = {"applied": 0, "nodes_verified": 0,
                       "nodes_verified_columnar": 0, "nodes_rejected": 0,
-                      "partial_commits": 0,
+                      "port_collisions": 0, "partial_commits": 0,
                       "commit_batches": 0, "batched_commits": 0,
                       "batched_eval_updates": 0}
         # commits are serialized through the commit thread, but the
@@ -1255,5 +1255,16 @@ class PlanApplier:
         proposed.extend(all_allocation)
 
         check_devices = any(a.allocated_devices for a in proposed)
-        fit, _, _ = allocs_fit(node, proposed, check_devices=check_devices)
+        fit, dim, _ = allocs_fit(node, proposed, check_devices=check_devices)
+        if not fit and dim.startswith("port collision"):
+            # the one reason of a rejected row that is kept: two plans
+            # handed out one port number on this node, which the
+            # in-flight overlay exists to prevent (structs/network.py);
+            # a verdict given again on a re-verified plan counts again,
+            # as in nodes_verified
+            from .metrics import REGISTRY
+
+            with self._stats_lock:
+                self.stats["port_collisions"] += 1
+            REGISTRY.incr("nomad.plan.port_collisions")
         return fit

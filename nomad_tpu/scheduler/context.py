@@ -133,6 +133,38 @@ class EvalContext:
             out.extend(self.plan.block_allocs_for_node(node_id))
         return out
 
+    def port_index(self, node: Node, proposed: Optional[List] = None):
+        """Which ports are taken on `node` for this evaluation, as a
+        NetworkIndex to assign from: the one answer the per-placement
+        tier and the host scorer share (structs/network.py has the
+        rule). `proposed` stands in for proposed_allocs(node.id) where
+        the caller has it already, or has taken its victims out."""
+        return self._port_index(node, proposed, self._inflight_ports([node]))
+
+    def port_indexes(self, nodes: List[Node]) -> List:
+        """port_index() of each of `nodes`, the in-flight overlay read
+        once for all of them."""
+        held = self._inflight_ports(nodes)
+        return [self._port_index(node, None, held) for node in nodes]
+
+    def _inflight_ports(self, nodes: List[Node]) -> Dict[str, set]:
+        # read before the snapshot's rows: an entry leaves the overlay
+        # only after its commit is published, so what is not read here
+        # is in no snapshot that is older either
+        from ..tensor.overlay import INFLIGHT
+
+        return INFLIGHT.ports_on([n.id for n in nodes],
+                                 getattr(self.snapshot, "index", None))
+
+    def _port_index(self, node: Node, proposed: Optional[List], held):
+        from ..structs.network import NetworkIndex
+
+        idx = NetworkIndex(node)
+        idx.add_allocs(self.proposed_allocs(node.id) if proposed is None
+                       else proposed)
+        idx.add_taken(held.get(node.id, ()))
+        return idx
+
     def shuffled_nodes(self, nodes: List[Node], attempt: int = 0) -> List[Node]:
         """Deterministic shuffle seeded by eval id + retry attempt
         (reference scheduler/util.go:167 shuffleNodes, seeded by eval and

@@ -194,13 +194,10 @@ class NodeScorer:
         # --- port assignment (reference rank.go:226-249: NetworkIndex
         # SetAllocs + AssignPorts inside BinPackIterator.Next) ---
         if self.wants_ports:
-            from ..structs.network import NetworkIndex
-
-            idx = NetworkIndex(node)
             counted = proposed if option.preempted_allocs is None else [
                 a for a in proposed
                 if a.id not in {v.id for v in option.preempted_allocs}]
-            idx.add_allocs(counted)
+            idx = self.ctx.port_index(node, counted)
             ports, err = idx.assign_ports(self.ask)
             if err and self.preemption_enabled:
                 # reserved-port conflict: free the holders (reference
@@ -215,8 +212,7 @@ class NodeScorer:
                         (option.preempted_allocs or []) + net_victims)
                     victim_ids = {v.id for v in option.preempted_allocs}
                     counted = [a for a in counted if a.id not in victim_ids]
-                    idx = NetworkIndex(node)
-                    idx.add_allocs(counted)
+                    idx = self.ctx.port_index(node, counted)
                     ports, err = idx.assign_ports(self.ask)
             if err:
                 if self.ctx.metrics is not None:

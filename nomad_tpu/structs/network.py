@@ -10,17 +10,38 @@ Design differences from the reference, TPU-first rationale:
   per-node host loop at solve time.
 - Exact port *numbers* (reserved-port collisions, dynamic assignment)
   are host-side and only touched for task groups that actually ask for
-  ports: at rank/commit time for the placement's node, and again by the
-  serialized plan applier via allocs_fit, which is what makes concurrent
-  double-bookings a partial-commit reject instead of a client crash.
-- Dynamic assignment is deterministic (lowest free port first) so a
-  replayed plan or a replica applying the same log picks identical
-  ports.
+  ports, on the nodes their placements land on.
+- Which numbers are taken on a node, for one evaluation, has ONE
+  answer, EvalContext.port_index(node): the node's agent-reserved
+  ports, the ports of ctx.proposed_allocs (the evaluation's snapshot
+  less what its plan stops, plus its plan's own rows), and the ports
+  the in-flight overlay holds there (tensor/overlay.py ports_on):
+  those of every evaluation of this server that has chosen its ports
+  and whose plan is not applied yet, and of every plan applied since
+  this evaluation's snapshot was taken. The per-placement tier chooses
+  a group's ports under its solve lock and registers them with the
+  usage they belong to before it lets go (tensor/placer.py
+  _assign_ports), and the host scorer (scheduler/rank.py) reads the
+  same index, so two evaluations in flight on one server do not hand
+  out the same number on a node: before, every one of them took the
+  lowest free port of its own snapshot on the same half-filled nodes
+  and the applier threw the rows away.
+- The serialized plan applier still re-checks every row that carries a
+  port (allocs_fit -> check_port_collisions) and rejects the node on a
+  collision (counted: nomad.plan.port_collisions). Nothing above
+  relaxes it; it is what guards the cases the overlay cannot see:
+  evaluations on different servers, a snapshot that outlives the
+  overlay's TTL, a host-scored remainder racing another one between
+  its read and its registration.
+- Dynamic assignment is deterministic (lowest free port first, for a
+  given taken set) and the numbers ride in the plan, so a replayed plan
+  or a replica applying the same log holds identical ports.
 """
 
 from __future__ import annotations
 
-from typing import Iterable, List, Optional, Sequence, Set, Tuple
+from typing import (Collection, Iterable, List, Optional, Sequence, Set,
+                    Tuple)
 
 from .alloc import AllocatedPort
 
@@ -35,6 +56,10 @@ class NetworkIndex:
         self.used: Set[int] = set(node.reserved.reserved_ports)
         self.collision = False           # reference: SetAllocs collision flag
         self.colliding_ports: List[int] = []
+        # no dynamic port below this one is free (used only grows)
+        self._lowest = self.min_dyn
+        # add_taken brought a port that no allocation counted here held
+        self.inflight = False
 
     # -- building up usage --
 
@@ -44,6 +69,15 @@ class NetworkIndex:
                 self.collision = True
                 self.colliding_ports.append(p)
             self.used.add(p)
+
+    def add_taken(self, ports: Collection[int]) -> None:
+        """Ports taken on the node that are no allocation of the view
+        yet (the in-flight overlay's). They may repeat ones already
+        counted (an evaluation's own rows are in its plan and in its
+        entry), which is no collision."""
+        if not self.used.issuperset(ports):
+            self.inflight = True
+            self.used.update(ports)
 
     def add_allocs(self, allocs: Sequence) -> None:
         """Register ports of non-terminal allocs (reference network.go
@@ -80,9 +114,14 @@ class NetworkIndex:
         return out, ""
 
     def _next_free(self, taken: Set[int]) -> Optional[int]:
-        for p in range(self.min_dyn, self.max_dyn + 1):
+        p = self._lowest
+        while p <= self.max_dyn and p in self.used:
+            p += 1
+        self._lowest = p
+        while p <= self.max_dyn:
             if p not in self.used and p not in taken:
                 return p
+            p += 1
         return None
 
 

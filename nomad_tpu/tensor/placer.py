@@ -11,9 +11,13 @@ Lowering strategy per evaluation:
   4. commits mapped back through the scheduler's commit callback so the
      plan object and ctx.proposed_allocs stay authoritative. Exact port
      numbers, device instance ids, and core ids are assigned host-side
-     per chosen node after the solve (counts were fit on-device). A
-     group of fresh placements that needs none of them is committed as
-     one AllocBlock, from the scan as from the count solve.
+     per chosen node after the solve (counts were fit on-device): the
+     ports while the solve lock is still held, so that they go into
+     the in-flight overlay with the usage they belong to and racing
+     evaluations never pick the same number (structs/network.py), the
+     ids in the row loop. A group of fresh placements that needs none
+     of them is committed as one AllocBlock, from the scan as from the
+     count solve.
 
 Preemption stays host-side: when the kernel finds no fit and preemption
 is enabled, the per-request fallback runs the host NodeScorer preemption
@@ -362,11 +366,22 @@ class TPUPlacer:
                 from ..core.metrics import REGISTRY
 
                 REGISTRY.incr("nomad.placer.host_cutover_groups")
+                held: Dict[str, list] = {}
                 for req in reqs:
                     option = self._host_one(ctx, job, tg, nodes, req,
                                             batch, preemption_enabled,
                                             attempt)
                     commit(req, option)
+                    if option is not None and option.allocated_ports:
+                        held.setdefault(option.node.id, []).extend(
+                            p.value for p in option.allocated_ports)
+                if held and ctx.plan is not None:
+                    # the scorer read the overlay's ports (ctx.port_index);
+                    # what it chose goes there in turn, for the
+                    # evaluations racing behind it
+                    from .overlay import INFLIGHT
+
+                    INFLIGHT.register(cluster, (), None, ctx.plan, held)
                 continue
 
             tgt = (prebuilt_tgt if prebuilt_tgt is not None
@@ -388,88 +403,94 @@ class TPUPlacer:
             for i, req in enumerate(reqs):
                 if req.ignore_node:
                     penalty_idx[i] = cluster.node_index.get(req.ignore_node, -1)
-            choices, founds, scores = self._scan_group(
+            choices, founds, scores, ports = self._scan_group(
                 ctx, tg, cluster, tgt, len(reqs), penalty_idx, tie_perm)
+            with TRACER.span("placer.rows", k=len(reqs)):
+                self._place_rows(ctx, job, tg, reqs, cluster, tgt, commit,
+                                 choices, founds, scores, ports,
+                                 batch=batch,
+                                 preemption_enabled=preemption_enabled,
+                                 attempt=attempt)
 
-            # exact port numbers / device instances / core ids are
-            # host-side, per chosen node, after the solve (the kernel only
-            # fit-checked the counts); per-node indexes carry assignments
-            # across this group's placements so they don't double-book
-            ask_res = ctx.tg_resources(tg)
-            wants_ports = bool(ask_res.reserved_port_asks()
-                               or ask_res.dynamic_port_count())
-            wants_devices = bool(ask_res.devices)
-            wants_cores = bool(ask_res.cores)
-            numa_pol = "none"
-            if wants_cores:
-                from ..scheduler.devices import combined_numa_affinity
+    def _place_rows(self, ctx, job, tg, reqs, cluster, tgt, commit,
+                    choices, founds, scores, ports, *, batch: bool,
+                    preemption_enabled: bool, attempt: int) -> None:
+        """The scan's placements handed over one row each: a RankedNode,
+        an AllocMetric and (in commit) an Allocation a placement. Exact
+        port numbers were chosen under the solve lock (`ports`:
+        _assign_ports); device instances and core ids are assigned
+        here, per chosen node (the kernel only fit-checked the counts),
+        with per-node indexes that carry the assignments across this
+        group's placements so they don't double-book."""
+        nodes = cluster.nodes
+        ask_res = ctx.tg_resources(tg)
+        wants_devices = bool(ask_res.devices)
+        wants_cores = bool(ask_res.cores)
+        numa_pol = "none"
+        if wants_cores:
+            from ..scheduler.devices import combined_numa_affinity
 
-                numa_pol = combined_numa_affinity(tg)
-            net_idx: Dict[int, object] = {}
-            dev_idx: Dict[int, object] = {}
-            core_used: Dict[int, set] = {}
+            numa_pol = combined_numa_affinity(tg)
+        dev_idx: Dict[int, object] = {}
+        core_used: Dict[int, set] = {}
 
-            n_feasible = int(tgt.feasible[: len(nodes)].sum())
-            preempt_queue: List[PlacementRequest] = []
-            for i, req in enumerate(reqs):
-                metrics = ctx.new_metrics()
-                metrics.nodes_in_pool = len(nodes)
-                metrics.nodes_evaluated = len(nodes)
-                if founds[i]:
-                    ni = int(choices[i])
-                    node = cluster.nodes[ni]
-                    option = RankedNode(node=node)
-                    option.final_score = float(scores[i])
-                    option.score_meta["normalized-score"] = option.final_score
-                    metrics.scores[f"{node.id}.normalized-score"] = option.final_score
-                    if wants_ports:
-                        from ..structs.network import NetworkIndex
-
-                        idx = net_idx.get(ni)
-                        if idx is None:
-                            idx = net_idx[ni] = NetworkIndex(node)
-                            idx.add_allocs(ctx.proposed_allocs(node.id))
-                        ports, err = idx.assign_ports(ask_res)
-                        if err:
-                            metrics.exhaust_node("ports")
-                            commit(req, None)
-                            continue
-                        option.allocated_ports = ports
-                    if wants_devices or wants_cores:
-                        ok = self._assign_ids(ctx, ask_res, numa_pol, ni, node,
-                                              option, dev_idx, core_used)
-                        if not ok:
-                            # count-fit admitted a node the exact id
-                            # assignment can't satisfy (NUMA require /
-                            # overlapping asks): host selector for this
-                            # request alone
-                            option = self._host_one(ctx, job, tg, nodes, req,
-                                                    batch, preemption_enabled,
-                                                    attempt)
-                            commit(req, option)
-                            if option is not None:
-                                # the fallback assigned ids on its own
-                                # node; drop that node's caches so later
-                                # kernel placements rebuild them from the
-                                # committed plan instead of double-booking
-                                self._invalidate_node(
-                                    cluster, option.node.id,
-                                    net_idx, dev_idx, core_used)
-                            continue
-                    commit(req, option)
-                    continue
-                if preemption_enabled:
-                    preempt_queue.append(req)
-                    continue
-                self._attribute_failure(ctx, metrics, len(nodes), n_feasible)
-                commit(req, None)
-            if preempt_queue:
-                self._preempt_batch(
-                    ctx, job, tg, preempt_queue, cluster, tgt, commit,
-                    sched_batch=batch, attempt=attempt,
-                    n_feasible=n_feasible,
-                    invalidate=lambda nid: self._invalidate_node(
-                        cluster, nid, net_idx, dev_idx, core_used))
+        n_feasible = int(tgt.feasible[: len(nodes)].sum())
+        preempt_queue: List[PlacementRequest] = []
+        for i, req in enumerate(reqs):
+            metrics = ctx.new_metrics()
+            metrics.nodes_in_pool = len(nodes)
+            metrics.nodes_evaluated = len(nodes)
+            if founds[i]:
+                ni = int(choices[i])
+                node = nodes[ni]
+                option = RankedNode(node=node)
+                option.final_score = float(scores[i])
+                option.score_meta["normalized-score"] = option.final_score
+                metrics.scores[f"{node.id}.normalized-score"] = option.final_score
+                if ports is not None:
+                    # this node's next share of what the hold chose
+                    mine = ports[ni].pop() if ports[ni] else None
+                    if mine is None:
+                        metrics.exhaust_node("ports")
+                        commit(req, None)
+                        continue
+                    option.allocated_ports = mine
+                if wants_devices or wants_cores:
+                    ok = self._assign_ids(ctx, ask_res, numa_pol, ni, node,
+                                          option, dev_idx, core_used)
+                    if not ok:
+                        # count-fit admitted a node the exact id
+                        # assignment can't satisfy (NUMA require /
+                        # overlapping asks): host selector for this
+                        # request alone (its ports from ctx.port_index,
+                        # which holds what the hold chose for the rest)
+                        option = self._host_one(ctx, job, tg, nodes, req,
+                                                batch, preemption_enabled,
+                                                attempt)
+                        commit(req, option)
+                        if option is not None:
+                            # the fallback assigned ids on its own
+                            # node; drop that node's caches so later
+                            # kernel placements rebuild them from the
+                            # committed plan instead of double-booking
+                            self._invalidate_node(
+                                cluster, option.node.id,
+                                dev_idx, core_used)
+                        continue
+                commit(req, option)
+                continue
+            if preemption_enabled:
+                preempt_queue.append(req)
+                continue
+            self._attribute_failure(ctx, metrics, len(nodes), n_feasible)
+            commit(req, None)
+        if preempt_queue:
+            self._preempt_batch(
+                ctx, job, tg, preempt_queue, cluster, tgt, commit,
+                sched_batch=batch, attempt=attempt,
+                n_feasible=n_feasible,
+                invalidate=lambda nid: self._invalidate_node(
+                    cluster, nid, dev_idx, core_used))
 
     # -- bulk (count-based) solve: the C2M path --
 
@@ -508,6 +529,12 @@ class TPUPlacer:
                    and not req.canary for req in reqs)
 
     @staticmethod
+    def _wants_ports(ctx, tg) -> bool:
+        ask_res = ctx.tg_resources(tg)
+        return bool(ask_res.reserved_port_asks()
+                    or ask_res.dynamic_port_count())
+
+    @staticmethod
     def _wants_exact_ids(ctx, tg) -> bool:
         """Whether a placement of this group carries something assigned
         on its chosen node after the solve (exact port numbers, device
@@ -522,7 +549,9 @@ class TPUPlacer:
                     tie_perm, columnar: bool = False):
         """One group's per-placement scan of k placements: admitted,
         staged outside _PER_EVAL_SOLVE_LOCK, solved under it ->
-        (choices, founds, scores), each of the padded length.
+        (choices, founds, scores, ports): the first three of the padded
+        length, the last _assign_ports' result for a group that asks
+        ports, else None.
         `penalty_idx` is the padded (k_pad,) reschedule-penalty row;
         `columnar` says the caller hands the result over as one
         AllocBlock (counted beside the staged solves: the share of
@@ -560,7 +589,7 @@ class TPUPlacer:
         k = bulk.count
         # a bulk request has no ignore_node: no reschedule penalty
         penalty_idx = np.full(_pad_pow2(k, floor=1), -1, dtype=np.int32)
-        choices, founds, scores = self._scan_group(
+        choices, founds, scores, _ = self._scan_group(
             ctx, tg, cluster, tgt, k, penalty_idx, tie_perm, columnar=True)
 
         nodes = cluster.nodes
@@ -663,7 +692,7 @@ class TPUPlacer:
 
     def _solve_staged(self, ctx, tg, cluster, k, k_pad, staged):
         """Take _PER_EVAL_SOLVE_LOCK, solve, release -> (choices, founds,
-        scores)."""
+        scores, ports)."""
         # The usage gather -> solve -> in-flight registration runs
         # as ONE critical section across racing workers: the device
         # serializes launches anyway, and without this ordering two
@@ -681,7 +710,8 @@ class TPUPlacer:
         # should show. Its children split it, one set per
         # evaluation and task group: placer.lock_wait, then
         # placer.locked around gather / pack / ship / device_wait /
-        # fetch / register. device=True mirrors each into the jax
+        # fetch / ports (a group that asks ports) / register.
+        # device=True mirrors each into the jax
         # profiler's trace, above the device's ops on one clock.
         with TRACER.span("worker.solve", k=k):
             with TRACER.span("placer.lock_wait", device=True):
@@ -696,7 +726,10 @@ class TPUPlacer:
         """One evaluation's usage gather -> solve -> in-flight
         registration; the caller holds _PER_EVAL_SOLVE_LOCK and staged
         the static arguments (_stage_statics) before taking it. Returns
-        (choices, founds, scores) per request, k of k_pad of them."""
+        (choices, founds, scores) per request, k of k_pad of them, and
+        the ports of a group that asks them (_assign_ports), chosen and
+        registered here: the hold is what makes an evaluation's read of
+        the ports taken and its own choice one step."""
         import jax
 
         from .kernels import solve_task_group_fused
@@ -734,14 +767,63 @@ class TPUPlacer:
                     choices = out[0].astype(np.int64)
                     founds = out[1] > 0.5
                     scores = out[2]
+            touched = ports = held = None
+            if self._wants_ports(ctx, tg):
+                with TRACER.span("placer.ports", device=True) as span:
+                    touched = np.unique(choices[founds], return_counts=True)
+                    ports, held = self._assign_ports(ctx, tg, cluster,
+                                                     *touched)
+                    span.set(nodes=len(held),
+                             ports=sum(map(len, held.values())))
             with TRACER.span("placer.register", device=True):
                 if ctx.plan is not None:
-                    rows, counts = np.unique(choices[founds],
-                                             return_counts=True)
+                    rows, counts = touched or np.unique(
+                        choices[founds], return_counts=True)
                     INFLIGHT.register(
                         cluster, rows,
-                        counts[:, None] * ctx.tg_vec(tg)[None, :], ctx.plan)
-        return choices, founds, scores
+                        counts[:, None] * ctx.tg_vec(tg)[None, :], ctx.plan,
+                        held)
+        return choices, founds, scores, ports
+
+    @staticmethod
+    def _assign_ports(ctx, tg, cluster, rows, counts):
+        """The exact port numbers of a group's found placements, chosen
+        per touched node (`rows` of `cluster`, `counts[i]` placements
+        on `rows[i]`) against ctx.port_index: lowest free first, so
+        deterministic for what is taken. The caller holds
+        _PER_EVAL_SOLVE_LOCK and registers the result in its in-flight
+        entry before releasing it, so no evaluation of this process can
+        read the same ports free. -> ({row: [a placement's
+        AllocatedPorts, ...]}, {node id: [port numbers]}); a node that
+        ran out holds fewer lists than placements. Work for the touched
+        nodes only; on a populated node proposed_allocs materialises
+        the rows of its blocks (ROADMAP C2)."""
+        from ..core.metrics import REGISTRY
+
+        ask_res = ctx.tg_resources(tg)
+        rows = rows.tolist()
+        nodes = [cluster.nodes[ni] for ni in rows]
+        ports: Dict[int, list] = {}
+        held: Dict[str, list] = {}
+        n_inflight = 0
+        for ni, n, node, idx in zip(rows, counts.tolist(), nodes,
+                                    ctx.port_indexes(nodes)):
+            n_inflight += idx.inflight
+            mine = ports[ni] = []
+            for _ in range(n):
+                got, err = idx.assign_ports(ask_res)
+                if err:
+                    break
+                mine.append(got)
+            # the row loop pops a node's placements off the end
+            mine.reverse()
+            if mine:
+                held[node.id] = [p.value for got in mine for p in got]
+        REGISTRY.incr("nomad.placer.port_nodes", len(nodes))
+        REGISTRY.incr("nomad.placer.port_nodes_inflight", n_inflight)
+        REGISTRY.incr("nomad.placer.ports_assigned",
+                      sum(map(len, held.values())))
+        return ports, held
 
     def _bulk_shape_ok(self, ctx, tg, tgt) -> bool:
         """Task-group-level bulk eligibility (the per-request conditions
