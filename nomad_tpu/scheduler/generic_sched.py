@@ -11,6 +11,7 @@ import copy as _copy
 import time
 from typing import List, Optional
 
+from ..obs import TRACER
 from ..structs import enums
 from ..structs.alloc import Allocation, RescheduleEvent, RescheduleTracker
 from ..structs.evaluation import Evaluation
@@ -102,17 +103,22 @@ class GenericScheduler:
         if job is not None:
             ctx.eligibility.set_job(job)
 
-        all_allocs = self.state.allocs_by_job(ev.job_id, ev.namespace)
-        tainted = tainted_nodes(self.state, all_allocs)
-        update_non_terminal_allocs_to_lost(self.plan, tainted, all_allocs)
+        # span worker.reconcile (arg `places`): the job's allocations,
+        # the tainted nodes and the reconciler's diff
+        with TRACER.span("worker.reconcile") as span:
+            all_allocs = self.state.allocs_by_job(ev.job_id, ev.namespace)
+            tainted = tainted_nodes(self.state, all_allocs)
+            update_non_terminal_allocs_to_lost(self.plan, tainted, all_allocs)
 
-        latest_dep = (self.state.latest_deployment_by_job(ev.job_id, ev.namespace)
-                      if not self.batch else None)
-        reconciler = AllocReconciler(
-            job if (job is not None and not job.stopped()) else None,
-            ev.job_id, all_allocs, tainted, batch=self.batch, eval_id=ev.id,
-            deployment=latest_dep)
-        results = reconciler.compute()
+            latest_dep = (
+                self.state.latest_deployment_by_job(ev.job_id, ev.namespace)
+                if not self.batch else None)
+            reconciler = AllocReconciler(
+                job if (job is not None and not job.stopped()) else None,
+                ev.job_id, all_allocs, tainted, batch=self.batch,
+                eval_id=ev.id, deployment=latest_dep)
+            results = reconciler.compute()
+            span.set(places=results.total_places())
         # per-TG desired-update annotations, surfaced by the dry-run plan
         # endpoint (reference scheduler/annotate.go:42 Annotate)
         self.annotations = dict(results.desired_tg_updates)

@@ -58,6 +58,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from ..obs import TRACER
 from ..state.deltas import ALLOC_ROW_KINDS
 from ..structs.resources import RESOURCE_DIMS
 
@@ -165,9 +166,42 @@ class _Epoch:
         self.static_ref = None
 
 
+class _Drain:
+    """One drain of a feed: a ``feed.drain`` span around work its caller
+    does under the feed's lock, counted in the feed's ``drains`` and
+    ``events_drained`` whether the tracer is on or not. The span's args:
+    ``events`` taken from the subscription, ``rows`` folded into the
+    base, and ``resync=True`` when the base was rebuilt."""
+
+    __slots__ = ("feed", "span", "mark")
+
+    def __init__(self, feed: "IncrementalFeed"):
+        self.feed = feed
+
+    def __enter__(self):
+        f = self.feed
+        self.mark = (f._events_taken, f._deltas_applied, f._resyncs)
+        self.span = TRACER.span("feed.drain", device=True)
+        self.span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        f = self.feed
+        events = f._events_taken - self.mark[0]
+        f._drains += 1
+        f._events_drained += events
+        self.span.set(events=events, rows=f._deltas_applied - self.mark[1])
+        if f._resyncs != self.mark[2]:
+            self.span.set(resync=True)
+        return self.span.__exit__(*exc)
+
+
 class IncrementalFeed:
     """Delta-fed usage state for one (store, broker) pair. All entry
-    points take ``self._lock``; nothing here runs on the commit path."""
+    points take ``self._lock``; nothing here runs on the commit path.
+    The two that drain for a reader (``base_for``, ``device_used``)
+    record the wait for the lock as a ``feed.lock_wait`` span and the
+    work under it as a ``feed.drain`` span (:class:`_Drain`)."""
 
     def __init__(self, store, broker, tracker: "StateTracker"):
         self.store = store
@@ -180,7 +214,11 @@ class IncrementalFeed:
         self._resyncs = 0
         self._deltas_applied = 0
         self._parity_checks = 0
-        self._alloc_uncounted = 0
+        # events taken from the subscription, and the drains that took
+        # them for a reader (stats()["drains"] / ["events_drained"])
+        self._events_taken = 0
+        self._drains = 0
+        self._events_drained = 0
         self._gauge_pub = None
         # moves whenever committed usage may have gone DOWN under a
         # consumer that chains its own copy (the bulk solver's carry,
@@ -198,20 +236,25 @@ class IncrementalFeed:
         means: do the full build."""
         if static is None:
             return None
-        with self._lock:
-            self._builds += 1
-            ep = self._epoch_for_locked(static)
-            if ep is None:
-                return None
-            self._fast_hits += 1
-            if (self.tracker.san_active
-                    and self._builds % PARITY_EVERY == 0):
-                self._verify_locked()
-                ep = self._epoch
-                if ep is None or ep.stale:
+        with TRACER.span("feed.lock_wait", device=True):
+            self._lock.acquire()
+        try:
+            with _Drain(self):
+                self._builds += 1
+                ep = self._epoch_for_locked(static)
+                if ep is None:
                     return None
-            self._gauges()
-            return ep.base_view
+                self._fast_hits += 1
+                if (self.tracker.san_active
+                        and self._builds % PARITY_EVERY == 0):
+                    self._verify_locked()
+                    ep = self._epoch
+                    if ep is None or ep.stale:
+                        return None
+                self._gauges()
+                return ep.base_view
+        finally:
+            self._lock.release()
 
     def device_used(self, static, mesh=None):
         """Device-resident f32 twin of the base (sharded over `mesh`
@@ -219,11 +262,16 @@ class IncrementalFeed:
         feed can't serve this static — caller falls back to host."""
         if static is None:
             return None
-        with self._lock:
-            ep = self._epoch_for_locked(static)
+        with TRACER.span("feed.lock_wait", device=True):
+            self._lock.acquire()
+        try:
+            with _Drain(self):
+                ep = self._epoch_for_locked(static)
             if ep is None:
                 return None
             return self._twin_locked(ep, mesh).arr
+        finally:
+            self._lock.release()
 
     def free_epoch(self) -> int:
         """How often this feed has seen committed usage fall (a job
@@ -231,17 +279,6 @@ class IncrementalFeed:
         attached; a resync counts as one. Nothing is drained here: the
         worker's tensor build drains before it asks for a solve."""
         return self._free_epoch
-
-    def take_build_delta_count(self) -> int:
-        """Exact Allocation-delta count since the previous take — the
-        per-build number the changed_allocs_per_build histogram wants.
-        Drains first so queued deltas land in THIS build's bucket."""
-        with self._lock:
-            ep = self._epoch
-            if ep is not None and not ep.stale:
-                self._drain_locked(ep)
-            out, self._alloc_uncounted = self._alloc_uncounted, 0
-            return out
 
     def force_verify(self) -> bool:
         """Drain + parity-digest now (chaos sweep, state smoke,
@@ -269,6 +306,8 @@ class IncrementalFeed:
                 "resyncs": self._resyncs,
                 "deltas_applied": self._deltas_applied,
                 "parity_checks": self._parity_checks,
+                "drains": self._drains,
+                "events_drained": self._events_drained,
             }
 
     # -- epoch lifecycle ----------------------------------------------
@@ -307,8 +346,7 @@ class IncrementalFeed:
         # are about to take, so all of it is (or will be) in the base
         evs = self.sub.next_events(timeout=0)
         self.sub.truncated = False
-        self._alloc_uncounted += sum(1 for e in evs
-                                     if e.topic == "Allocation")
+        self._events_taken += len(evs)
         store = self.store
         snap = store.snapshot()
         try:
@@ -339,6 +377,7 @@ class IncrementalFeed:
 
     def _drain_locked(self, ep: _Epoch) -> None:
         evs = self.sub.next_events(timeout=0)
+        self._events_taken += len(evs)
         if self.sub.truncated:
             # lapped ring or restore sentinel: the contract answer is a
             # full resync, never incremental patching
@@ -348,8 +387,6 @@ class IncrementalFeed:
                 self._epoch.static_ref = ep.static_ref
             return
         for e in evs:
-            if e.topic == "Allocation":
-                self._alloc_uncounted += 1
             if e.index <= ep.position:
                 continue        # already inside the resync base
             self._fold(ep, e)
@@ -539,8 +576,7 @@ class IncrementalFeed:
             self.sub.truncated = False
             snap = store.snapshot()
         try:
-            self._alloc_uncounted += sum(1 for e in evs
-                                         if e.topic == "Allocation")
+            self._events_taken += len(evs)
             if truncated:
                 self._resync_locked(ep.layout, ep.node_index, ep.n_pad)
                 if self._epoch is not None:
@@ -669,7 +705,8 @@ class StateTracker:
         with self._ilock:
             feeds = list(self.feeds)
         out = {"feeds": len(feeds), "builds": 0, "fast_hits": 0,
-               "resyncs": 0, "deltas_applied": 0, "parity_checks": 0}
+               "resyncs": 0, "deltas_applied": 0, "parity_checks": 0,
+               "drains": 0, "events_drained": 0}
         for f in feeds:
             for k, v in f.stats().items():
                 out[k] += v

@@ -45,6 +45,8 @@ from typing import Dict, Iterable, Optional, Sequence
 
 import numpy as np
 
+from ..obs import TRACER
+
 ENTRY_TTL = 60.0
 
 
@@ -185,35 +187,47 @@ class InflightOverlay:
         that solve plans around nodes that are freer than they look);
         read in the other order, a commit that lands between the two
         reads is in neither, the solve fills nodes that are already
-        full and the applier rejects its rows."""
+        full and the applier rejects its rows. Span `overlay.read`
+        (arg `entries`), the wait for the overlay's lock included."""
         now = time.time()
         exclude = id(exclude_plan) if exclude_plan is not None else None
-        with self._lock:
-            dead = [t for t, e in self._entries.items()
-                    if now - e["born"] > ENTRY_TTL]
-            for t in dead:
-                self._forget_ports(t, self._entries.pop(t)["ports"] or ())
-                self.stats["expired"] += 1
-            # an entry of ports alone (the host scorer's) has no usage
-            return [e for e in self._entries.values()
-                    if (e.get("plan") != exclude or exclude is None)
-                    and len(e["rows"])]
+        with TRACER.span("overlay.read", device=True) as span:
+            with self._lock:
+                dead = [t for t, e in self._entries.items()
+                        if now - e["born"] > ENTRY_TTL]
+                for t in dead:
+                    self._forget_ports(t, self._entries.pop(t)["ports"] or ())
+                    self.stats["expired"] += 1
+                # an entry of ports alone (the host scorer's) has no usage
+                out = [e for e in self._entries.values()
+                       if (e.get("plan") != exclude or exclude is None)
+                       and len(e["rows"])]
+            span.set(entries=len(out))
+        return out
 
     def fold(self, used, node_index: Dict[str, int], entries) -> None:
         """Add the deltas of `entries`, as open_entries() returned them
         before committed usage was read, into a canonical-order usage
-        matrix (in place). Called from the usage gathers."""
+        matrix (in place). Called from the usage gathers. Span
+        `overlay.fold` (args `entries`, `rows`: the rows added,
+        `remapped`: entries of another row order, found by node id)."""
         d = used.shape[1]
-        for e in entries:
-            rows, deltas = e["rows"], e["deltas"][:, :d]
-            if e["node_index"] is not node_index:
-                # another row order (a node joined or left since): find
-                # each node's row by id
-                nodes = e["nodes"]
-                at = np.array([node_index.get(nodes[r].id, -1)
-                               for r in rows], dtype=np.int64)
-                rows, deltas = at[at >= 0], deltas[at >= 0]
-            used[rows] += deltas
+        added = remapped = 0
+        with TRACER.span("overlay.fold", device=True,
+                         entries=len(entries)) as span:
+            for e in entries:
+                rows, deltas = e["rows"], e["deltas"][:, :d]
+                if e["node_index"] is not node_index:
+                    # another row order (a node joined or left since):
+                    # find each node's row by id
+                    nodes = e["nodes"]
+                    at = np.array([node_index.get(nodes[r].id, -1)
+                                   for r in rows], dtype=np.int64)
+                    rows, deltas = at[at >= 0], deltas[at >= 0]
+                    remapped += 1
+                used[rows] += deltas
+                added += len(rows)
+            span.set(rows=added, remapped=remapped)
 
 
 INFLIGHT = InflightOverlay()

@@ -191,36 +191,6 @@ def _count_preempt(**deltas: int) -> None:
             REGISTRY.incr(f"nomad.preempt.{key}", n)
 
 
-# Per tensor build, how many Allocation deltas hit the event stream
-# since the previous build anywhere in the process — the exact row
-# count the O(Δ) scatter update (tensor/incremental.py) touches instead
-# of a full O(nodes) rebuild. With an incremental feed attached to the
-# build's store the count is feed-native (exact: Allocation events the
-# feed actually drained, resyncs included); otherwise it falls back to
-# the process-wide counter diff that seeded the ROADMAP item.
-_DELTA_MARK_LOCK = __import__("threading").Lock()
-_DELTA_MARK = [0.0]
-
-
-def _changed_allocs_since_last_build(store=None) -> int:
-    from ..core.metrics import REGISTRY
-
-    if store is not None:
-        from .incremental import feed_for
-
-        feed = feed_for(store)
-        if feed is not None:
-            delta = float(feed.take_build_delta_count())
-            REGISTRY.observe("nomad.worker.changed_allocs_per_build", delta)
-            return int(delta)
-    now = REGISTRY.get("nomad.events.alloc_deltas")
-    with _DELTA_MARK_LOCK:
-        prev, _DELTA_MARK[0] = _DELTA_MARK[0], now
-    delta = max(0.0, now - prev)  # REGISTRY.reset between benches rewinds
-    REGISTRY.observe("nomad.worker.changed_allocs_per_build", delta)
-    return int(delta)
-
-
 # One solve at a time across racing workers' PER-EVAL kernel path (the
 # device serializes launches regardless); see the critical-section note
 # in place(). The bulk path has its own serializer (the solver service).
@@ -291,9 +261,7 @@ class TPUPlacer:
         # (optimistic-concurrency livelock). The permutation rides INTO
         # the kernel so the host-side node order stays canonical and the
         # per-node arrays stay cacheable across evals (ClusterStatic).
-        with TRACER.span("worker.tensor_build", n=len(nodes),
-                         changed_allocs=_changed_allocs_since_last_build(
-                             getattr(ctx.snapshot, "_store", None))):
+        with TRACER.span("worker.tensor_build", n=len(nodes)):
             cluster = ClusterTensors.build(ctx, nodes)
         nodes = cluster.nodes
         # crc32, not hash(): the seed must be deterministic ACROSS
@@ -590,34 +558,39 @@ class TPUPlacer:
         choices, founds, scores, _ = self._scan_group(
             ctx, tg, cluster, tgt, k, penalty_idx, tie_perm, columnar=True)
 
-        nodes = cluster.nodes
-        metrics = ctx.new_metrics()
-        metrics.nodes_in_pool = len(nodes)
-        metrics.nodes_evaluated = len(nodes)
-        name_indices = np.asarray(bulk.name_indices, dtype=np.int64)
-        found = np.flatnonzero(founds[:k])
-        if len(found):
-            # np.unique's sorted rows are the stable order's node runs
-            found = found[np.argsort(choices[found], kind="stable")]
-            rows, counts = np.unique(choices[found], return_counts=True)
-            rows = rows.tolist()
-            placed_scores = scores[found]
-            commit.commit_block(
-                tg,
-                [nodes[ni].id for ni in rows],
-                [nodes[ni].name for ni in rows],
-                counts.astype(np.int64),
-                name_indices[found],
-                float(placed_scores.mean()),
-                scores=placed_scores,
-                nodes_evaluated=len(nodes),
-                nodes_in_pool=len(nodes))
+        # the block's assembly, its commit and the tail: span
+        # placer.block (arg `nodes`, the nodes the block touches)
+        with TRACER.span("placer.block") as span:
+            nodes = cluster.nodes
+            metrics = ctx.new_metrics()
+            metrics.nodes_in_pool = len(nodes)
+            metrics.nodes_evaluated = len(nodes)
+            name_indices = np.asarray(bulk.name_indices, dtype=np.int64)
+            found = np.flatnonzero(founds[:k])
+            rows = ()
+            if len(found):
+                # np.unique's sorted rows are the stable order's node runs
+                found = found[np.argsort(choices[found], kind="stable")]
+                rows, counts = np.unique(choices[found], return_counts=True)
+                rows = rows.tolist()
+                placed_scores = scores[found]
+                commit.commit_block(
+                    tg,
+                    [nodes[ni].id for ni in rows],
+                    [nodes[ni].name for ni in rows],
+                    counts.astype(np.int64),
+                    name_indices[found],
+                    float(placed_scores.mean()),
+                    scores=placed_scores,
+                    nodes_evaluated=len(nodes),
+                    nodes_in_pool=len(nodes))
+            span.set(nodes=len(rows))
 
-        self._bulk_tail(ctx, job, tg, bulk.job_id,
-                        name_indices[~founds[:k]], metrics, cluster, tgt,
-                        commit, sched_batch=sched_batch,
-                        preemption_enabled=preemption_enabled,
-                        attempt=attempt)
+            self._bulk_tail(ctx, job, tg, bulk.job_id,
+                            name_indices[~founds[:k]], metrics, cluster,
+                            tgt, commit, sched_batch=sched_batch,
+                            preemption_enabled=preemption_enabled,
+                            attempt=attempt)
 
     def _bulk_tail(self, ctx, job, tg, job_id, tail_indices, metrics,
                    cluster, tgt, commit, *, sched_batch: bool,

@@ -261,15 +261,6 @@ def test_inactive_tracker_attach_is_a_noop():
     assert tracker.stats()["replicas"] == 0
 
 
-def test_changed_allocs_per_build_differences_the_delta_counter():
-    from nomad_tpu.tensor.placer import _changed_allocs_since_last_build
-    _changed_allocs_since_last_build()      # consume whatever preceded us
-    REGISTRY.incr("nomad.events.alloc_deltas", 5)
-    assert _changed_allocs_since_last_build() == 5
-    assert _changed_allocs_since_last_build() == 0
-    assert "nomad.worker.changed_allocs_per_build" in REGISTRY.dump()
-
-
 # --- the modelcheck scenario ---------------------------------------------
 
 def test_event_flow_scenario_holds():

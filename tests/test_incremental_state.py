@@ -222,21 +222,6 @@ def test_store_without_feed_gets_the_exact_full_build(tracked, monkeypatch):
                           cold.used[: len(nodes)])  # base untouched
 
 
-def test_feed_native_changed_allocs_count(tracked):
-    from nomad_tpu.tensor.placer import _changed_allocs_since_last_build
-
-    store, _, tracker, feed = tracked
-    nodes, static = _static_over(store, 3)
-    assert feed.base_for(static) is not None
-    _changed_allocs_since_last_build(store)         # drain the backlog
-    store.upsert_allocs([_alloc(f"ic{i}", nodes[0].id, 100, 64)
-                         for i in range(5)])
-    assert _changed_allocs_since_last_build(store) == 5
-    assert _changed_allocs_since_last_build(store) == 0
-    # the zero-arg legacy path (registry diff) still stands alone
-    assert _changed_allocs_since_last_build() >= 0
-
-
 def test_device_twin_flushes_to_exact_base(tracked):
     import jax
 
