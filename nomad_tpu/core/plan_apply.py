@@ -297,6 +297,23 @@ class _OverlaySnapshot:
             out.extend(block.allocs_for_row(m))
         return out
 
+    def ports_by_node(self, node_id):
+        """allocs_by_node's port numbers, read as the snapshot reads
+        them: the in-flight rows replace theirs by id, and the in-flight
+        blocks add their port column at the node's rows."""
+        overlay = self._replaced.get(node_id)
+        if overlay:
+            out = self._snap.ports_by_node(node_id, skip=overlay)
+            for a in overlay.values():
+                if not a.terminal_status():
+                    out.extend(p.value for p in a.allocated_ports)
+        else:
+            out = self._snap.ports_by_node(node_id)
+        for block, m in self._block_rows_of(node_id):
+            for _, values in block.ports_for_row(m):
+                out.extend(values)
+        return out
+
     def alloc_by_id(self, alloc_id):
         for by_id in self._replaced.values():
             if alloc_id in by_id:
@@ -1029,14 +1046,17 @@ class PlanApplier:
 
         The GIL-free scale path (reference plan_apply_pool.go:21
         EvaluatePool's role): nodes touched ONLY by new placements that
-        carry no ports/devices/cores — a block's nodes and the entire
-        bulk-placement shape — skip the per-node alloc walk entirely.
-        Their fit check is usage_row + sum(new vecs) <= available as
-        arrays from the plan to the verdict (_columnar_verdicts); the
-        accounting is exactly _node_plan_valid's (existing filters `not
-        terminal_status()`, the usage rows' predicate, and no new
-        ports/cores means no new collision is possible). Everything
-        else keeps the exact python check."""
+        carry no devices/cores — a block's nodes and the entire
+        bulk-placement shape, rows with no port — skip the per-node
+        alloc walk entirely. Their fit check is usage_row + sum(new
+        vecs) <= available as arrays from the plan to the verdict
+        (_columnar_verdicts); the accounting is exactly
+        _node_plan_valid's (existing filters `not terminal_status()`,
+        the usage rows' predicate). A placement without ports or cores
+        makes no new collision possible; the port numbers of a block
+        that carries them are checked on its nodes by allocs_fit's rule
+        without an Allocation (_block_port_verdicts). Everything else
+        keeps the exact python check."""
         from .metrics import REGISTRY
 
         result = PlanResult()
@@ -1107,9 +1127,11 @@ class PlanApplier:
         accumulator and read back at the plan's rows; one float64
         comparison a dimension gives the verdicts. A block's row on a
         node of `exact` gets none here: _node_plan_valid judges that
-        node, the block's placements on it. -> (nodes given a verdict,
-        ids of those it went against); a node that is down, draining or
-        gone is one."""
+        node, the block's placements on it. The port numbers of a block
+        that carries them get their verdict beside the fit's
+        (_block_port_verdicts), after the gathers. -> (nodes given a
+        verdict, ids of those it went against); a node that is down,
+        draining or gone is one."""
         cols = snap.node_columns()
         blocks = [_BlockRows.of(cols, block) for block in plan.alloc_blocks]
         ids = [nid for nid in fresh for _ in plan.node_allocation[nid]]
@@ -1128,10 +1150,12 @@ class PlanApplier:
             own = blocks[0]
             used, avail = cols.read(own.rows)
             ok = _fits(used, lambda d: own.counts * own.vec[d], avail)
+            bad = self._block_port_verdicts(snap, plan, exact)
             if ok.all():
-                return own.n_nodes, set()
+                return own.n_nodes, bad
             every = plan.alloc_blocks[0].live_node_counts()[0]
-            return own.n_nodes, set(itertools.compress(every, (~ok).tolist()))
+            return own.n_nodes, bad.union(
+                itertools.compress(every, (~ok).tolist()))
         pairs = [(b.rows, b.usage()) for b in blocks]
         if ids:
             pairs.append((cols.rows(ids), np.asarray(
@@ -1161,7 +1185,60 @@ class PlanApplier:
             every = list(itertools.compress(every, mine))
             ok = ok[np.asarray(mine, dtype=bool)]
         return (len(set(every)),
-                set(itertools.compress(every, (~ok).tolist())))
+                self._block_port_verdicts(snap, plan, exact).union(
+                    itertools.compress(every, (~ok).tolist())))
+
+    def _block_port_verdicts(self, snap, plan: Plan,
+                             exact: List[str]) -> set:
+        """The port check of the node rows of the plan's blocks that
+        carry ports, on the nodes outside `exact`, by allocs_fit's rule
+        (structs/network.py check_port_collisions): the node's
+        agent-reserved ports, the numbers its allocations hold in the
+        snapshot, in-flight results included (ports_by_node, read where
+        _node_plan_valid reads its allocations, without materialising
+        one), and the plan's new numbers there are all distinct. A node
+        that is down, draining or gone is left to the fit's verdict, as
+        _node_plan_valid rejects it before its port check. -> ids of the
+        nodes it rejects, each counted as a port collision."""
+        asked: Dict[str, list] = {}
+        kept = set(exact)
+        for block in plan.alloc_blocks:
+            if block.ports is None:
+                continue
+            for m in block.live_rows():
+                nid = block.node_ids[m]
+                if nid not in kept:
+                    values = asked.setdefault(nid, [])
+                    for _, row in block.ports_for_row(m):
+                        values.extend(row)
+        bad = set()
+        for nid, values in asked.items():
+            node = snap.node_by_id(nid)
+            if (node is None or node.status != enums.NODE_STATUS_READY
+                    or node.drain):
+                continue
+            held = snap.ports_by_node(nid)
+            taken = set(node.reserved.reserved_ports)
+            n = len(taken) + len(held) + len(values)
+            taken.update(held)
+            taken.update(values)
+            if len(taken) != n:
+                bad.add(nid)
+        self._count_port_collisions(len(bad))
+        return bad
+
+    def _count_port_collisions(self, n: int) -> None:
+        """Node rows rejected for a port number handed out twice: the
+        one reason of a rejected row that is kept, which the in-flight
+        overlay exists to prevent (structs/network.py); a verdict given
+        again on a re-verified plan counts again, as in nodes_verified."""
+        if not n:
+            return
+        from .metrics import REGISTRY
+
+        with self._stats_lock:
+            self.stats["port_collisions"] += n
+        REGISTRY.incr("nomad.plan.port_collisions", n)
 
     def _accumulator(self, n_rows: int) -> np.ndarray:
         """This thread's (n_rows, D) float64 zeros, for summing usage a
@@ -1257,14 +1334,5 @@ class PlanApplier:
         check_devices = any(a.allocated_devices for a in proposed)
         fit, dim, _ = allocs_fit(node, proposed, check_devices=check_devices)
         if not fit and dim.startswith("port collision"):
-            # the one reason of a rejected row that is kept: two plans
-            # handed out one port number on this node, which the
-            # in-flight overlay exists to prevent (structs/network.py);
-            # a verdict given again on a re-verified plan counts again,
-            # as in nodes_verified
-            from .metrics import REGISTRY
-
-            with self._stats_lock:
-                self.stats["port_collisions"] += 1
-            REGISTRY.incr("nomad.plan.port_collisions")
+            self._count_port_collisions(1)
         return fit

@@ -133,6 +133,28 @@ class EvalContext:
             out.extend(self.plan.block_allocs_for_node(node_id))
         return out
 
+    def proposed_ports(self, node_id: str) -> List[int]:
+        """The port numbers proposed_allocs(node_id) holds, read from
+        the snapshot's index entries and the plan's rows and blocks
+        without materialising a block position (StateSnapshot
+        .ports_by_node)."""
+        plan = self.plan
+        if plan is None:
+            return self.snapshot.ports_by_node(node_id)
+        placed = plan.node_allocation.get(node_id, ())
+        replaced = {a.id for bucket in (plan.node_update, plan.node_preemptions)
+                    for a in bucket.get(node_id, ())}
+        replaced.update(a.id for a in placed)
+        out = self.snapshot.ports_by_node(node_id, skip=replaced)
+        out.extend(p.value for a in placed if a.should_count_for_usage()
+                   for p in a.allocated_ports)
+        for block in plan.alloc_blocks:
+            for m in block.rows_for_node(node_id):
+                if m not in block.rejected_rows:
+                    for _, values in block.ports_for_row(m):
+                        out.extend(values)
+        return out
+
     def port_index(self, node: Node, proposed: Optional[List] = None):
         """Which ports are taken on `node` for this evaluation, as a
         NetworkIndex to assign from: the one answer the per-placement
@@ -160,8 +182,10 @@ class EvalContext:
         from ..structs.network import NetworkIndex
 
         idx = NetworkIndex(node)
-        idx.add_allocs(self.proposed_allocs(node.id) if proposed is None
-                       else proposed)
+        if proposed is None:
+            idx.add_ports(self.proposed_ports(node.id))
+        else:
+            idx.add_allocs(proposed)
         idx.add_taken(held.get(node.id, ()))
         return idx
 

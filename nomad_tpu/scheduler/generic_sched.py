@@ -390,14 +390,15 @@ class GenericScheduler:
 
         def commit_block(tg, node_ids, node_names, counts, name_indices,
                          mean_score, scores=None, nodes_evaluated=0,
-                         nodes_in_pool=0):
+                         nodes_in_pool=0, ports=None):
             """Columnar bulk commit: ONE AllocBlock rides the plan for K
             placements (structs/alloc.py AllocBlock). Only reachable for
             the fresh-placement shape commit_many covers, so the same
             constants apply; per-alloc ids/names materialize lazily.
             `scores` (per position) and the two node counts are the
             per-placement scan's: what its rows carried in their
-            AllocMetric."""
+            AllocMetric; `ports` (a BlockPorts, a row a position) its
+            port numbers, for a group that asks them."""
             from ..structs.alloc import AllocBlock
 
             block = AllocBlock(
@@ -419,6 +420,7 @@ class GenericScheduler:
                 mean_score=float(mean_score),
                 nodes_evaluated=nodes_evaluated,
                 nodes_in_pool=nodes_in_pool,
+                ports=ports,
                 allocated_at=now,
             )
             metrics = ctx.metrics

@@ -339,6 +339,41 @@ class StateSnapshot:
     def allocs_by_node(self, node_id: str) -> List[Allocation]:
         return self._allocs_from_index(self._store._allocs_by_node, node_id)
 
+    def ports_by_node(self, node_id: str, skip=()) -> List[int]:
+        """The port numbers the node's allocations hold, each allocation
+        not terminal (what the plan applier's allocs_fit counts of the
+        node's existing allocations), read from its index entries: a
+        row's allocated_ports, a block's port column at its node row (a
+        position promoted to a row counts as that row). No Allocation is
+        materialised. Allocation ids in `skip` are left out (an overlay
+        that replaces them)."""
+        store, index = self._store, self.index
+        allocs = store._allocs
+        out: List[int] = []
+        for entry in self._ids_from_index(store._allocs_by_node, node_id):
+            if type(entry) is BlockRef:
+                block = store._alloc_blocks.get(entry.block_id, index)
+                if (block is None or block.ports is None
+                        or entry.row in block.rejected_rows):
+                    continue
+                prefix = f"{block.id}{BLOCK_SEP}"
+                for p, values in block.ports_for_row(entry.row):
+                    aid = prefix + str(p)
+                    if aid in skip:
+                        continue
+                    row = allocs.get(aid, index)
+                    if row is None:
+                        out.extend(values)
+                    elif not row.terminal_status():
+                        out.extend(pt.value for pt in row.allocated_ports)
+                continue
+            if entry in skip:
+                continue
+            a = allocs.get(entry, index)
+            if a is not None and not a.terminal_status():
+                out.extend(pt.value for pt in a.allocated_ports)
+        return out
+
     def allocs_by_node_terminal(self, node_id: str, terminal: bool) -> List[Allocation]:
         return [a for a in self.allocs_by_node(node_id) if a.terminal_status() == terminal]
 
@@ -1440,9 +1475,11 @@ class StateStore:
         """Insert one columnar placement batch: O(touched nodes) host
         work for K allocations — one block row, one BlockRef cons per
         touched node, one vectorized usage add. This is the 2M-alloc
-        answer to _put_new_allocs_bulk's per-alloc loop; blocks carry no
-        ports/devices/cores/volumes by construction (the placer's bulk
-        eligibility gate)."""
+        answer to _put_new_allocs_bulk's per-alloc loop. A block carries
+        no devices, cores or volumes by construction (the placer's gate);
+        the ports of a group that asks them ride in its port column
+        (AllocBlock.ports), which the plan applier verified, so the
+        commit is the same for a block with ports as for one without."""
         block.modify_time = ts
         block.create_index = gen
         block.modify_index = gen

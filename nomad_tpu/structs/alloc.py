@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import ClassVar, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -222,6 +222,23 @@ def alloc_name(job_id: str, group: str, index: int) -> str:
     return f"{job_id}.{group}[{index}]"
 
 
+@dataclass(slots=True)
+class BlockPorts:
+    """The port numbers of an AllocBlock's positions, as columns: the
+    group's port labels once (reserved asks, then dynamic ones, as
+    NetworkIndex.assign_ports hands them out) and `values[p]` the
+    numbers position p holds under them, (K, len(labels)) int32."""
+
+    labels: List[str] = field(default_factory=list)
+    values: np.ndarray = field(
+        default_factory=lambda: np.zeros((0, 0), np.int32))
+
+    def at(self, p: int) -> List[AllocatedPort]:
+        return [AllocatedPort(label=label, value=value)
+                for label, value in zip(self.labels,
+                                        self.values[p].tolist())]
+
+
 # Block alloc id = "<block uuid>.<position>". The separator must be
 # URL-safe (ids ride in /v1/allocation/<id> paths — "#" would be eaten
 # as a fragment delimiter) and must not occur in uuids (hex + "-").
@@ -273,6 +290,9 @@ class AllocBlock:
     scores: np.ndarray = field(default_factory=lambda: np.zeros(0, np.float32))
     nodes_evaluated: int = 0
     nodes_in_pool: int = 0
+    # the ports of a group that asks them, a row of numbers a position;
+    # None for a block without ports, which encodes as it did before
+    ports: Optional[BlockPorts] = None
     allocated_at: float = 0.0
     modify_time: float = 0.0
     create_index: int = 0
@@ -289,6 +309,8 @@ class AllocBlock:
     # the live node rows in the row numbers of the store's dense
     # per-node columns (core/plan_apply.py _BlockRows)
     _store_rows: object = field(default=None, repr=False, compare=False)
+    # fields the wire codec leaves out while they hold None
+    _wire_omit_none: ClassVar[tuple] = ("ports",)
 
     def __deepcopy__(self, memo):
         import copy as _copy
@@ -305,6 +327,8 @@ class AllocBlock:
             mean_score=self.mean_score, scores=self.scores.copy(),
             nodes_evaluated=self.nodes_evaluated,
             nodes_in_pool=self.nodes_in_pool,
+            ports=(None if self.ports is None else BlockPorts(
+                list(self.ports.labels), self.ports.values.copy())),
             allocated_at=self.allocated_at,
             modify_time=self.modify_time, create_index=self.create_index,
             modify_index=self.modify_index,
@@ -399,6 +423,8 @@ class AllocBlock:
                 task_group=self.task_group,
                 deployment_id=self.deployment_id,
                 allocated_vec=self.allocated_vec,
+                allocated_ports=([] if self.ports is None
+                                 else self.ports.at(p)),
                 metrics=self._metrics_at(p, self.node_ids[m]),
                 allocated_at=self.allocated_at,
                 modify_time=self.modify_time,
@@ -406,6 +432,17 @@ class AllocBlock:
                 modify_index=self.modify_index,
             )
         return a
+
+    def ports_for_row(self, m: int) -> list:
+        """(position, [port numbers]) of row m's positions that GC left,
+        read from the port column; empty for a block without ports."""
+        if self.ports is None:
+            return []
+        off = self.offsets()
+        lo, hi = int(off[m]), int(off[m + 1])
+        return [(p, values) for p, values in zip(
+                    range(lo, hi), self.ports.values[lo:hi].tolist())
+                if p not in self.dropped]
 
     def allocs_for_row(self, m: int) -> List["Allocation"]:
         if m in self.rejected_rows:

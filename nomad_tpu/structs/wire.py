@@ -74,10 +74,14 @@ def wire_encode(obj: Any) -> Any:
         if cls.__name__ not in _REGISTRY:
             raise TypeError(f"unregistered wire type {cls.__name__}")
         # "_"-prefixed fields are derived caches (e.g. Node._avail_vec);
-        # they never ride the wire and decode falls back to the default
+        # they never ride the wire and decode falls back to the default.
+        # So does a field a struct names in `_wire_omit_none` while it
+        # holds None: a record without it encodes as before it existed
+        omit = getattr(cls, "_wire_omit_none", ())
         fields = {f.name: wire_encode(getattr(obj, f.name))
                   for f in dataclasses.fields(obj)
-                  if not f.name.startswith("_")}
+                  if not f.name.startswith("_")
+                  and not (f.name in omit and getattr(obj, f.name) is None)}
         return {"__t": cls.__name__, "__f": fields}
     raise TypeError(f"cannot wire-encode {type(obj).__name__}")
 

@@ -307,18 +307,19 @@ class TPUPlacer:
                             attempt=attempt)
                     continue
                 if (takes_blocks and bulk.count > self.HOST_CUTOVER
-                        and not self._wants_exact_ids(ctx, tg)):
-                    # spread / distinct_hosts / distinct_property rule
-                    # out the count solve, not the block: the scan's K
-                    # placements are committed as ONE AllocBlock too
+                        and not self._wants_ids(ctx, tg)):
+                    # spread / distinct_hosts / distinct_property / ports
+                    # rule out the count solve, not the block: the scan's
+                    # K placements are committed as ONE AllocBlock too,
+                    # with the ports chosen under the hold
                     self._place_scan_columnar(
                         ctx, job, tg, bulk, cluster, tgt, commit, tie_perm,
                         sched_batch=batch,
                         preemption_enabled=preemption_enabled,
                         attempt=attempt)
                     continue
-                # ports / devices / cores are assigned per placement on
-                # its chosen node: expand and fall through (reusing the
+                # devices / cores are assigned per placement on its
+                # chosen node: expand and fall through (reusing the
                 # tensors just built)
                 reqs = bulk.expand()
                 prebuilt_tgt = tgt
@@ -501,15 +502,14 @@ class TPUPlacer:
                     or ask_res.dynamic_port_count())
 
     @staticmethod
-    def _wants_exact_ids(ctx, tg) -> bool:
-        """Whether a placement of this group carries something assigned
-        on its chosen node after the solve (exact port numbers, device
-        instances, core ids): the row loop's wants_ports /
-        wants_devices / wants_cores as one test."""
+    def _wants_ids(ctx, tg) -> bool:
+        """Whether a placement of this group carries ids assigned on its
+        chosen node after the solve (device instances, core ids): the
+        row loop's wants_devices / wants_cores as one test. Port numbers
+        are chosen under the hold for every touched node at once
+        (_assign_ports), so asking ports keeps no group out of a block."""
         ask_res = ctx.tg_resources(tg)
-        return bool(ask_res.reserved_port_asks()
-                    or ask_res.dynamic_port_count()
-                    or ask_res.devices or ask_res.cores)
+        return bool(ask_res.devices or ask_res.cores)
 
     def _scan_group(self, ctx, tg, cluster, tgt, k: int, penalty_idx,
                     tie_perm, columnar: bool = False):
@@ -551,11 +551,14 @@ class TPUPlacer:
         numpy over K and a list over the touched nodes, not a
         RankedNode, an AllocMetric and an Allocation a placement. Block
         positions are the found placements in stable order of their
-        chosen node, each with its own name index and score."""
+        chosen node, each with its own name index and score, and with
+        the ports the hold chose for a group that asks them: a node's
+        positions take its port lists in the order the row loop pops
+        them, so the block is the row loop's placements port for port."""
         k = bulk.count
         # a bulk request has no ignore_node: no reschedule penalty
         penalty_idx = np.full(_pad_pow2(k, floor=1), -1, dtype=np.int32)
-        choices, founds, scores, _ = self._scan_group(
+        choices, founds, scores, ports = self._scan_group(
             ctx, tg, cluster, tgt, k, penalty_idx, tie_perm, columnar=True)
 
         # the block's assembly, its commit and the tail: span
@@ -572,7 +575,18 @@ class TPUPlacer:
                 # np.unique's sorted rows are the stable order's node runs
                 found = found[np.argsort(choices[found], kind="stable")]
                 rows, counts = np.unique(choices[found], return_counts=True)
+                block_ports = None
+                if ports is not None:
+                    n_found = len(found)
+                    found, rows, counts, block_ports = self._block_ports(
+                        found, rows, counts, ports)
+                    if len(found) < n_found:
+                        # a node that ran out of ports: its surplus
+                        # placements fail, as the row loop's did
+                        metrics.exhaust_node("ports")
+                        commit.fail_bulk(tg, n_found - len(found))
                 rows = rows.tolist()
+            if len(found):
                 placed_scores = scores[found]
                 commit.commit_block(
                     tg,
@@ -583,7 +597,8 @@ class TPUPlacer:
                     float(placed_scores.mean()),
                     scores=placed_scores,
                     nodes_evaluated=len(nodes),
-                    nodes_in_pool=len(nodes))
+                    nodes_in_pool=len(nodes),
+                    ports=block_ports)
             span.set(nodes=len(rows))
 
             self._bulk_tail(ctx, job, tg, bulk.job_id,
@@ -591,6 +606,32 @@ class TPUPlacer:
                             tgt, commit, sched_batch=sched_batch,
                             preemption_enabled=preemption_enabled,
                             attempt=attempt)
+
+    @staticmethod
+    def _block_ports(found, rows, counts, ports):
+        """Lay _assign_ports' lists (`ports`: {row: lists, the first
+        chosen last}) over a block's positions (`found`, in node runs of
+        `rows` x `counts`): a node's first placement takes its first
+        list. A node that ran out holds fewer lists than placements;
+        its last placements in scan order leave the block. -> (found,
+        rows, counts, BlockPorts) of what keeps its ports."""
+        from ..structs.alloc import BlockPorts
+
+        held = np.fromiter((len(ports[ni]) for ni in rows.tolist()),
+                           np.int64, len(rows))
+        if (held < counts).any():
+            starts = np.repeat(np.cumsum(counts) - counts, counts)
+            keep = np.arange(len(found)) - starts < np.repeat(held, counts)
+            found = found[keep]
+            counts = np.minimum(counts, held)
+            rows = rows[counts > 0]
+            counts = counts[counts > 0]
+        lists = [got for ni, c in zip(rows.tolist(), counts.tolist())
+                 for got in ports[ni][:-c - 1:-1]]
+        labels = [p.label for p in lists[0]] if lists else []
+        values = np.array([[p.value for p in got] for got in lists],
+                          np.int32).reshape(-1, len(labels))
+        return found, rows, counts, BlockPorts(labels, values)
 
     def _bulk_tail(self, ctx, job, tg, job_id, tail_indices, metrics,
                    cluster, tgt, commit, *, sched_batch: bool,
@@ -796,8 +837,9 @@ class TPUPlacer:
         read the same ports free. -> ({row: [a placement's
         AllocatedPorts, ...]}, {node id: [port numbers]}); a node that
         ran out holds fewer lists than placements. Work for the touched
-        nodes only; on a populated node proposed_allocs materialises
-        the rows of its blocks (ROADMAP C2)."""
+        nodes only, each read through EvalContext.proposed_ports: a
+        block's numbers come from its port column, no position of it is
+        materialised."""
         from ..core.metrics import REGISTRY
 
         ask_res = ctx.tg_resources(tg)
@@ -1073,7 +1115,8 @@ class TPUPlacer:
         nodes = cluster.nodes
         # exact port numbers / device instances / cores can't come from
         # the dense victim columns — those groups keep the host scanner
-        exact_needed = self._wants_exact_ids(ctx, tg)
+        exact_needed = (self._wants_ports(ctx, tg)
+                        or self._wants_ids(ctx, tg))
         ask_vec = ctx.tg_vec(tg)
 
         scorer = NodeScorer(ctx, job, tg, algorithm=self._host_algorithm(),

@@ -215,21 +215,26 @@ class TestFeedDrains:
         assert got["events_drained"] == feed.stats()["events_drained"]
 
 
+def _spans_of_a_drain(ports, count):
+    TRACER.set_enabled(True)
+    h = Harness()
+    h.store.upsert_nodes([_racked_node(i, cpu=16000) for i in range(64)])
+    job = _spread_job(count, ports=ports)
+    h.store.upsert_job(job)
+    TRACER.clear()
+    h.process(mock.eval_for(job), sched_config=_tpu_config())
+    assert len(h.store.snapshot().allocs_by_job(job.id)) == count
+    return TRACER.spans()
+
+
 class TestBlockSpan:
-    @pytest.mark.parametrize("ports,blocks", [(0, 1), (2, 0)])
+    @pytest.mark.parametrize("ports,blocks", [(0, 1), (2, 1)])
     def test_placer_block_for_a_block_group_and_not_for_rows(
             self, ports, blocks):
-        """300 fresh placements without a port leave the scan as one
-        AllocBlock under `placer.block`; with ports they are rows."""
-        TRACER.set_enabled(True)
-        h = Harness()
-        h.store.upsert_nodes([_racked_node(i, cpu=16000) for i in range(64)])
-        job = _spread_job(300, ports=ports)
-        h.store.upsert_job(job)
-        TRACER.clear()
-        h.process(mock.eval_for(job), sched_config=_tpu_config())
-        assert len(h.store.snapshot().allocs_by_job(job.id)) == 300
-        spans = TRACER.spans()
+        """300 fresh placements, with ports or without, leave the scan
+        as one AllocBlock under `placer.block`; a group under 256 takes
+        the row loop and opens none (test_no_placer_block_for_rows)."""
+        spans = _spans_of_a_drain(ports, 300)
         found = _named(spans, "placer.block")
         assert len(found) == blocks
         for span in found:
@@ -237,6 +242,11 @@ class TestBlockSpan:
             solve, = _named(spans, "worker.solve")
             assert solve[R_T1] <= span[R_T0]
             assert span[R_PARENT] == solve[R_PARENT]
+
+    def test_no_placer_block_for_rows(self):
+        spans = _spans_of_a_drain(2, 255)
+        assert not _named(spans, "placer.block")
+        assert _named(spans, "placer.rows")
 
 
 def test_the_kill_switch_records_none_and_the_counters_still_move():

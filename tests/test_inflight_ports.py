@@ -182,6 +182,33 @@ def test_racing_evaluations_hand_out_no_port_twice(racers):
         assert len(held) == len(set(held)) == 4
 
 
+@pytest.mark.parametrize("racers", [2, 4])
+def test_racing_blocks_hand_out_no_port_twice(racers):
+    """Jobs of 300 leave the scan as one block each, with their ports:
+    the racers still meet on half-filled nodes, and no node row is
+    rejected, every node's verdict given as arrays."""
+    jobs = [grid_job(300) for _ in range(racers)]
+    store = store_with(160 * racers, jobs)
+    planner = race(store, jobs)
+    stats = planner.applier.stats
+    assert stats["nodes_rejected"] == 0 and stats["port_collisions"] == 0
+    assert stats["nodes_verified_columnar"] == stats["nodes_verified"] > 0
+    assert len(planner.plans) == racers
+    assert all(len(p.alloc_blocks) == 1 and not p.node_allocation
+               for p in planner.plans)
+    snap = store.snapshot()
+    assert [len(live(snap, j)) for j in jobs] == [300] * racers
+    nodes, allocs = plain_state(snap)
+    assert reference.violations(nodes, allocs) == []
+    assert reference.census(nodes, allocs)["ports"] == 600 * racers
+    shared = [n for n in nodes
+              if len({a.job_id for a in snap.allocs_by_node(n)}) > 1]
+    assert shared
+    for node_id in shared:
+        held = snap.ports_by_node(node_id)
+        assert len(held) == len(set(held)) == 4
+
+
 def test_blind_to_the_overlays_ports_the_racers_collide(monkeypatch):
     """The control: the parent's view planted (nobody reads the
     overlay's ports). The applier catches every collision and counts
@@ -199,6 +226,25 @@ def test_blind_to_the_overlays_ports_the_racers_collide(monkeypatch):
             == stats["port_collisions"])
     assert len(planner.plans) > 4
     # the gate held: whatever was committed is sound
+    nodes, allocs = plain_state(store.snapshot())
+    assert reference.violations(nodes, allocs) == []
+
+
+def test_blind_to_the_overlays_ports_racing_blocks_collide(monkeypatch):
+    """The same control with blocks: the applier's port verdict on a
+    block's node rows catches what the racers handed out twice."""
+    monkeypatch.setattr(InflightOverlay, "ports_on",
+                        lambda self, node_ids, snapshot_index: {})
+    jobs = [grid_job(300) for _ in range(2)]
+    store = store_with(320, jobs)
+    before = REGISTRY.get("nomad.plan.port_collisions")
+    planner = race(store, jobs)
+    stats = planner.applier.stats
+    assert stats["port_collisions"] > 0
+    assert stats["nodes_rejected"] >= stats["port_collisions"]
+    assert (REGISTRY.get("nomad.plan.port_collisions") - before
+            == stats["port_collisions"])
+    assert planner.plans[0].alloc_blocks and planner.plans[1].alloc_blocks
     nodes, allocs = plain_state(store.snapshot())
     assert reference.violations(nodes, allocs) == []
 

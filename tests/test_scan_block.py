@@ -83,7 +83,7 @@ def test_block_arm_places_what_the_row_loop_places(monkeypatch):
     before = REGISTRY.get(COLUMNAR)
     block_h = _drain(nodes, job, "eval-parity")
     assert REGISTRY.get(COLUMNAR) == before + 1
-    monkeypatch.setattr(TPUPlacer, "_wants_exact_ids",
+    monkeypatch.setattr(TPUPlacer, "_wants_ids",
                         staticmethod(lambda ctx, tg: True))
     row_h = _drain(nodes, job, "eval-parity")
     assert REGISTRY.get(COLUMNAR) == before + 1
@@ -450,14 +450,22 @@ def test_other_groups_keep_the_row_loop(shape):
     before, staged = REGISTRY.get(COLUMNAR), REGISTRY.get(STAGED)
     commit = _Commit()
     _place(requests, nodes, job, commit)
-    assert REGISTRY.get(COLUMNAR) == before
     assert REGISTRY.get(STAGED) == staged + (
         0 if shape == "at_the_host_cutover" else 1)
+    if shape == "dynamic_port":
+        # a port keeps no group out of the block: its numbers ride in
+        # the block's port column, one a position
+        assert REGISTRY.get(COLUMNAR) == before + 1
+        ((args, kwargs),) = commit.blocks
+        assert not commit.rows and commit.failed == 0
+        assert sorted(args[4].tolist()) == list(range(k))
+        ports = kwargs["ports"]
+        assert ports.labels == ["p0"] and ports.values.shape == (k, 1)
+        return
+    assert REGISTRY.get(COLUMNAR) == before
     assert not commit.blocks and len(commit.rows) == k
     assert all(option is not None for _, option in commit.rows)
     assert [req.name for req, _ in commit.rows] == names[:k]
-    if shape == "dynamic_port":
-        assert all(len(o.allocated_ports) == 1 for _, o in commit.rows)
 
 
 def test_a_commit_that_takes_no_block_gets_rows():
@@ -473,13 +481,23 @@ def test_a_commit_that_takes_no_block_gets_rows():
 
 
 def test_a_drain_through_the_scheduler_with_a_port_keeps_rows():
+    """Its name is from the tree before ports rode in blocks: a drain of
+    a port-asking group now commits one block, whose positions hold
+    their ports, none twice on a node."""
     nodes = _racked_nodes(64, cpu=16000, mem=65536)
     before = REGISTRY.get(COLUMNAR)
     h = _drain(nodes, _spread_job(300, ports=1), "eval-port")
-    assert REGISTRY.get(COLUMNAR) == before
+    assert REGISTRY.get(COLUMNAR) == before + 1
     (plan,) = h.plans
-    assert not plan.alloc_blocks
-    assert sum(map(len, plan.node_allocation.values())) == 300
+    assert not plan.node_allocation
+    (block,) = plan.alloc_blocks
+    assert block.size == 300 and block.ports.values.shape == (300, 1)
+    held = {}
+    for a in h.store.snapshot().allocs_by_job(plan.job.id):
+        (port,) = a.allocated_ports
+        held.setdefault(a.node_id, []).append(port.value)
+    assert sum(map(len, held.values())) == 300
+    assert all(len(v) == len(set(v)) for v in held.values())
 
 
 # -- (f) the unplaced tail -------------------------------------------------
@@ -491,7 +509,7 @@ def test_the_unplaced_tail_is_one_coalesced_failure(monkeypatch):
     job = _spread_job(300)
     job.task_groups[0].tasks[0].resources.cpu = 500
     block_h = _drain(nodes, job, "eval-tail")
-    monkeypatch.setattr(TPUPlacer, "_wants_exact_ids",
+    monkeypatch.setattr(TPUPlacer, "_wants_ids",
                         staticmethod(lambda ctx, tg: True))
     row_h = _drain(nodes, job, "eval-tail")
     (block,) = block_h.plans[0].alloc_blocks
