@@ -34,7 +34,13 @@ class BlockedEvals:
         self._escaped: Dict[str, Evaluation] = {}
         # class -> {eval_id} potentially unblocked by that class
         self._captured: Dict[str, Evaluation] = {}
-        self.stats = {"blocked": 0, "unblocked": 0, "cancelled": 0}
+        # the newest store index at which capacity was freed or a node
+        # changed (unblock): an eval whose scheduler ran on an older
+        # snapshot missed it and must not wait for the next one
+        # (reference blocked_evals.go missedUnblock)
+        self._unblock_index = 0
+        self.stats = {"blocked": 0, "unblocked": 0, "cancelled": 0,
+                      "missed_unblocks": 0}
 
     def set_enabled(self, enabled: bool) -> None:
         with self._lock:
@@ -45,6 +51,18 @@ class BlockedEvals:
                 self._captured.clear()
 
     def block(self, ev: Evaluation) -> None:
+        with self._lock:
+            if not self._enabled:
+                return
+            missed = (ev.snapshot_index
+                      and ev.snapshot_index < self._unblock_index)
+            if missed:
+                self.stats["missed_unblocks"] += 1
+        if missed:
+            # the cluster changed after the scheduler's snapshot: retry
+            # now rather than wait for a change that already happened
+            self._enqueue(ev)
+            return
         with self._lock:
             if not self._enabled:
                 return
@@ -79,12 +97,15 @@ class BlockedEvals:
                 self._escaped.pop(ev.id, None)
                 self._captured.pop(ev.id, None)
 
-    def unblock(self, computed_class: str = "", quota: str = "") -> int:
-        """A node changed (or quota raised): release candidate evals back
-        to the broker (blocked_evals.go Unblock)."""
+    def unblock(self, computed_class: str = "", quota: str = "",
+                index: int = 0) -> int:
+        """A node changed (or quota raised, or capacity was freed at
+        store `index`): release candidate evals back to the broker
+        (blocked_evals.go Unblock)."""
         with self._lock:
             if not self._enabled:
                 return 0
+            self._unblock_index = max(self._unblock_index, index)
             release: List[Evaluation] = list(self._escaped.values())
             for ev in list(self._captured.values()):
                 elig = ev.class_eligibility.get(computed_class)

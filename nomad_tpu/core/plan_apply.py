@@ -245,6 +245,11 @@ class _OverlaySnapshot:
         # node id -> [(block, row)] of those blocks: the per-node reads'
         # index (allocs_by_node), built when the exact path first asks
         self._block_rows: Optional[Dict[str, list]] = None
+        # ids an in-flight result stops or preempts (a later plan that
+        # names one as its victim frees nothing by it), built when a
+        # plan with column evictions first asks
+        self._results = results
+        self._gone: Optional[set] = None
 
     def node_by_id(self, node_id):
         return self._snap.node_by_id(node_id)
@@ -313,6 +318,26 @@ class _OverlaySnapshot:
             for _, values in block.ports_for_row(m):
                 out.extend(values)
         return out
+
+    def live_mask(self, alloc_ids):
+        """The snapshot's liveness with what the in-flight results stop
+        or preempt taken out."""
+        gone = self._gone
+        if gone is None:
+            gone = self._gone = {
+                aid for by_id in self._replaced.values()
+                for aid, a in by_id.items() if a.terminal_status()}
+            for result in self._results:
+                if result.preempted:
+                    gone.update(result.preempted.alloc_ids)
+        live = self._snap.live_mask(alloc_ids)
+        if gone:
+            live &= np.fromiter((aid not in gone for aid in alloc_ids),
+                                bool, len(alloc_ids))
+        return live
+
+    def job_by_id(self, job_id, namespace="default"):
+        return self._snap.job_by_id(job_id, namespace)
 
     def alloc_by_id(self, alloc_id):
         for by_id in self._replaced.values():
@@ -429,6 +454,9 @@ class PlanApplier:
         # runs on the applier's thread, on the commit thread when it
         # re-verifies, and on whoever calls apply()
         self._scratch = threading.local()
+        # called with a committed plan's preemption evaluations (the
+        # server enqueues them); None leaves them in the store alone
+        self.on_evals = None
 
     def start(self) -> None:
         self._stop.clear()
@@ -947,19 +975,24 @@ class PlanApplier:
         for allocs in result.node_preemptions.values():
             preemptions.extend(allocs)
         if not (placements or stops or preemptions or result.alloc_blocks
-                or result.deployment is not None
+                or result.preempted or result.deployment is not None
                 or result.deployment_updates or plan.eval_updates):
             return None
-        return {
+        payload = {
             "result_allocs": stripped(placements),
             "stopped_allocs": stripped(stops),
             "preempted_allocs": stripped(preemptions),
             "deployment": result.deployment,
             "deployment_updates": result.deployment_updates,
-            "evals": list(plan.eval_updates),
+            "evals": list(plan.eval_updates) + result.preemption_evals,
             "alloc_blocks": list(result.alloc_blocks),
             "job": plan.job,
         }
+        if result.preempted:
+            # only where there are any: a payload without them encodes
+            # as it did before they existed
+            payload["preempted_positions"] = result.preempted
+        return payload
 
     def _commit(self, plan: Plan, result: PlanResult,
                 rejected: List[str]) -> PlanResult:
@@ -1003,6 +1036,10 @@ class PlanApplier:
             except Exception:
                 if self.logger:
                     self.logger.exception("post-apply hook failed")
+        if result.preemption_evals and self.on_evals is not None:
+            # committed with the plan: now the broker may have them
+            # (reference fsm.go applyPlanResults -> handleUpsertedEvals)
+            self.on_evals(result.preemption_evals)
         return result
 
     def submit_eval_updates(self, evals) -> Future:
@@ -1111,9 +1148,53 @@ class PlanApplier:
             sliced = block.without_nodes(rejected) if rejected else block
             if any(True for _ in sliced.live_rows()):
                 result.alloc_blocks.append(sliced)
+        if plan.preempted:
+            taken = (plan.preempted.without_nodes(rejected) if rejected
+                     else plan.preempted)
+            # a victim gone since: nothing of it to evict
+            taken = taken.take(np.flatnonzero(
+                snap.live_mask(taken.alloc_ids)).tolist())
+            result.preempted = taken if taken else None
+        if result.node_preemptions or result.preempted:
+            result.preemption_evals = self._preemption_evals(snap, result)
         result.deployment = plan.deployment
         result.deployment_updates = plan.deployment_updates
         return result, rejected
+
+    @staticmethod
+    def _preemption_evals(snap, result: PlanResult) -> list:
+        """One pending evaluation triggered by "preemption" per job that
+        loses allocations to this result, so that its scheduler places
+        them again (reference plan_apply.go applyPlan PreemptionEvals).
+        Span plan.preempt_evals (args `jobs`, `victims`); counter
+        nomad.preempt.evals."""
+        from ..structs.evaluation import Evaluation
+        from ..utils import generate_uuid
+        from .metrics import REGISTRY
+
+        with TRACER.span("plan.preempt_evals") as span:
+            jobs = {(a.namespace, a.job_id)
+                    for allocs in result.node_preemptions.values()
+                    for a in allocs}
+            taken = result.preempted
+            if taken:
+                jobs.update((g[0], g[1]) for g in taken.groups)
+            now = time.time()
+            evals = []
+            for namespace, job_id in sorted(jobs):
+                job = snap.job_by_id(job_id, namespace)
+                if job is None:
+                    continue
+                evals.append(Evaluation(
+                    id=generate_uuid(), namespace=namespace,
+                    priority=job.priority, type=job.type,
+                    triggered_by=enums.TRIGGER_PREEMPTION, job_id=job_id,
+                    status=enums.EVAL_STATUS_PENDING, create_time=now,
+                    modify_time=now))
+            span.set(jobs=len(evals), victims=len(taken or ()) + sum(
+                len(v) for v in result.node_preemptions.values()))
+        REGISTRY.incr("nomad.preempt.evals", len(evals))
+        return evals
 
     def _columnar_verdicts(self, snap, plan: Plan, fresh: List[str],
                            exact: List[str]) -> Tuple[int, set]:
@@ -1140,7 +1221,7 @@ class PlanApplier:
         inflight = getattr(snap, "inflight", None)
         others, other_rows = inflight(cols) if inflight else ([], None)
         if (len(blocks) == 1 and not ids and not exact and other_rows is None
-                and blocks[0].distinct
+                and not plan.preempted and blocks[0].distinct
                 and all(blocks[0].row_set.isdisjoint(o.row_set)
                         for o in others)):
             # one block on nodes of its own that nothing in flight
@@ -1169,6 +1250,14 @@ class PlanApplier:
         pairs += [(o.rows, o.usage()) for o in others]
         if other_rows is not None:
             pairs.append(other_rows)
+        if plan.preempted:
+            # used - victims + asked <= avail: a victim still live at
+            # this snapshot, and not stopped or preempted by an
+            # in-flight result, frees what it holds
+            taken = plan.preempted
+            live = snap.live_mask(taken.alloc_ids)
+            pairs.append((cols.rows(taken.node_ids)[live],
+                          -taken.vecs[live]))
         at = np.concatenate([r for r, _ in pairs])
         acc = self._accumulator(cols.capacity())
         try:
@@ -1326,6 +1415,8 @@ class PlanApplier:
 
         removed = {a.id for a in plan.node_update.get(node_id, ())}
         removed |= {a.id for a in plan.node_preemptions.get(node_id, ())}
+        if plan.preempted:
+            removed.update(plan.preempted.on_node(node_id))
         proposed = [a for a in existing if a.id not in removed]
         updated_ids = {a.id for a in all_allocation}
         proposed = [a for a in proposed if a.id not in updated_ids]

@@ -170,6 +170,8 @@ class Server:
                 threshold=self.config.plan_rejection_threshold,
                 window=self.config.plan_rejection_window,
                 on_bad_node=self._on_bad_node))
+        # a committed plan's preemption evaluations go to the broker
+        self.plan_applier.on_evals = self.broker.enqueue_all
         self.heartbeats = HeartbeatManager(
             self, ttl=self.config.heartbeat_ttl,
             shards=self.config.heartbeat_shards,
@@ -447,14 +449,15 @@ class Server:
                 continue
             if kind in ("node-upsert", "node-status", "node-eligibility", "node-drain"):
                 if payload is not None and payload.ready():
-                    self.blocked.unblock(payload.computed_class)
+                    self.blocked.unblock(payload.computed_class,
+                                         index=index)
             elif kind in ("alloc-stop", "alloc-preempt", "alloc-client-update",
                           "alloc-transition"):
                 # capacity freed by a terminal alloc can unblock evals
                 # (reference fsm.go:412,470 Unblock on alloc updates)
                 a = payload
                 if a is not None and (a.terminal_status() or a.server_terminal()):
-                    self.blocked.unblock("")
+                    self.blocked.unblock("", index=index)
 
     def _on_bad_node(self, node_id: str) -> None:
         """A node crossed the plan-rejection threshold: quarantine it so

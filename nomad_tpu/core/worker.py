@@ -52,6 +52,13 @@ class _EvalRun:
         self.ev = ev
         self.token = token
         self.snapshot = snapshot
+        # status writes submitted by update_eval, waited for once the
+        # scheduler has returned: [(future or None, eval)]
+        self._pending: list = []
+        # this eval, blocked again: handed to the blocked tracker after
+        # the ack (one released or retried at once while its delivery
+        # is unacked would be dropped by the broker)
+        self._blocked_after_ack: list = []
 
     def run(self):
         """Process the eval; ack on success (after every status write
@@ -84,7 +91,10 @@ class _EvalRun:
                         f"nomad.worker.invoke_scheduler_{ev.type}"), \
                         TRACER.span("worker.schedule", type=ev.type):
                     sched.process(ev)
+                self._settle()
                 server.broker.ack(ev.id, self.token)
+                for blocked in self._blocked_after_ack:
+                    server.blocked.block(blocked)
             self.worker._count("processed")
             return self.snapshot
         except Exception:
@@ -135,9 +145,30 @@ class _EvalRun:
                 10.0, self.server.config.nack_timeout / 2.0))
 
     def update_eval(self, ev: Evaluation) -> None:
-        self._persist_eval(ev)
-        if ev.should_block():
-            self.server.blocked.block(ev)
+        """The eval's status write, submitted at once and waited for in
+        _settle, after the scheduler returns and before the ack: later
+        writes of this eval still land after it (the commit thread takes
+        them in order), and worker.schedule ends before the status is
+        visible to a reader of the store."""
+        try:
+            fut = self.server.plan_applier.submit_eval_updates([ev])
+        except RuntimeError:
+            fut = None      # applier stopped: _settle writes directly
+        self._pending.append((fut, ev))
+
+    def _settle(self) -> None:
+        """Wait for update_eval's writes (durability before the ack, as
+        _persist_eval); what should block is blocked after the ack."""
+        pending, self._pending = self._pending, []
+        for fut, ev in pending:
+            with TRACER.span("eval.persist"):
+                if fut is None:
+                    self.server.store.upsert_evals([ev])
+                else:
+                    fut.result(timeout=max(
+                        10.0, self.server.config.nack_timeout / 2.0))
+            if ev.should_block():
+                self._blocked_after_ack.append(ev)
 
     def create_eval(self, ev: Evaluation) -> None:
         self._persist_eval(ev)
@@ -148,7 +179,7 @@ class _EvalRun:
 
     def reblock_eval(self, ev: Evaluation) -> None:
         self._persist_eval(ev)
-        self.server.blocked.block(ev)
+        self._blocked_after_ack.append(ev)
 
 
 class Worker:

@@ -124,6 +124,8 @@ class EvalContext:
             removed.add(a.id)
         for a in self.plan.node_preemptions.get(node_id, ()):
             removed.add(a.id)
+        if self.plan.preempted:
+            removed.update(self.plan.preempted.on_node(node_id))
         out = [a for a in existing if a.id not in removed]
         # placements may update an existing alloc in place (inplace update):
         placed_ids = {a.id for a in self.plan.node_allocation.get(node_id, ())}
@@ -145,6 +147,8 @@ class EvalContext:
         replaced = {a.id for bucket in (plan.node_update, plan.node_preemptions)
                     for a in bucket.get(node_id, ())}
         replaced.update(a.id for a in placed)
+        if plan.preempted:
+            replaced.update(plan.preempted.on_node(node_id))
         out = self.snapshot.ports_by_node(node_id, skip=replaced)
         out.extend(p.value for a in placed if a.should_count_for_usage()
                    for p in a.allocated_ports)

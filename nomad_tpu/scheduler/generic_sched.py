@@ -473,6 +473,7 @@ class GenericScheduler:
             # this eval IS a blocked eval being retried: reblock it
             reblocked = _copy.copy(ev)
             reblocked.status = enums.EVAL_STATUS_BLOCKED
+            reblocked.snapshot_index = getattr(self.state, "index", 0) or 0
             self.planner.reblock_eval(reblocked)
             self.blocked = reblocked
             return
@@ -487,6 +488,9 @@ class GenericScheduler:
             status_description=(BLOCKED_EVAL_MAX_PLAN_DESC if max_plan
                                 else BLOCKED_EVAL_FAILED_PLACEMENT_DESC),
             previous_eval=ev.id,
+            # the snapshot the placements failed on: a capacity change
+            # after it was missed (core/blocked.py)
+            snapshot_index=getattr(self.state, "index", 0) or 0,
         )
         # class eligibility lets the blocked-evals tracker unblock cheaply
         # (reference generic_sched.go:225 createBlockedEval)

@@ -80,16 +80,40 @@ def basic_resource_distance(need: np.ndarray, have: np.ndarray) -> float:
     return float(np.sqrt(d))
 
 
+def plan_preempted_counts(plan) -> Dict[tuple, int]:
+    """Evictions already in `plan` per (namespace, job id, task group):
+    its rows (node_preemptions) and its columns (plan.preempted) alike
+    (reference preemption.go SetPreemptions over the plan's
+    NodePreemptions)."""
+    counts: Dict[tuple, int] = {}
+    if plan is None:
+        return counts
+    for allocs in plan.node_preemptions.values():
+        for a in allocs:
+            k = (a.namespace, a.job_id, a.task_group)
+            counts[k] = counts.get(k, 0) + 1
+    if plan.preempted:
+        for k in plan.preempted.groups:
+            k = tuple(k)
+            counts[k] = counts.get(k, 0) + 1
+    return counts
+
+
+def max_parallel_of(job, tg_name: str) -> int:
+    """The migrate max_parallel a victim's group is held to, 0 for
+    none (what _max_parallel_penalty reads)."""
+    if job is None:
+        return 0
+    tg = job.lookup_task_group(tg_name)
+    if tg is None or tg.migrate is None:
+        return 0
+    return max(0, int(tg.migrate.max_parallel))
+
+
 def _max_parallel_penalty(alloc: Allocation, counts: Dict[tuple, int]) -> float:
     """Score penalty once a victim's task group is at its migrate
     max_parallel in this selection (reference scoreForTaskGroup)."""
-    job = alloc.job
-    if job is None:
-        return 0.0
-    tg = job.lookup_task_group(alloc.task_group)
-    if tg is None or tg.migrate is None:
-        return 0.0
-    max_parallel = tg.migrate.max_parallel
+    max_parallel = max_parallel_of(alloc.job, alloc.task_group)
     if max_parallel <= 0:
         return 0.0
     n = counts.get((alloc.namespace, alloc.job_id, alloc.task_group), 0)
@@ -156,10 +180,12 @@ def preempt_for_task_group(
                     used += a.allocated_vec
             need = used + ask_vec - node.available_vec()
             need = np.maximum(need, 0.0)
-            group.sort(key=lambda a: (
-                basic_resource_distance(need, a.allocated_vec)
-                + _max_parallel_penalty(a, counts)))
-            pick = group.pop(0)
+            # the closest, ties to the first in canonical order (the
+            # group keeps that order: the device path's columns are it)
+            best = min(range(len(group)), key=lambda k: (
+                basic_resource_distance(need, group[k].allocated_vec)
+                + _max_parallel_penalty(group[k], counts), k))
+            pick = group.pop(best)
             victims.append(pick)
             victim_ids.add(pick.id)
             ckey = (pick.namespace, pick.job_id, pick.task_group)

@@ -117,6 +117,7 @@ class NodeScorer:
         self.spread = SpreadScorer(job, tg, ctx.snapshot)
         self.penalty_nodes: FrozenSet[str] = frozenset()
         self._ppc_cache = None
+        self.counts_override: Optional[dict] = None
 
     def has_affinities_or_spreads(self) -> bool:
         return bool(self.affinities) or self.spread.has_spreads()
@@ -127,19 +128,22 @@ class NodeScorer:
         (reference preemption.go scoreForTaskGroup numPreemptedAllocs).
         Cached against the plan's total preemption count — a full-cluster
         scan calls rank() per node and must not rebuild an identical dict
-        every time."""
+        every time. `counts_override`, where a caller sets it, stands in
+        for the plan's (the system scheduler's device path ranks a node
+        with the counts of the nodes before it alone)."""
+        if self.counts_override is not None:
+            return self.counts_override
         plan = self.ctx.plan
         if plan is None:
             return {}
-        total = sum(len(v) for v in plan.node_preemptions.values())
+        total = (sum(len(v) for v in plan.node_preemptions.values())
+                 + len(plan.preempted or ()))
         cached = self._ppc_cache
         if cached is not None and cached[0] == total:
             return cached[1]
-        counts: dict = {}
-        for allocs in plan.node_preemptions.values():
-            for a in allocs:
-                k = (a.namespace, a.job_id, a.task_group)
-                counts[k] = counts.get(k, 0) + 1
+        from .preemption import plan_preempted_counts
+
+        counts = plan_preempted_counts(plan)
         self._ppc_cache = (total, counts)
         return counts
 

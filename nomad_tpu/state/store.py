@@ -374,6 +374,30 @@ class StateSnapshot:
                 out.extend(pt.value for pt in a.allocated_ports)
         return out
 
+    def live_mask(self, alloc_ids: List[str]) -> np.ndarray:
+        """Whether each allocation is live (not terminal) at this
+        snapshot, a block position read from its block without being
+        materialised (a promoted one as its row)."""
+        store, index = self._store, self.index
+        allocs, blocks = store._allocs, store._alloc_blocks
+        out = np.zeros(len(alloc_ids), dtype=bool)
+        for i, aid in enumerate(alloc_ids):
+            a = allocs.get(aid, index)
+            if a is not None:
+                out[i] = not a.terminal_status()
+                continue
+            sep = aid.rfind(BLOCK_SEP)
+            if sep < 0:
+                continue
+            block = blocks.get(aid[:sep], index)
+            try:
+                p = int(aid[sep + 1:])
+            except ValueError:
+                continue
+            out[i] = (block is not None and 0 <= p < block.size
+                      and block.visible(p))
+        return out
+
     def allocs_by_node_terminal(self, node_id: str, terminal: bool) -> List[Allocation]:
         return [a for a in self.allocs_by_node(node_id) if a.terminal_status() == terminal]
 
@@ -1224,12 +1248,14 @@ class StateStore:
         alloc_blocks: List[AllocBlock] = (),
         job=None,
         ts: float = None,
+        preempted_positions=None,
     ) -> int:
         return self.upsert_plan_results_batch([{
             "result_allocs": result_allocs, "stopped_allocs": stopped_allocs,
             "preempted_allocs": preempted_allocs, "deployment": deployment,
             "deployment_updates": deployment_updates, "evals": evals,
-            "alloc_blocks": alloc_blocks, "job": job}], ts=ts)
+            "alloc_blocks": alloc_blocks, "job": job,
+            "preempted_positions": preempted_positions}], ts=ts)
 
     def upsert_plan_results_batch(self, payloads: List[dict],
                                   ts: float = None) -> int:
@@ -1262,7 +1288,8 @@ class StateStore:
                         p.get("deployment_updates", ()),
                         p.get("evals", ()),
                         p.get("alloc_blocks", ()),
-                        gen, live, ts, events, job=p.get("job"))
+                        gen, live, ts, events, job=p.get("job"),
+                        preempted_positions=p.get("preempted_positions"))
                     rows += len(p.get("result_allocs", ())) + sum(
                         int(b.counts.sum()) for b in p.get("alloc_blocks", ()))
                     blocks += len(p.get("alloc_blocks", ()))
@@ -1367,7 +1394,8 @@ class StateStore:
     def _apply_plan_payload(self, result_allocs, stopped_allocs,
                             preempted_allocs, deployment, deployment_updates,
                             evals, alloc_blocks, gen: int, live: int,
-                            ts: float, events: list, job=None) -> None:
+                            ts: float, events: list, job=None,
+                            preempted_positions=None) -> None:
         """One plan's writes inside an open transaction. Must hold
         _write_lock; the caller owns _begin/_commit."""
         self._rehydrate_alloc_jobs(result_allocs, job)
@@ -1380,6 +1408,9 @@ class StateStore:
         for alloc in preempted_allocs:
             self._put_alloc(alloc, gen, live, ts)
             events.append(("alloc-preempt", alloc))
+        if preempted_positions:
+            events.extend(self._put_preempted_positions(
+                preempted_positions, gen, live, ts))
         new_allocs: List[Allocation] = []
         for alloc in result_allocs:
             # ANY alloc without an existing row is a first insert and
@@ -1416,6 +1447,26 @@ class StateStore:
         for ev in evals:
             self._put_eval(ev, gen, live, ts)
             events.append(("eval-upsert", ev))
+
+    def _put_preempted_positions(self, taken, gen: int, live: int,
+                                 ts: float) -> list:
+        """A plan's column evictions (structs/plan.py PreemptedPositions):
+        each victim that is still live becomes its evicted row, a block
+        position promoted as any first write to one promotes it, with
+        the id of the placement it made room for. The one place a
+        victim is materialised. -> the events of the evictions."""
+        events = []
+        for aid, by in zip(taken.alloc_ids, taken.by):
+            prev = self._latest_alloc(aid)
+            if prev is None or prev.terminal_status():
+                continue
+            evicted = prev.copy_for_update()
+            evicted.desired_status = enums.ALLOC_DESIRED_EVICT
+            evicted.desired_description = f"Preempted by alloc ID {by}"
+            evicted.preempted_by_allocation = by
+            self._put_alloc(evicted, gen, live, ts, prev=prev)
+            events.append(("alloc-preempt", evicted))
+        return events
 
     def _put_new_allocs_bulk(self, allocs: List[Allocation], gen: int,
                              live: int, ts: float, events: list) -> None:

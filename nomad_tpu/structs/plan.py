@@ -11,6 +11,63 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+import numpy as np
+
+
+@dataclass(slots=True)
+class PreemptedPositions:
+    """The allocations a plan preempts, as columns, one entry a victim:
+    its id (a block position "<block>.<p>" or a row's), its node, the
+    id of the placement it makes room for, its allocated_vec and its
+    (namespace, job id, task group). The system scheduler's device
+    path hands its evictions over this way, beside the AllocBlock of
+    its placements: no Allocation is built for a victim until the store
+    commits the eviction, and the applier checks the nodes as arrays
+    (core/plan_apply.py)."""
+
+    alloc_ids: List[str] = field(default_factory=list)
+    node_ids: List[str] = field(default_factory=list)
+    by: List[str] = field(default_factory=list)
+    vecs: np.ndarray = field(default_factory=lambda: np.zeros((0, 0)))
+    groups: List[tuple] = field(default_factory=list)
+    # node id -> its victims' ids (built on first use, never serialized)
+    _by_node: Optional[dict] = field(default=None, repr=False,
+                                     compare=False)
+
+    def __len__(self) -> int:
+        return len(self.alloc_ids)
+
+    def on_node(self, node_id: str) -> List[str]:
+        by_node = self._by_node
+        if by_node is None:
+            by_node = self._by_node = {}
+            for aid, nid in zip(self.alloc_ids, self.node_ids):
+                by_node.setdefault(nid, []).append(aid)
+        return by_node.get(node_id, [])
+
+    def take(self, keep: List[int]) -> "PreemptedPositions":
+        """The entries at the indices `keep`, in that order."""
+        if len(keep) == len(self.alloc_ids):
+            return self
+        return PreemptedPositions(
+            [self.alloc_ids[i] for i in keep],
+            [self.node_ids[i] for i in keep], [self.by[i] for i in keep],
+            self.vecs[keep], [self.groups[i] for i in keep])
+
+    def without_nodes(self, node_ids) -> "PreemptedPositions":
+        """The entries whose node is not in `node_ids` (a partial
+        commit's rejected nodes)."""
+        bad = set(node_ids)
+        return self.take([i for i, nid in enumerate(self.node_ids)
+                          if nid not in bad])
+
+    def concat(self, more: "PreemptedPositions") -> "PreemptedPositions":
+        """These entries, then `more`'s (a job's groups in turn)."""
+        return PreemptedPositions(
+            self.alloc_ids + more.alloc_ids, self.node_ids + more.node_ids,
+            self.by + more.by, np.concatenate([self.vecs, more.vecs]),
+            self.groups + more.groups)
+
 
 @dataclass(slots=True)
 class Plan:
@@ -38,6 +95,9 @@ class Plan:
     # solver service uses these to confirm or correct its
     # device-resident usage overlay (tensor/solver.py ledger).
     post_apply_hooks: List[object] = field(default_factory=list)
+    # evictions as columns (PreemptedPositions), beside node_preemptions'
+    # rows; None while the plan has none
+    preempted: Optional[PreemptedPositions] = None
 
     def append_alloc(self, alloc) -> None:
         self.node_allocation.setdefault(alloc.node_id, []).append(alloc)
@@ -80,6 +140,7 @@ class Plan:
             and not self.node_allocation
             and not self.node_preemptions
             and not self.alloc_blocks
+            and not self.preempted
             and self.deployment is None
             and not self.deployment_updates
         )
@@ -104,6 +165,11 @@ class PlanResult:
     node_preemptions: Dict[str, list] = field(default_factory=dict)
     # committed AllocBlocks (possibly sliced: rejected node rows marked)
     alloc_blocks: List[object] = field(default_factory=list)
+    # the plan's column evictions on the nodes that were not rejected
+    preempted: Optional[PreemptedPositions] = None
+    # one evaluation per job that lost allocations to this plan,
+    # triggered by "preemption" (committed with it)
+    preemption_evals: List[object] = field(default_factory=list)
     deployment: object = None
     deployment_updates: List[object] = field(default_factory=list)
     # If set, the plan was partially committed and the scheduler should
@@ -127,6 +193,7 @@ class PlanResult:
             not self.node_update
             and not self.node_allocation
             and not self.alloc_blocks
+            and not self.preempted
             and not self.deployment_updates
             and self.deployment is None
         )

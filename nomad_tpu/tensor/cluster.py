@@ -245,9 +245,11 @@ class ClusterTensors:
         plan = ctx.plan
         touched = ()
         if plan is not None and (plan.node_update or plan.node_preemptions
-                                 or plan.node_allocation):
+                                 or plan.node_allocation or plan.preempted):
             touched = (set(plan.node_update) | set(plan.node_preemptions)
-                       | set(plan.node_allocation))
+                       | set(plan.node_allocation)
+                       | set(plan.preempted.node_ids if plan.preempted
+                             else ()))
         blocks = plan.alloc_blocks if plan is not None else ()
         # incremental fast path (tensor/incremental.py): the feed's
         # delta-fed base already IS latest-committed usage in this
@@ -479,6 +481,168 @@ def build_victim_tensors(ctx: EvalContext, cluster: "ClusterTensors",
     return VictimTensors(v_pad=v_pad, prio=prio, vec=vec, elig=elig,
                          flagged=flagged, refs=per_node,
                          evictable=evictable, net_prio=net_prio)
+
+
+@dataclass
+class SystemVictims:
+    """A system group's victim columns (kernels.system_place): per node
+    row, one column a preemptible allocation of the node as the plan
+    leaves it, in (priority, alloc id) order, which is the exact host
+    arm's candidate order (scheduler/preemption.py victim_candidates).
+    Host arrays; `ids[i]`, `groups[i]` name row i's columns. `holds`
+    says which rows carry a live allocation with a port number (any
+    allocation, preemptible or not): the port assignment of a row
+    without one and without reserved ports is the lowest numbers of its
+    dynamic range."""
+
+    v_pad: int
+    prio: np.ndarray       # (Np, V) priority, 0 on empty columns
+    vec: np.ndarray        # (Np, V, D) allocated_vec
+    valid: np.ndarray      # (Np, V) bool: a preemptible allocation
+    flagged: np.ndarray    # (Np, V) bool: holds ports or devices
+    mp: np.ndarray         # (Np, V) its group's migrate max_parallel
+    grp: np.ndarray        # (Np, V) int32 first column of its group
+    cnt0: np.ndarray       # (Np, V) its group's evictions in the plan
+    ids: List[List[str]]
+    groups: List[List[tuple]]
+    holds: np.ndarray      # (Np,) bool
+    n_victims: int = 0
+
+
+def build_system_victims(ctx: EvalContext, cluster: "ClusterTensors",
+                         current_priority: int,
+                         v_floor: int = 8) -> SystemVictims:
+    """The victim columns of every node of `cluster`, read from the
+    snapshot's per-node index entries without materialising a block
+    position: a row as it is, a block's node row position by position
+    (a position promoted to a row is that row, a GC'd one is gone), each
+    block's job, priority and port column once. A node the in-progress
+    plan touches is read from ctx.proposed_allocs instead (rare: a
+    destructive update, or an earlier group's placements). Eligibility
+    is preemption.is_preemptible's: priority at least PRIORITY_DELTA
+    under `current_priority`, not terminal, counting for usage."""
+    from ..scheduler.preemption import (PRIORITY_DELTA, max_parallel_of,
+                                        plan_preempted_counts,
+                                        victim_candidates,
+                                        victim_holds_exact_resources)
+    from ..state.store import BlockRef
+    from ..structs.alloc import BLOCK_SEP
+
+    snap = ctx.snapshot
+    store, index = snap._store, snap.index
+    rows_t, blocks_t = store._allocs, store._alloc_blocks
+    by_node = store._allocs_by_node
+    plan = ctx.plan
+    touched: set = set()
+    if plan is not None:
+        touched.update(plan.node_update, plan.node_preemptions,
+                       plan.node_allocation)
+        if plan.preempted:
+            touched.update(plan.preempted.node_ids)
+        for block in plan.alloc_blocks:
+            touched.update(block.live_node_counts()[0])
+    top = current_priority - PRIORITY_DELTA
+    mp_of: Dict[tuple, int] = {}
+
+    def group_of(a) -> tuple:
+        key = (a.namespace, a.job_id, a.task_group)
+        if key not in mp_of:
+            mp_of[key] = max_parallel_of(a.job, a.task_group)
+        return key
+
+    def from_row(a, out, i) -> None:
+        if a.allocated_ports:
+            holds[i] = True
+        if (a.job is not None and a.job.priority <= top
+                and a.should_count_for_usage()):
+            out.append((a.job.priority, a.id, a.allocated_vec,
+                        victim_holds_exact_resources(a), group_of(a)))
+
+    nodes = cluster.nodes
+    holds = np.zeros(cluster.n_pad, dtype=bool)
+    per_node: List[list] = []
+    for i, node in enumerate(nodes):
+        out: list = []
+        if node.id in touched:
+            proposed = ctx.proposed_allocs(node.id)
+            holds[i] = any(a.allocated_ports for a in proposed
+                           if a.should_count_for_usage())
+            out = [(a.job.priority, a.id, a.allocated_vec,
+                    victim_holds_exact_resources(a), group_of(a))
+                   for a in victim_candidates(proposed, current_priority)]
+            per_node.append(out)
+            continue
+        for entry in snap._ids_from_index(by_node, node.id):
+            if type(entry) is not BlockRef:
+                a = rows_t.get(entry, index)
+                if a is not None and not a.terminal_status():
+                    from_row(a, out, i)
+                continue
+            block = blocks_t.get(entry.block_id, index)
+            if block is None or entry.row in block.rejected_rows:
+                continue
+            job = block.job
+            ported = block.ports is not None
+            wanted = job is not None and job.priority <= top
+            if not wanted and not ported:
+                continue
+            prefix = block.id + BLOCK_SEP
+            if wanted:
+                key = (block.namespace, block.job_id, block.task_group)
+                if key not in mp_of:
+                    mp_of[key] = max_parallel_of(job, block.task_group)
+            for p in block.positions_for_row(entry.row):
+                if p in block.dropped:
+                    continue
+                aid = prefix + str(p)
+                row = rows_t.get(aid, index)
+                if row is not None:
+                    if not row.terminal_status():
+                        from_row(row, out, i)
+                    continue
+                if ported:
+                    holds[i] = True
+                if wanted:
+                    out.append((job.priority, aid, block.allocated_vec,
+                                ported, key))
+        out.sort(key=lambda c: (c[0], c[1]))
+        per_node.append(out)
+
+    n_pad, d = cluster.n_pad, cluster.available.shape[1]
+    v_max = max((len(c) for c in per_node), default=0)
+    v_pad = _pad_pow2(max(v_max, 1), floor=v_floor)
+    prio = np.zeros((n_pad, v_pad))
+    vec = np.zeros((n_pad, v_pad, d))
+    valid = np.zeros((n_pad, v_pad), dtype=bool)
+    flagged = np.zeros((n_pad, v_pad), dtype=bool)
+    mp = np.zeros((n_pad, v_pad))
+    grp = np.zeros((n_pad, v_pad), dtype=np.int32)
+    cnt0 = np.zeros((n_pad, v_pad))
+    counts = plan_preempted_counts(plan)
+    ids: List[List[str]] = []
+    groups: List[List[tuple]] = []
+    total = 0
+    for i, cands in enumerate(per_node):
+        ids.append([c[1] for c in cands])
+        groups.append([c[4] for c in cands])
+        if not cands:
+            continue
+        k = len(cands)
+        total += k
+        prio[i, :k] = [c[0] for c in cands]
+        vec[i, :k] = [c[2][:d] for c in cands]
+        valid[i, :k] = True
+        flagged[i, :k] = [c[3] for c in cands]
+        first: Dict[tuple, int] = {}
+        for v, c in enumerate(cands):
+            key = c[4]
+            grp[i, v] = first.setdefault(key, v)
+            mp[i, v] = mp_of[key]
+            cnt0[i, v] = counts.get(key, 0)
+    return SystemVictims(v_pad=v_pad, prio=prio, vec=vec, valid=valid,
+                         flagged=flagged, mp=mp, grp=grp, cnt0=cnt0,
+                         ids=ids, groups=groups, holds=holds,
+                         n_victims=total)
 
 
 @dataclass

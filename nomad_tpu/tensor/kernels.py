@@ -1047,3 +1047,116 @@ def preempt_solve(
     _, (picks, victims, flagged, scores) = jax.lax.scan(
         step, (used0, ev0, taken0), jnp.arange(active.shape[0]))
     return (picks.astype(jnp.int32), victims, flagged, scores.astype(f))
+
+
+def _system_place_xp(xp, loop, available, used, ask, feasible, target,
+                     preempt, v_prio, v_vec, v_valid, v_mp, v_grp, v_cnt0):
+    """One system group on every node at once: fit, and on a node
+    without room the victims by upstream's rule (preemption.go
+    PreemptForTaskGroup; scheduler/preemption.py preempt_for_task_group
+    is the exact host arm it must equal). Priority groups lowest first;
+    in the lowest group left, the candidate whose resources come
+    closest to what is still missing (basicResourceDistance over the
+    dimensions still short) plus the max_parallel penalty, ties to the
+    first column; stop when the ask fits; then filterSuperset: victims
+    highest priority first, each dropped again if the ask still fits
+    without it. Columns are per node in (priority, alloc id) order, so
+    "the first column" is the host's stable sort.
+
+    `xp` is the array namespace and `loop(n, body, state)` its loop
+    (a Python loop for numpy, fori_loop on the device): the ONE copy of
+    the rule for the kernel and its numpy mirror. The V steps are over
+    a node's victim columns; the nodes are the vector axis."""
+    f = used.dtype
+    n, v = v_prio.shape
+    cols = xp.arange(v)[None, :]
+    inf = xp.asarray(float("inf"), dtype=f)
+
+    def fits(taken):
+        freed = xp.sum(v_vec * taken[:, :, None].astype(f), axis=1)
+        return xp.all(used - freed + ask[None, :] <= available, axis=1), freed
+
+    fits0, _ = fits(xp.zeros((n, v), dtype=bool))
+    placeable = target & feasible
+    evicting = placeable & ~fits0 & preempt
+
+    def pick(t, state):
+        picked, order, cnt = state
+        ok, freed = fits(picked)
+        left = v_valid & ~picked
+        low = xp.min(xp.where(left, v_prio, inf), axis=1)
+        cand = left & (v_prio == low[:, None])
+        need = xp.maximum(used - freed + ask[None, :] - available, 0.0)
+        short = need > 0.0
+        safe = xp.where(short, need, 1.0)
+        rel = (v_vec - need[:, None, :]) / safe[:, None, :]
+        dist = xp.sqrt(xp.sum(xp.where(short[:, None, :], rel * rel, 0.0),
+                              axis=2))
+        over = (v_mp > 0.0) & (cnt >= v_mp)
+        pen = xp.where(over, (cnt + 1.0 - v_mp) * 50.0, 0.0)
+        score = xp.where(cand, dist + pen, inf)
+        best = xp.argmin(score, axis=1)
+        go = evicting & ~ok & xp.any(cand, axis=1)
+        sel = (cols == best[:, None]) & go[:, None]
+        group = xp.sum(xp.where(sel, v_grp, 0), axis=1)
+        cnt = cnt + ((v_grp == group[:, None]) & go[:, None]).astype(f)
+        return picked | sel, xp.where(sel, t, order), cnt
+
+    picked, order, _ = loop(v, pick, (xp.zeros((n, v), dtype=bool),
+                                      xp.full((n, v), v, dtype=xp.int32),
+                                      v_cnt0))
+    freed_ok, _ = fits(picked)
+    evicted = evicting & freed_ok
+
+    def superset(t, state):
+        kept, seen = state
+        left = kept & ~seen
+        top = xp.max(xp.where(left, v_prio, -inf), axis=1)
+        first = xp.where(left & (v_prio == top[:, None]), order, v + 1)
+        drop = (cols == xp.argmin(first, axis=1)[:, None]) \
+            & xp.any(left, axis=1)[:, None]
+        still, _ = fits(kept & ~drop)
+        return (xp.where(drop & still[:, None], False, kept),
+                seen | drop)
+
+    victims, _ = loop(v, superset, (picked & evicted[:, None],
+                                    xp.zeros((n, v), dtype=bool)))
+    placed = (placeable & fits0) | evicted
+    _, freed = fits(victims)
+    after = used - freed + ask[None, :] * placed[:, None].astype(f)
+    fitness = _fit_scores_xp(xp, available, after, False)
+    return placed, victims, fitness.astype(f)
+
+
+def _fori(n, body, state):
+    return jax.lax.fori_loop(0, n, body, state)
+
+
+@jax.jit
+def system_place(available, used, ask, feasible, target, preempt, v_prio,
+                 v_vec, v_valid, v_mp, v_grp, v_cnt0):
+    """A system job's group on every node in ONE launch
+    (_system_place_xp has the rule): (N, D) capacity and proposed
+    usage, (D,) ask, (N,) feasibility mask and nodes to place on, a
+    scalar bool preemption switch, and the (N, V) victim columns of
+    tensor/cluster.build_system_victims (priority, (N, V, D) usage,
+    preemptible, the victim's group max_parallel, the group's column
+    within the node, the group's preemptions already in the plan).
+    -> placed (N,) bool, victims (N, V) bool, fitness (N,) of the node
+    as the placement leaves it."""
+    return _system_place_xp(jnp, _fori, available, used, ask, feasible,
+                            target, preempt, v_prio, v_vec, v_valid, v_mp,
+                            v_grp, v_cnt0)
+
+
+def system_place_np(*args):
+    """Numpy mirror of `system_place` (float64), same rule, same column
+    order: the parity oracle of the tests."""
+    import numpy as np
+
+    def loop(n, body, state):
+        for t in range(n):
+            state = body(t, state)
+        return state
+
+    return _system_place_xp(np, loop, *args)

@@ -150,8 +150,14 @@ def _preempt_solve_host(available, used, ask, feasible, net_prio, active,
 # kernel_preempted counts only validated successes). Mirrored into the
 # Registry as nomad.preempt.* for the obs plane; read via
 # preempt_stats() (chip_smoke leg D, chaos solve-smoke).
+# The system path's (TPUPlacer.place_system): preempting_nodes = nodes
+# whose placement preempted, victims = allocations it preempted,
+# host_fallback_nodes = nodes it handed to the exact host scorer
+# (a victim holding ports or devices, or max_parallel penalties that
+# couple the node to an earlier one in the same launch).
 PREEMPT_STATS = {"kernel_preempted": 0, "host_preempted": 0,
-                 "victim_parity_checked": 0}
+                 "victim_parity_checked": 0, "preempting_nodes": 0,
+                 "victims": 0, "host_fallback_nodes": 0}
 _PREEMPT_STATS_LOCK = __import__("threading").Lock()
 # shapes (n_pad, k_pad, v_pad, d) already compiled: later launches of the
 # same shape run under a jit_guard no_retrace window (retrace there is a
@@ -162,6 +168,7 @@ _PREEMPT_WARM: set = set()
 _FUSED_WARM: set = set()
 _BULK_FUSED_WARM: set = set()
 _BULK_WARM: set = set()
+_SYSTEM_WARM: set = set()
 
 
 def _warm_launch(fn, shape_key, warm: set):
@@ -187,8 +194,8 @@ def _count_preempt(**deltas: int) -> None:
         for key, n in deltas.items():
             PREEMPT_STATS[key] += n
     for key, n in deltas.items():
-        if n:
-            REGISTRY.incr(f"nomad.preempt.{key}", n)
+        # at 0 too: a reader of the share finds its counters
+        REGISTRY.incr(f"nomad.preempt.{key}", n)
 
 
 # One solve at a time across racing workers' PER-EVAL kernel path (the
@@ -1146,6 +1153,12 @@ class TPUPlacer:
             return m
 
         n_kernel = n_host = n_parity = 0
+        # a full host scan that found no node finds none for the next
+        # request either (the same ask, no node penalty) until a commit
+        # changes the plan: a job that can preempt nothing, placed again
+        # by the preemption evaluation of a job that lost allocations,
+        # scans once, not once a request
+        scan_failed = False
         for i, req in enumerate(reqs):
             option = None
             kernel_row = False
@@ -1173,14 +1186,19 @@ class TPUPlacer:
                         host_metrics()
                         option = scorer.rank(node)
             if option is None and not kernel_row:
-                # aggregate misprediction: full host scan for this one
-                with TRACER.span("worker.preempt"):
+                if scan_failed and not req.ignore_node:
                     host_metrics()
-                    option = self._preempt_fallback(ctx, job, tg, nodes,
-                                                    req, sched_batch,
-                                                    attempt)
+                else:
+                    # aggregate misprediction: full host scan for this one
+                    with TRACER.span("worker.preempt"):
+                        host_metrics()
+                        option = self._preempt_fallback(
+                            ctx, job, tg, nodes, req, sched_batch, attempt)
+                    if option is None and not req.ignore_node:
+                        scan_failed = True
             if option is not None:
                 commit(req, option)
+                scan_failed = False
                 prop_cache.pop(option.node.id, None)
                 scorer.record_placement(option.node)
                 if invalidate is not None:
@@ -1258,6 +1276,182 @@ class TPUPlacer:
         option.preempted_allocs = chosen or None
         option.final_score = score
         return option
+
+    # -- a system group on every node: kernels.system_place --
+
+    def place_system(self, ctx, job, tg, nodes, targets, *,
+                     preemption_enabled: bool) -> "SystemPlacement":
+        """One system (or sysbatch) group on each of `targets` (fresh
+        nodes of `nodes`, the job's ready nodes in canonical order) in
+        ONE launch: the cached feasibility mask, the fit, and on a node
+        without room the victims by upstream's rule
+        (kernels.system_place), read from the victim columns of
+        build_system_victims. What the columns cannot model goes to the
+        exact host scorer (NodeScorer.rank) node by node, in node order:
+        a node whose victims hold ports or devices, and a node whose
+        candidates' max_parallel penalty would read an eviction of an
+        earlier node of this launch (the host's penalty counts the whole
+        plan so far). The device's placements keep their order and get
+        their port numbers under _PER_EVAL_SOLVE_LOCK, as the scan's do.
+        Spans: system.place (args `nodes`, `preempting`) around it all,
+        preempt.columns (args `nodes`, `victims`) around the columns."""
+        from .cluster import build_system_victims
+
+        with TRACER.span("system.place", nodes=len(targets)) as span:
+            with TRACER.span("worker.tensor_build", n=len(nodes)):
+                cluster = ClusterTensors.build(ctx, nodes)
+            tgt = build_task_group_tensors(ctx, job, tg, cluster,
+                                           algorithm=self.algorithm)
+            target = np.zeros(cluster.n_pad, dtype=bool)
+            index = cluster.node_index
+            target[[index[n.id] for n in targets]] = True
+            with TRACER.span("preempt.columns",
+                             nodes=len(cluster.nodes)) as cspan:
+                vt = build_system_victims(ctx, cluster, job.priority)
+                cspan.set(victims=vt.n_victims)
+            out = self._system_launch(ctx, tg, cluster, tgt, target, vt,
+                                      preemption_enabled)
+            self._system_fallbacks(ctx, job, tg, cluster, vt, out,
+                                   preemption_enabled)
+            span.set(preempting=out.n_preempting)
+        return out
+
+    def _system_launch(self, ctx, tg, cluster, tgt, target, vt,
+                       preemption_enabled: bool) -> "SystemPlacement":
+        """Gather usage, launch, fetch, choose ports and register the
+        in-flight entry under _PER_EVAL_SOLVE_LOCK."""
+        import jax
+
+        from .kernels import system_place
+
+        f = np.float64 if jax.config.jax_enable_x64 else np.float32
+        n = len(cluster.nodes)
+        static = (vt.prio.astype(f), vt.vec.astype(f), vt.valid,
+                  vt.mp.astype(f), vt.grp, vt.cnt0.astype(f))
+        shape_key = (cluster.n_pad, vt.v_pad, cluster.available.shape[1],
+                     np.dtype(f).name)
+        with TRACER.span("system.lock_wait", device=True):
+            _PER_EVAL_SOLVE_LOCK.acquire()
+        try:
+            with TRACER.span("system.locked", device=True):
+                cluster.refresh_usage(ctx)
+                args = (cluster.available.astype(f), cluster.used.astype(f),
+                        tgt.ask.astype(f), tgt.feasible, target,
+                        np.bool_(preemption_enabled)) + static
+                with _warm_launch(system_place, shape_key, _SYSTEM_WARM):
+                    with TRACER.span("system.ship", device=True):
+                        res = system_place(*jax.device_put(args))
+                    with TRACER.span("system.fetch", device=True):
+                        placed, victims, fitness = jax.device_get(res)
+                out = SystemPlacement(cluster, vt, placed[:n], victims[:n],
+                                      fitness[:n],
+                                      target[:n] & tgt.feasible[:n])
+                self._system_register(ctx, tg, cluster, vt, out)
+        finally:
+            _PER_EVAL_SOLVE_LOCK.release()
+        return out
+
+    def _system_register(self, ctx, tg, cluster, vt, out) -> None:
+        """Under the lock: the device placements' ports, and their
+        in-flight entry (usage and ports) for the evaluations racing
+        behind this one."""
+        from .overlay import INFLIGHT
+
+        held = self._system_ports(ctx, tg, cluster, vt, out,
+                                  out.device_rows())
+        if ctx.plan is not None:
+            rows = out.device_rows()
+            INFLIGHT.register(
+                cluster, rows,
+                np.repeat(ctx.tg_vec(tg)[None, :], len(rows), 0),
+                ctx.plan, held)
+
+    @staticmethod
+    def _system_ports(ctx, tg, cluster, vt, out, rows) -> dict:
+        """The port numbers of the device's placements on `rows`, as
+        _assign_ports chooses them (lowest free first): the lowest of
+        the node's dynamic range where the node holds no port and the
+        overlay none and the agent reserves none, else the node's
+        ctx.port_index. A node out of numbers loses its placement (the
+        host scorer fails it the same way). Sets out.labels and
+        out.port_values by row; -> {node id: numbers}."""
+        from .overlay import INFLIGHT
+
+        ask = ctx.tg_resources(tg)
+        labels = [label for net in ask.networks for label in net.dynamic_ports]
+        if not labels:
+            return {}
+        nodes = cluster.nodes
+        inflight = INFLIGHT.ports_on([nodes[r].id for r in rows.tolist()],
+                                     getattr(ctx.snapshot, "index", None))
+        values = np.zeros((len(out.placed), len(labels)), dtype=np.int32)
+        held: Dict[str, list] = {}
+        for r in rows.tolist():
+            node = nodes[r]
+            res = node.resources
+            if (not vt.holds[r] and node.id not in inflight
+                    and not node.reserved.reserved_ports
+                    and res.min_dynamic_port + len(labels) - 1
+                    <= res.max_dynamic_port):
+                got = list(range(res.min_dynamic_port,
+                                 res.min_dynamic_port + len(labels)))
+            else:
+                ports, err = ctx.port_index(node).assign_ports(ask)
+                if err:
+                    out.placed[r] = False
+                    out.victims[r] = False
+                    continue
+                got = [p.value for p in ports]
+            values[r] = got
+            held[node.id] = got
+        out.labels, out.port_values = labels, values
+        return held
+
+    def _system_fallbacks(self, ctx, job, tg, cluster, vt, out,
+                          preemption_enabled: bool) -> None:
+        """The nodes the columns cannot model, through the exact host
+        scorer in node order, each with the max_parallel counts of the
+        plan and of the nodes before it in this launch."""
+        from ..scheduler.preemption import plan_preempted_counts
+        from .overlay import INFLIGHT
+
+        coupled = np.flatnonzero(
+            out.victims.any(axis=1)
+            & (vt.valid & (vt.mp > 0))[: len(out.victims)].any(axis=1))
+        walk = sorted(out.exact_rows.union(coupled.tolist()))
+        if not walk:
+            return
+        base = plan_preempted_counts(ctx.plan)
+        extra: Dict[tuple, int] = {}
+        scorer = NodeScorer(ctx, job, tg, preemption_enabled=preemption_enabled,
+                            current_priority=job.priority)
+        held: Dict[str, list] = {}
+        for r in walk:
+            mine = [vt.groups[r][v] for v in range(len(vt.groups[r]))
+                    if vt.mp[r, v] > 0]
+            if r not in out.exact_rows and not any(
+                    extra.get(key) for key in mine):
+                for v in np.flatnonzero(out.victims[r]).tolist():
+                    key = vt.groups[r][v]
+                    extra[key] = extra.get(key, 0) + 1
+                continue
+            counts = dict(base)
+            for key, c in extra.items():
+                counts[key] = counts.get(key, 0) + c
+            scorer.counts_override = counts
+            metrics = ctx.new_metrics()
+            metrics.nodes_evaluated += 1
+            option = scorer.rank(cluster.nodes[r])
+            out.fallback.append((r, option, metrics))
+            out.exact_rows.add(r)
+            for victim in (option.preempted_allocs or ()) if option else ():
+                key = (victim.namespace, victim.job_id, victim.task_group)
+                extra[key] = extra.get(key, 0) + 1
+            if option is not None and option.allocated_ports:
+                held[option.node.id] = [p.value
+                                        for p in option.allocated_ports]
+        if held and ctx.plan is not None:
+            INFLIGHT.register(cluster, (), None, ctx.plan, held)
 
     @staticmethod
     def _bulk_trajectory_mean(counts: np.ndarray, cluster, tgt) -> float:
@@ -1372,3 +1566,65 @@ class TPUPlacer:
                           attempt: int) -> Optional[RankedNode]:
         return self._host_one(ctx, job, tg, nodes, req, batch,
                               preemption_enabled=True, attempt=attempt)
+
+
+class SystemPlacement:
+    """What TPUPlacer.place_system decided, by node row of `cluster`:
+    `placed` / `victims` / `fitness` as the launch returned them,
+    `feasible` the target rows that passed feasibility, `exact_rows`
+    the rows the host scorer decided instead (`fallback`: (row,
+    RankedNode or None, its AllocMetric) in node order), `labels` and
+    `port_values` (a row of numbers a node row) the ports of a group
+    that asks them (None otherwise)."""
+
+    def __init__(self, cluster, vt, placed, victims, fitness, feasible):
+        self.cluster, self.vt = cluster, vt
+        self.placed = np.array(placed, dtype=bool)
+        self.victims = np.array(victims, dtype=bool)
+        self.fitness, self.feasible = np.asarray(fitness), feasible
+        # a victim that holds ports or devices: the host scorer's node
+        self.exact_rows: set = set(np.flatnonzero(
+            (self.victims & vt.flagged[: len(self.victims)]).any(axis=1)
+        ).tolist())
+        self.fallback: list = []
+        self.labels = None
+        self.port_values = None
+
+    def device_rows(self) -> np.ndarray:
+        keep = self.placed.copy()
+        if self.exact_rows:
+            keep[list(self.exact_rows)] = False
+        return np.flatnonzero(keep)
+
+    def block_ports(self, rows: np.ndarray):
+        """The BlockPorts of the device's placements on `rows`, None for
+        a group without ports."""
+        from ..structs.alloc import BlockPorts
+
+        if self.labels is None:
+            return None
+        return BlockPorts(list(self.labels), self.port_values[rows])
+
+    @property
+    def n_preempting(self) -> int:
+        rows = self.device_rows()
+        return int(self.victims[rows].any(axis=1).sum()) + sum(
+            1 for _, option, _ in self.fallback
+            if option is not None and option.preempted_allocs)
+
+    def scores(self, rows: np.ndarray) -> np.ndarray:
+        """The normalized score of the device's placements on `rows`,
+        as NodeScorer.rank normalizes it: the binpack fit, and on a
+        node that preempts the mean of that and the preemption score of
+        its victims' net priority (rank.go netPriority)."""
+        from ..scheduler.rank import preemption_score
+
+        fit = self.fitness[rows].astype(np.float64)
+        sel = self.victims[rows]
+        prio = np.where(sel, self.vt.prio[rows], 0.0)
+        top = prio.max(axis=1) if prio.size else np.zeros(len(rows))
+        net = np.where(top > 0, top + prio.sum(axis=1)
+                       / np.maximum(top, 1.0), 0.0)
+        pscore = np.array([preemption_score(x) for x in net.tolist()])
+        return np.where(sel.any(axis=1), (fit + pscore) / 2.0,
+                        fit).astype(np.float32)

@@ -59,12 +59,14 @@ class Harness:
                 deployment_updates=plan.deployment_updates,
                 evals=list(plan.eval_updates),
                 alloc_blocks=list(plan.alloc_blocks),
+                preempted_positions=plan.preempted,
             )
             result = PlanResult(
                 node_allocation=plan.node_allocation,
                 node_update=plan.node_update,
                 node_preemptions=plan.node_preemptions,
                 alloc_blocks=list(plan.alloc_blocks),
+                preempted=plan.preempted,
                 alloc_index=index,
             )
             self._run_hooks(plan, result)
