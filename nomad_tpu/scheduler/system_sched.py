@@ -320,13 +320,12 @@ class SystemScheduler:
         if not len(at):
             return 0
         vt = out.vt
-        pairs = list(zip(rows[at].tolist(), cols.tolist()))
         taken = PreemptedPositions(
-            alloc_ids=[vt.ids[r][v] for r, v in pairs],
+            alloc_ids=vt.ids_at(rows[at], cols),
             node_ids=[block.node_ids[q] for q in at.tolist()],
             by=[f"{block.id}{BLOCK_SEP}{q}" for q in at.tolist()],
             vecs=vt.vec[rows[at], cols].astype(np.float64),
-            groups=[vt.groups[r][v] for r, v in pairs])
+            groups=vt.groups_at(rows[at], cols))
         have = self.plan.preempted
         self.plan.preempted = have.concat(taken) if have else taken
         return len(at)

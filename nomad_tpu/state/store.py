@@ -38,6 +38,7 @@ from ..structs.node import Node
 from ..analysis.ownership import GLOBAL as _OWN
 from ..analysis.sanitizer import sanitized
 from ..obs import TRACER
+from .alloc_columns import AllocColumns
 from .mvcc import ConsList, SnapshotTracker, VersionedTable, cons, cons_from_iter, cons_iter
 from .watch import WatchTable
 
@@ -702,6 +703,13 @@ class StateStore:
         # NodeColumns.read gather them without the lock. The last row
         # is given to no node: row -1, a node the store never saw.
         self._avail_mat = np.full((256, RESOURCE_DIMS), -np.inf)
+        # Per-node allocation columns on the same row index, for a system
+        # evaluation's victim columns (state/alloc_columns.py): built by
+        # the first evaluation that reads them (alloc_columns()), kept
+        # from then on by the writers that move _usage_mat, derived like
+        # it, rebuilt on restore, read without the lock by per-node
+        # stamps. A store no system evaluation reads keeps none.
+        self._alloc_cols: Optional[AllocColumns] = None
 
         self._all_tables = [
             self._nodes, self._jobs, self._job_versions, self._evals, self._allocs,
@@ -1106,6 +1114,30 @@ class StateStore:
             if vec is not None:
                 row = self._usage_row(node_id)
                 self._usage_mat[row] = vec
+        if self._alloc_cols is not None:
+            self._alloc_cols = self._build_alloc_columns()
+
+    def alloc_columns(self) -> AllocColumns:
+        """The per-node allocation columns (state/alloc_columns.py):
+        built from the latest tables on the first call, under the write
+        lock, and kept by every write from then on."""
+        cols = self._alloc_cols
+        if cols is None:
+            with self._write_lock:
+                if self._alloc_cols is None:
+                    self._alloc_cols = self._build_alloc_columns()
+                cols = self._alloc_cols
+        return cols
+
+    def _build_alloc_columns(self) -> AllocColumns:
+        """Must hold _write_lock. The columns of the latest tables, every
+        node row stamped with the generation they are built at."""
+        gen = self._next_gen
+        cols = AllocColumns(self._usage_row, self._usage_rows, RESOURCE_DIMS,
+                            gen=gen)
+        cols.rebuild((a for _, a in self._allocs.iterate(gen)),
+                     (b for _, b in self._alloc_blocks.iterate(gen)))
+        return cols
 
     def _usage_add(self, node_id: str, delta, gen: int, live: int) -> None:
         cur = self._node_usage.get_latest(node_id)
@@ -1127,6 +1159,10 @@ class StateStore:
 
         pc = prev is not None and not prev.terminal_status()
         nc = new is not None and not new.terminal_status()
+        cols = self._alloc_cols
+        if cols is not None:
+            cols.transition(prev, new, pc, nc, gen,
+                            self._alloc_blocks.get_latest)
         if (pc and nc and prev.node_id == new.node_id
                 and np.array_equal(prev.allocated_vec, new.allocated_vec)):
             return  # annotation-only rewrite; no resource movement
@@ -1226,8 +1262,14 @@ class StateStore:
                 merged.desired_transition = transition
                 merged.modify_index = gen
                 # desired_transition never flips should_count_for_usage
-                # (that's client_terminal-only), so no usage row change
+                # (that's client_terminal-only), so no usage row change;
+                # a block position's first write gives it a row all the same
                 self._allocs.put(alloc_id, merged, gen, live)
+                if self._alloc_cols is not None:
+                    self._alloc_cols.transition(
+                        existing, merged, not existing.terminal_status(),
+                        not merged.terminal_status(), gen,
+                        self._alloc_blocks.get_latest)
                 events.append(("alloc-transition", merged))
             for ev in evals:
                 self._put_eval(ev, gen, live, ts)
@@ -1512,6 +1554,8 @@ class StateStore:
         for (node_id, _), (vec, count) in usage.items():
             self._usage_add(node_id, vec if count == 1 else vec * count,
                             gen, live)
+        if self._alloc_cols is not None:
+            self._alloc_cols.rows_added(allocs, gen)
         for table, groups in ((self._allocs_by_node, by_node),
                               (self._allocs_by_job, by_job),
                               (self._allocs_by_eval, by_eval)):
@@ -1543,6 +1587,8 @@ class StateStore:
             self._allocs_by_node.put(nid, cons(BlockRef(block.id, m), cell),
                                      gen, live)
             self._usage_add(nid, vec * c if c != 1 else vec, gen, live)
+        if self._alloc_cols is not None:
+            self._alloc_cols.block_added(block, gen)
         jkey = (block.namespace, block.job_id)
         jcell = self._allocs_by_job.get_latest(jkey)
         self._allocs_by_job.put(jkey, cons(BlockRef(block.id), jcell),
@@ -2118,6 +2164,8 @@ class StateStore:
                 block = block.with_dropped(positions)
                 if block.live_size() <= 0:
                     self._alloc_blocks.delete(bid, gen, live)
+                    if self._alloc_cols is not None:
+                        self._alloc_cols.block_gone(bid, gen)
                     dead_blocks.add(bid)
                 else:
                     self._alloc_blocks.put(bid, block, gen, live)

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass, field
+from itertools import repeat
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -483,56 +484,241 @@ def build_victim_tensors(ctx: EvalContext, cluster: "ClusterTensors",
                          evictable=evictable, net_prio=net_prio)
 
 
-@dataclass
 class SystemVictims:
     """A system group's victim columns (kernels.system_place): per node
     row, one column a preemptible allocation of the node as the plan
     leaves it, in (priority, alloc id) order, which is the exact host
     arm's candidate order (scheduler/preemption.py victim_candidates).
-    Host arrays; `ids[i]`, `groups[i]` name row i's columns. `holds`
-    says which rows carry a live allocation with a port number (any
-    allocation, preemptible or not): the port assignment of a row
-    without one and without reserved ports is the lowest numbers of its
-    dynamic range."""
+    Host arrays (`Np` node rows, `V` columns). `holds` says which rows
+    carry a live allocation with a port number (any allocation,
+    preemptible or not): the port assignment of a row without one and
+    without reserved ports is the lowest numbers of its dynamic range.
+    Which allocation and which group a column is, is resolved on demand
+    (`ids_at`, `groups_at`): a block position's id only for the columns
+    asked about. `kept` counts the rows read from the store's kept
+    columns rather than walked."""
 
-    v_pad: int
-    prio: np.ndarray       # (Np, V) priority, 0 on empty columns
-    vec: np.ndarray        # (Np, V, D) allocated_vec
-    valid: np.ndarray      # (Np, V) bool: a preemptible allocation
-    flagged: np.ndarray    # (Np, V) bool: holds ports or devices
-    mp: np.ndarray         # (Np, V) its group's migrate max_parallel
-    grp: np.ndarray        # (Np, V) int32 first column of its group
-    cnt0: np.ndarray       # (Np, V) its group's evictions in the plan
-    ids: List[List[str]]
-    groups: List[List[tuple]]
-    holds: np.ndarray      # (Np,) bool
-    n_victims: int = 0
+    def __init__(self, v_pad, prio, vec, valid, flagged, mp, grp, cnt0,
+                 holds, names, n_victims, kept):
+        self.v_pad = v_pad
+        self.prio = prio        # (Np, V) priority, 0 on empty columns
+        self.vec = vec          # (Np, V, D) allocated_vec
+        self.valid = valid      # (Np, V) bool: a preemptible allocation
+        self.flagged = flagged  # (Np, V) bool: holds ports or devices
+        self.mp = mp            # (Np, V) its group's migrate max_parallel
+        self.grp = grp          # (Np, V) int32 first column of its group
+        self.cnt0 = cnt0        # (Np, V) its group's evictions in the plan
+        self.holds = holds      # (Np,) bool
+        self._names = names
+        self.n_victims = n_victims
+        self.kept = kept
+
+    def ids_at(self, rows, cols) -> List[str]:
+        """The allocation id of each column (rows[i], cols[i])."""
+        return self._names.ids(np.asarray(rows), np.asarray(cols))
+
+    def groups_at(self, rows, cols) -> List[tuple]:
+        """(namespace, job id, task group) of each column."""
+        return self._names.groups(np.asarray(rows), np.asarray(cols))
 
 
-def build_system_victims(ctx: EvalContext, cluster: "ClusterTensors",
-                         current_priority: int,
-                         v_floor: int = 8) -> SystemVictims:
-    """The victim columns of every node of `cluster`, read from the
-    snapshot's per-node index entries without materialising a block
-    position: a row as it is, a block's node row position by position
-    (a position promoted to a row is that row, a GC'd one is gone), each
-    block's job, priority and port column once. A node the in-progress
-    plan touches is read from ctx.proposed_allocs instead (rare: a
-    destructive update, or an earlier group's placements). Eligibility
-    is preemption.is_preemptible's: priority at least PRIORITY_DELTA
-    under `current_priority`, not terminal, counting for usage."""
-    from ..scheduler.preemption import (PRIORITY_DELTA, max_parallel_of,
-                                        plan_preempted_counts,
-                                        victim_candidates,
-                                        victim_holds_exact_resources)
-    from ..state.store import BlockRef
+class _ColumnNames:
+    """Who each victim column is: `src[r, v]` indexes `refs`, an
+    allocation id, or a block id whose node row is `sub` (-1 for an
+    allocation row), the column then being that node row's `off[r,
+    v]`-th live position in id order at the snapshot; `gid[r, v]`
+    indexes `keys`, the groups."""
+
+    def __init__(self, snap, src, off, gid, refs, sub, keys):
+        self.snap = snap
+        self.src, self.off, self.gid = src, off, gid
+        self.refs, self.sub, self.keys = refs, sub, keys
+        self._positions: Dict[tuple, List[str]] = {}
+
+    def ids(self, rows, cols) -> List[str]:
+        src = self.src[rows, cols]
+        out = self.refs[src].tolist()
+        for i in np.flatnonzero(self.sub[src] >= 0).tolist():
+            names = self._positions_of(out[i], int(self.sub[src[i]]))
+            out[i] = names[int(self.off[rows[i], cols[i]])]
+        return out
+
+    def groups(self, rows, cols) -> List[tuple]:
+        keys = self.keys
+        return [keys[g] for g in self.gid[rows, cols].tolist()]
+
+    def _positions_of(self, block_id: str, m: int) -> List[str]:
+        """The ids of node row m's positions that are live and no row
+        shadows, in id order (what the store's slot for the row counts)."""
+        got = self._positions.get((block_id, m))
+        if got is None:
+            from ..structs.alloc import BLOCK_SEP
+
+            store, index = self.snap._store, self.snap.index
+            block = store._alloc_blocks.get(block_id, index)
+            prefix = block_id + BLOCK_SEP
+            rows = store._allocs
+            got = self._positions[(block_id, m)] = sorted(
+                prefix + str(p) for p in block.positions_for_row(m)
+                if p not in block.dropped
+                and rows.get(prefix + str(p), index) is None)
+        return got
+
+
+class _Kept:
+    """What _read_kept gathered: `ok` the node rows it read, `holds`
+    those holding a port number; one entry a column, in column order
+    (by node, then candidate order): `node`, `col`, `src` (its slot
+    among the gathered ones, whose `prio`, `vec`, `flag`, `mp`, `gid`
+    (into `keys`), `ref`, `sub` it takes) and `off` (its place among
+    its block row's positions)."""
+
+    def __init__(self, n: int):
+        self.ok = np.zeros(n, dtype=bool)
+        self.holds = np.zeros(0, np.int64)
+        self.node = self.col = self.src = self.off = np.zeros(0, np.int64)
+        self.prio = self.mp = self.gid = self.sub = np.zeros(0, np.int64)
+        self.vec = np.zeros((0, RESOURCE_DIMS))
+        self.flag = np.zeros(0, dtype=bool)
+        self.ref = np.zeros(0, dtype=object)
+        self.keys: List[tuple] = []
+
+
+def _stamps(cols, rows: np.ndarray) -> np.ndarray:
+    stamp = cols.stamp
+    out = np.full(len(rows), cols.base, np.int64)
+    inside = (rows >= 0) & (rows < len(stamp))
+    out[inside] = stamp[rows[inside]]
+    return out
+
+
+def _read_kept(snap, cluster, touched: set, top: int) -> _Kept:
+    """The victim columns of the nodes the store's kept allocation
+    columns (state/alloc_columns.py) hold as the snapshot sees them: no
+    write to the node's slots since the snapshot's index, read without a
+    lock (a stamp check before and after the gathers), and the plan
+    leaves the node alone. A node whose block row sits beside another
+    slot of the same block (a row of one of its positions, which sorts
+    inside it) is left out too: every other node's slots sort as
+    (priority, id) by their keys, the few equal keys by their ids."""
+    store, index = snap._store, snap.index
+    nodes = cluster.nodes
+    n = len(nodes)
+    out = _Kept(n)
+    cols = store.alloc_columns()
+    assignment = cols.assignment
+    n_rows = len(assignment.ids)
+    static = cluster.static
+    # the node set's rows, once a node-set version (a restore is a new
+    # version; and every row of columns built since stamps past it)
+    rows = (static.usage_rows if static is not None
+            and static.usage_rows is not None else
+            np.fromiter(map(assignment.get, (nd.id for nd in nodes),
+                            repeat(-1)), np.int64, n))
+    ok = (rows >= 0) & (rows < n_rows)
+    for nid in touched:
+        i = cluster.node_index.get(nid)
+        if i is not None:
+            ok[i] = False
+    ok &= _stamps(cols, rows) <= index
+    top_slot = cols.top
+    node = cols.node[:top_slot]
+    count, prio = cols.count[:top_slot], cols.prio[:top_slot]
+    inv = np.full(n_rows + 1, -1, np.int64)
+    inv[rows[ok]] = np.flatnonzero(ok)
+    ci = inv[np.where((node >= 0) & (node < n_rows), node, n_rows)]
+    live = np.flatnonzero((ci >= 0) & (count > 0))
+    holds = ci[live[cols.ports[:top_slot][live]]]
+    el = live[prio[live] <= top]
+    c_ci, c_count, c_prio = ci[el], count[el], prio[el]
+    c_vec, c_flag = cols.vec[el], cols.flag[el]
+    c_mp, c_key, c_unit = cols.mp[el], cols.key[el], cols.unit[el]
+    c_ref, c_sub = cols.ref[el], cols.sub[el]
+    c_grp = cols.grp[el]
+    local = np.zeros(len(cols.groups), np.int64)   # groups only grow
+    local[c_grp] = 1
+    gids = np.flatnonzero(local)
+    local[gids] = np.arange(len(gids))
+    c_gid = local[c_grp]
+    keys = [cols.groups[g] for g in gids.tolist()]
+    if store._alloc_cols is not cols:
+        return out   # a restore: every node is walked
+    ok &= _stamps(cols, rows) <= index
+    ok[_mixed(c_ci, c_unit, c_sub)] = False
+    keep = ok[c_ci]
+    c_ci, c_count, c_prio, c_vec, c_flag, c_mp, c_key, c_ref, c_sub, \
+        c_gid = (x[keep] for x in (c_ci, c_count, c_prio, c_vec, c_flag,
+                                   c_mp, c_key, c_ref, c_sub, c_gid))
+    out.ok, out.holds = ok, holds[ok[holds]]
+    order = _candidate_order(c_ci, c_prio, c_key, c_ref, c_sub, n)
+    cnt = c_count[order]
+    total = int(cnt.sum())
+    src = np.repeat(order, cnt)
+    out.node = c_ci[src]
+    step = np.arange(total)
+    first = np.flatnonzero(np.r_[True, out.node[1:] != out.node[:-1]]) \
+        if total else np.zeros(0, np.int64)
+    out.col = step - np.repeat(first, np.diff(np.r_[first, total]))
+    out.off = step - np.repeat(np.cumsum(cnt) - cnt, cnt)
+    out.src = src
+    out.prio, out.vec, out.flag, out.mp = c_prio, c_vec, c_flag, c_mp
+    out.gid, out.ref, out.sub, out.keys = c_gid, c_ref, c_sub, keys
+    return out
+
+
+def _mixed(ci, unit, sub) -> np.ndarray:
+    """Node indexes where a block's row slot has another slot of the
+    same block beside it (a row of one of its positions, or a second
+    row of the block): there the block does not sort as one unit."""
+    comb = (ci.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)) \
+        ^ unit.astype(np.uint64)
+    blk = sub >= 0
+    rows = ~blk & (unit != 0)
+    b = comb[blk]
+    uniq, counts = np.unique(b, return_counts=True)
+    return np.union1d(ci[rows][np.isin(comb[rows], b)],
+                      ci[blk][np.isin(b, uniq[counts > 1])])
+
+
+def _candidate_order(ci, prio, key, ref, sub, n: int) -> np.ndarray:
+    """The slots in column order: by node, then (priority, id), the
+    host arm's candidate order. One sort of a packed (node, priority,
+    key) number where the widths fit, else a lexsort; slots whose keys
+    tie are put in order by their ids (a block's row by its prefix)."""
     from ..structs.alloc import BLOCK_SEP
 
-    snap = ctx.snapshot
-    store, index = snap._store, snap.index
-    rows_t, blocks_t = store._allocs, store._alloc_blocks
-    by_node = store._allocs_by_node
-    plan = ctx.plan
+    if not len(ci):
+        return np.zeros(0, np.int64)
+    pmin = int(prio.min())
+    pb = int(int(prio.max()) - pmin).bit_length()
+    kb = 64 - max(int(n - 1).bit_length(), 1) - pb
+    if kb >= 16:
+        short = key >> np.uint64(64 - kb)
+        packed = ((ci.astype(np.uint64) << np.uint64(pb + kb))
+                  | ((prio - pmin).astype(np.uint64) << np.uint64(kb))
+                  | short)
+        order = np.argsort(packed, kind="quicksort")
+        ranked = packed[order]
+        same = ranked[1:] == ranked[:-1]
+    else:
+        order = np.lexsort((key, prio, ci))
+        a, b = order[1:], order[:-1]
+        same = (ci[a] == ci[b]) & (prio[a] == prio[b]) & (key[a] == key[b])
+    starts = np.flatnonzero(same & ~np.r_[False, same[:-1]])
+    for st in starts.tolist():
+        end = st
+        while end < len(same) and same[end]:
+            end += 1
+        run = order[st:end + 1]
+        names = [ref[j] + BLOCK_SEP if sub[j] >= 0 else ref[j]
+                 for j in run.tolist()]
+        order[st:end + 1] = run[sorted(range(len(run)),
+                                       key=names.__getitem__)]
+    return order
+
+
+def _plan_nodes(plan) -> set:
+    """The nodes an in-progress plan touches."""
     touched: set = set()
     if plan is not None:
         touched.update(plan.node_update, plan.node_preemptions,
@@ -541,75 +727,111 @@ def build_system_victims(ctx: EvalContext, cluster: "ClusterTensors",
             touched.update(plan.preempted.node_ids)
         for block in plan.alloc_blocks:
             touched.update(block.live_node_counts()[0])
-    top = current_priority - PRIORITY_DELTA
-    mp_of: Dict[tuple, int] = {}
+    return touched
 
-    def group_of(a) -> tuple:
-        key = (a.namespace, a.job_id, a.task_group)
-        if key not in mp_of:
-            mp_of[key] = max_parallel_of(a.job, a.task_group)
-        return key
 
-    def from_row(a, out, i) -> None:
+def _walk_node(ctx, node_id: str, touched: set, top: int,
+               current_priority: int):
+    """One node's candidates read from the snapshot's index entries
+    without materialising a block position: a row as it is, a block's
+    node row position by position (a position promoted to a row is that
+    row, a GC'd one is gone); a node the plan touches from
+    ctx.proposed_allocs. -> ([(priority, id, allocated_vec, flagged,
+    group, max_parallel)] in candidate order, holds a port number)."""
+    from ..scheduler.preemption import (max_parallel_of, victim_candidates,
+                                        victim_holds_exact_resources)
+    from ..state.store import BlockRef
+    from ..structs.alloc import BLOCK_SEP
+
+    def group(a) -> tuple:
+        return (a.namespace, a.job_id, a.task_group)
+
+    def cand(a) -> tuple:
+        return (a.job.priority, a.id, a.allocated_vec,
+                victim_holds_exact_resources(a), group(a),
+                max_parallel_of(a.job, a.task_group))
+
+    if node_id in touched:
+        proposed = ctx.proposed_allocs(node_id)
+        holds = any(a.allocated_ports for a in proposed
+                    if a.should_count_for_usage())
+        return ([cand(a) for a in victim_candidates(proposed,
+                                                    current_priority)],
+                holds)
+    snap = ctx.snapshot
+    store, index = snap._store, snap.index
+    rows_t, blocks_t = store._allocs, store._alloc_blocks
+    out: list = []
+    holds = False
+
+    def from_row(a) -> None:
+        nonlocal holds
         if a.allocated_ports:
-            holds[i] = True
+            holds = True
         if (a.job is not None and a.job.priority <= top
                 and a.should_count_for_usage()):
-            out.append((a.job.priority, a.id, a.allocated_vec,
-                        victim_holds_exact_resources(a), group_of(a)))
+            out.append(cand(a))
 
-    nodes = cluster.nodes
-    holds = np.zeros(cluster.n_pad, dtype=bool)
-    per_node: List[list] = []
-    for i, node in enumerate(nodes):
-        out: list = []
-        if node.id in touched:
-            proposed = ctx.proposed_allocs(node.id)
-            holds[i] = any(a.allocated_ports for a in proposed
-                           if a.should_count_for_usage())
-            out = [(a.job.priority, a.id, a.allocated_vec,
-                    victim_holds_exact_resources(a), group_of(a))
-                   for a in victim_candidates(proposed, current_priority)]
-            per_node.append(out)
+    for entry in snap._ids_from_index(store._allocs_by_node, node_id):
+        if type(entry) is not BlockRef:
+            a = rows_t.get(entry, index)
+            if a is not None and not a.terminal_status():
+                from_row(a)
             continue
-        for entry in snap._ids_from_index(by_node, node.id):
-            if type(entry) is not BlockRef:
-                a = rows_t.get(entry, index)
-                if a is not None and not a.terminal_status():
-                    from_row(a, out, i)
+        block = blocks_t.get(entry.block_id, index)
+        if block is None or entry.row in block.rejected_rows:
+            continue
+        job = block.job
+        ported = block.ports is not None
+        wanted = job is not None and job.priority <= top
+        if not wanted and not ported:
+            continue
+        prefix = block.id + BLOCK_SEP
+        key = (block.namespace, block.job_id, block.task_group)
+        mp = max_parallel_of(job, block.task_group) if wanted else 0
+        for p in block.positions_for_row(entry.row):
+            if p in block.dropped:
                 continue
-            block = blocks_t.get(entry.block_id, index)
-            if block is None or entry.row in block.rejected_rows:
+            aid = prefix + str(p)
+            row = rows_t.get(aid, index)
+            if row is not None:
+                if not row.terminal_status():
+                    from_row(row)
                 continue
-            job = block.job
-            ported = block.ports is not None
-            wanted = job is not None and job.priority <= top
-            if not wanted and not ported:
-                continue
-            prefix = block.id + BLOCK_SEP
+            if ported:
+                holds = True
             if wanted:
-                key = (block.namespace, block.job_id, block.task_group)
-                if key not in mp_of:
-                    mp_of[key] = max_parallel_of(job, block.task_group)
-            for p in block.positions_for_row(entry.row):
-                if p in block.dropped:
-                    continue
-                aid = prefix + str(p)
-                row = rows_t.get(aid, index)
-                if row is not None:
-                    if not row.terminal_status():
-                        from_row(row, out, i)
-                    continue
-                if ported:
-                    holds[i] = True
-                if wanted:
-                    out.append((job.priority, aid, block.allocated_vec,
-                                ported, key))
-        out.sort(key=lambda c: (c[0], c[1]))
-        per_node.append(out)
+                out.append((job.priority, aid, block.allocated_vec,
+                            ported, key, mp))
+    out.sort(key=lambda c: (c[0], c[1]))
+    return out, holds
 
+
+def build_system_victims(ctx: EvalContext, cluster: "ClusterTensors",
+                         current_priority: int,
+                         v_floor: int = 8) -> SystemVictims:
+    """The victim columns of every node of `cluster`. A node no write
+    has touched since the evaluation's snapshot, and which the
+    in-progress plan leaves alone, is read from the store's kept
+    per-node allocation columns with a few array operations
+    (_read_kept); every other node is walked from the snapshot's index
+    entries (_walk_node). Eligibility is preemption.is_preemptible's:
+    priority at least PRIORITY_DELTA under `current_priority`, not
+    terminal, counting for usage; max_parallel is the victim's own
+    job's, as the host arm reads it."""
+    from ..scheduler.preemption import PRIORITY_DELTA, plan_preempted_counts
+
+    plan = ctx.plan
+    touched = _plan_nodes(plan)
+    top = current_priority - PRIORITY_DELTA
+    nodes = cluster.nodes
+    kept = _read_kept(ctx.snapshot, cluster, touched, top)
+    walked = {i: _walk_node(ctx, nodes[i].id, touched, top,
+                            current_priority)
+              for i in np.flatnonzero(~kept.ok).tolist()}
     n_pad, d = cluster.n_pad, cluster.available.shape[1]
-    v_max = max((len(c) for c in per_node), default=0)
+    v_max = max([int(kept.col.max()) + 1 if len(kept.col) else 0]
+                + [len(c) for c, _ in walked.values()])
     v_pad = _pad_pow2(max(v_max, 1), floor=v_floor)
     prio = np.zeros((n_pad, v_pad))
     vec = np.zeros((n_pad, v_pad, d))
@@ -618,31 +840,62 @@ def build_system_victims(ctx: EvalContext, cluster: "ClusterTensors",
     mp = np.zeros((n_pad, v_pad))
     grp = np.zeros((n_pad, v_pad), dtype=np.int32)
     cnt0 = np.zeros((n_pad, v_pad))
+    src = np.full((n_pad, v_pad), -1, np.int64)
+    off = np.zeros((n_pad, v_pad), np.int64)
+    gid = np.zeros((n_pad, v_pad), np.int64)
+    holds = np.zeros(n_pad, dtype=bool)
     counts = plan_preempted_counts(plan)
-    ids: List[List[str]] = []
-    groups: List[List[tuple]] = []
-    total = 0
-    for i, cands in enumerate(per_node):
-        ids.append([c[1] for c in cands])
-        groups.append([c[4] for c in cands])
+
+    # the kept nodes, as arrays
+    keys = list(kept.keys)
+    at, col, s = kept.node, kept.col, kept.src
+    holds[kept.holds] = True
+    prio[at, col] = kept.prio[s]
+    vec[at, col] = kept.vec[s][:, :d]
+    valid[at, col] = True
+    flagged[at, col] = kept.flag[s]
+    mp[at, col] = kept.mp[s]
+    g = kept.gid[s]
+    _, first, back = np.unique(at * max(len(keys), 1) + g,
+                               return_index=True, return_inverse=True)
+    grp[at, col] = col[first][back.reshape(-1)]
+    cnt0[at, col] = np.array([counts.get(k, 0) for k in keys],
+                             dtype=np.float64)[g] if keys else 0.0
+    src[at, col] = s
+    off[at, col] = kept.off
+    gid[at, col] = g
+
+    # the walked nodes, node by node
+    refs: list = []
+    kid = {k: i for i, k in enumerate(keys) if k is not None}
+    for i, (cands, held) in walked.items():
+        holds[i] = held
         if not cands:
             continue
         k = len(cands)
-        total += k
         prio[i, :k] = [c[0] for c in cands]
         vec[i, :k] = [c[2][:d] for c in cands]
         valid[i, :k] = True
         flagged[i, :k] = [c[3] for c in cands]
-        first: Dict[tuple, int] = {}
+        mp[i, :k] = [c[5] for c in cands]
+        firsts: Dict[tuple, int] = {}
         for v, c in enumerate(cands):
-            key = c[4]
-            grp[i, v] = first.setdefault(key, v)
-            mp[i, v] = mp_of[key]
-            cnt0[i, v] = counts.get(key, 0)
-    return SystemVictims(v_pad=v_pad, prio=prio, vec=vec, valid=valid,
-                         flagged=flagged, mp=mp, grp=grp, cnt0=cnt0,
-                         ids=ids, groups=groups, holds=holds,
-                         n_victims=total)
+            grp[i, v] = firsts.setdefault(c[4], v)
+            cnt0[i, v] = counts.get(c[4], 0)
+            if c[4] not in kid:
+                kid[c[4]] = len(keys)
+                keys.append(c[4])
+            gid[i, v] = kid[c[4]]
+        at_ref = len(kept.ref) + len(refs)
+        src[i, :k] = np.arange(at_ref, at_ref + k)
+        refs.extend(c[1] for c in cands)
+    walked_refs = np.empty(len(refs), dtype=object)
+    walked_refs[:] = refs
+    names = _ColumnNames(
+        ctx.snapshot, src, off, gid, np.concatenate([kept.ref, walked_refs]),
+        np.concatenate([kept.sub, np.full(len(refs), -1, np.int64)]), keys)
+    return SystemVictims(v_pad, prio, vec, valid, flagged, mp, grp, cnt0,
+                         holds, names, int(valid.sum()), int(kept.ok.sum()))
 
 
 @dataclass

@@ -154,10 +154,13 @@ def _preempt_solve_host(available, used, ask, feasible, net_prio, active,
 # whose placement preempted, victims = allocations it preempted,
 # host_fallback_nodes = nodes it handed to the exact host scorer
 # (a victim holding ports or devices, or max_parallel penalties that
-# couple the node to an earlier one in the same launch).
+# couple the node to an earlier one in the same launch); column_nodes =
+# nodes whose victim columns were built, kept_column_nodes = those of
+# them read from the store's kept allocation columns (not walked).
 PREEMPT_STATS = {"kernel_preempted": 0, "host_preempted": 0,
                  "victim_parity_checked": 0, "preempting_nodes": 0,
-                 "victims": 0, "host_fallback_nodes": 0}
+                 "victims": 0, "host_fallback_nodes": 0,
+                 "column_nodes": 0, "kept_column_nodes": 0}
 _PREEMPT_STATS_LOCK = __import__("threading").Lock()
 # shapes (n_pad, k_pad, v_pad, d) already compiled: later launches of the
 # same shape run under a jit_guard no_retrace window (retrace there is a
@@ -1294,7 +1297,8 @@ class TPUPlacer:
         plan so far). The device's placements keep their order and get
         their port numbers under _PER_EVAL_SOLVE_LOCK, as the scan's do.
         Spans: system.place (args `nodes`, `preempting`) around it all,
-        preempt.columns (args `nodes`, `victims`) around the columns."""
+        preempt.columns (args `nodes`, `victims`, `kept`: nodes read from
+        the store's kept columns) around the columns."""
         from .cluster import build_system_victims
 
         with TRACER.span("system.place", nodes=len(targets)) as span:
@@ -1308,7 +1312,9 @@ class TPUPlacer:
             with TRACER.span("preempt.columns",
                              nodes=len(cluster.nodes)) as cspan:
                 vt = build_system_victims(ctx, cluster, job.priority)
-                cspan.set(victims=vt.n_victims)
+                cspan.set(victims=vt.n_victims, kept=vt.kept)
+            _count_preempt(column_nodes=len(cluster.nodes),
+                           kept_column_nodes=vt.kept)
             out = self._system_launch(ctx, tg, cluster, tgt, target, vt,
                                       preemption_enabled)
             self._system_fallbacks(ctx, job, tg, cluster, vt, out,
@@ -1427,12 +1433,12 @@ class TPUPlacer:
                             current_priority=job.priority)
         held: Dict[str, list] = {}
         for r in walk:
-            mine = [vt.groups[r][v] for v in range(len(vt.groups[r]))
-                    if vt.mp[r, v] > 0]
+            coupled_cols = np.flatnonzero(vt.valid[r] & (vt.mp[r] > 0))
+            mine = vt.groups_at(np.full(len(coupled_cols), r), coupled_cols)
             if r not in out.exact_rows and not any(
                     extra.get(key) for key in mine):
-                for v in np.flatnonzero(out.victims[r]).tolist():
-                    key = vt.groups[r][v]
+                chosen = np.flatnonzero(out.victims[r])
+                for key in vt.groups_at(np.full(len(chosen), r), chosen):
                     extra[key] = extra.get(key, 0) + 1
                 continue
             counts = dict(base)
