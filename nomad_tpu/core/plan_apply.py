@@ -41,6 +41,7 @@ import numpy as np
 from ..analysis.sanitizer import sanitized
 from ..obs import RECORDER, TRACER
 from ..structs import allocs_fit, enums
+from ..structs.funcs import foreign_devices
 from ..structs.plan import Plan, PlanResult
 from ..structs.resources import RESOURCE_DIMS
 
@@ -432,7 +433,8 @@ class PlanApplier:
         self._stop = threading.Event()
         self.stats = {"applied": 0, "nodes_verified": 0,
                       "nodes_verified_columnar": 0, "nodes_rejected": 0,
-                      "port_collisions": 0, "partial_commits": 0,
+                      "port_collisions": 0, "device_collisions": 0,
+                      "partial_commits": 0,
                       "commit_batches": 0, "batched_commits": 0,
                       "batched_eval_updates": 0}
         # commits are serialized through the commit thread, but the
@@ -1424,6 +1426,16 @@ class PlanApplier:
 
         check_devices = any(a.allocated_devices for a in proposed)
         fit, dim, _ = allocs_fit(node, proposed, check_devices=check_devices)
+        if fit and check_devices and foreign_devices(node, placements):
+            fit, dim = False, "device oversubscribed"
         if not fit and dim.startswith("port collision"):
             self._count_port_collisions(1)
+        elif not fit and dim == "device oversubscribed":
+            # a device instance held twice, or a new one not the node's:
+            # what the in-flight overlay's instances exist to prevent
+            from .metrics import REGISTRY
+
+            with self._stats_lock:
+                self.stats["device_collisions"] += 1
+            REGISTRY.incr("nomad.plan.device_collisions")
         return fit

@@ -169,6 +169,36 @@ def gpu_node(**overrides) -> Node:
     return n
 
 
+def nvidia_node(**overrides) -> Node:
+    """mock.node() with upstream's mock.NvidiaNode() device group: two
+    healthy NVIDIA 1080 Ti GPUs, their attributes typed with units as
+    the device plugin fingerprints them (nomad/mock/node.go)."""
+    n = node(**overrides)
+    add_nvidia_gpus(n)
+    return n
+
+
+def add_nvidia_gpus(n: Node, instance_ids=None) -> None:
+    """Give `n` NvidiaNode()'s device group (two instances, ids fresh
+    uuids unless given) and recompute its class. A unit-bearing
+    attribute is held in its string form, which is what travels over
+    the wire (structs/attribute.py reads it typed)."""
+    n.resources.devices = [
+        NodeDeviceResource(
+            vendor="nvidia", type="gpu", name="1080ti",
+            instance_ids=list(instance_ids or (generate_uuid(),
+                                               generate_uuid())),
+            attributes={
+                "memory": "11 GiB",
+                "cuda_cores": 3584,
+                "graphics_clock": "1480 MHz",
+                "memory_bandwidth": "11 GB/s",
+            },
+        )
+    ]
+    n.compute_class()
+
+
 # -- the ladder mix: BASELINE.md's cluster shape, used by chip_smoke.py
 #    and the sharded-solver tests ----------------------------------------
 

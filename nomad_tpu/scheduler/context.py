@@ -193,6 +193,33 @@ class EvalContext:
         idx.add_taken(held.get(node.id, ()))
         return idx
 
+    def device_index(self, node: Node, proposed: Optional[List] = None):
+        """Which device instances are taken on `node` for this
+        evaluation, as a DeviceIndex to assign from: those of
+        proposed_allocs(node.id) (or of `proposed`, where the caller has
+        it already or has taken its victims out) and those the in-flight
+        overlay holds there (tensor/overlay.py devices_on), the one
+        answer the per-placement tier and the host scorer share, as
+        port_index() is for ports."""
+        return self.device_indexes([node], proposed)[0]
+
+    def device_indexes(self, nodes: List[Node],
+                       proposed: Optional[List] = None) -> List:
+        """device_index() of each of `nodes`, the overlay read once
+        (before the snapshot's rows, as for ports)."""
+        from ..tensor.overlay import INFLIGHT
+        from .devices import DeviceIndex
+
+        held = INFLIGHT.devices_on([n.id for n in nodes],
+                                   getattr(self.snapshot, "index", None))
+        out = []
+        for node in nodes:
+            idx = DeviceIndex(node, self.proposed_allocs(node.id)
+                              if proposed is None else proposed)
+            idx.add_taken(held.get(node.id, {}))
+            out.append(idx)
+        return out
+
     def shuffled_nodes(self, nodes: List[Node], attempt: int = 0) -> List[Node]:
         """Deterministic shuffle seeded by eval id + retry attempt
         (reference scheduler/util.go:167 shuffleNodes, seeded by eval and

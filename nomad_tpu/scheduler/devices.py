@@ -15,38 +15,84 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..structs import Node
+from ..structs import Node, enums
+from ..structs.attribute import Attribute, as_attribute, parse_attribute
 from ..structs.resources import NodeDeviceResource, RequestedDevice
 from .feasible import check_constraint
 
 
-def resolve_device_target(target: str, group: NodeDeviceResource) -> Tuple[str, bool]:
-    """Resolve "${device.*}" interpolation against one device group
-    (reference structs/devices.go device constraint targets)."""
-    if not target.startswith("${device."):
-        return target, True  # literal
-    key = target[len("${device."):-1]
-    if key == "vendor":
-        return group.vendor, True
-    if key == "type":
-        return group.type, True
-    if key in ("model", "name"):
-        return group.name, True
-    if key.startswith("attr."):
-        val = group.attributes.get(key[len("attr."):])
-        return ("" if val is None else str(val)), val is not None
-    return "", False
+def resolve_device_target(target: str, group: NodeDeviceResource
+                          ) -> Tuple[Optional[Attribute], bool]:
+    """Resolve a "${device.*}" target against one device group, or parse
+    a literal, as a typed value (reference feasible.go
+    resolveDeviceTarget: a literal goes through ParseAttribute, so
+    "2 GiB" is two gibibytes)."""
+    if not target.startswith("${"):
+        return parse_attribute(target), True
+    if target in ("${device.model}", "${device.name}"):
+        return Attribute(group.name), True
+    if target == "${device.vendor}":
+        return Attribute(group.vendor), True
+    if target == "${device.type}":
+        return Attribute(group.type), True
+    if target.startswith("${device.attr.") and target.endswith("}"):
+        val = group.attributes.get(target[len("${device.attr."):-1])
+        return (None, False) if val is None else (as_attribute(val), True)
+    return None, False
+
+
+def check_attribute_constraint(operand: str, lval: Optional[Attribute],
+                               rval: Optional[Attribute], lfound: bool,
+                               rfound: bool, regex_cache=None,
+                               version_cache=None) -> bool:
+    """A device constraint on typed values (reference feasible.go
+    checkAttributeConstraint): ordering and equality through
+    Attribute.compare, so values of different unit families or types
+    fail; the string operators (version, regex, sets) on string values
+    only, by the node constraint's own rules."""
+    if operand in (enums.CONSTRAINT_DISTINCT_HOSTS,
+                   enums.CONSTRAINT_DISTINCT_PROPERTY):
+        return True
+    if operand in ("!=", "not"):
+        if not (lfound or rfound):
+            return False
+        if not (lfound and rfound):
+            return True
+        v, ok = lval.compare(rval)
+        return ok and v != 0
+    if operand == enums.CONSTRAINT_IS_SET:
+        return lfound
+    if operand == enums.CONSTRAINT_IS_NOT_SET:
+        return not lfound
+    if not (lfound and rfound):
+        return False
+    if operand in _ORDER:
+        v, ok = lval.compare(rval)
+        return ok and v in _ORDER[operand]
+    if not (isinstance(lval.value, str) and isinstance(rval.value, str)):
+        return False
+    return check_constraint(operand, lval.value, rval.value, True, True,
+                            regex_cache, version_cache)
+
+
+# the compare() results each ordering operator accepts
+_ORDER = {"=": (0,), "==": (0,), "is": (0,), "<": (-1,), "<=": (-1, 0),
+          ">": (1,), ">=": (0, 1)}
 
 
 def group_meets_constraints(group: NodeDeviceResource, ask: RequestedDevice,
                             regex_cache=None, version_cache=None) -> bool:
     for c in ask.constraints:
-        lval, lok = resolve_device_target(c.ltarget, group)
-        rval, rok = resolve_device_target(c.rtarget, group)
-        if not check_constraint(c.operand, lval, rval, lok, rok,
-                                regex_cache, version_cache):
+        if not _holds(c, group, regex_cache, version_cache):
             return False
     return True
+
+
+def _holds(c, group: NodeDeviceResource, regex_cache, version_cache) -> bool:
+    lval, lok = resolve_device_target(c.ltarget, group)
+    rval, rok = resolve_device_target(c.rtarget, group)
+    return check_attribute_constraint(c.operand, lval, rval, lok, rok,
+                                      regex_cache, version_cache)
 
 
 def matching_groups(node: Node, ask: RequestedDevice,
@@ -66,10 +112,7 @@ def group_affinity_score(group: NodeDeviceResource, ask: RequestedDevice,
     total, weights = 0.0, 0.0
     for aff in ask.affinities:
         weights += abs(aff.weight)
-        lval, lok = resolve_device_target(aff.ltarget, group)
-        rval, rok = resolve_device_target(aff.rtarget, group)
-        if check_constraint(aff.operand, lval, rval, lok, rok,
-                            regex_cache, version_cache):
+        if _holds(aff, group, regex_cache, version_cache):
             total += aff.weight
     return total / weights if weights else 0.0
 
@@ -106,12 +149,22 @@ class DeviceIndex:
     def __init__(self, node: Node, proposed_allocs: Sequence = ()):
         self.node = node
         self.used: Dict[str, set] = {}
+        # whether add_taken() knew of an instance the allocations did not
+        self.inflight = False
         for a in proposed_allocs:
             self.add_alloc(a)
 
     def add_alloc(self, alloc) -> None:
         for dev_id, instances in (alloc.allocated_devices or {}).items():
             self.used.setdefault(dev_id, set()).update(instances)
+
+    def add_taken(self, held: Dict[str, Sequence[str]]) -> None:
+        """Instances taken by what no allocation here shows: the
+        in-flight overlay's ({device group id: instance ids})."""
+        for dev_id, instances in held.items():
+            used = self.used.setdefault(dev_id, set())
+            self.inflight = self.inflight or not used.issuperset(instances)
+            used.update(instances)
 
     def assign(self, asks: Sequence[RequestedDevice],
                regex_cache=None, version_cache=None) -> Optional[Dict[str, List[str]]]:

@@ -309,27 +309,23 @@ def driver_mask(tg: TaskGroup, nodes: Sequence[Node]) -> np.ndarray:
 
 
 def device_mask(tg: TaskGroup, nodes: Sequence[Node]) -> np.ndarray:
-    """DeviceChecker (reference feasible.go:1259): node must have enough
-    instances of each requested device type (ignoring current usage —
-    usage is checked during ranking/fit)."""
-    asks = []
-    for t in tg.tasks:
-        for d in t.resources.devices:
-            asks.append(d)
+    """DeviceChecker (reference feasible.go:1259): the node must have
+    enough instances of each requested device among its groups that
+    match the ask's name and meet the ask's constraints (ignoring
+    current usage: usage is checked during ranking/fit). Instances are
+    counted over all such groups, as the count columns and DeviceIndex
+    count them (scheduler/devices.py groups_capacity)."""
+    from .devices import device_capacity
+
+    asks = [d for t in tg.tasks for d in t.resources.devices]
     if not asks:
         return np.ones(len(nodes), dtype=bool)
+    regex_cache: dict = {}
+    version_cache: dict = {}
     out = np.empty(len(nodes), dtype=bool)
     for i, node in enumerate(nodes):
-        ok = True
-        for ask in asks:
-            have = 0
-            for group in node.resources.devices:
-                if group.matches(ask.name):
-                    have += len(group.instance_ids)
-            if have < ask.count:
-                ok = False
-                break
-        out[i] = ok
+        out[i] = all(device_capacity(node, ask, regex_cache, version_cache)
+                     >= ask.count for ask in asks)
     return out
 
 
@@ -473,7 +469,9 @@ def tg_mask_signature(job: Job, tg: TaskGroup) -> tuple:
     """Cache key capturing every input of feasible_mask_static other than
     the node set itself."""
     drivers = tuple(sorted({t.driver for t in tg.tasks}))
-    devs = tuple(sorted((d.name, d.count)
+    devs = tuple(sorted((d.name, d.count,
+                         tuple((c.ltarget, c.operand, c.rtarget)
+                               for c in d.constraints))
                         for t in tg.tasks for d in t.resources.devices))
     modes = set()
     for net in tg.networks:

@@ -127,12 +127,11 @@ def preempt_for_task_group(
     proposed: Sequence[Allocation],
     ask_vec: np.ndarray,
     current_priority: int,
-    check_devices: bool = False,
-    ask_devices=(),
     preempted_counts: Optional[Dict[tuple, int]] = None,
 ) -> Optional[List[Allocation]]:
     """Pick a minimal set of lower-priority allocs whose removal lets the
-    ask fit (reference preemption.go:127 PreemptForTaskGroup). Returns
+    ask's resources fit (reference preemption.go:127 PreemptForTaskGroup;
+    device instances are preempt_for_device's, after this). Returns
     None/empty when impossible. `preempted_counts` carries per-(ns, job,
     tg) evictions already in the plan so migrate max_parallel penalties
     apply across the whole eval."""
@@ -149,15 +148,11 @@ def preempt_for_task_group(
     victims: List[Allocation] = []
     victim_ids = set()
 
-    placement = Allocation(
-        id="_cand", allocated_vec=ask_vec,
-        allocated_devices={d.name: ["?"] * d.count for d in ask_devices}
-        if check_devices else {})
+    placement = Allocation(id="_cand", allocated_vec=ask_vec)
 
     def fits_now() -> bool:
         remaining = [a for a in proposed if a.id not in victim_ids]
-        fit, _, _ = allocs_fit(node, remaining + [placement],
-                               check_devices=check_devices)
+        fit, _, _ = allocs_fit(node, remaining + [placement])
         return fit
 
     if fits_now():

@@ -180,7 +180,6 @@ class NodeScorer:
 
             victims = preempt_for_task_group(
                 node, proposed, self.ask_vec, self.current_priority,
-                check_devices=check_devices, ask_devices=self.ask.devices,
                 preempted_counts=self._plan_preempted_counts())
             if not victims:
                 if self.ctx.metrics is not None:
@@ -233,9 +232,9 @@ class NodeScorer:
                 victim_ids = {v.id for v in option.preempted_allocs}
                 counted_for_ids = [a for a in proposed if a.id not in victim_ids]
         if self.ask.devices:
-            from .devices import DeviceIndex, device_affinity_boost
+            from .devices import device_affinity_boost
 
-            didx = DeviceIndex(node, counted_for_ids)
+            didx = self.ctx.device_index(node, counted_for_ids)
             assignment = didx.assign(self.ask.devices,
                                      self.ctx.regex_cache, self.ctx.version_cache)
             if assignment is None and self.preemption_enabled:
@@ -252,7 +251,7 @@ class NodeScorer:
                     victim_ids = {v.id for v in option.preempted_allocs}
                     counted_for_ids = [a for a in counted_for_ids
                                        if a.id not in victim_ids]
-                    didx = DeviceIndex(node, counted_for_ids)
+                    didx = self.ctx.device_index(node, counted_for_ids)
                     assignment = didx.assign(self.ask.devices,
                                              self.ctx.regex_cache,
                                              self.ctx.version_cache)

@@ -62,15 +62,20 @@ def allocs_fit(node, allocs: Iterable, check_devices: bool = False):
     Mirrors reference funcs.go:141 AllocsFit: client-terminal allocs are
     free; reserved cores must not overlap; assigned ports must not
     collide (with each other or the node's agent-reserved ports); used
-    must be a subset of available (total - reserved); optional device
-    oversubscription check. The port check is what lets the serialized
-    plan applier catch two concurrent plans double-booking a port
+    must be a subset of available (total - reserved); optionally, no
+    device instance of the node held twice (reference
+    DeviceAccounter.AddAllocs, which skips an instance the node no longer
+    has: `foreign_devices` is the applier's test of new placements for
+    that). The port and instance checks are what let the serialized plan
+    applier catch two concurrent plans double-booking a port or a GPU
     (reference plan_apply.go evaluateNodePlan -> AllocsFit)."""
     allocs = list(allocs)
     used = np.zeros(RESOURCE_DIMS, dtype=np.float64)
     seen_cores: set = set()
     core_overlap = False
-    dev_used: dict = {}
+    dev_held: set = set()
+    dev_clash = False
+    node_devs = _node_instances(node) if check_devices else frozenset()
     any_ports = False
 
     for alloc in allocs:
@@ -84,7 +89,10 @@ def allocs_fit(node, allocs: Iterable, check_devices: bool = False):
             seen_cores.add(core)
         if check_devices:
             for dev_id, inst in alloc.allocated_devices.items():
-                dev_used[dev_id] = dev_used.get(dev_id, 0) + len(inst)
+                for i in inst:
+                    if (dev_id, i) in node_devs:
+                        dev_clash = dev_clash or (dev_id, i) in dev_held
+                        dev_held.add((dev_id, i))
 
     if core_overlap:
         return False, "cores", used
@@ -101,13 +109,26 @@ def allocs_fit(node, allocs: Iterable, check_devices: bool = False):
     if over.any():
         return False, dim_name(int(np.argmax(over))), used
 
-    if check_devices:
-        for group in node.resources.devices:
-            cap = len(group.instance_ids)
-            if dev_used.get(group.id, 0) > cap:
-                return False, "device oversubscribed", used
+    if dev_clash:
+        return False, "device oversubscribed", used
 
     return True, "", used
+
+
+def _node_instances(node) -> frozenset:
+    return frozenset((g.id, i) for g in node.resources.devices
+                     for i in g.instance_ids)
+
+
+def foreign_devices(node, allocs: Iterable) -> bool:
+    """Does any of these allocs hold a device instance that is no
+    instance of a group of the node? The applier asks it of a plan's new
+    placements only: a running alloc whose instance the node has since
+    dropped is skipped, as allocs_fit skips it, and does not close the
+    node to later plans."""
+    held = {(dev_id, i) for a in allocs
+            for dev_id, inst in a.allocated_devices.items() for i in inst}
+    return bool(held) and not held <= _node_instances(node)
 
 
 def proposed_usage(allocs: Iterable) -> np.ndarray:

@@ -16,6 +16,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .constraint import Affinity, Constraint
+
 # Dense resource dimensions. Order is load-bearing: tensorization and the
 # JAX kernels index by these constants.
 R_CPU = 0    # MHz of cpu shares
@@ -61,8 +63,8 @@ class RequestedDevice:
 
     name: str = ""          # vendor/type[/name] selector
     count: int = 1
-    constraints: list = field(default_factory=list)
-    affinities: list = field(default_factory=list)
+    constraints: List[Constraint] = field(default_factory=list)
+    affinities: List[Affinity] = field(default_factory=list)
 
 
 @dataclass(slots=True)

@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import TRACER
 from ..structs import Job, Node, TaskGroup, enums
 from ..structs.resources import RESOURCE_DIMS
 from ..scheduler.context import EvalContext
@@ -925,6 +926,9 @@ class TaskGroupTensors:
     extra_used: np.ndarray = None   # (Np, E)
     extra_ask: np.ndarray = None    # (E,)
     dev_affinity: np.ndarray = None  # (Np,) device-affinity sub-score
+    # per node, per device ask: the ids of the node's device groups that
+    # serve it (the capacity columns' own match; None without an ask)
+    dev_groups: list = None
     # distinct_property cap tables (reference propertyset.go); P
     # interned row pairs as spread_rows
     dp_rows: tuple = ()
@@ -1114,29 +1118,39 @@ def _spread_tensors(ctx: EvalContext, job: Job, tg: TaskGroup,
 def _device_core_tensors(ctx: EvalContext, tg: TaskGroup,
                          cluster: ClusterTensors):
     """Per-ask device capacity/usage columns + a reserved-cores column +
-    the device-affinity sub-score vector. Capacity is constraint-filtered
-    per ask (reference feasible.go:1259 DeviceChecker + device.go); usage
-    comes from the store's device-usage rows plus plan deltas.
+    the device-affinity sub-score vector + per node and ask the matched
+    group ids. Capacity is constraint-filtered per ask (reference
+    feasible.go:1259 DeviceChecker + device.go); usage comes from the
+    store's device-usage rows plus plan deltas (the in-flight overlay's
+    instances are added under the solve lock: fold_inflight_devices).
+    Span `build.device_columns` (a group that asks a device or cores).
 
     Count-fit on the device is intentionally slightly optimistic when
     several asks share one group's instances or NUMA "require" constrains
     core identity: the post-solve host assignment catches those and falls
     back per request (same contract as exact port numbers)."""
-    from ..scheduler.devices import (accumulate_dev_usage,
-                                     combined_numa_affinity,
-                                     device_affinity_boost, groups_capacity,
-                                     matching_groups)
-
     ask_res = ctx.tg_resources(tg)
     asks = ask_res.devices
     cores = int(ask_res.cores)
     e = len(asks) + (1 if cores else 0)
+    if e == 0:
+        n_pad = cluster.n_pad
+        z = np.zeros((n_pad, 0))
+        return z, z, np.zeros(0), np.zeros(n_pad), None
+    with TRACER.span("build.device_columns", asks=len(asks),
+                     cores=bool(cores)):
+        return _device_core_columns(ctx, asks, cores, cluster)
+
+
+def _device_core_columns(ctx: EvalContext, asks, cores: int,
+                         cluster: ClusterTensors):
+    from ..scheduler.devices import (accumulate_dev_usage,
+                                     device_affinity_boost, groups_capacity,
+                                     matching_groups)
+
+    e = len(asks) + (1 if cores else 0)
     nodes = cluster.nodes
     n_pad = cluster.n_pad
-    if e == 0:
-        z = np.zeros((n_pad, 0))
-        return z, z, np.zeros(0), np.zeros(n_pad), "none"
-
     snap = ctx.snapshot
     used = np.zeros((n_pad, e))
     any_affinities = any(a.affinities for a in asks)
@@ -1192,7 +1206,38 @@ def _device_core_tensors(ctx: EvalContext, tg: TaskGroup,
             used[i, -1] = row.get("cores", 0)
     extra_ask = np.array([float(a.count) for a in asks]
                          + ([float(cores)] if cores else []))
-    return cap, used, extra_ask, dev_aff, combined_numa_affinity(tg)
+    return cap, used, extra_ask, dev_aff, match_lists if asks else None
+
+
+def fold_inflight_devices(extra_used: np.ndarray, cluster: ClusterTensors,
+                          dev_groups: list, held: list) -> None:
+    """Add the device instances the in-flight overlay holds (`held`:
+    [{node id: {device group id: instance ids}}], one map an entry,
+    INFLIGHT.devices_held) to a device-asking group's count columns
+    (`extra_used`, in place), each through the groups that serve its
+    ask on that node (`dev_groups`, TaskGroupTensors.dev_groups):
+    racing evaluations' instances, as their usage is folded into the
+    dense dims. Called under the solve lock: one pass over the entries'
+    nodes in the interpreter, one indexed add."""
+    index = cluster.node_index
+    rows, cols, adds = [], [], []
+    for by_node in held:
+        for node_id, groups in by_node.items():
+            i = index.get(node_id)
+            if i is None or i >= len(dev_groups):
+                continue
+            for ei, gids in enumerate(dev_groups[i]):
+                n = 0
+                for gid in gids:
+                    ids = groups.get(gid)
+                    if ids:
+                        n += len(ids)
+                if n:
+                    rows.append(i)
+                    cols.append(ei)
+                    adds.append(n)
+    if rows:
+        np.add.at(extra_used, (rows, cols), adds)
 
 
 def _distinct_property_tensors(ctx: EvalContext, job: Job, tg: TaskGroup,
@@ -1293,8 +1338,8 @@ def build_task_group_tensors(
         feas[: len(nodes)] &= reserved_ports_mask(tg, nodes, ctx.proposed_allocs)
         dh_tg = True
 
-    extra_cap, extra_used, extra_ask, dev_aff, _ = _device_core_tensors(
-        ctx, tg, cluster)
+    extra_cap, extra_used, extra_ask, dev_aff, dev_groups = (
+        _device_core_tensors(ctx, tg, cluster))
     dp_rows, dp_counts, dp_limit = _distinct_property_tensors(
         ctx, job, tg, cluster)
 
@@ -1317,6 +1362,7 @@ def build_task_group_tensors(
         extra_used=extra_used,
         extra_ask=extra_ask,
         dev_affinity=dev_aff,
+        dev_groups=dev_groups,
         dp_rows=dp_rows,
         dp_counts=dp_counts,
         dp_limit=dp_limit,

@@ -22,19 +22,22 @@ backstop for evals that die between solve and submit. Like the carry,
 this is optimism-repair only — the serialized plan applier remains the
 correctness gate.
 
-Ports ride in the same entries. Usage is additive and read live, so an
-entry's usage dies when its plan is applied (the committed usage takes
-over). A port is a number, and the ports an evaluation sees committed
-are those of ITS OWN SNAPSHOT (EvalContext.proposed_allocs): a plan
-applied after that snapshot was taken is in neither the snapshot nor
-the open entries, and the evaluation would hand its numbers out again
-on the same half-filled node. So an entry that carries ports outlives
-its commit: confirm() drops the ports of the nodes the applier
-rejected, stamps the rest with the store's index (at or past the
-commit's), and keeps them readable for evaluations whose snapshot is
-older than that index, until the store's snapshot tracker says none
-such is left (or ENTRY_TTL after the commit, whichever comes first).
-ports_on() is the one reader; structs/network.py has the whole rule.
+Ports and device instances ride in the same entries ("held" values:
+ids handed out on a node, as opposed to usage, which is a sum). Usage is
+additive and read live, so an entry's usage dies when its plan is
+applied (the committed usage takes over). A port or an instance is an
+id, and the ids an evaluation sees committed are those of ITS OWN
+SNAPSHOT (EvalContext.proposed_allocs): a plan applied after that
+snapshot was taken is in neither the snapshot nor the open entries, and
+the evaluation would hand the same ids out again on the same
+half-filled node. So an entry that holds ids outlives its commit:
+confirm() drops the ids on the nodes the applier rejected, stamps the
+rest with the store's index (at or past the commit's), and keeps them
+readable for evaluations whose snapshot is older than that index, until
+the store's snapshot tracker says none such is left (or ENTRY_TTL after
+the commit, whichever comes first). One rule for every kind of id;
+ports_on() and devices_on() are its readers. structs/network.py has the
+rule for ports, scheduler/devices.py DeviceIndex for instances.
 """
 
 from __future__ import annotations
@@ -55,26 +58,34 @@ class InflightOverlay:
         self._lock = threading.Lock()
         self._entries: Dict[int, dict] = {}  # token -> open entry
         self._token = 0
-        # node id -> {token: the port numbers that entry holds there},
-        # of open entries and of closed ones alike
+        # node id -> {token: what that entry holds there}, of open
+        # entries and of closed ones alike: port numbers, and device
+        # instances as {device group id: [instance ids]}
         self._ports: Dict[str, Dict[int, Sequence[int]]] = {}
-        # token -> (store index at the close, closed at, the store,
-        # node ids): entries whose plan is applied and whose ports an
-        # evaluation with an older snapshot still has to see
+        self._devices: Dict[str, Dict[int, Dict[str, Sequence[str]]]] = {}
+        self._held = {"ports": self._ports, "devices": self._devices}
+        # token -> (store index at the close, closed at, the store, what
+        # it holds by kind and node): entries whose plan is applied and
+        # whose ids an evaluation with an older snapshot still has to see
         self._closed: Dict[int, tuple] = {}
         self.stats = {"registered": 0, "confirmed": 0, "expired": 0,
-                      "ports_retired": 0}
+                      "retired": 0}
 
-    def register(self, cluster, rows, deltas, plan, ports=None) -> None:
+    def register(self, cluster, rows, deltas, plan, ports=None,
+                 devices=None) -> None:
         """Record one eval's in-flight usage: `deltas[i]` (a resource
         vector) on row `rows[i]` of `cluster` (a ClusterTensors; each
         row once), and arrange for the plan outcome to close the entry
         (planner contract: hooks fire with the commit). The entry keeps
         its rows for the row order it was registered against, so a fold
         into that order is one indexed add. `ports` ({node id: the port
-        numbers handed out there}) makes them readable through
-        ports_on() by every evaluation of this process."""
-        if not len(rows) and not ports:
+        numbers handed out there}) and `devices` ({node id: {device
+        group id: the instance ids handed out there}}) make them
+        readable through ports_on() / devices_on() by every evaluation
+        of this process."""
+        held = {kind: by_node for kind, by_node in
+                (("ports", ports), ("devices", devices)) if by_node}
+        if not len(rows) and not held:
             return
         now = time.time()
         with self._lock:
@@ -83,10 +94,12 @@ class InflightOverlay:
             self._entries[token] = {
                 "rows": rows, "deltas": deltas, "nodes": cluster.nodes,
                 "node_index": cluster.node_index, "born": now,
-                "plan": id(plan), "ports": ports,
+                "plan": id(plan), "held": held,
                 "store": getattr(cluster, "_store", None)}
-            for node_id, values in (ports or {}).items():
-                self._ports.setdefault(node_id, {})[token] = values
+            for kind, by_node in held.items():
+                table = self._held[kind]
+                for node_id, values in by_node.items():
+                    table.setdefault(node_id, {})[token] = values
             self.stats["registered"] += 1
         if plan is not None:
             plan.post_apply_hooks.append(
@@ -110,23 +123,27 @@ class InflightOverlay:
             if entry is None:
                 return
             self.stats["confirmed"] += 1
-            if entry["ports"]:
+            if entry["held"]:
                 rejected = set(rejected_node_ids)
-                self._forget_ports(token, rejected)
-                kept = [n for n in entry["ports"] if n not in rejected]
-                if kept:
+                self._forget(token, rejected)
+                held = {kind: kept for kind, kept in (
+                    (kind, {n: v for n, v in by_node.items()
+                            if n not in rejected})
+                    for kind, by_node in entry["held"].items()) if kept}
+                if held:
                     store = entry["store"]
                     self._closed[token] = (
                         None if store is None else store.latest_index,
-                        now, store, kept)
+                        now, store, held)
             self._retire(now)
 
-    def _forget_ports(self, token: int, node_ids: Iterable[str]) -> None:
+    def _forget(self, token: int, node_ids: Iterable[str]) -> None:
         for node_id in node_ids:
-            held = self._ports.get(node_id)
-            if held is not None and held.pop(token, None) is not None \
-                    and not held:
-                del self._ports[node_id]
+            for table in self._held.values():
+                held = table.get(node_id)
+                if held is not None and held.pop(token, None) is not None \
+                        and not held:
+                    del table[node_id]
 
     def retire(self) -> None:
         """Drop the closed entries nobody can need: every live snapshot
@@ -137,7 +154,7 @@ class InflightOverlay:
 
     def _retire(self, now: float) -> None:
         floors: dict = {}
-        for token, (index, at, store, node_ids) in list(self._closed.items()):
+        for token, (index, at, store, held) in list(self._closed.items()):
             if now - at <= ENTRY_TTL:
                 if index is None:
                     continue
@@ -148,34 +165,68 @@ class InflightOverlay:
                 if floor < index:
                     continue
             del self._closed[token]
-            self._forget_ports(token, node_ids)
-            self.stats["ports_retired"] += 1
+            self._forget(token, _nodes_of(held))
+            self.stats["retired"] += 1
 
     def ports_on(self, node_ids: Iterable[str],
                  snapshot_index: Optional[int]) -> Dict[str, set]:
         """{node id: port numbers} that entries hold on `node_ids` and
         an evaluation whose snapshot is at `snapshot_index` cannot see
-        committed: those of every open entry (the evaluation's own
-        among them: a port is a number, not a sum, and its own
-        placements that are not rows of its plan yet must not be handed
-        out twice either) and of every closed one stamped past that
-        index. Nodes without any are left out."""
+        committed (`_visible`). Nodes without any are left out."""
         out: Dict[str, set] = {}
+        for node_id, values in self._visible(self._ports, node_ids,
+                                             snapshot_index):
+            out.setdefault(node_id, set()).update(values)
+        return out
+
+    def devices_on(self, node_ids: Iterable[str],
+                   snapshot_index: Optional[int]) -> Dict[str, Dict[str, set]]:
+        """{node id: {device group id: instance ids}} that entries hold
+        on `node_ids` and an evaluation whose snapshot is at
+        `snapshot_index` cannot see committed (`_visible`). Nodes
+        without any are left out."""
+        out: Dict[str, Dict[str, set]] = {}
+        for node_id, groups in self._visible(self._devices, node_ids,
+                                             snapshot_index):
+            mine = out.setdefault(node_id, {})
+            for gid, ids in groups.items():
+                mine.setdefault(gid, set()).update(ids)
+        return out
+
+    def devices_held(self, snapshot_index: Optional[int],
+                     exclude_plan=None) -> list:
+        """[{node id: {device group id: instance ids}}], one map an
+        entry, of the entries devices_on() reads (the same rule, taken
+        entry by entry rather than node by node, and without a set a
+        node): what the count columns' fold adds
+        (cluster.fold_inflight_devices)."""
+        exclude = id(exclude_plan) if exclude_plan is not None else None
         with self._lock:
-            if not self._ports:
-                return out
-            closed = self._closed
+            out = [e["held"]["devices"] for e in self._entries.values()
+                   if "devices" in e["held"] and e["plan"] != exclude]
+            out.extend(
+                stamp[3]["devices"] for stamp in self._closed.values()
+                if "devices" in stamp[3] and _unseen(stamp, snapshot_index))
+        return out
+
+    def _visible(self, table, node_ids, snapshot_index):
+        """(node id, what one entry holds there) of every open entry
+        (the evaluation's own among them: an id is not a sum, and its
+        own placements that are not rows of its plan yet must not be
+        handed out twice either) and of every closed one stamped past
+        `snapshot_index`, on `node_ids`."""
+        with self._lock:
+            if not table:
+                return []
+            closed, out = self._closed, []
             for node_id in node_ids:
-                held = self._ports.get(node_id)
+                held = table.get(node_id)
                 if not held:
                     continue
                 for token, values in held.items():
-                    stamp = closed.get(token)
-                    if (stamp is None or stamp[0] is None
-                            or snapshot_index is None
-                            or stamp[0] > snapshot_index):
-                        out.setdefault(node_id, set()).update(values)
-        return out
+                    if _unseen(closed.get(token), snapshot_index):
+                        out.append((node_id, values))
+            return out
 
     def open_entries(self, exclude_plan=None) -> list:
         """The live (non-TTL-expired) entries not owned by
@@ -196,9 +247,9 @@ class InflightOverlay:
                 dead = [t for t, e in self._entries.items()
                         if now - e["born"] > ENTRY_TTL]
                 for t in dead:
-                    self._forget_ports(t, self._entries.pop(t)["ports"] or ())
+                    self._forget(t, _nodes_of(self._entries.pop(t)["held"]))
                     self.stats["expired"] += 1
-                # an entry of ports alone (the host scorer's) has no usage
+                # an entry of ids alone (the host scorer's) has no usage
                 out = [e for e in self._entries.values()
                        if (e.get("plan") != exclude or exclude is None)
                        and len(e["rows"])]
@@ -228,6 +279,22 @@ class InflightOverlay:
                 used[rows] += deltas
                 added += len(rows)
             span.set(rows=added, remapped=remapped)
+
+
+def _unseen(stamp, snapshot_index: Optional[int]) -> bool:
+    """May an evaluation whose snapshot is at `snapshot_index` not see
+    what the entry holds as committed? `stamp` is its `_closed` record
+    (index, closed at, store, held), None while it is open: yes while
+    open, and after its commit while the commit's index is unknown or
+    past the snapshot."""
+    return (stamp is None or stamp[0] is None or snapshot_index is None
+            or stamp[0] > snapshot_index)
+
+
+def _nodes_of(held: dict) -> set:
+    """The node ids on which an entry's held ids ({kind: {node id:
+    ids}}) lie, of any kind."""
+    return {node_id for by_node in held.values() for node_id in by_node}
 
 
 INFLIGHT = InflightOverlay()

@@ -12,9 +12,10 @@ Lowering strategy per evaluation:
      plan object and ctx.proposed_allocs stay authoritative. Exact port
      numbers, device instance ids, and core ids are assigned host-side
      per chosen node after the solve (counts were fit on-device): the
-     ports while the solve lock is still held, so that they go into
-     the in-flight overlay with the usage they belong to and racing
-     evaluations never pick the same number (structs/network.py), the
+     ports and the device instances while the solve lock is still
+     held, so that they go into the in-flight overlay with the usage
+     they belong to and racing evaluations never pick the same number
+     or instance (structs/network.py, scheduler/devices.py), the core
      ids in the row loop. A group of fresh placements that needs none
      of them is committed as one AllocBlock, from the scan as from the
      count solve.
@@ -346,21 +347,22 @@ class TPUPlacer:
 
                 REGISTRY.incr("nomad.placer.host_cutover_groups")
                 held: Dict[str, list] = {}
+                held_devices: Dict[str, dict] = {}
                 for req in reqs:
                     option = self._host_one(ctx, job, tg, nodes, req,
                                             batch, preemption_enabled,
                                             attempt)
                     commit(req, option)
-                    if option is not None and option.allocated_ports:
-                        held.setdefault(option.node.id, []).extend(
-                            p.value for p in option.allocated_ports)
-                if held and ctx.plan is not None:
-                    # the scorer read the overlay's ports (ctx.port_index);
-                    # what it chose goes there in turn, for the
-                    # evaluations racing behind it
+                    _hold(option, held, held_devices)
+                if (held or held_devices) and ctx.plan is not None:
+                    # the scorer read the overlay's ports and instances
+                    # (ctx.port_index, ctx.device_index); what it chose
+                    # goes there in turn, for the evaluations racing
+                    # behind it
                     from .overlay import INFLIGHT
 
-                    INFLIGHT.register(cluster, (), None, ctx.plan, held)
+                    INFLIGHT.register(cluster, (), None, ctx.plan, held,
+                                      held_devices)
                 continue
 
             tgt = (prebuilt_tgt if prebuilt_tgt is not None
@@ -382,36 +384,42 @@ class TPUPlacer:
             for i, req in enumerate(reqs):
                 if req.ignore_node:
                     penalty_idx[i] = cluster.node_index.get(req.ignore_node, -1)
-            choices, founds, scores, ports = self._scan_group(
+            choices, founds, scores, ports, devices = self._scan_group(
                 ctx, tg, cluster, tgt, len(reqs), penalty_idx, tie_perm)
             with TRACER.span("placer.rows", k=len(reqs)):
                 self._place_rows(ctx, job, tg, reqs, cluster, tgt, commit,
-                                 choices, founds, scores, ports,
+                                 choices, founds, scores, ports, devices,
                                  batch=batch,
                                  preemption_enabled=preemption_enabled,
                                  attempt=attempt)
 
     def _place_rows(self, ctx, job, tg, reqs, cluster, tgt, commit,
-                    choices, founds, scores, ports, *, batch: bool,
-                    preemption_enabled: bool, attempt: int) -> None:
+                    choices, founds, scores, ports, devices, *,
+                    batch: bool, preemption_enabled: bool,
+                    attempt: int) -> None:
         """The scan's placements handed over one row each: a RankedNode,
         an AllocMetric and (in commit) an Allocation a placement. Exact
-        port numbers were chosen under the solve lock (`ports`:
-        _assign_ports); device instances and core ids are assigned
-        here, per chosen node (the kernel only fit-checked the counts),
-        with per-node indexes that carry the assignments across this
-        group's placements so they don't double-book."""
+        port numbers and device instances were chosen under the solve
+        lock (`ports`: _assign_ports, `devices`: _assign_devices); core
+        ids are assigned here, per chosen node (the kernel only
+        fit-checked the counts), with per-node sets that carry the
+        assignments across this group's placements so they don't
+        double-book. A node that ran short of instances sends its
+        request to the host selector alone."""
         nodes = cluster.nodes
         ask_res = ctx.tg_resources(tg)
-        wants_devices = bool(ask_res.devices)
         wants_cores = bool(ask_res.cores)
         numa_pol = "none"
         if wants_cores:
             from ..scheduler.devices import combined_numa_affinity
 
             numa_pol = combined_numa_affinity(tg)
-        dev_idx: Dict[int, object] = {}
         core_used: Dict[int, set] = {}
+        # what the host selector chose for a device-asking group's
+        # requests: registered with the overlay below, as the host
+        # cut-over registers its own
+        held_ports: Dict[str, list] = {}
+        held_devices: Dict[str, dict] = {}
 
         n_feasible = int(tgt.feasible[: len(nodes)].sum())
         preempt_queue: List[PlacementRequest] = []
@@ -434,28 +442,35 @@ class TPUPlacer:
                         commit(req, None)
                         continue
                     option.allocated_ports = mine
-                if wants_devices or wants_cores:
-                    ok = self._assign_ids(ctx, ask_res, numa_pol, ni, node,
-                                          option, dev_idx, core_used)
-                    if not ok:
-                        # count-fit admitted a node the exact id
-                        # assignment can't satisfy (NUMA require /
-                        # overlapping asks): host selector for this
-                        # request alone (its ports from ctx.port_index,
-                        # which holds what the hold chose for the rest)
-                        option = self._host_one(ctx, job, tg, nodes, req,
-                                                batch, preemption_enabled,
-                                                attempt)
-                        commit(req, option)
-                        if option is not None:
-                            # the fallback assigned ids on its own
-                            # node; drop that node's caches so later
-                            # kernel placements rebuild them from the
-                            # committed plan instead of double-booking
-                            self._invalidate_node(
-                                cluster, option.node.id,
-                                dev_idx, core_used)
-                        continue
+                ok = True
+                if devices is not None:
+                    got = devices[ni].pop() if devices[ni] else None
+                    ok = got is not None
+                    option.allocated_devices = got or {}
+                if ok and wants_cores:
+                    ok = self._assign_cores(ctx, ask_res, numa_pol, ni,
+                                            node, option, core_used)
+                if not ok:
+                    # count-fit admitted a node the exact assignment
+                    # can't satisfy (instances taken by a racing
+                    # evaluation / NUMA require / overlapping asks): host
+                    # selector for this request alone (its ports and
+                    # instances from ctx.port_index / ctx.device_index,
+                    # which hold what the hold chose for the rest)
+                    option = self._host_one(ctx, job, tg, nodes, req,
+                                            batch, preemption_enabled,
+                                            attempt)
+                    commit(req, option)
+                    if option is not None:
+                        # the fallback assigned ids on its own node;
+                        # drop that node's cores so later kernel
+                        # placements rebuild them from the committed
+                        # plan instead of double-booking
+                        self._invalidate_node(cluster, option.node.id,
+                                              core_used)
+                        if devices is not None:
+                            _hold(option, held_ports, held_devices)
+                    continue
                 commit(req, option)
                 continue
             if preemption_enabled:
@@ -463,13 +478,18 @@ class TPUPlacer:
                 continue
             self._attribute_failure(ctx, metrics, len(nodes), n_feasible)
             commit(req, None)
+        if (held_ports or held_devices) and ctx.plan is not None:
+            from .overlay import INFLIGHT
+
+            INFLIGHT.register(cluster, (), None, ctx.plan, held_ports,
+                              held_devices)
         if preempt_queue:
             self._preempt_batch(
                 ctx, job, tg, preempt_queue, cluster, tgt, commit,
                 sched_batch=batch, attempt=attempt,
                 n_feasible=n_feasible,
                 invalidate=lambda nid: self._invalidate_node(
-                    cluster, nid, dev_idx, core_used))
+                    cluster, nid, core_used))
 
     # -- bulk (count-based) solve: the C2M path --
 
@@ -525,9 +545,9 @@ class TPUPlacer:
                     tie_perm, columnar: bool = False):
         """One group's per-placement scan of k placements: admitted,
         staged outside _PER_EVAL_SOLVE_LOCK, solved under it ->
-        (choices, founds, scores, ports): the first three of the padded
-        length, the last _assign_ports' result for a group that asks
-        ports, else None.
+        (choices, founds, scores, ports, devices): the first three of the
+        padded length, then _assign_ports' result for a group that asks
+        ports and _assign_devices' for one that asks devices, else None.
         `penalty_idx` is the padded (k_pad,) reschedule-penalty row;
         `columnar` says the caller hands the result over as one
         AllocBlock (counted beside the staged solves: the share of
@@ -568,7 +588,7 @@ class TPUPlacer:
         k = bulk.count
         # a bulk request has no ignore_node: no reschedule penalty
         penalty_idx = np.full(_pad_pow2(k, floor=1), -1, dtype=np.int32)
-        choices, founds, scores, ports = self._scan_group(
+        choices, founds, scores, ports, _ = self._scan_group(
             ctx, tg, cluster, tgt, k, penalty_idx, tie_perm, columnar=True)
 
         # the block's assembly, its commit and the tail: span
@@ -681,10 +701,12 @@ class TPUPlacer:
         with allocations) ride with it in the one device_put. Which is
         which follows from the group's tensors, nothing else.
         -> (device arguments, layout, usage buffer, extra usage columns
-        or None). The usage buffer is this group's (n_pad, D) f32
-        gather target, allocated here so that the hold does not; the
-        extra columns (device / core counts, this evaluation's own
-        view) extend it under placer.pack."""
+        or None, the device asks' matched groups or None). The usage
+        buffer is this group's (n_pad, D) f32 gather target, allocated
+        here so that the hold does not; the extra columns (device / core
+        counts, this evaluation's own view) extend it under placer.pack,
+        a device ask's with the in-flight overlay's instances added
+        through its matched groups (cluster.fold_inflight_devices)."""
         import jax
 
         from ..core.metrics import REGISTRY
@@ -737,11 +759,11 @@ class TPUPlacer:
         REGISTRY.incr("nomad.placer.scan_steps", scan_steps(active))
         REGISTRY.incr("nomad.placer.scan_steps_padded", len(active))
         args = (avail, feasible, aff, placed, *spread, *dp, ev)
-        return args, layout, usage_buf, extra_used
+        return args, layout, usage_buf, extra_used, tgt.dev_groups
 
     def _solve_staged(self, ctx, tg, cluster, k, k_pad, staged):
         """Take _PER_EVAL_SOLVE_LOCK, solve, release -> (choices, founds,
-        scores, ports)."""
+        scores, ports, devices)."""
         # The usage gather -> solve -> in-flight registration runs
         # as ONE critical section across racing workers: the device
         # serializes launches anyway, and without this ordering two
@@ -759,7 +781,8 @@ class TPUPlacer:
         # should show. Its children split it, one set per
         # evaluation and task group: placer.lock_wait, then
         # placer.locked around gather / pack / ship / device_wait /
-        # fetch / ports (a group that asks ports) / register.
+        # fetch / ports (a group that asks ports) / devices (a group
+        # that asks devices) / register.
         # device=True mirrors each into the jax
         # profiler's trace, above the device's ops on one clock.
         with TRACER.span("worker.solve", k=k):
@@ -775,22 +798,33 @@ class TPUPlacer:
         """One evaluation's usage gather -> solve -> in-flight
         registration; the caller holds _PER_EVAL_SOLVE_LOCK and staged
         the static arguments (_stage_statics) before taking it. Returns
-        (choices, founds, scores) per request, k of k_pad of them, and
-        the ports of a group that asks them (_assign_ports), chosen and
+        (choices, founds, scores) per request, k of k_pad of them, the
+        ports of a group that asks them (_assign_ports) and the device
+        instances of one that asks them (_assign_devices), chosen and
         registered here: the hold is what makes an evaluation's read of
-        the ports taken and its own choice one step."""
+        the ids taken and its own choice one step."""
         import jax
 
         from .kernels import solve_task_group_fused
         from .overlay import INFLIGHT
 
-        args, layout, usage, extra_used = staged
+        args, layout, usage, extra_used, dev_groups = staged
         with TRACER.span("placer.locked", cpu=True, device=True, k=k,
                          k_pad=k_pad, n_pad=cluster.n_pad):
             with TRACER.span("placer.gather", device=True):
                 cluster.refresh_usage(ctx, out=usage)
 
             with TRACER.span("placer.pack", device=True):
+                if dev_groups is not None:
+                    # racing evaluations' instances, as their usage is
+                    # in the gather (this evaluation's own are its plan)
+                    from .cluster import fold_inflight_devices
+
+                    fold_inflight_devices(
+                        extra_used, cluster, dev_groups,
+                        INFLIGHT.devices_held(
+                            getattr(ctx.snapshot, "index", None),
+                            exclude_plan=ctx.plan))
                 if extra_used is not None:
                     usage = np.concatenate([usage, extra_used], axis=1)
 
@@ -818,7 +852,7 @@ class TPUPlacer:
                     choices = out[0].astype(np.int64)
                     founds = out[1] > 0.5
                     scores = out[2]
-            touched = ports = held = None
+            touched = ports = held = devices = held_devices = None
             if self._wants_ports(ctx, tg):
                 with TRACER.span("placer.ports", device=True) as span:
                     touched = np.unique(choices[founds], return_counts=True)
@@ -826,6 +860,13 @@ class TPUPlacer:
                                                      *touched)
                     span.set(nodes=len(held),
                              ports=sum(map(len, held.values())))
+            if ctx.tg_resources(tg).devices:
+                with TRACER.span("placer.devices", device=True) as span:
+                    touched = touched or np.unique(choices[founds],
+                                                   return_counts=True)
+                    devices, held_devices = self._assign_devices(
+                        ctx, tg, cluster, *touched)
+                    span.set(nodes=len(held_devices))
             with TRACER.span("placer.register", device=True):
                 if ctx.plan is not None:
                     rows, counts = touched or np.unique(
@@ -833,8 +874,8 @@ class TPUPlacer:
                     INFLIGHT.register(
                         cluster, rows,
                         counts[:, None] * ctx.tg_vec(tg)[None, :], ctx.plan,
-                        held)
-        return choices, founds, scores, ports
+                        held, held_devices)
+        return choices, founds, scores, ports, devices
 
     @staticmethod
     def _assign_ports(ctx, tg, cluster, rows, counts):
@@ -876,6 +917,48 @@ class TPUPlacer:
         REGISTRY.incr("nomad.placer.ports_assigned",
                       sum(map(len, held.values())))
         return ports, held
+
+    @staticmethod
+    def _assign_devices(ctx, tg, cluster, rows, counts):
+        """The device instances of a group's found placements, chosen
+        per touched node (`rows` of `cluster`, `counts[i]` placements on
+        `rows[i]`) against ctx.device_indexes: the node's groups that
+        serve each ask, highest affinity first, then the emptiest, then
+        the first free ids, so deterministic for what is taken. The
+        caller holds _PER_EVAL_SOLVE_LOCK and registers the result in
+        its in-flight entry before releasing it, so no evaluation of
+        this process can read the same instances free. -> ({row: [a
+        placement's {device group id: instance ids}, ...]}, {node id:
+        {device group id: instance ids}}); a node that ran short holds
+        fewer assignments than placements."""
+        from ..core.metrics import REGISTRY
+
+        asks = ctx.tg_resources(tg).devices
+        nodes = [cluster.nodes[ni] for ni in rows.tolist()]
+        devices: Dict[int, list] = {}
+        held: Dict[str, dict] = {}
+        n_inflight = n_assigned = 0
+        for ni, n, node, idx in zip(rows.tolist(), counts.tolist(), nodes,
+                                    ctx.device_indexes(nodes)):
+            n_inflight += idx.inflight
+            mine = devices[ni] = []
+            for _ in range(n):
+                got = idx.assign(asks, ctx.regex_cache, ctx.version_cache)
+                if got is None:
+                    break
+                mine.append(got)
+            # the row loop pops a node's placements off the end
+            mine.reverse()
+            if mine:
+                on_node = held[node.id] = {}
+                for got in mine:
+                    for gid, ids in got.items():
+                        on_node.setdefault(gid, []).extend(ids)
+                        n_assigned += len(ids)
+        REGISTRY.incr("nomad.placer.device_nodes", len(nodes))
+        REGISTRY.incr("nomad.placer.device_nodes_inflight", n_inflight)
+        REGISTRY.incr("nomad.placer.devices_assigned", n_assigned)
+        return devices, held
 
     def _bulk_shape_ok(self, ctx, tg, tgt) -> bool:
         """Task-group-level bulk eligibility (the per-request conditions
@@ -1508,39 +1591,22 @@ class TPUPlacer:
         if n_feasible > 0:
             metrics.exhaust_node("resources")
 
-    def _assign_ids(self, ctx, ask_res, numa_pol: str, ni: int, node,
-                    option: RankedNode, dev_idx: Dict[int, object],
-                    core_used: Dict[int, set]) -> bool:
-        """Post-solve concrete id assignment for one placement on the
-        chosen node. Per-node indexes live for the group's whole pass so
-        sibling placements never double-book. A False return leaves any
-        staged device instances reserved — conservative, and only
-        reachable on count-fit mispredictions."""
-        from ..scheduler.devices import DeviceIndex, select_cores, used_cores
+    def _assign_cores(self, ctx, ask_res, numa_pol: str, ni: int, node,
+                      option: RankedNode, core_used: Dict[int, set]) -> bool:
+        """Post-solve core id assignment for one placement on the chosen
+        node. Per-node sets live for the group's whole pass so sibling
+        placements never double-book."""
+        from ..scheduler.devices import select_cores, used_cores
 
-        proposed = None
-        if ask_res.devices:
-            idx = dev_idx.get(ni)
-            if idx is None:
-                proposed = ctx.proposed_allocs(node.id)
-                idx = dev_idx[ni] = DeviceIndex(node, proposed)
-            assignment = idx.assign(ask_res.devices, ctx.regex_cache,
-                                    ctx.version_cache)
-            if assignment is None:
-                return False
-            option.allocated_devices = assignment
-        if ask_res.cores:
-            taken = core_used.get(ni)
-            if taken is None:
-                if proposed is None:
-                    proposed = ctx.proposed_allocs(node.id)
-                taken = core_used[ni] = used_cores(proposed)
-            cores = select_cores(node, (), int(ask_res.cores), numa_pol,
-                                 taken=taken)
-            if cores is None:
-                return False
-            taken.update(cores)
-            option.allocated_cores = cores
+        taken = core_used.get(ni)
+        if taken is None:
+            taken = core_used[ni] = used_cores(ctx.proposed_allocs(node.id))
+        cores = select_cores(node, (), int(ask_res.cores), numa_pol,
+                             taken=taken)
+        if cores is None:
+            return False
+        taken.update(cores)
+        option.allocated_cores = cores
         return True
 
     @staticmethod
@@ -1634,3 +1700,16 @@ class SystemPlacement:
         pscore = np.array([preemption_score(x) for x in net.tolist()])
         return np.where(sel.any(axis=1), (fit + pscore) / 2.0,
                         fit).astype(np.float32)
+
+
+def _hold(option, ports: Dict[str, list], devices: Dict[str, dict]) -> None:
+    """Add what a host-scored option holds on its node (port numbers,
+    device instances) to the maps INFLIGHT.register takes."""
+    if option is None:
+        return
+    node_id = option.node.id
+    if option.allocated_ports:
+        ports.setdefault(node_id, []).extend(
+            p.value for p in option.allocated_ports)
+    for gid, ids in (option.allocated_devices or {}).items():
+        devices.setdefault(node_id, {}).setdefault(gid, []).extend(ids)

@@ -156,7 +156,7 @@ def test_static_plus_usage_equals_the_plain_scan_bit_for_bit(shape, seed):
     tie_perm = np.random.default_rng(seed).permutation(
         cluster.n_pad).astype(np.int32)
 
-    args, layout, usage, extra_used = TPUPlacer()._stage_statics(
+    args, layout, usage, extra_used, _ = TPUPlacer()._stage_statics(
         tgt, cluster, penalty_idx, active, tie_perm)
     da = cluster.static.device_arrays
     resident = {id(a) for v in da.values()

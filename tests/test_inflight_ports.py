@@ -334,7 +334,7 @@ def test_a_confirmed_entrys_ports_go_once_no_older_snapshot_is_left():
     old.close()
     overlay.retire()
     assert not overlay._closed and not overlay._ports
-    assert overlay.stats["ports_retired"] == 1
+    assert overlay.stats["retired"] == 1
 
 
 def test_the_ttl_backstop(monkeypatch):
@@ -451,9 +451,10 @@ def test_a_group_without_ports_does_no_port_work():
     registered_ports = []
     register = InflightOverlay.register
 
-    def spy(self, cluster, rows, deltas, plan, ports=None):
+    def spy(self, cluster, rows, deltas, plan, ports=None, devices=None):
         registered_ports.append(ports)
-        register(self, cluster, rows, deltas, plan, ports)
+        assert devices is None
+        register(self, cluster, rows, deltas, plan, ports, devices)
 
     InflightOverlay.register = spy
     try:
