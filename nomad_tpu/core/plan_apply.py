@@ -41,7 +41,8 @@ import numpy as np
 from ..analysis.sanitizer import sanitized
 from ..obs import RECORDER, TRACER
 from ..structs import allocs_fit, enums
-from ..structs.funcs import foreign_devices
+from ..structs.alloc import device_pairs
+from ..structs.funcs import foreign_devices, node_instances
 from ..structs.plan import Plan, PlanResult
 from ..structs.resources import RESOURCE_DIMS
 
@@ -318,6 +319,19 @@ class _OverlaySnapshot:
         for block, m in self._block_rows_of(node_id):
             for _, values in block.ports_for_row(m):
                 out.extend(values)
+        return out
+
+    def devices_by_node(self, node_id):
+        """allocs_by_node's device instances, read as ports_by_node
+        reads ports."""
+        overlay = self._replaced.get(node_id)
+        out = self._snap.devices_by_node(node_id, skip=overlay or ())
+        for a in (overlay or {}).values():
+            if not a.terminal_status():
+                out.extend(device_pairs(a))
+        for block, m in self._block_rows_of(node_id):
+            for _, pairs in block.devices_for_row(m):
+                out.extend(pairs)
         return out
 
     def live_mask(self, alloc_ids):
@@ -1085,17 +1099,18 @@ class PlanApplier:
 
         The GIL-free scale path (reference plan_apply_pool.go:21
         EvaluatePool's role): nodes touched ONLY by new placements that
-        carry no devices/cores — a block's nodes and the entire
-        bulk-placement shape, rows with no port — skip the per-node
+        carry no cores — a block's nodes and the entire bulk-placement
+        shape, rows with no port or device — skip the per-node
         alloc walk entirely. Their fit check is usage_row + sum(new
         vecs) <= available as arrays from the plan to the verdict
         (_columnar_verdicts); the accounting is exactly
         _node_plan_valid's (existing filters `not terminal_status()`,
-        the usage rows' predicate). A placement without ports or cores
-        makes no new collision possible; the port numbers of a block
-        that carries them are checked on its nodes by allocs_fit's rule
-        without an Allocation (_block_port_verdicts). Everything else
-        keeps the exact python check."""
+        the usage rows' predicate). A placement without ports, devices
+        or cores makes no new collision possible; the port numbers and
+        device instances of a block that carries them are checked on its
+        nodes by allocs_fit's rule without an Allocation
+        (_block_id_verdicts). Everything else keeps the exact python
+        check."""
         from .metrics import REGISTRY
 
         result = PlanResult()
@@ -1210,11 +1225,11 @@ class PlanApplier:
         accumulator and read back at the plan's rows; one float64
         comparison a dimension gives the verdicts. A block's row on a
         node of `exact` gets none here: _node_plan_valid judges that
-        node, the block's placements on it. The port numbers of a block
-        that carries them get their verdict beside the fit's
-        (_block_port_verdicts), after the gathers. -> (nodes given a
-        verdict, ids of those it went against); a node that is down,
-        draining or gone is one."""
+        node, the block's placements on it. The port numbers and device
+        instances of a block that carries them get their verdict beside
+        the fit's (_block_id_verdicts), after the gathers. -> (nodes
+        given a verdict, ids of those it went against); a node that is
+        down, draining or gone is one."""
         cols = snap.node_columns()
         blocks = [_BlockRows.of(cols, block) for block in plan.alloc_blocks]
         ids = [nid for nid in fresh for _ in plan.node_allocation[nid]]
@@ -1233,7 +1248,7 @@ class PlanApplier:
             own = blocks[0]
             used, avail = cols.read(own.rows)
             ok = _fits(used, lambda d: own.counts * own.vec[d], avail)
-            bad = self._block_port_verdicts(snap, plan, exact)
+            bad = self._block_id_verdicts(snap, plan, exact)
             if ok.all():
                 return own.n_nodes, bad
             every = plan.alloc_blocks[0].live_node_counts()[0]
@@ -1276,8 +1291,14 @@ class PlanApplier:
             every = list(itertools.compress(every, mine))
             ok = ok[np.asarray(mine, dtype=bool)]
         return (len(set(every)),
-                self._block_port_verdicts(snap, plan, exact).union(
+                self._block_id_verdicts(snap, plan, exact).union(
                     itertools.compress(every, (~ok).tolist())))
+
+    def _block_id_verdicts(self, snap, plan: Plan, exact: List[str]) -> set:
+        """The port and the device verdicts of the plan's blocks' node
+        rows outside `exact`."""
+        return (self._block_port_verdicts(snap, plan, exact)
+                | self._block_device_verdicts(snap, plan, exact))
 
     def _block_port_verdicts(self, snap, plan: Plan,
                              exact: List[str]) -> set:
@@ -1317,6 +1338,60 @@ class PlanApplier:
                 bad.add(nid)
         self._count_port_collisions(len(bad))
         return bad
+
+    def _block_device_verdicts(self, snap, plan: Plan,
+                               exact: List[str]) -> set:
+        """The device check of the node rows of the plan's blocks that
+        carry instances, on the nodes outside `exact`, by allocs_fit's
+        rule (structs/funcs.py): no instance of the node held twice
+        among its allocations in the snapshot, in-flight results
+        included (devices_by_node, read where _node_plan_valid reads its
+        allocations, without materialising one), and the plan's new
+        instances there; and no new instance that is not the node's
+        (foreign_devices). A node that is down, draining or gone is left
+        to the fit's verdict, as _node_plan_valid rejects it before its
+        device check. -> ids of the nodes it rejects, each counted as a
+        device collision."""
+        asked: Dict[str, list] = {}
+        kept = set(exact)
+        for block in plan.alloc_blocks:
+            if block.devices is None:
+                continue
+            for m in block.live_rows():
+                nid = block.node_ids[m]
+                if nid not in kept:
+                    pairs = asked.setdefault(nid, [])
+                    for _, held in block.devices_for_row(m):
+                        pairs.extend(held)
+        bad = set()
+        for nid, pairs in asked.items():
+            node = snap.node_by_id(nid)
+            if (node is None or node.status != enums.NODE_STATUS_READY
+                    or node.drain):
+                continue
+            own = node_instances(node)
+            if not own.issuperset(pairs):
+                bad.add(nid)
+                continue
+            held = [x for x in snap.devices_by_node(nid) if x in own]
+            held.extend(pairs)
+            if len(set(held)) != len(held):
+                bad.add(nid)
+        self._count_device_collisions(len(bad))
+        return bad
+
+    def _count_device_collisions(self, n: int) -> None:
+        """Node rows rejected for a device instance held twice, or a new
+        one not the node's: what the in-flight overlay's instances exist
+        to prevent (scheduler/devices.py), counted as port collisions
+        are."""
+        if not n:
+            return
+        from .metrics import REGISTRY
+
+        with self._stats_lock:
+            self.stats["device_collisions"] += n
+        REGISTRY.incr("nomad.plan.device_collisions", n)
 
     def _count_port_collisions(self, n: int) -> None:
         """Node rows rejected for a port number handed out twice: the
@@ -1431,11 +1506,5 @@ class PlanApplier:
         if not fit and dim.startswith("port collision"):
             self._count_port_collisions(1)
         elif not fit and dim == "device oversubscribed":
-            # a device instance held twice, or a new one not the node's:
-            # what the in-flight overlay's instances exist to prevent
-            from .metrics import REGISTRY
-
-            with self._stats_lock:
-                self.stats["device_collisions"] += 1
-            REGISTRY.incr("nomad.plan.device_collisions")
+            self._count_device_collisions(1)
         return fit

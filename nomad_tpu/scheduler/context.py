@@ -12,6 +12,7 @@ from typing import Dict, List, Optional
 
 from ..structs import AllocMetric, Job, Node, Plan, TaskGroup
 from ..structs import enums
+from ..structs.alloc import device_pairs
 
 
 class EvalEligibility:
@@ -135,6 +136,27 @@ class EvalContext:
             out.extend(self.plan.block_allocs_for_node(node_id))
         return out
 
+    def _replaced(self, node_id: str) -> set:
+        """Ids of the node's allocations the in-progress plan stops,
+        preempts or places again: what proposed_allocs(node_id) drops of
+        the snapshot's."""
+        plan = self.plan
+        replaced = {a.id for bucket in (plan.node_update,
+                                        plan.node_preemptions,
+                                        plan.node_allocation)
+                    for a in bucket.get(node_id, ())}
+        if plan.preempted:
+            replaced.update(plan.preempted.on_node(node_id))
+        return replaced
+
+    def _plan_rows(self, node_id: str):
+        """The in-progress plan's live block rows on the node, as
+        (block, row)."""
+        for block in self.plan.alloc_blocks:
+            for m in block.rows_for_node(node_id):
+                if m not in block.rejected_rows:
+                    yield block, m
+
     def proposed_ports(self, node_id: str) -> List[int]:
         """The port numbers proposed_allocs(node_id) holds, read from
         the snapshot's index entries and the plan's rows and blocks
@@ -143,20 +165,32 @@ class EvalContext:
         plan = self.plan
         if plan is None:
             return self.snapshot.ports_by_node(node_id)
-        placed = plan.node_allocation.get(node_id, ())
-        replaced = {a.id for bucket in (plan.node_update, plan.node_preemptions)
-                    for a in bucket.get(node_id, ())}
-        replaced.update(a.id for a in placed)
-        if plan.preempted:
-            replaced.update(plan.preempted.on_node(node_id))
-        out = self.snapshot.ports_by_node(node_id, skip=replaced)
-        out.extend(p.value for a in placed if a.should_count_for_usage()
-                   for p in a.allocated_ports)
-        for block in plan.alloc_blocks:
-            for m in block.rows_for_node(node_id):
-                if m not in block.rejected_rows:
-                    for _, values in block.ports_for_row(m):
-                        out.extend(values)
+        out = self.snapshot.ports_by_node(node_id,
+                                          skip=self._replaced(node_id))
+        out.extend(p.value for a in plan.node_allocation.get(node_id, ())
+                   if a.should_count_for_usage() for p in a.allocated_ports)
+        for block, m in self._plan_rows(node_id):
+            for _, values in block.ports_for_row(m):
+                out.extend(values)
+        return out
+
+    def proposed_devices(self, node_id: str) -> List[tuple]:
+        """The (device group id, instance id) pairs proposed_allocs(
+        node_id) holds, read as proposed_ports() reads ports: the
+        snapshot's index entries (StateSnapshot.devices_by_node), the
+        plan's rows and its blocks' device columns, no block position
+        materialised."""
+        plan = self.plan
+        if plan is None:
+            return self.snapshot.devices_by_node(node_id)
+        out = self.snapshot.devices_by_node(node_id,
+                                            skip=self._replaced(node_id))
+        for a in plan.node_allocation.get(node_id, ()):
+            if a.should_count_for_usage():
+                out.extend(device_pairs(a))
+        for block, m in self._plan_rows(node_id):
+            for _, pairs in block.devices_for_row(m):
+                out.extend(pairs)
         return out
 
     def port_index(self, node: Node, proposed: Optional[List] = None):
@@ -196,7 +230,7 @@ class EvalContext:
     def device_index(self, node: Node, proposed: Optional[List] = None):
         """Which device instances are taken on `node` for this
         evaluation, as a DeviceIndex to assign from: those of
-        proposed_allocs(node.id) (or of `proposed`, where the caller has
+        proposed_devices(node.id) (or of `proposed`, where the caller has
         it already or has taken its victims out) and those the in-flight
         overlay holds there (tensor/overlay.py devices_on), the one
         answer the per-placement tier and the host scorer share, as
@@ -214,8 +248,11 @@ class EvalContext:
                                    getattr(self.snapshot, "index", None))
         out = []
         for node in nodes:
-            idx = DeviceIndex(node, self.proposed_allocs(node.id)
-                              if proposed is None else proposed)
+            if proposed is None:
+                idx = DeviceIndex(node)
+                idx.add_held(self.proposed_devices(node.id))
+            else:
+                idx = DeviceIndex(node, proposed)
             idx.add_taken(held.get(node.id, {}))
             out.append(idx)
         return out

@@ -158,6 +158,12 @@ class DeviceIndex:
         for dev_id, instances in (alloc.allocated_devices or {}).items():
             self.used.setdefault(dev_id, set()).update(instances)
 
+    def add_held(self, pairs: Sequence[Tuple[str, str]]) -> None:
+        """Instances the allocations hold, as (device group id, instance
+        id) pairs (EvalContext.proposed_devices)."""
+        for dev_id, instance in pairs:
+            self.used.setdefault(dev_id, set()).add(instance)
+
     def add_taken(self, held: Dict[str, Sequence[str]]) -> None:
         """Instances taken by what no allocation here shows: the
         in-flight overlay's ({device group id: instance ids})."""

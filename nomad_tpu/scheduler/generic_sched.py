@@ -390,7 +390,7 @@ class GenericScheduler:
 
         def commit_block(tg, node_ids, node_names, counts, name_indices,
                          mean_score, scores=None, nodes_evaluated=0,
-                         nodes_in_pool=0, ports=None):
+                         nodes_in_pool=0, ports=None, devices=None):
             """Columnar bulk commit: ONE AllocBlock rides the plan for K
             placements (structs/alloc.py AllocBlock). Only reachable for
             the fresh-placement shape commit_many covers, so the same
@@ -398,7 +398,8 @@ class GenericScheduler:
             `scores` (per position) and the two node counts are the
             per-placement scan's: what its rows carried in their
             AllocMetric; `ports` (a BlockPorts, a row a position) its
-            port numbers, for a group that asks them."""
+            port numbers and `devices` (a BlockDevices) its device
+            instances, for a group that asks them."""
             from ..structs.alloc import AllocBlock
 
             block = AllocBlock(
@@ -421,6 +422,7 @@ class GenericScheduler:
                 nodes_evaluated=nodes_evaluated,
                 nodes_in_pool=nodes_in_pool,
                 ports=ports,
+                devices=devices,
                 allocated_at=now,
             )
             metrics = ctx.metrics

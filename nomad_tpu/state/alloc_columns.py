@@ -286,7 +286,8 @@ class AllocColumns:
         self.count[got] = (block.counts if counts is None else counts)[at]
         self.prio[got] = NO_JOB if job is None else job.priority
         self.vec[got] = block.allocated_vec
-        self.ports[got] = self.flag[got] = block.ports is not None
+        self.ports[got] = block.ports is not None
+        self.flag[got] = block.ports is not None or block.devices is not None
         self.grp[got] = self._group(
             (block.namespace, block.job_id, block.task_group), len(got))
         self.mp[got] = max_parallel_of(job, block.task_group)

@@ -296,6 +296,22 @@ def _block_usage_into(blocks, promoted, usage) -> None:
         usage[nid] = row if prev is None else prev + row
 
 
+def _block_dev_usage_into(blocks, promoted, dev_usage) -> None:
+    """Fold the device instances of the blocks that carry them into the
+    per-node `dev_usage` rows: a live node row's positions, less the
+    dropped and promoted ones (a promoted row counts its own)."""
+    for b in blocks:
+        if b.devices is None:
+            continue
+        skip = set(promoted.get(b.id, ()))
+        for m in b.live_rows():
+            counts = b.device_counts_for_row(m, skip)
+            if counts:
+                row = dev_usage.setdefault(b.node_ids[m], {})
+                for gid, n in counts.items():
+                    row[gid] = row.get(gid, 0) + n
+
+
 def restore_store(store, data: dict) -> None:
     """Replace the store's contents with a dump (restore-on-start and
     follower install-snapshot). Publishes at the dump's index. Accepts
@@ -408,6 +424,7 @@ def restore_store(store, data: dict) -> None:
                     accumulate_dev_usage(dev_usage.setdefault(a.node_id, {}), a)
         if blocks:
             _block_usage_into(blocks, promoted, usage)
+            _block_dev_usage_into(blocks, promoted, dev_usage)
             for b in blocks:
                 store._alloc_blocks.put(b.id, b, gen, live)
                 for m in b.live_rows():

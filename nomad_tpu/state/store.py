@@ -29,7 +29,7 @@ from typing import Callable, Dict, Iterable, Iterator, List, Optional, Tuple
 import numpy as np
 
 from ..structs import enums
-from ..structs.alloc import BLOCK_SEP, AllocBlock, Allocation
+from ..structs.alloc import BLOCK_SEP, AllocBlock, Allocation, device_pairs
 from ..structs.resources import RESOURCE_DIMS
 from ..structs.deployment import Deployment
 from ..structs.evaluation import Evaluation
@@ -340,39 +340,62 @@ class StateSnapshot:
     def allocs_by_node(self, node_id: str) -> List[Allocation]:
         return self._allocs_from_index(self._store._allocs_by_node, node_id)
 
-    def ports_by_node(self, node_id: str, skip=()) -> List[int]:
-        """The port numbers the node's allocations hold, each allocation
-        not terminal (what the plan applier's allocs_fit counts of the
-        node's existing allocations), read from its index entries: a
-        row's allocated_ports, a block's port column at its node row (a
-        position promoted to a row counts as that row). No Allocation is
-        materialised. Allocation ids in `skip` are left out (an overlay
-        that replaces them)."""
+    def _held_by_node(self, node_id: str, skip, of_block, of_row):
+        """What each of the node's allocations that is not terminal
+        holds (what the plan applier's allocs_fit counts of the node's
+        existing allocations), read from its index entries with no
+        Allocation materialised: a block's node row through
+        `of_block(block, row)` -> [(position, held)] (its column, empty
+        for a block without one), a row, and a position promoted to one,
+        through `of_row(alloc)`. Allocation ids in `skip` are left out
+        (an overlay that replaces them). The one walk of ports_by_node
+        and devices_by_node."""
         store, index = self._store, self.index
         allocs = store._allocs
-        out: List[int] = []
         for entry in self._ids_from_index(store._allocs_by_node, node_id):
             if type(entry) is BlockRef:
                 block = store._alloc_blocks.get(entry.block_id, index)
-                if (block is None or block.ports is None
-                        or entry.row in block.rejected_rows):
+                if block is None or entry.row in block.rejected_rows:
                     continue
                 prefix = f"{block.id}{BLOCK_SEP}"
-                for p, values in block.ports_for_row(entry.row):
+                for p, held in of_block(block, entry.row):
                     aid = prefix + str(p)
                     if aid in skip:
                         continue
                     row = allocs.get(aid, index)
                     if row is None:
-                        out.extend(values)
+                        yield held
                     elif not row.terminal_status():
-                        out.extend(pt.value for pt in row.allocated_ports)
+                        yield of_row(row)
                 continue
             if entry in skip:
                 continue
             a = allocs.get(entry, index)
             if a is not None and not a.terminal_status():
-                out.extend(pt.value for pt in a.allocated_ports)
+                yield of_row(a)
+
+    def ports_by_node(self, node_id: str, skip=()) -> List[int]:
+        """The port numbers the node's allocations hold (_held_by_node):
+        a row's allocated_ports, a block's port column at its node
+        row."""
+        out: List[int] = []
+        for values in self._held_by_node(
+                node_id, skip, AllocBlock.ports_for_row,
+                lambda a: [pt.value for pt in a.allocated_ports]):
+            out.extend(values)
+        return out
+
+    def devices_by_node(self, node_id: str,
+                        skip=()) -> List[Tuple[str, str]]:
+        """The (device group id, instance id) pairs the node's
+        allocations hold (_held_by_node): a row's allocated_devices, a
+        block's device column at its node row. An instance held twice is
+        listed twice."""
+        out: List[Tuple[str, str]] = []
+        for pairs in self._held_by_node(node_id, skip,
+                                        AllocBlock.devices_for_row,
+                                        device_pairs):
+            out.extend(pairs)
         return out
 
     def live_mask(self, alloc_ids: List[str]) -> np.ndarray:
@@ -1183,6 +1206,18 @@ class StateStore:
         accumulate_dev_usage(row, alloc, sign)
         self._node_dev_usage.put(alloc.node_id, row, gen, live)
 
+    def _dev_counts_add(self, node_id: str, counts: Dict[str, int],
+                        gen: int, live: int) -> None:
+        """Add {device group id: instances} to the node's device-usage
+        row (a block's node row: AllocBlock.device_counts_for_row)."""
+        if not counts:
+            return
+        cur = self._node_dev_usage.get_latest(node_id)
+        row = dict(cur) if cur else {}
+        for gid, n in counts.items():
+            row[gid] = row.get(gid, 0) + n
+        self._node_dev_usage.put(node_id, row, gen, live)
+
     _MISS = object()  # "caller did not look up prev" sentinel
 
     def _latest_alloc(self, alloc_id: str) -> Optional[Allocation]:
@@ -1571,10 +1606,11 @@ class StateStore:
         work for K allocations — one block row, one BlockRef cons per
         touched node, one vectorized usage add. This is the 2M-alloc
         answer to _put_new_allocs_bulk's per-alloc loop. A block carries
-        no devices, cores or volumes by construction (the placer's gate);
-        the ports of a group that asks them ride in its port column
-        (AllocBlock.ports), which the plan applier verified, so the
-        commit is the same for a block with ports as for one without."""
+        no cores or volumes by construction (the placer's gate); the
+        ports and device instances of a group that asks them ride in its
+        columns (AllocBlock.ports, AllocBlock.devices), which the plan
+        applier verified, and the instances count in each node's
+        device-usage row as a row's do."""
         block.modify_time = ts
         block.create_index = gen
         block.modify_index = gen
@@ -1587,6 +1623,9 @@ class StateStore:
             self._allocs_by_node.put(nid, cons(BlockRef(block.id, m), cell),
                                      gen, live)
             self._usage_add(nid, vec * c if c != 1 else vec, gen, live)
+            if block.devices is not None:
+                self._dev_counts_add(nid, block.device_counts_for_row(m),
+                                     gen, live)
         if self._alloc_cols is not None:
             self._alloc_cols.block_added(block, gen)
         jkey = (block.namespace, block.job_id)

@@ -217,6 +217,12 @@ class Allocation:
         return new
 
 
+def device_pairs(alloc: Allocation) -> list:
+    """The (device group id, instance id) pairs an allocation holds."""
+    return [(gid, i) for gid, ids in (alloc.allocated_devices or {}).items()
+            for i in ids]
+
+
 def alloc_name(job_id: str, group: str, index: int) -> str:
     """Reference structs.AllocName format "<job>.<group>[<index>]"."""
     return f"{job_id}.{group}[{index}]"
@@ -237,6 +243,57 @@ class BlockPorts:
         return [AllocatedPort(label=label, value=value)
                 for label, value in zip(self.labels,
                                         self.values[p].tolist())]
+
+
+@dataclass(slots=True)
+class BlockDevices:
+    """The device instances of an AllocBlock's positions, as columns:
+    the device group ids once (`groups`), and position p's instances
+    `ids[offsets[p]:offsets[p + 1]]`, instance j of the group
+    `groups[group[j]]`, in the order DeviceIndex.assign handed them
+    out."""
+
+    groups: List[str] = field(default_factory=list)
+    group: np.ndarray = field(default_factory=lambda: np.zeros(0, np.int32))
+    ids: List[str] = field(default_factory=list)
+    offsets: np.ndarray = field(
+        default_factory=lambda: np.zeros(1, np.int64))
+    # the pairs a position, built on first read (never serialized)
+    _held: object = field(default=None, repr=False, compare=False)
+
+    @classmethod
+    def of(cls, assignments) -> "BlockDevices":
+        """The column of positions that hold `assignments` ({device
+        group id: instance ids} a position, as a row's
+        allocated_devices), in order."""
+        index: Dict[str, int] = {}
+        group: List[int] = []
+        ids: List[str] = []
+        offsets = [0]
+        for got in assignments:
+            for gid, inst in got.items():
+                group.extend([index.setdefault(gid, len(index))] * len(inst))
+                ids.extend(inst)
+            offsets.append(len(ids))
+        return cls(list(index), np.asarray(group, np.int32), ids,
+                   np.asarray(offsets, np.int64))
+
+    def at(self, p: int) -> Dict[str, List[str]]:
+        out: Dict[str, List[str]] = {}
+        for gid, i in self.held()[p]:
+            out.setdefault(gid, []).append(i)
+        return out
+
+    def held(self) -> List[list]:
+        """The (device group id, instance id) pairs of each position, a
+        list a position (built once: the column is frozen)."""
+        held = self._held
+        if held is None:
+            off = self.offsets.tolist()
+            pairs = list(zip([self.groups[g] for g in self.group.tolist()],
+                             self.ids))
+            held = self._held = [pairs[a:b] for a, b in zip(off, off[1:])]
+        return held
 
 
 # Block alloc id = "<block uuid>.<position>". The separator must be
@@ -293,6 +350,10 @@ class AllocBlock:
     # the ports of a group that asks them, a row of numbers a position;
     # None for a block without ports, which encodes as it did before
     ports: Optional[BlockPorts] = None
+    # the device instances of a group that asks them, a run of ids a
+    # position; None for a block without devices, which encodes as
+    # before
+    devices: Optional[BlockDevices] = None
     allocated_at: float = 0.0
     modify_time: float = 0.0
     create_index: int = 0
@@ -310,7 +371,7 @@ class AllocBlock:
     # per-node columns (core/plan_apply.py _BlockRows)
     _store_rows: object = field(default=None, repr=False, compare=False)
     # fields the wire codec leaves out while they hold None
-    _wire_omit_none: ClassVar[tuple] = ("ports",)
+    _wire_omit_none: ClassVar[tuple] = ("ports", "devices")
 
     def __deepcopy__(self, memo):
         import copy as _copy
@@ -329,6 +390,9 @@ class AllocBlock:
             nodes_in_pool=self.nodes_in_pool,
             ports=(None if self.ports is None else BlockPorts(
                 list(self.ports.labels), self.ports.values.copy())),
+            devices=(None if self.devices is None else BlockDevices(
+                list(self.devices.groups), self.devices.group.copy(),
+                list(self.devices.ids), self.devices.offsets.copy())),
             allocated_at=self.allocated_at,
             modify_time=self.modify_time, create_index=self.create_index,
             modify_index=self.modify_index,
@@ -425,6 +489,8 @@ class AllocBlock:
                 allocated_vec=self.allocated_vec,
                 allocated_ports=([] if self.ports is None
                                  else self.ports.at(p)),
+                allocated_devices=({} if self.devices is None
+                                   else self.devices.at(p)),
                 metrics=self._metrics_at(p, self.node_ids[m]),
                 allocated_at=self.allocated_at,
                 modify_time=self.modify_time,
@@ -443,6 +509,27 @@ class AllocBlock:
         return [(p, values) for p, values in zip(
                     range(lo, hi), self.ports.values[lo:hi].tolist())
                 if p not in self.dropped]
+
+    def devices_for_row(self, m: int) -> list:
+        """(position, [(device group id, instance id)]) of row m's
+        positions that GC left, read from the device column; empty for a
+        block without devices."""
+        if self.devices is None:
+            return []
+        held = self.devices.held()
+        return [(p, held[p]) for p in self.positions_for_row(m)
+                if p not in self.dropped]
+
+    def device_counts_for_row(self, m: int, skip=()) -> Dict[str, int]:
+        """{device group id: instances} that row m's positions hold,
+        those GC left and not in `skip` (the node's device-usage row's
+        schema, scheduler/devices.py accumulate_dev_usage)."""
+        out: Dict[str, int] = {}
+        for p, pairs in self.devices_for_row(m):
+            if p not in skip:
+                for gid, _ in pairs:
+                    out[gid] = out.get(gid, 0) + 1
+        return out
 
     def allocs_for_row(self, m: int) -> List["Allocation"]:
         if m in self.rejected_rows:

@@ -75,7 +75,7 @@ def allocs_fit(node, allocs: Iterable, check_devices: bool = False):
     core_overlap = False
     dev_held: set = set()
     dev_clash = False
-    node_devs = _node_instances(node) if check_devices else frozenset()
+    node_devs = node_instances(node) if check_devices else frozenset()
     any_ports = False
 
     for alloc in allocs:
@@ -115,7 +115,8 @@ def allocs_fit(node, allocs: Iterable, check_devices: bool = False):
     return True, "", used
 
 
-def _node_instances(node) -> frozenset:
+def node_instances(node) -> frozenset:
+    """The node's device instances, as (device group id, instance id)."""
     return frozenset((g.id, i) for g in node.resources.devices
                      for i in g.instance_ids)
 
@@ -128,7 +129,7 @@ def foreign_devices(node, allocs: Iterable) -> bool:
     node to later plans."""
     held = {(dev_id, i) for a in allocs
             for dev_id, inst in a.allocated_devices.items() for i in inst}
-    return bool(held) and not held <= _node_instances(node)
+    return bool(held) and not held <= node_instances(node)
 
 
 def proposed_usage(allocs: Iterable) -> np.ndarray:

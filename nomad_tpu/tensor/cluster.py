@@ -784,6 +784,8 @@ def _walk_node(ctx, node_id: str, touched: set, top: int,
             continue
         job = block.job
         ported = block.ports is not None
+        # a victim that holds ports or devices: the host scorer's node
+        flagged = ported or block.devices is not None
         wanted = job is not None and job.priority <= top
         if not wanted and not ported:
             continue
@@ -803,7 +805,7 @@ def _walk_node(ctx, node_id: str, touched: set, top: int,
                 holds = True
             if wanted:
                 out.append((job.priority, aid, block.allocated_vec,
-                            ported, key, mp))
+                            flagged, key, mp))
     out.sort(key=lambda c: (c[0], c[1]))
     return out, holds
 
@@ -1191,6 +1193,9 @@ def _device_core_columns(ctx: EvalContext, asks, cores: int,
     if plan is not None:
         touched = (set(plan.node_update) | set(plan.node_preemptions)
                    | set(plan.node_allocation))
+        for block in plan.alloc_blocks:
+            if block.devices is not None:
+                touched.update(block.live_node_counts()[0])
     for i, node in enumerate(nodes):
         if node.id in touched:
             row = {}

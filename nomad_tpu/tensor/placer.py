@@ -16,9 +16,9 @@ Lowering strategy per evaluation:
      held, so that they go into the in-flight overlay with the usage
      they belong to and racing evaluations never pick the same number
      or instance (structs/network.py, scheduler/devices.py), the core
-     ids in the row loop. A group of fresh placements that needs none
-     of them is committed as one AllocBlock, from the scan as from the
-     count solve.
+     ids in the row loop. A group of fresh placements that needs no
+     core ids is committed as one AllocBlock, from the scan as from the
+     count solve, its ports and instances in the block's columns.
 
 Preemption stays host-side: when the kernel finds no fit and preemption
 is enabled, the per-request fallback runs the host NodeScorer preemption
@@ -319,19 +319,20 @@ class TPUPlacer:
                     continue
                 if (takes_blocks and bulk.count > self.HOST_CUTOVER
                         and not self._wants_ids(ctx, tg)):
-                    # spread / distinct_hosts / distinct_property / ports
-                    # rule out the count solve, not the block: the scan's
-                    # K placements are committed as ONE AllocBlock too,
-                    # with the ports chosen under the hold
+                    # spread / distinct_hosts / distinct_property /
+                    # ports / devices rule out the count solve, not the
+                    # block: the scan's K placements are committed as ONE
+                    # AllocBlock too, with the ports and instances
+                    # chosen under the hold
                     self._place_scan_columnar(
                         ctx, job, tg, bulk, cluster, tgt, commit, tie_perm,
                         sched_batch=batch,
                         preemption_enabled=preemption_enabled,
                         attempt=attempt)
                     continue
-                # devices / cores are assigned per placement on its
-                # chosen node: expand and fall through (reusing the
-                # tensors just built)
+                # cores are assigned per placement on its chosen node:
+                # expand and fall through (reusing the tensors just
+                # built)
                 reqs = bulk.expand()
                 prebuilt_tgt = tgt
             else:
@@ -534,12 +535,12 @@ class TPUPlacer:
     @staticmethod
     def _wants_ids(ctx, tg) -> bool:
         """Whether a placement of this group carries ids assigned on its
-        chosen node after the solve (device instances, core ids): the
-        row loop's wants_devices / wants_cores as one test. Port numbers
-        are chosen under the hold for every touched node at once
-        (_assign_ports), so asking ports keeps no group out of a block."""
-        ask_res = ctx.tg_resources(tg)
-        return bool(ask_res.devices or ask_res.cores)
+        chosen node after the solve: core ids, the row loop's
+        wants_cores. Port numbers and device instances are chosen under
+        the hold for every touched node at once (_assign_ports,
+        _assign_devices), so asking them keeps no group out of a
+        block."""
+        return bool(ctx.tg_resources(tg).cores)
 
     def _scan_group(self, ctx, tg, cluster, tgt, k: int, penalty_idx,
                     tie_perm, columnar: bool = False):
@@ -582,13 +583,17 @@ class TPUPlacer:
         RankedNode, an AllocMetric and an Allocation a placement. Block
         positions are the found placements in stable order of their
         chosen node, each with its own name index and score, and with
-        the ports the hold chose for a group that asks them: a node's
-        positions take its port lists in the order the row loop pops
-        them, so the block is the row loop's placements port for port."""
+        the ports and device instances the hold chose for a group that
+        asks them: a node's positions take its port lists and its
+        instance assignments in the order the row loop pops them, so
+        the block is the row loop's placements port for port and
+        instance for instance. A node that ran short of instances sends
+        its surplus placements to the host selector, each on its own, as
+        the row loop does (_host_surplus)."""
         k = bulk.count
         # a bulk request has no ignore_node: no reschedule penalty
         penalty_idx = np.full(_pad_pow2(k, floor=1), -1, dtype=np.int32)
-        choices, founds, scores, ports, _ = self._scan_group(
+        choices, founds, scores, ports, devices = self._scan_group(
             ctx, tg, cluster, tgt, k, penalty_idx, tie_perm, columnar=True)
 
         # the block's assembly, its commit and the tail: span
@@ -600,21 +605,35 @@ class TPUPlacer:
             metrics.nodes_evaluated = len(nodes)
             name_indices = np.asarray(bulk.name_indices, dtype=np.int64)
             found = np.flatnonzero(founds[:k])
-            rows = ()
+            rows, surplus = (), found[:0]
             if len(found):
                 # np.unique's sorted rows are the stable order's node runs
                 found = found[np.argsort(choices[found], kind="stable")]
                 rows, counts = np.unique(choices[found], return_counts=True)
-                block_ports = None
+                block_ports = block_devices = None
                 if ports is not None:
-                    n_found = len(found)
-                    found, rows, counts, block_ports = self._block_ports(
-                        found, rows, counts, ports)
-                    if len(found) < n_found:
+                    keep, block_ports = self._block_ports(rows, counts,
+                                                          ports)
+                    if not keep.all():
                         # a node that ran out of ports: its surplus
                         # placements fail, as the row loop's did
                         metrics.exhaust_node("ports")
-                        commit.fail_bulk(tg, n_found - len(found))
+                        commit.fail_bulk(tg, int((~keep).sum()))
+                        found = found[keep]
+                        rows, counts = np.unique(choices[found],
+                                                 return_counts=True)
+                if devices is not None:
+                    keep, block_devices = self._block_devices(rows, counts,
+                                                              devices)
+                    if not keep.all():
+                        # a node that ran short of instances: its
+                        # surplus goes to the host selector, in scan order
+                        surplus = np.sort(found[~keep])
+                        found = found[keep]
+                        if block_ports is not None:
+                            block_ports.values = block_ports.values[keep]
+                        rows, counts = np.unique(choices[found],
+                                                 return_counts=True)
                 rows = rows.tolist()
             if len(found):
                 placed_scores = scores[found]
@@ -628,40 +647,95 @@ class TPUPlacer:
                     scores=placed_scores,
                     nodes_evaluated=len(nodes),
                     nodes_in_pool=len(nodes),
-                    ports=block_ports)
+                    ports=block_ports,
+                    devices=block_devices)
             span.set(nodes=len(rows))
 
+            if len(surplus):
+                self._host_surplus(ctx, job, tg, bulk.job_id,
+                                   name_indices[surplus], cluster, commit,
+                                   batch=sched_batch,
+                                   preemption_enabled=preemption_enabled,
+                                   attempt=attempt)
+                # the tail's failure, if any, is this metric's
+                ctx.metrics = metrics
             self._bulk_tail(ctx, job, tg, bulk.job_id,
                             name_indices[~founds[:k]], metrics, cluster,
                             tgt, commit, sched_batch=sched_batch,
                             preemption_enabled=preemption_enabled,
                             attempt=attempt)
 
+    def _host_surplus(self, ctx, job, tg, job_id, surplus_indices, cluster,
+                      commit, *, batch: bool, preemption_enabled: bool,
+                      attempt: int) -> None:
+        """The placements a device block left out (`surplus_indices`:
+        their name indices, in scan order), each to the host selector on
+        its own, as _place_rows sends a request whose node ran short of
+        instances; what it chooses is registered with the in-flight
+        overlay, as there. Counter nomad.placer.device_block_surplus."""
+        from ..core.metrics import REGISTRY
+        from ..scheduler.reconcile import BulkPlacementRequest
+        from .overlay import INFLIGHT
+
+        REGISTRY.incr("nomad.placer.device_block_surplus",
+                      len(surplus_indices))
+        held_ports: Dict[str, list] = {}
+        held_devices: Dict[str, dict] = {}
+        for req in BulkPlacementRequest(task_group=tg, job_id=job_id,
+                                        name_indices=surplus_indices
+                                        ).expand():
+            option = self._host_one(ctx, job, tg, cluster.nodes, req, batch,
+                                    preemption_enabled, attempt)
+            commit(req, option)
+            _hold(option, held_ports, held_devices)
+        if (held_ports or held_devices) and ctx.plan is not None:
+            INFLIGHT.register(cluster, (), None, ctx.plan, held_ports,
+                              held_devices)
+
     @staticmethod
-    def _block_ports(found, rows, counts, ports):
+    def _keep_first(counts, held) -> np.ndarray:
+        """Over positions in node runs of `counts`, whether each is among
+        the first `held[i]` of its run i: those a node's ids serve."""
+        starts = np.repeat(np.cumsum(counts) - counts, counts)
+        return np.arange(len(starts)) - starts < np.repeat(held, counts)
+
+    @classmethod
+    def _block_ports(cls, rows, counts, ports):
         """Lay _assign_ports' lists (`ports`: {row: lists, the first
-        chosen last}) over a block's positions (`found`, in node runs of
-        `rows` x `counts`): a node's first placement takes its first
-        list. A node that ran out holds fewer lists than placements;
-        its last placements in scan order leave the block. -> (found,
-        rows, counts, BlockPorts) of what keeps its ports."""
+        chosen last}) over a block's positions (node runs of `rows` x
+        `counts`): a node's first placement takes its first list. A node
+        that ran out holds fewer lists than placements; its last
+        placements in scan order leave the block. -> (the mask of the
+        positions that keep their ports, their BlockPorts)."""
         from ..structs.alloc import BlockPorts
 
         held = np.fromiter((len(ports[ni]) for ni in rows.tolist()),
                            np.int64, len(rows))
-        if (held < counts).any():
-            starts = np.repeat(np.cumsum(counts) - counts, counts)
-            keep = np.arange(len(found)) - starts < np.repeat(held, counts)
-            found = found[keep]
-            counts = np.minimum(counts, held)
-            rows = rows[counts > 0]
-            counts = counts[counts > 0]
-        lists = [got for ni, c in zip(rows.tolist(), counts.tolist())
+        lists = [got for ni, c in zip(rows.tolist(),
+                                      np.minimum(counts, held).tolist())
                  for got in ports[ni][:-c - 1:-1]]
         labels = [p.label for p in lists[0]] if lists else []
         values = np.array([[p.value for p in got] for got in lists],
                           np.int32).reshape(-1, len(labels))
-        return found, rows, counts, BlockPorts(labels, values)
+        return (cls._keep_first(counts, held),
+                BlockPorts(labels, values))
+
+    @classmethod
+    def _block_devices(cls, rows, counts, devices):
+        """Lay _assign_devices' choice (`devices`: {row: assignments,
+        the first chosen last}) over a block's positions as
+        _block_ports lays ports: a node's first placement takes its
+        first assignment, and the last placements of a node that ran
+        short leave the block. -> (the mask of the positions that keep
+        their instances, their BlockDevices)."""
+        from ..structs.alloc import BlockDevices
+
+        held = np.fromiter((len(devices[ni]) for ni in rows.tolist()),
+                           np.int64, len(rows))
+        return (cls._keep_first(counts, held), BlockDevices.of(
+            got for ni, c in zip(rows.tolist(),
+                                 np.minimum(counts, held).tolist())
+            for got in devices[ni][:-c - 1:-1]))
 
     def _bulk_tail(self, ctx, job, tg, job_id, tail_indices, metrics,
                    cluster, tgt, commit, *, sched_batch: bool,

@@ -555,16 +555,20 @@ def test_a_group_without_a_device_ask_does_no_device_work():
 
 
 def test_a_device_group_opens_both_spans():
+    """A group of fresh placements that asks a device leaves the scan
+    as one block (tests/test_device_blocks.py): placer.block, and no
+    placer.rows."""
     TRACER.set_enabled(True)
     TRACER.clear()
-    job = gpu_job(24)
-    store = gpu_store(64, [job])
+    job = gpu_job(300)
+    store = gpu_store(320, [job])
     race(store, [job])
-    assert len(live(store.snapshot(), job)) == 24
+    assert len(live(store.snapshot(), job)) == 300
     names = [r[R_NAME] for r in TRACER.spans()]
     assert names.count("placer.devices") == 1
     assert names.count("build.device_columns") >= 1
-    assert "placer.rows" in names
+    assert names.count("placer.block") == 1
+    assert "placer.rows" not in names
 
 
 # -- the configuration, the cell and the deploy kind -------------------------
