@@ -220,6 +220,16 @@ class _Bucket:
         return (want - self.tokens) / self.rate
 
 
+def _held(lock) -> bool:
+    """Whether another thread holds `lock`, found without waiting: a
+    Condition's RLock has no `locked()` before Python 3.14, so this is
+    a non-blocking acquire, released at once when it succeeds."""
+    if lock.acquire(blocking=False):
+        lock.release()
+        return False
+    return True
+
+
 class AdmissionController:
     """Per-server tiered admission: queue-depth watermarks pick the
     shed floor, per-tier token buckets smooth bursts, and a brownout
@@ -252,6 +262,8 @@ class AdmissionController:
             if t != TIER_LIVENESS}
         # (name, depth_fn, soft, hard, commit_path)
         self._queues: List[Tuple[str, Callable[[], int], int, int, bool]] = []
+        # the locks the depth functions take (register_queue's ``lock``)
+        self._depth_locks: list = []
         self._refresh_s = refresh_s
         self._pressure = 0
         self._pressure_stamp = -1.0
@@ -265,20 +277,28 @@ class AdmissionController:
         # (mono_ts, tier, "admit"|"shed", source)
         self._ledger: deque = deque(maxlen=ledger_size)
         self.stats = {"admitted": 0, "shed": 0, "degraded_entries": 0}
+        # degraded_now()'s counters, present at 0 before the first read
+        REGISTRY.incr("nomad.load.commit_flag_reads", 0)
+        REGISTRY.incr("nomad.load.commit_flag_reads_busy", 0)
 
     # -- wiring ------------------------------------------------------
 
     def register_queue(self, name: str, depth_fn: Callable[[], int],
                        soft: int, hard: int,
-                       commit_path: bool = False) -> None:
+                       commit_path: bool = False,
+                       lock=None) -> None:
         """Register a live queue-depth source. ``soft`` tripped sheds
         tier 3 (and tier 2 once any TWO soft marks trip), ``hard``
         tripped sheds tiers >= 2 (>= 1 when two hard marks trip).
         ``commit_path`` queues (raft proposals, plan queue) also feed
         the brownout detector — sustained pressure THERE is what
-        degrades reads."""
+        degrades reads. ``lock`` is the lock ``depth_fn`` takes, if
+        any: `degraded_now()` counts a read as busy while it is held
+        and a pressure evaluation is due."""
         with self._lock:
             self._queues.append((name, depth_fn, soft, hard, commit_path))
+            if lock is not None:
+                self._depth_locks.append(lock)
             self._pressure_stamp = -1.0  # force recompute
 
     def set_alive(self, alive: bool) -> None:
@@ -372,6 +392,25 @@ class AdmissionController:
         with self._lock:
             self._eval_pressure_locked(self._clock())
             return self._degraded
+
+    def degraded_now(self) -> bool:
+        """The brownout flag as the last pressure evaluation left it,
+        for the store's commit path: one read, no lock, no evaluation,
+        so a commit never waits behind an admission decision nor for
+        the depth functions' locks that an evaluation takes. The flag
+        is at most as old as the last admission, park or `degraded()`
+        call, each of which advances the state machine. Counts the read
+        in ``nomad.load.commit_flag_reads``, and in
+        ``nomad.load.commit_flag_reads_busy`` when `degraded()` would
+        have waited at it: the controller's lock was held, or an
+        evaluation was due and a depth function's lock was held."""
+        busy = self._lock.locked() or (
+            self._clock() - self._pressure_stamp >= self._refresh_s
+            and any(map(_held, self._depth_locks)))
+        REGISTRY.incr("nomad.load.commit_flag_reads")
+        if busy:
+            REGISTRY.incr("nomad.load.commit_flag_reads_busy")
+        return self._degraded
 
     # -- the admission gate ------------------------------------------
 

@@ -229,15 +229,15 @@ class Server:
         self.loadctl.register_queue(
             "plan", self.plan_queue.depth,
             self.config.loadctl_plan_soft, self.config.loadctl_plan_hard,
-            commit_path=True)
+            commit_path=True, lock=self.plan_queue._lock)
         self.loadctl.register_queue(
             "broker", self.broker.pending_count,
             self.config.loadctl_broker_soft,
-            self.config.loadctl_broker_hard)
+            self.config.loadctl_broker_hard, lock=self.broker._lock)
         self.loadctl.register_queue(
             "parked", self.store.watches.parked,
             self.config.loadctl_parked_soft,
-            self.config.loadctl_parked_hard)
+            self.config.loadctl_parked_hard, lock=self.store.watches._lock)
         self.store.watches.admission = self.loadctl
 
     # -- lifecycle (leader.go:357 establishLeadership) --

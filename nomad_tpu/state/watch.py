@@ -152,11 +152,14 @@ class WatchTable:
 
     def _on_commit(self, index: int, events: list) -> None:
         """One commit -> one timestamped notification batch. Runs on
-        the store's commit path (under raft, the apply thread): heap
-        pops and Event.set only — never blocks, never re-enters the
-        store."""
+        the store's commit path (under raft, the apply thread): reads
+        the admission controller's brownout flag without its lock
+        (`degraded_now`: no pressure evaluation, so the commit never
+        waits for an admission decision), then heap pops under the
+        table's own lock, which parks and cancels hold for one heap
+        operation, and Event.set. Never re-enters the store."""
         adm = self.admission
-        degraded = adm is not None and adm.degraded()
+        degraded = adm is not None and adm.degraded_now()
         batch: List[_Waiter] = []
         with self._lock:
             heap = self._heap

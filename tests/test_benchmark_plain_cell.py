@@ -79,10 +79,12 @@ def test_the_source_names_the_arm_and_every_cut_has_its_reason():
 def test_every_metric_that_lists_the_cell_has_its_reader_file():
     bench = load_cell(CELL, toy=False)[0]
     names = [m["name"] for m in metrics_of(bench, "per_layer", CELL)]
-    assert len(names) == len(set(names)) == 27
-    # the incremental feed's three and the reconcile's are read here too
+    assert len(names) == len(set(names)) == 28
+    # the incremental feed's three, the reconcile's and the commit
+    # path's read of the brownout flag are read here too
     assert {"feed.lock_wait_ms", "feed.events_per_drain",
-            "feed.us_per_event", "worker.reconcile_ms"} <= set(names)
+            "feed.us_per_event", "worker.reconcile_ms",
+            "load.commit_read_busy_pct"} <= set(names)
     for m in metrics_of(bench, "per_layer", CELL):
         spec = layers.load(m["name"])
         assert (spec["unit"], spec["layer"], spec["moves"]) == (

@@ -5,6 +5,7 @@ quarantine + admission, transport ingress bounds, and the HTTP overload
 surface (413 / 400 / 429 / 504 / degraded-consistency header).
 """
 
+import contextlib
 import http.client
 import json
 import threading
@@ -36,7 +37,9 @@ from nomad_tpu.core.loadctl import (
     remaining,
     tier_for_method,
 )
+from nomad_tpu.core.metrics import REGISTRY
 from nomad_tpu.raft.transport import SocketTransport
+from nomad_tpu.state.store import StateStore
 from nomad_tpu.structs.wire import wire_encode
 
 
@@ -188,6 +191,155 @@ class TestAdmission:
         clk.advance(1.0)
         assert adm.shed_floor() == TIER_NONE
         assert adm.try_admit(TIER_READ) is None
+
+
+# ---------------------------------------------------------------------------
+# the store's commit path reads the brownout flag without the lock
+# ---------------------------------------------------------------------------
+
+
+def _commit(store):
+    """One store commit, as FSM mutations make it."""
+    with store._write_lock:
+        gen, _ = store._begin()
+        store._commit(gen, [])
+
+
+def _park(store, index):
+    """A blocking query parked at `index` on its own thread; returns once
+    the waiter is in the table."""
+    out = {}
+    t = threading.Thread(target=lambda: out.setdefault(
+        "res", store.watches.wait_min_index(index, timeout=10.0)))
+    t.start()
+    deadline = time.monotonic() + 5.0
+    while store.watches.parked() < 1 and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert store.watches.parked() == 1
+    return t, out
+
+
+@contextlib.contextmanager
+def _lock_held(lock):
+    """`lock` held by this thread, as a racing admission decision holds
+    the controller's lock or a subsystem holds its depth counter's."""
+    lock.acquire()
+    try:
+        yield
+    finally:
+        lock.release()
+
+
+def _commit_returns(store):
+    """Commit on another thread and require it to return within a few
+    seconds (it must not wait for what this thread holds)."""
+    committer = threading.Thread(target=_commit, args=(store,), daemon=True)
+    committer.start()
+    committer.join(5.0)
+    assert not committer.is_alive(), "the commit waited for the lock"
+
+
+def _brownout(adm):
+    depth = [0]
+    adm.register_queue("commit_q", lambda: depth[0], soft=10, hard=100,
+                       commit_path=True)
+    return depth
+
+
+class TestCommitPathFlag:
+    @pytest.mark.parametrize("holder", ["controller", "depth_source"])
+    def test_commit_wakes_a_waiter_while_the_admission_lock_is_held(
+            self, holder):
+        clk = FakeClock()
+        store = StateStore()
+        adm = controller(clk)
+        # a depth source whose counter sits under a Condition's RLock,
+        # as the broker's and the plan queue's do
+        cond = threading.Condition()
+
+        def depth():
+            with cond:
+                return 0
+
+        adm.register_queue("q", depth, soft=10, hard=100, lock=cond)
+        store.watches.admission = adm
+        waiter, out = _park(store, 1)
+        clk.advance(0.01)  # the park's evaluation is stale: one is due
+        reads0 = REGISTRY.get("nomad.load.commit_flag_reads")
+        busy0 = REGISTRY.get("nomad.load.commit_flag_reads_busy")
+        with _lock_held(adm._lock if holder == "controller" else cond):
+            _commit_returns(store)
+            # the waiter woke while another thread still holds the lock
+            waiter.join(5.0)
+            assert not waiter.is_alive()
+        idx, wake_ts = out["res"]
+        assert idx == 1 and wake_ts is not None
+        assert REGISTRY.get("nomad.load.commit_flag_reads") >= reads0 + 1
+        assert (REGISTRY.get("nomad.load.commit_flag_reads_busy")
+                >= busy0 + 1)
+
+    def test_a_read_with_every_lock_free_is_not_busy(self):
+        adm = controller()
+        cond = threading.Condition()
+        adm.register_queue("q", lambda: 0, soft=10, hard=100, lock=cond)
+        busy0 = REGISTRY.get("nomad.load.commit_flag_reads_busy")
+        assert adm.degraded_now() is False
+        # a depth source's lock held while the cached pressure is fresh:
+        # degraded() would not have evaluated, so would not have waited
+        adm.degraded()
+        with _lock_held(cond):
+            adm.degraded_now()
+        assert REGISTRY.get("nomad.load.commit_flag_reads_busy") == busy0
+        # the failed probe left the lock as it found it
+        assert cond.acquire(blocking=False)
+        cond.release()
+
+    def test_a_waiter_claimed_in_brownout_is_coalesced_and_woken(self):
+        clk = FakeClock()
+        adm = controller(clk, brownout_after=1.0)
+        depth = _brownout(adm)
+        store = StateStore()
+        store.watches.admission = adm
+        # parked while calm: a brownout sheds new watch parks
+        waiter, out = _park(store, 1)
+        depth[0] = 100
+        clk.advance(0.01)
+        assert not adm.degraded()
+        clk.advance(1.1)
+        assert adm.degraded()
+        coalesced0 = REGISTRY.get("nomad.load.coalesced_wakeups")
+        t0 = time.time()
+        with _lock_held(adm._lock):
+            _commit_returns(store)
+            # the flush needs nothing the lock's holder has either
+            waiter.join(5.0)
+            assert not waiter.is_alive()
+        idx, wake_ts = out["res"]
+        assert idx == 1
+        # held in the coalescing window, then flushed: never lost
+        window = store.watches.COALESCE_WINDOW
+        assert 0.9 * window <= wake_ts - t0 < window + 2.0
+        assert REGISTRY.get("nomad.load.coalesced_wakeups") >= coalesced0 + 1
+        store.watches.teardown()
+
+    def test_the_flag_is_the_one_degraded_returns_and_is_not_advanced(self):
+        clk = FakeClock()
+        adm = controller(clk, brownout_after=1.0, brownout_exit=3.0)
+        depth = _brownout(adm)
+        depth[0] = 100
+        clk.advance(0.01)
+        assert adm.degraded() is adm.degraded_now() is False
+        clk.advance(1.1)
+        # the read alone evaluates nothing: the flag moves only when an
+        # admission, a park or degraded() evaluates pressure
+        assert adm.degraded_now() is False
+        assert adm.degraded() is adm.degraded_now() is True
+        depth[0] = 0
+        clk.advance(0.01)
+        assert adm.degraded() is adm.degraded_now() is True
+        clk.advance(3.1)
+        assert adm.degraded_now() is True
+        assert adm.degraded() is adm.degraded_now() is False
 
 
 # ---------------------------------------------------------------------------
