@@ -85,8 +85,8 @@ FULL = Sizes(nodes=10_240, jobs=500, per_job=4_000, service_jobs=4,
              service_count=256, joint_jobs=8, joint_count=1_000,
              preempt_nodes=1_024, preempt_hi=512, workers=24,
              http_sample=3, rtt_reps=200)
-# same legs, same code paths (every count clears BULK_MIN, HOST_CUTOVER
-# and PREEMPT_DEVICE_MIN), at a size a CPU test can afford
+# same legs, same code paths (every count clears BULK_PLACE_MIN,
+# HOST_CUTOVER and PREEMPT_DEVICE_MIN), at a size a CPU test can afford
 TOY = Sizes(nodes=256, jobs=48, per_job=300, service_jobs=2,
             service_count=32, joint_jobs=8, joint_count=256,
             preempt_nodes=512, preempt_hi=512, workers=8,
@@ -659,10 +659,9 @@ def leg_sweep(run: Run) -> None:
     each once at C2M width from seeded inputs, against plain numpy."""
     import jax
 
-    from nomad_tpu.tensor import incremental, kernels
+    from nomad_tpu.tensor import incremental
     from nomad_tpu.tensor.cluster import _pad_pow2
     from nomad_tpu.tensor.jit_guard import cache_size
-    from nomad_tpu.tensor.placer import TPUPlacer
 
     z, leg = run.sizes, "E sweep"
     t_leg = time.perf_counter()
@@ -676,96 +675,12 @@ def leg_sweep(run: Run) -> None:
     used = np.zeros((n_pad, d), f32)
     used[:n, 0] = (avail[:n, 0] * rng.uniform(0.2, 0.8, n)) // 50 * 50
     used[:n, 1] = (avail[:n, 1] * rng.uniform(0.1, 0.5, n)) // 32 * 32
-    feas = np.zeros(n_pad, bool)
-    feas[:n] = rng.random(n) > 0.1
     ask = np.array([50.0, 32.0, 0.0, 0.0], f32)
-    k = min(z.per_job, 4096)
-    step = TPUPlacer.BULK_STEP
-    n_steps = _pad_pow2(k, floor=step) // step
-    zeros = np.zeros(n_pad, f32)
-
-    def bestfit(avail_rows, used_rows):
-        """funcs.go ScoreFitBinPack on (cpu, mem), written out here so
-        the reference shares no code with the kernels."""
-        with np.errstate(divide="ignore", invalid="ignore"):   # pad rows
-            free = 1.0 - used_rows[..., :2] / avail_rows[..., :2]
-        return np.clip(20.0 - (10.0 ** free).sum(axis=-1), 0.0, 18.0) / 18.0
-
-    def node_score(row_avail, row_used, held):
-        """One placement's normalized score: fit after it lands, plus
-        the job anti-affinity term once the node already holds `held` of
-        this group, averaged over the terms present (rank.go:596,800)."""
-        fit = bestfit(row_avail, row_used + ask)
-        return np.where(held > 0, (fit - (held + 1.0) / k) / 2.0, fit)
-
-    def trajectory_mean(counts):
-        """Mean score over every placement of a per-node count vector,
-        each node filled one placement at a time."""
-        nz = np.nonzero(counts)[0]
-        c = counts[nz]
-        rows = np.repeat(nz, c)
-        held = np.arange(c.sum()) - np.repeat(np.cumsum(c) - c, c)
-        return float(node_score(
-            avail[rows].astype(np.float64),
-            used[rows] + held[:, None] * ask[None, :].astype(np.float64),
-            held).mean())
-
-    # numpy mirror of the count solve: per step, score every node, hand
-    # the step's budget to the best-scoring nodes up to what each still
-    # holds, rescore (kernels._bulk_scan's contract; ties may fall on
-    # other nodes than the device's tie-break picks, scores cannot)
-    a64, u64 = avail.astype(np.float64), used.astype(np.float64)
-    ref, left = np.zeros(n_pad, np.int64), k
-    for _ in range(n_steps):
-        now = u64 + ref[:, None] * ask[None, :]
-        room = np.floor(np.min((a64 - now)[:, :2] / ask[:2], axis=1))
-        room = np.where(feas, room, 0).astype(np.int64)
-        score = np.where(room > 0, node_score(a64, now, ref), -np.inf)
-        order = np.argsort(-score, kind="stable")
-        budget = min(left, step)
-        fill = np.minimum(room[order], budget)
-        take = np.clip(budget - (np.cumsum(fill) - fill), 0, fill)
-        ref[order] += take
-        left -= int(take.sum())
-    assert left == 0, left
-    ref_mean = trajectory_mean(ref)
-
-    def check_counts(name, counts):
-        """A count solve's output: every placement made, none on an
-        infeasible node, no node over capacity, and a trajectory that
-        scores like the mirror's (within the bound
-        tests/test_tensor_placer.py holds the bulk path's score to)."""
-        counts = np.asarray(counts).astype(np.int64)
-        assert counts.sum() == k, (name, int(counts.sum()), k)
-        assert (counts >= 0).all() and not counts[~feas].any(), name
-        after = used + counts[:, None] * ask[None, :]
-        assert (after <= avail).all(), f"{name}: oversubscribed"
-        got = trajectory_mean(counts)
-        assert got >= ref_mean - 5e-3, (name, got, ref_mean)
-        return {"score": round(got, 5), "mirror_score": round(ref_mean, 5)}
 
     def timed(fn):
         t0 = time.perf_counter()
         out = jax.device_get(fn())
         return out, time.perf_counter() - t0
-
-    def bulk_fused():
-        dyn = np.concatenate([used, zeros[:, None], zeros[:, None]], axis=1)
-        args = jax.device_put((avail, feas, zeros, dyn, ask, np.int32(k),
-                               f32(k), np.uint32(run.seed)))
-        return kernels.solve_bulk_fused(*args, batch=step, n_steps=n_steps)
-
-    def bulk_generic():
-        e = np.zeros((0, n_pad))
-        args = jax.device_put((
-            avail.astype(np.float64), used.astype(np.float64),
-            ask.astype(np.float64), feas, np.zeros(n_pad, np.int32),
-            np.zeros(n_pad, np.int32), np.zeros(n_pad), np.zeros(n_pad),
-            e.astype(np.int32), e.astype(bool), np.zeros((0, 1), np.int32),
-            np.zeros((0, 1)), np.zeros(0, bool), np.zeros(0), np.int32(k),
-            float(k), False, False, False,
-            rng.permutation(n_pad).astype(np.int32)))
-        return kernels.solve_bulk(*args, batch=step, n_steps=n_steps)
 
     rows = rng.choice(n, 8, replace=False).astype(np.int32)
     delta = np.tile(ask, (8, 1)) * rng.integers(1, 9, (8, 1)).astype(f32)
@@ -785,9 +700,6 @@ def leg_sweep(run: Run) -> None:
         return {"equal": True}
 
     entries = [
-        ("solve_bulk_fused", kernels.solve_bulk_fused, bulk_fused,
-         check_counts),
-        ("solve_bulk", kernels.solve_bulk, bulk_generic, check_counts),
         ("state_fold", incremental._scatter_fn(donate=False),
          scatter(False), check_scatter),
         ("state_scatter", incremental._scatter_fn(donate=True),
@@ -802,7 +714,7 @@ def leg_sweep(run: Run) -> None:
         out, warm_s = timed(launch)
         swept[name] = {"cold_s": round(cold_s, 3), "warm_s": round(warm_s, 5),
                        **check(name, out)}
-    run.say(leg, n_pad=n_pad, k=k, swept=swept,
+    run.say(leg, n_pad=n_pad, swept=swept,
             skipped_already_launched=[e[0] for e in entries
                                       if e[0] not in swept])
     cold = run.watch.between(cold_mark)

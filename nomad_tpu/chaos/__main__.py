@@ -925,7 +925,8 @@ def solve_smoke(nodes_n: int = 40, jobs_n: int = 4,
                 count: int = 256) -> int:
     """Global-batch solve smoke (scripts/check.sh --solve-smoke): a
     live 3-node cluster with batched workers under "tpu-solve", jobs
-    sized to engage the bulk tier (count >= tensor/placer BULK_MIN).
+    sized to engage the bulk tier (count >= scheduler/reconcile
+    BULK_PLACE_MIN).
     Asserts: every placement lands, at least one whole worker batch
     went through the joint auction launch, the selected assignment's
     packing score is >= the in-launch greedy counterfactual (the

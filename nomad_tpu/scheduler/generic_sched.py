@@ -15,7 +15,7 @@ from ..obs import TRACER
 from ..structs import enums
 from ..structs.alloc import Allocation, RescheduleEvent, RescheduleTracker
 from ..structs.evaluation import Evaluation
-from ..utils import generate_uuid, generate_uuids
+from ..utils import generate_uuid
 from .context import EvalContext
 from .placer import HostPlacer, placer_for_algorithm
 from .reconcile import AllocReconciler, PlacementRequest
@@ -349,52 +349,13 @@ class GenericScheduler:
             self.plan.append_alloc(alloc)
             self.queued_allocs[tg.name] = self.queued_allocs.get(tg.name, 0) + 1
 
-        def commit_many(tg, node, reqs, mean_score):
-            """Bulk fast path: semantically the `commit(req, option)`
-            success arm specialized to fresh placements (no canary, no
-            previous_alloc, no ports/devices/cores — the placer's bulk
-            eligibility), with the per-request constants hoisted out of
-            the loop."""
-            dep_id = (self.deployment.id
-                      if self.deployment is not None
-                      and tg.update is not None else "")
-            vec = ctx.tg_vec(tg)
-            bucket = self.plan.node_allocation.setdefault(node.id, [])
-            tg_name = tg.name
-            node_id, node_name = node.id, node.name
-            metrics = ctx.metrics
-            if metrics is not None:
-                metrics.scores.setdefault("bulk.normalized-score", mean_score)
-            ids = generate_uuids(len(reqs))
-            for req, aid in zip(reqs, ids):
-                bucket.append(Allocation(
-                    id=aid,
-                    eval_id=ev.id,
-                    deployment_id=dep_id,
-                    name=req.name,
-                    namespace=job.namespace,
-                    node_id=node_id,
-                    node_name=node_name,
-                    job_id=job.id,
-                    job=job,
-                    job_version=job.version,
-                    task_group=tg_name,
-                    allocated_vec=vec,
-                    desired_status=enums.ALLOC_DESIRED_RUN,
-                    client_status=enums.ALLOC_CLIENT_PENDING,
-                    metrics=metrics,
-                    allocated_at=now,
-                ))
-            self.queued_allocs[tg_name] = (
-                self.queued_allocs.get(tg_name, 0) + len(reqs))
-
         def commit_block(tg, node_ids, node_names, counts, name_indices,
                          mean_score, scores=None, nodes_evaluated=0,
                          nodes_in_pool=0, ports=None, devices=None):
             """Columnar bulk commit: ONE AllocBlock rides the plan for K
             placements (structs/alloc.py AllocBlock). Only reachable for
-            the fresh-placement shape commit_many covers, so the same
-            constants apply; per-alloc ids/names materialize lazily.
+            fresh placements (no canary, no previous allocation, no core
+            ids); per-alloc ids/names materialize lazily.
             `scores` (per position) and the two node counts are the
             per-placement scan's: what its rows carried in their
             AllocMetric; `ports` (a BlockPorts, a row a position) its
@@ -449,7 +410,6 @@ class GenericScheduler:
                 prev.coalesced_failures += n
             self.queued_allocs.setdefault(tg.name, 0)
 
-        commit.commit_many = commit_many
         commit.commit_block = commit_block
         commit.fail_bulk = fail_bulk
         placer.place(

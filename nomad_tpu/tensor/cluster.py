@@ -134,8 +134,8 @@ class ClusterStatic:
         # affinity vectors, interned attribute rows, zero placement
         # counts), uploaded once per node-set version so a solve ships
         # only what its evaluation fixes (tensor/solver.py
-        # ensure_resident; kernels.solve_bulk_fused,
-        # solve_task_group_fused)
+        # ensure_resident; kernels.solve_task_group_fused and the solver
+        # service's kernels.solve_bulk_multi)
         self.device_arrays: Dict = {}
         # the placement counts of a job with none on these nodes: one
         # read-only instance, whose identity says "resident zeros"

@@ -64,10 +64,6 @@ def _fit_scores_xp(xp, available, used, spread_alg):
     return xp.where(spread_alg, spread, binpack) / BINPACK_MAX_FIT_SCORE
 
 
-def _free_fractions(available: jnp.ndarray, used: jnp.ndarray) -> jnp.ndarray:
-    return _free_fractions_xp(jnp, available, used)
-
-
 def fit_scores(available: jnp.ndarray, used: jnp.ndarray,
                spread_alg: jnp.ndarray) -> jnp.ndarray:
     """Normalized fit score per node in [0, 1].
@@ -605,163 +601,6 @@ def solve_task_group_fused(used, avail, feasible, affinity, placed,
                       founds.astype(scores.dtype), scores])
 
 
-# ---------------------------------------------------------------------------
-# bulk solve: K identical placements as counts, O(K/B) sequential steps
-# ---------------------------------------------------------------------------
-#
-# The C2M engine. A fresh job's task group asks for K identical
-# placements; the per-placement loop costs K sequential steps of some
-# twenty small device ops each (15 us a step at 16,384 nodes on a v5e,
-# PERF.md section 5: their count sets it, not the bytes a step
-# touches). This solver instead
-# assigns a BATCH of B placements per step: score all nodes once
-# (identical math to score_nodes), then give the best-scoring nodes
-# their fill in score order — per-node capacity for binpack (the greedy
-# winner keeps winning until full, so fill-to-capacity IS the greedy
-# trajectory), one per node per step for spread (approximating the
-# round-robin; parity is measured, not assumed). Counts, not choices,
-# come back: one (N,) readback regardless of K. This is the
-# "batched feasibility masking + scoring + assignment" shape BASELINE.md
-# names as the north-star design.
-
-
-def _bulk_scan(
-    available,         # (N, D)
-    used0,             # (N, D)
-    ask,               # (D,)
-    feasible,          # (N,) bool
-    placed_tg0,        # (N,) int32
-    placed_job0,       # (N,) int32
-    affinity_boost,    # (N,)
-    dev_affinity,      # (N,)
-    spread_val_id,     # (S, N) int32
-    spread_val_ok,     # (S, N) bool
-    spread_counts0,    # (S, V) int32
-    spread_desired,    # (S, V)
-    spread_has_targets,  # (S,) bool
-    spread_weight,     # (S,)
-    k_total,           # () int32 placements wanted
-    tg_count,          # ()
-    dh_job,            # () bool
-    dh_tg,             # () bool
-    spread_alg,        # () bool
-    tie_perm,          # (N,) int32
-    *,
-    batch: int,        # placements per step
-    n_steps: int,      # static scan length >= ceil(k_total / batch)
-):
-    """-> (N,) int32 per-node placement counts in canonical order —
-    ONE readback regardless of K. Runs in permuted node space like
-    solve_task_group; counts map back at the end. (The trajectory's
-    mean score is recomputed host-side by _bulk_trajectory_mean — the
-    step-start scores here under-report a fill-to-capacity batch.)"""
-    n = available.shape[0]
-    s = spread_val_id.shape[0]
-    dp_val_id = jnp.zeros((0, n), jnp.int32)
-    dp_val_ok = jnp.zeros((0, n), bool)
-    dp_counts = jnp.zeros((0, 1), jnp.int32)
-    dp_limit = jnp.zeros(0)
-    (available, used0, placed_tg0, placed_job0, feasible,
-     affinity_boost, dev_affinity, spread_val_id, spread_val_ok,
-     dp_val_id, dp_val_ok) = _permute_node_axis(
-        tie_perm, available, used0, placed_tg0, placed_job0, feasible,
-        affinity_boost, dev_affinity, spread_val_id, spread_val_ok,
-        dp_val_id, dp_val_ok)
-
-    # per-node max one placement under distinct_hosts; else fill for
-    # binpack, one-per-step for spread (WorstFit drops a node's score
-    # after each placement, so greedy round-robins)
-    single = dh_job | dh_tg | spread_alg
-
-    ask_pos = ask > 0
-
-    def step(carry, _):
-        used, ptg, pjob, scnt, taken, remaining = carry
-        score, _, _ = score_nodes(
-            available=available, used=used, ask=ask, feasible=feasible,
-            placed_tg=ptg, placed_job=pjob, affinity_boost=affinity_boost,
-            dev_affinity=dev_affinity, penalty_idx=jnp.int32(-1),
-            spread_val_id=spread_val_id, spread_val_ok=spread_val_ok,
-            spread_counts=scnt, spread_desired=spread_desired,
-            spread_has_targets=spread_has_targets, spread_weight=spread_weight,
-            dp_val_id=dp_val_id, dp_val_ok=dp_val_ok, dp_counts=dp_counts,
-            dp_limit=dp_limit,
-            lowest_boost=-1.0, tg_count=tg_count,
-            dh_job=dh_job, dh_tg=dh_tg, spread_alg=spread_alg,
-        )
-        budget = jnp.minimum(remaining, batch)
-        # how many MORE fit on each node; a zero ask in every dimension
-        # means infinite per-node capacity, so clamp to the step budget
-        # BEFORE the int32 cast (inf -> INT32_MAX would overflow the
-        # cumsum below)
-        free = available - used
-        per_dim = jnp.where(ask_pos[None, :], jnp.floor(free / jnp.where(
-            ask_pos, ask, 1.0)[None, :]), jnp.inf)
-        cap = jnp.min(per_dim, axis=1)
-        cap = jnp.clip(cap, 0, None)
-        cap = jnp.where(score > NEG, cap, 0.0)
-        cap = jnp.where(single, jnp.minimum(cap, 1.0), cap)
-        cap = jnp.minimum(cap, budget.astype(cap.dtype)).astype(jnp.int32)
-        order = jnp.argsort(-score)               # stable: ties by index
-        cap_sorted = cap[order]
-        cum = jnp.cumsum(cap_sorted)
-        take_sorted = jnp.clip(budget - (cum - cap_sorted), 0, cap_sorted)
-        take = jnp.zeros(n, jnp.int32).at[order].set(take_sorted)
-
-        used = used + ask[None, :] * take[:, None].astype(used.dtype)
-        ptg = ptg + take
-        pjob = pjob + take
-        if s:
-            scnt = scnt.at[jnp.arange(s)[:, None], spread_val_id].add(
-                jnp.where(spread_val_ok, take[None, :], 0))
-        placed_now = jnp.sum(take).astype(jnp.int32)
-        return (used, ptg, pjob, scnt, taken + take,
-                remaining - placed_now), None
-
-    init = (used0, placed_tg0, placed_job0, spread_counts0,
-            jnp.zeros(n, jnp.int32), jnp.int32(k_total))
-    (used, ptg, pjob, scnt, taken, remaining), _ = jax.lax.scan(
-        init=init, f=step, xs=None, length=n_steps)
-    return jnp.zeros(n, jnp.int32).at[tie_perm].set(taken)
-
-
-solve_bulk = partial(jax.jit, static_argnames=("batch", "n_steps"))(_bulk_scan)
-
-
-@partial(jax.jit, static_argnames=("batch", "n_steps"))
-def solve_bulk_fused(
-    available,   # (N, D) — device-RESIDENT per node-set version
-    feasible,    # (N,) bool — resident per task-group mask signature
-    aff,         # (N,) — resident per affinity signature
-    dyn,         # (N, D+2) float32: used | placed_tg | placed_job (per eval)
-    ask,         # (D,)
-    k_total,     # () int32
-    tg_count,    # () float
-    seed,        # () uint32: tie-break permutation PRNG seed
-    *,
-    batch: int,
-    n_steps: int,
-):
-    """Transfer-minimal bulk solve: the big static arrays live on the
-    device across evals (see the fused-transfer note above); each eval
-    ships one (N, D+2) f32 matrix + a handful of scalars, and the
-    tie-break permutation is generated ON DEVICE from the seed. No spread/dh/dp tables by bulk
-    eligibility (placer._bulk_eligible)."""
-    n, d = available.shape
-    tie_perm = jax.random.permutation(
-        jax.random.PRNGKey(seed), n).astype(jnp.int32)
-    f = available.dtype
-    return _bulk_scan(
-        available, dyn[:, :d].astype(f), ask.astype(f), feasible,
-        dyn[:, d].astype(jnp.int32), dyn[:, d + 1].astype(jnp.int32),
-        aff.astype(f), jnp.zeros(n, f),
-        jnp.zeros((0, n), jnp.int32), jnp.zeros((0, n), bool),
-        jnp.zeros((0, 1), jnp.int32), jnp.zeros((0, 1), f),
-        jnp.zeros(0, bool), jnp.zeros(0, f),
-        k_total, tg_count, False, False, False, tie_perm,
-        batch=batch, n_steps=n_steps)
-
-
 @partial(jax.jit, static_argnames=())
 def score_nodes_once(
     available, used, ask, feasible, placed_tg, placed_job, affinity_boost,
@@ -796,6 +635,21 @@ def score_nodes_once(
     return score
 
 
+# ---------------------------------------------------------------------------
+# count solve: K identical placements as per-node counts, one fill pass
+# ---------------------------------------------------------------------------
+#
+# The C2M engine. A fresh job's BestFit task group asks for K identical
+# placements; the per-placement scan costs K sequential steps of some
+# twenty small device ops each (15 us a step at 16,384 nodes on a v5e,
+# PERF.md section 5: their count sets it, not the bytes a step
+# touches). The count solve scores every node once and fills the best
+# ones to capacity in score order; counts, not choices, come back: one
+# (N,) readback regardless of K. The solver service
+# (tensor/solver.BulkSolverService) is its one caller on the placement
+# path.
+
+
 def _solve_bulk_multi_impl(
     used0,       # (N, D) f32 usage carry — device-RESIDENT, donated back
     available,   # (N, D) f32 resident capacity
@@ -822,20 +676,19 @@ def _solve_bulk_multi_impl(
     the device between launches and that cost amortizes over G evals. Eval i places
     k[i] allocations of ask[i] by BestFit fill-to-capacity against the
     usage state left by eval i-1, with tie-breaks from a per-eval
-    on-device permutation of seeds[i] (same PRNG as solve_bulk_fused).
+    on-device score jitter drawn from seeds[i].
 
     ONE fill pass per eval, not a scan of score-refresh steps: a node's
     BestFit score depends only on its own usage, so filling the best
     node to capacity never re-orders the remaining nodes — the one-pass
-    sorted fill IS the re-scored greedy trajectory (the refresh steps of
-    _bulk_scan only repeat the score + full-sort work, ~12ms of device
-    time per step at 10K nodes). The in-eval anti-affinity term is
-    dropped for the same reason the trajectory tolerates it in
-    _bulk_scan: under fill-to-capacity every chosen node saturates its
-    capacity regardless of score magnitude, so the anti term can only
-    affect reported scores (recomputed host-side), not choices, except
-    through order among non-equal nodes — bounded by the same score
-    parity the bulk path is benched against. No statics besides G, so
+    sorted fill IS the re-scored greedy trajectory (re-scoring after
+    each batch of placements would only repeat the score + full-sort
+    work, ~12ms of device time per step at 10K nodes). The in-eval
+    anti-affinity term is dropped: under fill-to-capacity every chosen
+    node saturates its capacity regardless of score magnitude, so the
+    anti term can only affect reported scores (recomputed host-side),
+    not choices, except through order among non-equal nodes — bounded
+    by the same score parity the bulk path is benched against. No statics besides G, so
     the jit cache holds exactly two graph variants (G=1, G=G_PAD)."""
     n, d = available.shape
     f = available.dtype
@@ -903,69 +756,15 @@ solve_bulk_multi = partial(jax.jit, static_argnames=("g",),
 
 
 @jax.jit
-def preempt_pick(
-    available,   # (N, D) capacity
-    used0,       # (N, D) proposed usage
-    evictable0,  # (N, D) sum of preemptible lower-priority alloc usage
-    ask,         # (D,)
-    feasible,    # (N,) bool constraint/driver mask
-    net_prio,    # (N,) approximate netPriority of the node's preemptible
-                 #      set: max + sum/max (reference rank.go netPriority
-                 #      over the victim set; the per-node aggregate is an
-                 #      upper bound used only to ORDER candidate nodes —
-                 #      the host recomputes the exact score for the
-                 #      chosen node's actual victims)
-    active,      # (K,) bool request slots
-):
-    """Batched preemption node choice for K requests -> (K,) int32 node
-    index per request (-1 = no preemptible node). Mirrors the host
-    fallback's node ordering: fit score after eviction + the logistic
-    preemption penalty (rank.go:894 preemptionScore), averaged like
-    ScoreNormalizationIterator. The scan carries usage and remaining
-    evictable capacity so sibling requests don't pile onto one node;
-    exact victim selection stays host-side per chosen node
-    (scheduler/preemption.py)."""
-    f = available.dtype
-    rate, origin = 0.0048, 2048.0
-    pscore_node = 1.0 / (1.0 + jnp.exp(rate * (net_prio - origin)))
-
-    def step(carry, i):
-        used, evictable = carry
-        new_used = used + ask[None, :]
-        deficit = jnp.maximum(new_used - available, 0.0)
-        can = feasible & jnp.all(deficit <= evictable, axis=1)
-        needs_evict = jnp.any(deficit > 0.0, axis=1)
-        fitness = fit_scores(available, jnp.minimum(new_used, available), False)
-        divisor = 1.0 + needs_evict.astype(f)
-        score = (fitness + jnp.where(needs_evict, pscore_node, 0.0)) / divisor
-        score = jnp.where(can, score, NEG)
-        best = jnp.argmax(score)
-        found = (score[best] > NEG) & active[i]
-
-        def apply(c):
-            used, evictable = c
-            used = used.at[best].set(
-                jnp.minimum(used[best] + ask, available[best]))
-            evictable = evictable.at[best].set(
-                jnp.maximum(evictable[best] - deficit[best], 0.0))
-            return used, evictable
-
-        used, evictable = jax.lax.cond(found, apply, lambda c: c,
-                                       (used, evictable))
-        return (used, evictable), jnp.where(found, best, -1)
-
-    _, picks = jax.lax.scan(step, (used0, evictable0),
-                            jnp.arange(active.shape[0]))
-    return picks.astype(jnp.int32)
-
-
-@jax.jit
 def preempt_solve(
     available,   # (N, D) capacity
     used0,       # (N, D) proposed usage
     ask,         # (D,)
     feasible,    # (N,) bool constraint/driver mask
-    net_prio,    # (N,) approximate netPriority aggregate (see preempt_pick)
+    net_prio,    # (N,) approximate netPriority of the node's preemptible
+                 #      set: max + sum/max (reference rank.go netPriority
+                 #      over the victim set; the per-node aggregate is an
+                 #      upper bound used only to ORDER candidate nodes)
     active,      # (K,) bool request slots
     v_prio,      # (N, V) f32 victim priorities (column order: priority
                  #        asc, alloc id asc — scheduler.preemption.
@@ -977,8 +776,9 @@ def preempt_solve(
                  #        for the exact host scanner
 ):
     """Whole preemption solve for K requests in ONE launch: node choice
-    (same ordering as preempt_pick — fit after eviction + logistic
-    preemption penalty) AND concrete victim selection.
+    (the host fallback's ordering: fit score after eviction + the
+    logistic preemption penalty, rank.go:894 preemptionScore, averaged
+    like ScoreNormalizationIterator) AND concrete victim selection.
 
     Victims are a priority-ascending PREFIX of the chosen node's
     still-unclaimed eligible column, taken until the deficit is covered

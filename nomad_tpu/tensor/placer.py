@@ -6,8 +6,12 @@ Lowering strategy per evaluation:
   1. one ClusterTensors build (nodes + proposed usage),
   2. per task group: host-precompiled feasibility/affinity/spread arrays,
      device/core count columns, and distinct_property cap tables,
-  3. one jitted solve_task_group scan placing all of the group's
-     requests with full cross-placement visibility,
+  3. one solve per task group: the host scorer per placement for a
+     group of at most HOST_CUTOVER; the count solve through the solver
+     service (tensor/solver.py, one AllocBlock) for the BestFit bulk
+     request the reconciler emits (scheduler/reconcile.py
+     BULK_PLACE_MIN); else one jitted solve_task_group scan placing all
+     of the group's requests with full cross-placement visibility,
   4. commits mapped back through the scheduler's commit callback so the
      plan object and ctx.proposed_allocs stay authoritative. Exact port
      numbers, device instance ids, and core ids are assigned host-side
@@ -20,9 +24,10 @@ Lowering strategy per evaluation:
      core ids is committed as one AllocBlock, from the scan as from the
      count solve, its ports and instances in the block's columns.
 
-Preemption stays host-side: when the kernel finds no fit and preemption
-is enabled, the per-request fallback runs the host NodeScorer preemption
-path (reference rank.go:205-587's preemption fallback arm). A request
+Preemption: what the solve left unplaced, with preemption enabled, is
+one kernels.preempt_solve launch (node and victims, each row revalidated
+on the host); flagged rows take the host NodeScorer preemption path
+(reference rank.go:205-587's preemption fallback arm). A request
 whose post-solve id assignment fails (NUMA "require" mispredicted by
 count-fit, overlapping device asks) falls back to the host selector for
 that request alone.
@@ -45,45 +50,12 @@ from .cluster import ClusterTensors, build_task_group_tensors, _pad_pow2
 
 def _binpack_fitness_np(available: np.ndarray, used: np.ndarray) -> np.ndarray:
     """Vectorized BestFit-v3 fit score (reference funcs.go:236
-    ScoreFitBinPack), shared by the preemption pick mirror and the bulk
+    ScoreFitBinPack), shared by the preemption solve mirror and the bulk
     trajectory mean. Thin wrapper over kernels._fit_scores_xp — the one
     formula the device kernels, the batch solver, and this host oracle
     all evaluate (parity pinned by test_batch_solver.py)."""
     from .kernels import fit_scores_np
     return fit_scores_np(available, used, spread_alg=False)
-
-
-def _preempt_pick_host(available, used, evictable, ask, feasible, net_prio,
-                       active) -> np.ndarray:
-    """Numpy mirror of kernels.preempt_pick for small (nodes x requests)
-    shapes — identical node ordering, no device round trip."""
-    pscore = 1.0 / (1.0 + np.exp(0.0048 * (net_prio - 2048.0)))
-    evictable = evictable.copy()
-    picks = np.full(active.shape[0], -1, dtype=np.int32)
-    neg = -1.0e30
-    for i in range(active.shape[0]):
-        if not active[i]:
-            continue
-        new_used = used + ask[None, :]
-        deficit = np.maximum(new_used - available, 0.0)
-        can = feasible & (deficit <= evictable).all(axis=1)
-        if not can.any():
-            continue
-        needs_evict = (deficit > 0.0).any(axis=1)
-        fitness = _binpack_fitness_np(available,
-                                      np.minimum(new_used, available))
-        score = np.where(
-            can,
-            (fitness + np.where(needs_evict, pscore, 0.0))
-            / (1.0 + needs_evict.astype(float)),
-            neg)
-        best = int(np.argmax(score))
-        if score[best] <= neg:
-            continue
-        picks[i] = best
-        used[best] = np.minimum(used[best] + ask, available[best])
-        evictable[best] = np.maximum(evictable[best] - deficit[best], 0.0)
-    return picks
 
 
 def _preempt_solve_host(available, used, ask, feasible, net_prio, active,
@@ -167,11 +139,9 @@ _PREEMPT_STATS_LOCK = __import__("threading").Lock()
 # same shape run under a jit_guard no_retrace window (retrace there is a
 # bug, not a warmup)
 _PREEMPT_WARM: set = set()
-# same discipline for the other placer-driven launch sites: per-eval
-# fused solve, resident bulk solve, generic bulk solve
+# same discipline for the other placer-driven launch sites: the
+# per-placement scan and the system placement
 _FUSED_WARM: set = set()
-_BULK_FUSED_WARM: set = set()
-_BULK_WARM: set = set()
 _SYSTEM_WARM: set = set()
 
 
@@ -308,9 +278,9 @@ class TPUPlacer:
                 tgt = build_task_group_tensors(ctx, job, tg, cluster,
                                                algorithm=self.algorithm)
                 takes_blocks = getattr(commit, "commit_block", None) is not None
-                if takes_blocks and self._bulk_shape_ok(ctx, tg, tgt):
-                    with TRACER.span("worker.solve_bulk", k=bulk.count,
-                                     columnar=True):
+                if takes_blocks and self._bulk_shape_ok(ctx, tg, tgt,
+                                                        bulk.count):
+                    with TRACER.span("worker.solve_bulk", k=bulk.count):
                         self._place_bulk_columnar(
                             ctx, job, tg, bulk, cluster, tgt, commit, seed,
                             sched_batch=batch,
@@ -320,8 +290,9 @@ class TPUPlacer:
                 if (takes_blocks and bulk.count > self.HOST_CUTOVER
                         and not self._wants_ids(ctx, tg)):
                     # spread / distinct_hosts / distinct_property /
-                    # ports / devices rule out the count solve, not the
-                    # block: the scan's K placements are committed as ONE
+                    # ports / devices / a CSI mask / K over the service's
+                    # ceiling rule out the count solve, not the block:
+                    # the scan's K placements are committed as ONE
                     # AllocBlock too, with the ports and instances
                     # chosen under the hold
                     self._place_scan_columnar(
@@ -369,16 +340,6 @@ class TPUPlacer:
             tgt = (prebuilt_tgt if prebuilt_tgt is not None
                    else build_task_group_tensors(ctx, job, tg, cluster,
                                                  algorithm=self.algorithm))
-
-            if self._bulk_eligible(ctx, tg, reqs, tgt):
-                with TRACER.span("worker.solve_bulk", k=len(reqs),
-                                 columnar=False):
-                    self._place_bulk(ctx, job, tg, reqs, cluster, tgt,
-                                     commit, tie_perm, seed,
-                                     sched_batch=batch,
-                                     preemption_enabled=preemption_enabled,
-                                     attempt=attempt)
-                continue
 
             penalty_idx = np.full(_pad_pow2(len(reqs), floor=1), -1,
                                   dtype=np.int32)
@@ -492,39 +453,14 @@ class TPUPlacer:
                 invalidate=lambda nid: self._invalidate_node(
                     cluster, nid, core_used))
 
-    # -- bulk (count-based) solve: the C2M path --
-
     # Path-selection constants. Each was set from a launch cost seen in
     # an earlier environment; value not measured on the current chip
-    # (ROADMAP D3).
-    BULK_MIN = 256     # selects count solve vs per-placement scan
-    BULK_STEP = 256    # placements assigned per scan step
+    # (ROADMAP D3). When a group becomes a count solve is the
+    # reconciler's call (scheduler/reconcile.py BULK_PLACE_MIN).
     HOST_CUTOVER = 16  # selects host oracle (at/below) vs device launch
     # selects preempt_solve on the device (n_pad * k_pad at/above) vs
     # its numpy mirror
     PREEMPT_DEVICE_MIN = 1 << 18
-
-    def _bulk_eligible(self, ctx, tg, reqs, tgt) -> bool:
-        """K large, every request a fresh placement, BestFit binpack with
-        no spread/distinct-hosts semantics (fill-to-capacity is only the
-        exact greedy trajectory for BestFit: the winner keeps winning
-        until full; WorstFit/spread round-robin per placement, which a
-        batched step would mis-place — measured, not guessed), and
-        nothing that needs per-alloc host-side id assignment (exact
-        ports, device instances, cores) or distinct_property tables."""
-        if len(reqs) < self.BULK_MIN:
-            return False
-        if tgt.spread_alg or tgt.dh_job or tgt.dh_tg:
-            return False
-        if tgt.spread_rows or tgt.dp_rows:
-            return False
-        if tgt.extra_ask is not None and len(tgt.extra_ask):
-            return False
-        ask_res = ctx.tg_resources(tg)
-        if ask_res.reserved_port_asks() or ask_res.dynamic_port_count():
-            return False
-        return all(req.previous_alloc is None and not req.ignore_node
-                   and not req.canary for req in reqs)
 
     @staticmethod
     def _wants_ports(ctx, tg) -> bool:
@@ -1034,100 +970,62 @@ class TPUPlacer:
         REGISTRY.incr("nomad.placer.devices_assigned", n_assigned)
         return devices, held
 
-    def _bulk_shape_ok(self, ctx, tg, tgt) -> bool:
-        """Task-group-level bulk eligibility (the per-request conditions
-        of _bulk_eligible hold for a BulkPlacementRequest by
-        construction)."""
+    def _bulk_shape_ok(self, ctx, tg, tgt, k: int) -> bool:
+        """Whether a BulkPlacementRequest of `k` placements of `tg` is a
+        count solve: BestFit binpack with no spread / distinct-hosts /
+        distinct_property semantics (fill-to-capacity is the exact greedy
+        trajectory only for BestFit: the winner keeps winning until
+        full; WorstFit and spread round-robin per placement), no port or
+        device asks (per-placement ids), a feasibility mask the node set
+        caches (none for CSI volumes or reserved ports) and k within the
+        solver service's int16 counts. Every other group takes the
+        per-placement scan. The per-request conditions (fresh, no
+        ignore_node, no canary) hold for a bulk request by construction
+        (scheduler/reconcile.py)."""
+        from .solver import BulkSolverService
+
+        if k > BulkSolverService.MAX_K or tgt.feas_base is None:
+            return False
         if tgt.spread_alg or tgt.dh_job or tgt.dh_tg:
             return False
         if tgt.spread_rows or tgt.dp_rows:
             return False
         if tgt.extra_ask is not None and len(tgt.extra_ask):
             return False
-        ask_res = ctx.tg_resources(tg)
-        if ask_res.reserved_port_asks() or ask_res.dynamic_port_count():
-            return False
-        return True
+        return not self._wants_ports(ctx, tg)
 
-    def _solve_bulk_counts(self, ctx, cluster, tgt, k: int, seed,
-                           tie_perm) -> np.ndarray:
-        """Run the count-based bulk solve through whichever backend fits
-        (solver service with device-resident carry > fused resident
-        arrays > generic kernel) -> (N_pad,) int64 per-node counts."""
-        from .kernels import solve_bulk, solve_bulk_fused
-        from .solver import BulkSolverService
+    def _solve_bulk_counts(self, ctx, cluster, tgt, k: int,
+                           seed) -> np.ndarray:
+        """The count solve, through the solver service (one
+        device-resident usage carry, kernels.solve_bulk_multi) -> (N_pad,)
+        int64 per-node counts."""
+        # The service serializes ALL bulk solves — including
+        # partial-commit retries (placed_tg/placed_job nonzero) — on one
+        # device-resident carry, so racing workers can never
+        # double-book. Retries routed around the service (the pre-r5
+        # gate) solved against store-latest usage and collided with
+        # each other, compounding the rejection cascade at 2M scale.
+        # Cost: the carry solve drops the per-node anti-affinity term
+        # for the retried remainder (a score preference, not a capacity
+        # constraint; fresh solves have placed_* == 0).
+        from .incremental import device_used_fn, free_epoch_fn
+        from .solver import get_service
 
-        k_pad = _pad_pow2(k, floor=self.BULK_STEP)
-        n_steps = k_pad // self.BULK_STEP
         static = cluster.static
-        if (static is not None and tgt.feas_base is not None
-                and k <= BulkSolverService.MAX_K):
-            # The service path serializes ALL bulk solves — including
-            # partial-commit retries (placed_tg/placed_job nonzero) —
-            # on one device-resident carry, so racing workers can never
-            # double-book. Retries routed around the service (the pre-r5
-            # gate) solved against store-latest usage and collided with
-            # each other, compounding the rejection cascade at 2M scale.
-            # Cost: the carry solve drops the per-node anti-affinity
-            # term for the retried remainder (a score preference, not a
-            # capacity constraint; fresh solves have placed_* == 0).
-            from .incremental import device_used_fn, free_epoch_fn
-            from .solver import get_service
-
-            service = get_service()
-            counts, solve_token = service.solve(
-                static=static, feas_base=tgt.feas_base,
-                aff=tgt.affinity_boost, ask=tgt.ask, k=k,
-                tg_count=tgt.tg_count, seed=seed,
-                used_fn=cluster.latest_usage,
-                used_dev_fn=device_used_fn(cluster._store, static),
-                free_epoch_fn=free_epoch_fn(cluster._store),
-                joint=(self.algorithm == enums.SCHED_ALG_TPU_SOLVE))
-            if ctx.plan is not None:
-                ctx.plan.post_apply_hooks.append(
-                    lambda result, _t=solve_token: service.confirm(
-                        _t, getattr(result, "rejected_nodes", None) or ()))
-            return counts
-        if static is not None and tgt.feas_base is not None:
-            from .solver import ensure_resident
-
-            f32 = np.float32
-            avail_dev, feas_dev, aff_dev = ensure_resident(
-                static, tgt.feas_base, tgt.affinity_boost)
-            import jax
-
-            dyn = np.concatenate(
-                [cluster.used, tgt.placed_tg[:, None],
-                 tgt.placed_job[:, None]], axis=1).astype(f32)
-            # avail/feas/aff are already device-resident (device_put of
-            # a committed array is a no-op); ship the per-solve host
-            # args explicitly — scalars included, an implicit scalar
-            # transfer trips the warm window's transfer guard
-            host = jax.device_put((dyn, tgt.ask.astype(f32), np.int32(k),
-                                   f32(tgt.tg_count), np.uint32(seed)))
-            fused_key = (dyn.shape, tgt.ask.shape,
-                         np.shape(avail_dev), n_steps)
-            with _warm_launch(solve_bulk_fused, fused_key,
-                              _BULK_FUSED_WARM):
-                out = jax.device_get(solve_bulk_fused(
-                    avail_dev, feas_dev, aff_dev, *host,
-                    batch=self.BULK_STEP, n_steps=n_steps))
-            return out.astype(np.int64)
-        import jax
-
-        args = (cluster.available, cluster.used, tgt.ask, tgt.feasible,
-                tgt.placed_tg, tgt.placed_job, tgt.affinity_boost,
-                np.zeros(cluster.n_pad), tgt.spread_val_id,
-                tgt.spread_val_ok, tgt.spread_counts, tgt.spread_desired,
-                tgt.spread_has_targets, tgt.spread_weight, np.int32(k),
-                tgt.tg_count, tgt.dh_job, tgt.dh_tg, tgt.spread_alg,
-                tie_perm)
-        dev = jax.device_put(args)
-        bulk_key = tuple(np.shape(a) for a in args) + (n_steps,)
-        with _warm_launch(solve_bulk, bulk_key, _BULK_WARM):
-            out = jax.device_get(solve_bulk(
-                *dev, batch=self.BULK_STEP, n_steps=n_steps))
-        return out.astype(np.int64)
+        service = get_service()
+        counts, solve_token = service.solve(
+            static=static, feas_base=tgt.feas_base,
+            aff=tgt.affinity_boost, ask=tgt.ask, k=k,
+            tg_count=tgt.tg_count, seed=seed,
+            used_fn=cluster.latest_usage,
+            used_dev_fn=device_used_fn(cluster._store, static),
+            free_epoch_fn=free_epoch_fn(cluster._store),
+            joint=(self.algorithm == enums.SCHED_ALG_TPU_SOLVE))
+        if ctx.plan is not None:
+            ctx.plan.post_apply_hooks.append(
+                lambda result, _t=solve_token: service.confirm(
+                    _t, getattr(result, "rejected_nodes", None) or ()))
+        return counts
 
     def _place_bulk_columnar(self, ctx, job, tg, bulk, cluster, tgt,
                              commit, seed, *, sched_batch: bool,
@@ -1136,11 +1034,7 @@ class TPUPlacer:
         is O(touched nodes), not O(K) — per-alloc ids/names materialize
         lazily from the block (structs/alloc.py AllocBlock)."""
         k = bulk.count
-        tie_perm = None  # only the generic kernel consumes it
-        if cluster.static is None or tgt.feas_base is None:
-            tie_perm = np.random.default_rng(seed).permutation(
-                cluster.n_pad).astype(np.int32)
-        counts = self._solve_bulk_counts(ctx, cluster, tgt, k, seed, tie_perm)
+        counts = self._solve_bulk_counts(ctx, cluster, tgt, k, seed)
         # everything below is pure host work on fetched counts — under a
         # pipelined solver this "apply" window runs WHILE the device
         # solves the next batch; the span makes that overlap visible
@@ -1170,61 +1064,6 @@ class TPUPlacer:
                         commit, sched_batch=sched_batch,
                         preemption_enabled=preemption_enabled,
                         attempt=attempt)
-
-    def _place_bulk(self, ctx, job, tg, reqs, cluster, tgt, commit,
-                    tie_perm, seed, *, sched_batch: bool,
-                    preemption_enabled: bool, attempt: int) -> None:
-        """Place K identical requests as per-node COUNTS from one
-        solve_bulk launch (one readback regardless of K), then commit
-        through the scheduler's normal commit callback so plan assembly
-        stays authoritative. With a cached ClusterStatic the fused entry
-        runs against device-resident capacity/mask/affinity arrays and
-        ships only the (N, D+2) dynamic matrix + scalars per eval."""
-        k = len(reqs)
-        counts = self._solve_bulk_counts(ctx, cluster, tgt, k, seed, tie_perm)
-        mean_score = self._bulk_trajectory_mean(counts, cluster, tgt)
-
-        # one shared metrics object for the whole group: per-alloc
-        # AllocMetric at bulk scale is pure overhead (the mean normalized
-        # score is what the benches and the eval summary consume)
-        metrics = ctx.new_metrics()
-        metrics.nodes_in_pool = len(cluster.nodes)
-        metrics.nodes_evaluated = len(cluster.nodes)
-        metrics.scores["bulk.normalized-score"] = mean_score
-
-        commit_many = getattr(commit, "commit_many", None)
-        pos = 0
-        if commit_many is not None:
-            for ni in np.nonzero(counts)[0]:
-                c = int(counts[ni])
-                commit_many(tg, cluster.nodes[ni], reqs[pos:pos + c],
-                            mean_score)
-                pos += c
-        else:
-            for ni in np.nonzero(counts)[0]:
-                node = cluster.nodes[ni]
-                for _ in range(int(counts[ni])):
-                    req = reqs[pos]
-                    pos += 1
-                    option = RankedNode(node=node)
-                    option.final_score = mean_score
-                    commit(req, option)
-        unplaced = reqs[pos:]
-        if not unplaced:
-            return
-        n_feasible = int(tgt.feasible[: len(cluster.nodes)].sum())
-        if preemption_enabled:
-            self._preempt_batch(ctx, job, tg, unplaced, cluster, tgt,
-                                commit, sched_batch=sched_batch,
-                                attempt=attempt, n_feasible=n_feasible)
-            return
-        for req in unplaced:
-            metrics = ctx.new_metrics()
-            metrics.nodes_in_pool = len(cluster.nodes)
-            metrics.nodes_evaluated = len(cluster.nodes)
-            self._attribute_failure(ctx, metrics, len(cluster.nodes),
-                                    n_feasible)
-            commit(req, None)
 
     # -- batched preemption: kernel node choice + host victim selection --
 

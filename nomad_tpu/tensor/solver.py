@@ -204,8 +204,8 @@ def ensure_resident(static, feas_base, aff, mesh=None, uploads=None):
     aff_cache hold the strong refs, so ids can't be recycled). The ONE
     place the cache-key protocol lives (resident_rows and
     resident_zero_counts key the same way); used by the service (single
-    and mesh layouts, distinguished by a cache-key tag), the placer's
-    single-eval fused path and its per-placement scan. A mask that is
+    and mesh layouts, distinguished by a cache-key tag) and the placer's
+    per-placement scan. A mask that is
     the evaluation's own (`feas_base` None) gives None."""
     import jax
 

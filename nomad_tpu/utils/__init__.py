@@ -1,3 +1,3 @@
 """Cross-cutting helpers (reference helper/ — 40 packages; only what we need)."""
 
-from .ids import generate_secret_uuid, generate_uuid, generate_uuids, short_id  # noqa: F401
+from .ids import generate_secret_uuid, generate_uuid, short_id  # noqa: F401

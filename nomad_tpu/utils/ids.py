@@ -34,13 +34,5 @@ def generate_secret_uuid() -> str:
     return _format_uuid(secrets.token_hex(16))
 
 
-def generate_uuids(n: int) -> list:
-    """Batch mint: one urandom syscall + hexlify for n ids (~40% cheaper
-    per id than the PRNG path at bulk-placement scale, and CSPRNG-grade
-    as a bonus)."""
-    h = os.urandom(16 * n).hex()
-    return [_format_uuid(h[32 * i:32 * i + 32]) for i in range(n)]
-
-
 def short_id(full: str) -> str:
     return full.split("-")[0]

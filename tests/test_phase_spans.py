@@ -435,7 +435,7 @@ class TestSolverServiceSpans:
             for _ in range(16):
                 s.register_node(mock.node())
             job = mock.batch_job()
-            job.task_groups[0].count = 256     # BULK_MIN: the count solve
+            job.task_groups[0].count = 256    # BULK_PLACE_MIN: the count solve
             s.register_job(job)
             assert s.wait_for_idle(60.0)
         finally:

@@ -331,17 +331,10 @@ def _run_preempt_scenario(n_nodes=16, hi_count=32):
 
 def test_e2e_mirror_path_no_host_rows():
     """Small shapes route through the numpy mirror; every preempted
-    placement must resolve from the kernel columns (host_preempted == 0
-    — victims hold no ports/devices here), victims are unique, and
-    capacity holds after the wave."""
-    from nomad_tpu.tensor.placer import TPUPlacer
-
-    old = TPUPlacer.BULK_MIN
-    TPUPlacer.BULK_MIN = 16
-    try:
-        delta, hi_placed, evicted = _run_preempt_scenario()
-    finally:
-        TPUPlacer.BULK_MIN = old
+    placement (the scan's unplaced rows) must resolve from the kernel
+    columns (host_preempted == 0 — victims hold no ports/devices here),
+    victims are unique, and capacity holds after the wave."""
+    delta, hi_placed, evicted = _run_preempt_scenario()
     assert len(hi_placed) == 32
     assert delta["kernel_preempted"] >= 1
     assert delta["host_preempted"] == 0
@@ -357,8 +350,7 @@ def test_e2e_device_path_warm_no_retrace():
     from nomad_tpu.tensor.kernels import preempt_solve
     from nomad_tpu.tensor.placer import TPUPlacer
 
-    old_bulk, old_min = TPUPlacer.BULK_MIN, TPUPlacer.PREEMPT_DEVICE_MIN
-    TPUPlacer.BULK_MIN = 16
+    old_min = TPUPlacer.PREEMPT_DEVICE_MIN
     TPUPlacer.PREEMPT_DEVICE_MIN = 0
     try:
         delta, hi_placed, _ = _run_preempt_scenario()
@@ -372,7 +364,6 @@ def test_e2e_device_path_warm_no_retrace():
         assert delta2["host_preempted"] == 0
         assert preempt_solve._cache_size() == warm_size
     finally:
-        TPUPlacer.BULK_MIN = old_bulk
         TPUPlacer.PREEMPT_DEVICE_MIN = old_min
 
 

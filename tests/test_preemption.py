@@ -1,9 +1,7 @@
 """Preemption semantics (reference scheduler/preemption.go):
 priority-delta filter, migrate max_parallel penalty, network (reserved
-port) and device preemption, and the batched node-choice parity between
-the device kernel and its host mirror."""
-
-import numpy as np
+port) and device preemption. The device kernel's parity with its host
+mirror is pinned in test_preempt_solve.py."""
 
 from nomad_tpu import mock
 from nomad_tpu.scheduler.preemption import (
@@ -129,27 +127,3 @@ class TestDevicePreemption:
         ask = [RequestedDevice(name="nvidia/gpu", count=1)]
         # holder is within the priority delta of 75 -> not preemptible
         assert preempt_for_device(node, [a], ask, 75) is None
-
-
-class TestBatchedPickParity:
-    def test_host_mirror_matches_kernel(self):
-        from nomad_tpu.tensor.kernels import preempt_pick
-        from nomad_tpu.tensor.placer import _preempt_pick_host
-
-        rng = np.random.default_rng(5)
-        n, d, k = 32, 4, 16
-        avail = (rng.integers(2, 9, size=(n, d)) * 500).astype(np.float64)
-        used = avail * rng.uniform(0.6, 1.0, size=(n, d))
-        evictable = used * rng.uniform(0.0, 0.9, size=(n, d))
-        ask = np.array([400, 300, 0, 0], dtype=np.float64)
-        feasible = rng.random(n) > 0.2
-        net_prio = rng.uniform(0, 100, size=n)
-        active = np.ones(k, dtype=bool)
-
-        host = _preempt_pick_host(avail, used.copy(), evictable, ask,
-                                  feasible, net_prio, active)
-        f32 = np.float32
-        dev = np.asarray(preempt_pick(
-            avail.astype(f32), used.astype(f32), evictable.astype(f32),
-            ask.astype(f32), feasible, net_prio.astype(f32), active))
-        assert (host == dev).all()

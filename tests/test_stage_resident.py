@@ -161,7 +161,7 @@ def test_a_count_solve_reads_the_scans_cache_entries_as_its_own():
     counts are those of a static the scan never touched."""
     import jax
 
-    from nomad_tpu.tensor.kernels import solve_bulk_fused
+    from nomad_tpu.tensor.kernels import solve_bulk_multi
     from nomad_tpu.tensor.solver import ensure_resident
 
     h = Harness()
@@ -189,14 +189,17 @@ def test_a_count_solve_reads_the_scans_cache_entries_as_its_own():
         assert a.dtype == b.dtype and a.shape == b.shape
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
-    dyn = np.concatenate([cluster.used, tgt.placed_tg[:, None],
-                          tgt.placed_job[:, None]], axis=1).astype(F32)
-    host = jax.device_put((dyn, tgt.ask.astype(F32), np.int32(40),
-                           F32(tgt.tg_count), np.uint32(7)))
+    used = cluster.used.astype(F32)
+    d = used.shape[1]
 
     def counts(resident):
-        return np.asarray(solve_bulk_fused(*resident, *host, batch=256,
-                                           n_steps=1))
+        avail, feas, aff = resident
+        _, out = solve_bulk_multi(
+            jax.device_put(used), avail, feas[None, :], aff[None, :],
+            tgt.ask.astype(F32)[None, :], np.array([40], np.int32),
+            np.array([tgt.tg_count], F32), np.array([7], np.uint32),
+            np.zeros(64, np.int32), np.zeros((64, d), F32), g=1)
+        return np.asarray(out)[0]
 
     got = counts(shared)
     assert got.sum() == 40

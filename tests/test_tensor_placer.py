@@ -19,7 +19,6 @@ from nomad_tpu.structs import Affinity, Constraint, Spread, SpreadTarget, enums
 from nomad_tpu.structs.operator import SchedulerConfiguration
 from nomad_tpu.structs.resources import Resources
 from nomad_tpu.tensor.cluster import ClusterTensors, build_task_group_tensors
-from nomad_tpu.tensor.placer import TPUPlacer
 from nomad_tpu.testing import Harness
 
 
@@ -290,17 +289,23 @@ def test_tpu_beats_or_matches_host_binpack_score():
 
 
 class TestBulkSolve:
-    """The count-based bulk path (tensor/placer.py _place_bulk +
-    kernels.solve_bulk): engaged for large fresh BestFit groups, must
+    """The count solve (tensor/placer.py _place_bulk_columnar through the
+    solver service, kernels.solve_bulk_multi): engaged for the bulk
+    request the reconciler emits for a large fresh BestFit group, must
     place everything the exact per-placement scan would, respect
     capacity, fail the remainder into a blocked eval, and score on par
     with the exact path's trajectory."""
 
-    def _run(self, bulk_min, count=600, n_nodes=64, cpu=100, mem=64):
-        from nomad_tpu.tensor.placer import TPUPlacer
+    def _run(self, exact=False, count=600, n_nodes=64, cpu=100, mem=64,
+             tg_extra=None):
+        """One eval of a fresh batch job. `exact` keeps the reconciler
+        from emitting a bulk request, so its placements arrive one
+        request each and take the per-placement scan."""
+        from nomad_tpu.scheduler import reconcile
 
-        old = TPUPlacer.BULK_MIN
-        TPUPlacer.BULK_MIN = bulk_min
+        old = reconcile.BULK_PLACE_MIN
+        if exact:
+            reconcile.BULK_PLACE_MIN = 1 << 30
         try:
             h = Harness()
             rng = random.Random(7)
@@ -314,6 +319,8 @@ class TestBulkSolve:
             job.task_groups[0].count = count
             job.task_groups[0].tasks[0].resources.cpu = cpu
             job.task_groups[0].tasks[0].resources.memory_mb = mem
+            if tg_extra is not None:
+                tg_extra(h, job.task_groups[0])
             h.store.upsert_job(job)
             h.process(mock.eval_for(job), sched_config=_tpu_config())
             snap = h.store.snapshot()
@@ -321,11 +328,10 @@ class TestBulkSolve:
                       if not a.terminal_status()]
             return h, job, snap, allocs
         finally:
-            TPUPlacer.BULK_MIN = old
+            reconcile.BULK_PLACE_MIN = old
 
-    def test_bulk_places_all_and_respects_capacity(self):
-        h, job, snap, allocs = self._run(bulk_min=256)
-        assert len(allocs) == 600
+    @staticmethod
+    def _assert_fits(snap):
         from nomad_tpu.structs import allocs_fit
 
         for n in snap.nodes():
@@ -333,14 +339,31 @@ class TestBulkSolve:
                     if not a.terminal_status()]
             fit, dim, _ = allocs_fit(n, live)
             assert fit, (n.id, dim)
+
+    def test_bulk_places_all_and_respects_capacity(self):
+        h, job, snap, allocs = self._run()
+        assert len(allocs) == 600
+        self._assert_fits(snap)
         # bulk allocs carry the shared trajectory-mean score
         scored = [a for a in allocs if a.metrics is not None
                   and "bulk.normalized-score" in a.metrics.scores]
         assert scored
 
     def test_bulk_score_parity_with_exact_scan(self):
-        _, _, _, bulk = self._run(bulk_min=256)
-        _, _, _, exact = self._run(bulk_min=1 << 30)
+        from nomad_tpu.core.metrics import REGISTRY
+
+        _, _, _, bulk = self._run()
+        staged = REGISTRY.get("nomad.placer.staged_solves")
+        columnar = REGISTRY.get("nomad.placer.columnar_scan_groups")
+        _, _, _, exact = self._run(exact=True)
+        # the exact side is one per-placement scan, handed over a row
+        # each: each placement's metric carries its own node's score
+        assert REGISTRY.get("nomad.placer.staged_solves") == staged + 1
+        assert REGISTRY.get("nomad.placer.columnar_scan_groups") == columnar
+        assert all(f"{a.node_id}.normalized-score" in a.metrics.scores
+                   for a in exact)
+        assert all("bulk.normalized-score" in a.metrics.scores
+                   for a in bulk)
 
         def mean(allocs):
             out = []
@@ -359,19 +382,65 @@ class TestBulkSolve:
     def test_bulk_overflow_blocks(self):
         """More asks than the cluster fits: bulk places what fits and
         the rest lands in a blocked eval, same as the exact path."""
-        h, job, snap, allocs = self._run(bulk_min=256, count=600,
-                                         n_nodes=4, cpu=500, mem=256)
+        h, job, snap, allocs = self._run(count=600, n_nodes=4, cpu=500,
+                                         mem=256)
         assert 0 < len(allocs) < 600
         ev = h.assert_eval_status(enums.EVAL_STATUS_COMPLETE)
         assert ev.failed_tg_allocs
         assert ev.blocked_eval
 
+    def test_a_group_over_the_services_ceiling_takes_the_scan(self):
+        """A bulk request of more placements than the service's int16
+        counts hold is placed whole by the per-placement scan, as one
+        block, with no node over capacity."""
+        from nomad_tpu.core.metrics import REGISTRY
+        from nomad_tpu.tensor.solver import BulkSolverService
+
+        columnar = REGISTRY.get("nomad.placer.columnar_scan_groups")
+        old = BulkSolverService.MAX_K
+        BulkSolverService.MAX_K = 64
+        try:
+            _, _, snap, allocs = self._run(count=300)
+        finally:
+            BulkSolverService.MAX_K = old
+        assert len(allocs) == 300
+        assert REGISTRY.get("nomad.placer.columnar_scan_groups") == (
+            columnar + 1)
+        self._assert_fits(snap)
+
+    def test_a_group_that_claims_a_host_volume_takes_the_scan(self):
+        """A group that claims a host volume is no bulk request (claims
+        are recorded per allocation): its placements take the
+        per-placement scan's rows, all of them, with no node over
+        capacity."""
+        from nomad_tpu.core.metrics import REGISTRY
+        from nomad_tpu.structs.volumes import (ClientHostVolumeConfig,
+                                               VolumeRequest)
+
+        def claim(h, tg):
+            for n in h.store.snapshot().nodes():
+                n = copy.copy(n)
+                n.host_volumes = {"data": ClientHostVolumeConfig(
+                    name="data", path="/srv/data")}
+                n.compute_class()
+                h.store.upsert_node(n)
+            tg.volumes = {"data": VolumeRequest(
+                name="data", type="host", source="data")}
+
+        staged = REGISTRY.get("nomad.placer.staged_solves")
+        _, _, snap, allocs = self._run(count=300, tg_extra=claim)
+        assert len(allocs) == 300
+        assert REGISTRY.get("nomad.placer.staged_solves") == staged + 1
+        assert all(f"{a.node_id}.normalized-score" in a.metrics.scores
+                   for a in allocs)
+        self._assert_fits(snap)
+
 
 class TestBulkSolverService:
     """The batched solver service (tensor/solver.py): the multi-eval
     kernel chained on a device-resident usage carry must produce the
-    same fill-to-capacity trajectories as per-eval solve_bulk_fused
-    launches with host-carried usage."""
+    same fill-to-capacity trajectories as one-eval launches with
+    host-carried usage."""
 
     def _cluster(self, n_nodes=48, seed=3):
         h = Harness()
@@ -447,11 +516,12 @@ class TestBulkSolverService:
     def test_service_end_to_end_capacity(self):
         """Concurrent fresh bulk jobs through the real service: every
         alloc placed, no node oversubscribed."""
+        from nomad_tpu.scheduler import reconcile
         from nomad_tpu.structs import allocs_fit
-        from nomad_tpu.tensor.placer import TPUPlacer
 
-        old = TPUPlacer.BULK_MIN
-        TPUPlacer.BULK_MIN = 64
+        # jobs of 150 become bulk requests, so count solves
+        old = reconcile.BULK_PLACE_MIN
+        reconcile.BULK_PLACE_MIN = 64
         try:
             h = self._cluster()
             jobs = []
@@ -474,7 +544,7 @@ class TestBulkSolverService:
                 fit, dim, _ = allocs_fit(node, live)
                 assert fit, (node.id, dim)
         finally:
-            TPUPlacer.BULK_MIN = old
+            reconcile.BULK_PLACE_MIN = old
 
 
 def test_bulk_solve_after_outside_placements_does_not_block():
